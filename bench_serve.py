@@ -13,6 +13,12 @@ arrivals ~ Poisson(rate), prompt/output lengths drawn from configurable
 mixes.  Offered loads are fractions of the measured continuous-mode
 saturation capacity, so rows are comparable across boxes.
 
+A host-only run: a d=384 float32 model on the CPU backend, to compare
+batching POLICIES.  It builds engines in this process and later starts a
+cluster whose replica is another process, so neither may need a chip: JAX
+is held to the CPU here and in every child, and the report says so.  None
+of its numbers is a device number.
+
 Usage:
     python bench_serve.py            # full sweep -> BENCH_SERVE.json
     python bench_serve.py --smoke    # small counts, no artifact rewrite
@@ -29,6 +35,8 @@ import time
 from typing import Dict, List, Optional
 
 import numpy as np
+
+os.environ["JAX_PLATFORMS"] = "cpu"  # host-only run, see the docstring
 
 # Length mixes (tokens).  Outputs are deliberately long-tailed: the gap
 # between continuous and whole-request batching IS the tail (a gang drains
@@ -569,7 +577,14 @@ def main(argv=None) -> Dict:
     n_row = 16 if args.smoke else 64
     levels = (1.0, 2.0) if args.smoke else (0.5, 1.0, 2.0)
 
+    import jax
+
+    dev = jax.devices()[0]
     report: Dict = {"metric": "serve_engine_bench",
+                    "host_only": "CPU backend; no accelerator was used",
+                    "device": {"platform": dev.platform,
+                               "kind": dev.device_kind,
+                               "count": len(jax.devices())},
                     "engine": ENGINE_KW,
                     "prompt_mix": list(PROMPT_MIX),
                     "output_mix": list(OUTPUT_MIX),

@@ -19,8 +19,15 @@ tpu.py:71 TPUAcceleratorManager) — re-designed for this framework:
   ``{"TPU": n}`` with n < host chips gets ``TPU_VISIBLE_CHIPS`` plus the
   chip/host-bounds variables that make libtpu carve out a sub-host topology
   (reference: tpu.py:155-196; the 1-chip and 2-chip bounds come from the
-  jax#14977 recipe).  n == all chips clears the bounds so JAX uses the
-  host defaults.
+  jax#14977 recipe).  n == all chips keeps the host's own bounds.
+- **Worker environment** (`worker_env`): what every spawner hands a worker
+  process — pinned to the CPU backend until a chip grant, and carrying the
+  host's libtpu configuration so that a granted worker starts without a
+  metadata server.
+- **Peaks** (`peak_flops`): the one table of per-chip peak FLOP/s, keyed by
+  ``device_kind``; an unknown device is an error.
+- **Compile cache** (`enable_compile_cache`): where a process that compiles
+  for the chip keeps JAX's persistent compilation cache.
 
 The head's scheduler owns the per-node chip-ID pool (scheduler.py
 ``allocate_tpu_chips``); the worker applies the env right before running the
@@ -32,7 +39,8 @@ from __future__ import annotations
 import glob
 import os
 import re
-from typing import Dict, List, Optional
+import sys
+from typing import Dict, List, Mapping, Optional
 
 TPU_VALID_CHIP_OPTIONS = (1, 2, 4, 8)
 
@@ -168,17 +176,17 @@ def visibility_env(chip_ids: List[int], host_chips: Optional[int] = None) -> Dic
 
     Empty-string values mean "unset this variable" (the worker applies them
     with ``os.environ.pop``).  Granting every chip on the host clears the
-    sub-host bounds so libtpu uses its defaults.
+    per-process view and leaves the host's bounds as they are.
     """
     if host_chips is None:
         host_chips = num_chips()
     n = len(chip_ids)
     if n == 0 or n == host_chips:
-        return {
-            "TPU_VISIBLE_CHIPS": "",
-            "TPU_CHIPS_PER_HOST_BOUNDS": "",
-            "TPU_HOST_BOUNDS": "",
-        }
+        # The whole host: its own bounds (inherited through `worker_env`, or
+        # libtpu's defaults) already describe it.  Unsetting them makes
+        # libtpu ask the metadata server instead, which a host without a
+        # network cannot answer.
+        return {"TPU_VISIBLE_CHIPS": ""}
     env = {"TPU_VISIBLE_CHIPS": ",".join(str(c) for c in sorted(chip_ids))}
     if n == 1:
         env["TPU_CHIPS_PER_HOST_BOUNDS"] = "1,1,1"
@@ -195,13 +203,121 @@ def visibility_env(chip_ids: List[int], host_chips: Optional[int] = None) -> Dic
 def apply_visibility(chip_ids: List[int], host_chips: Optional[int] = None) -> None:
     """Apply `visibility_env` to this process.  Must run before the first
     ``import jax`` to take effect (reference applies the same env dance at
-    task start: tpu.py:155 set_current_process_visible_accelerator_ids)."""
+    task start: tpu.py:155 set_current_process_visible_accelerator_ids).
+
+    A grant pins JAX to the TPU and to nothing after it: if libtpu cannot
+    start, JAX raises instead of computing on the CPU.  A process whose JAX
+    already came up on another platform cannot be re-pointed, so it raises
+    here, before user code runs."""
     for k, v in visibility_env(chip_ids, host_chips).items():
         if v == "":
             os.environ.pop(k, None)
         else:
             os.environ[k] = v
-    if chip_ids:
-        # The worker was spawned with JAX_PLATFORMS=cpu so it could not steal
-        # the host's chips; a task granted chips flips back to TPU.
-        os.environ["JAX_PLATFORMS"] = "tpu,cpu"
+    if not chip_ids:
+        return
+    # The worker was spawned with JAX_PLATFORMS=cpu so it could not steal
+    # the host's chips; a task granted chips flips to the TPU.
+    os.environ["JAX_PLATFORMS"] = "tpu"
+    jax = sys.modules.get("jax")
+    backend = jax.default_backend() if jax is not None else "tpu"
+    if backend != "tpu":
+        raise RuntimeError(
+            f"granted TPU chips {sorted(chip_ids)}, but JAX was imported in "
+            f"this process before the grant and runs on {backend!r}; chip "
+            f"grants need a fresh worker")
+
+
+#: Variables that carve chips out for ONE process.  A worker never inherits
+#: the spawner's; a chip grant sets its own (`visibility_env`).  Every other
+#: ``TPU_*`` variable describes the HOST to libtpu (accelerator type,
+#: topology, worker id and hostnames, ``TPU_SKIP_MDS_QUERY``) and must reach
+#: the worker: without them libtpu queries the metadata server, and on a
+#: host with no network that blocked a worker for over 200 s and gave it no
+#: device (measured on the one-chip v5e machine).
+_PER_PROCESS_TPU_ENV = (
+    "TPU_VISIBLE_CHIPS", "TPU_VISIBLE_DEVICES", "TPU_PROCESS_BOUNDS",
+    "TPU_PROCESS_ADDRESSES", "TPU_PROCESS_PORT", "CLOUD_TPU_TASK_ID",
+)
+
+
+def worker_env(base: Optional[Mapping[str, str]] = None) -> Dict[str, str]:
+    """Environment for a process this runtime spawns (worker, zygote, node
+    daemon, external head), from ``base`` (default: this process's own).
+
+    - ``JAX_PLATFORMS=cpu`` whatever the spawner's own value: a worker only
+      reaches a chip through a grant (`apply_visibility`), never because
+      the driver's environment says ``tpu``.
+    - per-process chip isolation is dropped, host-level libtpu
+      configuration is kept (see ``_PER_PROCESS_TPU_ENV``);
+    - ``JAX_COMPILATION_CACHE_DIR`` and everything else pass through;
+    - the package's parent directory leads ``PYTHONPATH`` so the worker can
+      import ray_tpu regardless of the spawner's cwd."""
+    env = dict(os.environ if base is None else base)
+    for k in _PER_PROCESS_TPU_ENV:
+        env.pop(k, None)
+    pkg_parent = _checkout_dir()
+    env["PYTHONPATH"] = (
+        pkg_parent + os.pathsep + env["PYTHONPATH"]
+        if env.get("PYTHONPATH") else pkg_parent
+    )
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
+def _checkout_dir() -> str:
+    """The directory that holds the ``ray_tpu`` package."""
+    return os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def compile_cache_dir() -> str:
+    """Where the processes of this checkout keep JAX's persistent compile
+    cache: ``JAX_COMPILATION_CACHE_DIR`` where it is set, otherwise
+    ``<checkout>/.jax_cache``.  The path is part of the cache key, so it is
+    never a temporary, per-pid or timed name."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        _checkout_dir(), ".jax_cache")
+
+
+def enable_compile_cache() -> None:
+    """Turn on JAX's persistent compilation cache at `compile_cache_dir`;
+    call in every process that compiles for the chip, before it compiles.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself, and no
+    directory is set in code."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    jax = sys.modules.get("jax")
+    if jax is None:
+        # Not imported yet: JAX reads the variable at import (and this
+        # process's children inherit it).
+        os.environ["JAX_COMPILATION_CACHE_DIR"] = compile_cache_dir()
+    else:
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+
+
+#: Peak dense bf16 FLOP/s of ONE chip, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud TPU documentation, system architecture pages
+#: ("TPU v5e": 197 TFLOP/s; "TPU v5p": 459; "TPU v4": 275; "TPU v3": 123;
+#: "TPU v2": 45; "TPU v6e": 918).  Only "TPU v5 lite" has been seen on a
+#: machine (one-chip v5e); the other kinds are JAX's names for those chips.
+PEAK_BF16_FLOPS = {
+    "TPU v2": 45e12,
+    "TPU v3": 123e12,
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5": 459e12,       # v5p
+    "TPU v6 lite": 918e12,  # v6e
+}
+
+
+def peak_flops(device_kind: str) -> float:
+    """Peak bf16 FLOP/s of one chip of ``device_kind``.  A device that is
+    not in the table is an error, not a default: a utilization against an
+    invented peak is worse than none."""
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s on record for device_kind {device_kind!r}; add "
+            f"it to ray_tpu.accelerators.PEAK_BF16_FLOPS with its source "
+            f"(known: {sorted(PEAK_BF16_FLOPS)})") from None

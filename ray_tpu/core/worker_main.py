@@ -909,19 +909,22 @@ class Worker:
                 os.environ[k] = v
             if spec.get("tpu_chips") is not None:
                 # Chip grant from the scheduler: narrow this process's TPU
-                # view before user code first imports jax (reference:
-                # tpu.py:155 set_current_process_visible_accelerator_ids runs
-                # in the worker at task start).  Takes effect only when jax
-                # has not initialized its backend in this process yet — chip
-                # tasks should land on fresh workers (dedicated actor
-                # processes do by construction).
+                # view and pin JAX to the TPU before user code first
+                # imports jax (reference: tpu.py:155
+                # set_current_process_visible_accelerator_ids runs in the
+                # worker at task start).  The head sends grants to fresh
+                # workers only; a process whose jax already runs on another
+                # platform raises here.
                 from ray_tpu import accelerators
 
                 tpu_keys = ("TPU_VISIBLE_CHIPS", "TPU_CHIPS_PER_HOST_BOUNDS",
-                            "TPU_HOST_BOUNDS", "JAX_PLATFORMS")
+                            "TPU_HOST_BOUNDS", "JAX_PLATFORMS",
+                            "JAX_COMPILATION_CACHE_DIR")
                 for k in tpu_keys:
                     saved_env.setdefault(k, os.environ.get(k))
                 accelerators.apply_visibility(spec["tpu_chips"])
+                # This process compiles for the chip from here on.
+                accelerators.enable_compile_cache()
             if renv.get("working_dir_key"):
                 saved_cwd = os.getcwd()
                 saved_wd_path = self._setup_working_dir(

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
@@ -165,6 +166,31 @@ def llama_sharding_rules() -> ShardingRules:
     ])
 
 
+def _flash_per_shard(config: LlamaConfig, q, k, v):
+    """Flash attention under pjit.  XLA cannot partition a Pallas kernel
+    ("Mosaic kernels cannot be automatically partitioned"), so under an
+    ambient mesh (``jax.set_mesh``) the kernel runs per shard: batch over
+    dp/fsdp and heads over tp, both independent in attention.  A dim its
+    axes do not divide stays replicated."""
+    flash = functools.partial(
+        flash_attention, causal=True, block_q=config.flash_block_q,
+        block_k=config.flash_block_k)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.manual_axes:  # no mesh / already per-shard
+        return flash(q, k, v)
+    sizes = mesh.shape
+
+    def dividing(dim: int, *axes: str):
+        axes = tuple(a for a in axes if a in sizes)
+        fits = dim % math.prod(sizes[a] for a in axes) == 0
+        return axes if axes and fits else None
+
+    spec = P(dividing(q.shape[0], AXIS_DP, AXIS_FSDP),
+             dividing(k.shape[1], AXIS_TP), None, None)
+    return jax.shard_map(flash, in_specs=(spec,) * 3, out_specs=spec,
+                         check_vma=False)(q, k, v)
+
+
 def _attention(config: LlamaConfig, x, layer, cos, sin, lora_layer=None):
     B, S, d = x.shape
     hd = config.head_dim
@@ -202,9 +228,7 @@ def _attention(config: LlamaConfig, x, layer, cos, sin, lora_layer=None):
     else:
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
-        out = flash_attention(q, k, v, causal=True,
-                              block_q=config.flash_block_q,
-                              block_k=config.flash_block_k)
+        out = _flash_per_shard(config, q, k, v)
     out = out.transpose(0, 2, 1, 3).reshape(B, S, d)
     return out @ a["wo"]
 

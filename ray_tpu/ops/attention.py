@@ -1,13 +1,17 @@
-"""Flash attention: Pallas TPU kernels (forward + backward) with a jnp
-reference fallback for CPU tests.
+"""Flash attention: Pallas TPU kernels (forward + backward); off the TPU the
+jnp reference, chosen in one place (`flash_attention`).
 
 Design notes (TPU-first):
 - Online-softmax forward keeps the S matrix out of HBM entirely; K/V for one
-  (batch, head) live in VMEM (fine up to ~8k tokens at head_dim 128 bf16 —
-  longer sequences shard over the `sp` mesh axis via ring_attention).
+  (batch, head) live in VMEM (up to 12k tokens at head_dim 128 bf16, see
+  ``KV_RESIDENT_BYTES`` — longer sequences shard over the `sp` mesh axis via
+  ring_attention).
 - Backward is the standard two-kernel split (dq; dk+dv) driven by the saved
   logsumexp and delta = rowsum(dO * O), so nothing quadratic is
-  rematerialized in HBM.
+  rematerialized in HBM.  dK/dV walk Q in chunks, so their VMEM use does
+  not grow with the sequence or the GQA group.
+- On a TPU nothing here picks interpret mode or the reference by itself: a
+  shape the kernels cannot take raises before anything is traced.
 - GQA is handled in the BlockSpec index maps (kv head = q head // group), no
   KV broadcast copies.
 - `q_offset` supports sequence-parallel callers whose Q block sits at a
@@ -30,11 +34,41 @@ DEFAULT_BLOCK_K = 512
 NEG_INF = -1e30
 
 
+#: Most bytes of K (and of V) one head may hold for the forward and dq
+#: kernels, which keep both whole in VMEM.  Measured by compiling for a
+#: described v5e (tests/test_chip_compile.py): 3 MiB per array (12288 x 128
+#: bf16, 6144 x 128 f32) compiles forward and backward, 4 MiB is refused
+#: with "Ran out of memory in memory space vmem".
+KV_RESIDENT_BYTES = 3 << 20
+
+
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def kernel_blocks(q, k, block_q: int, block_k: int) -> Tuple[int, int]:
+    """(bq, bk) the kernels run ``q``/``k`` ([B, H, S, D]) with, or a
+    ValueError naming what they cannot take.  The blocks are caps: they
+    shrink by powers of two until they divide the sequence (768 -> 256)."""
+    Sq, Sk, D = q.shape[2], k.shape[2], k.shape[3]
+    bq, bk = min(block_q, Sq), min(block_k, Sk)
+    while bq > 16 and Sq % bq:
+        bq //= 2
+    while bk > 16 and Sk % bk:
+        bk //= 2
+    if Sq % bq or Sk % bk:
+        raise ValueError(
+            f"flash attention needs sequence lengths its blocks divide: "
+            f"Sq={Sq}, Sk={Sk} against blocks of {bq} and {bk}; pad the "
+            f"sequence to a multiple of 16")
+    kv_bytes = Sk * D * k.dtype.itemsize
+    if kv_bytes > KV_RESIDENT_BYTES:
+        raise ValueError(
+            f"flash attention keeps K and V of one head in VMEM: {Sk} x {D} "
+            f"{k.dtype} is {kv_bytes / 2**20:.1f} MiB each, over the "
+            f"{KV_RESIDENT_BYTES >> 20} MiB that compiles; shard the "
+            f"sequence over the sp axis (ring_attention) or shorten it")
+    return bq, bk
 
 
 # --------------------------------------------------------------- reference
@@ -212,54 +246,79 @@ def _bwd_dq_kernel(q_off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _bwd_dkv_kernel(q_off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, sm_scale, causal, block_q, group):
+                    dk_ref, dv_ref, dk_acc, dv_acc,
+                    *, sm_scale, causal, block_q):
+    """One (k block, q head of the GQA group, q chunk) grid step: the q
+    chunk's blocks are looped here and dK/dV accumulate in f32 VMEM scratch
+    across the two innermost (sequential) grid dims, so VMEM holds one q
+    chunk at a time — independent of the sequence length and group size."""
     kb_mat = k_ref[0, 0].astype(jnp.float32)                # [bk, D]
     vb_mat = v_ref[0, 0].astype(jnp.float32)
-    bk, D = kb_mat.shape
-    Sq = q_ref.shape[2]
+    bk = kb_mat.shape[0]
+    chunk = q_ref.shape[2]
     k_idx = pl.program_id(2)
-    q_off = q_off_ref[0]
+    g, c = pl.program_id(3), pl.program_id(4)
+    # Global row of this chunk's first q row, relative to the K columns.
+    row0 = c * chunk + q_off_ref[0]
 
-    def qhead(g, carry):
-        """Accumulate over the `group` q-heads mapping to this kv head."""
+    @pl.when((g == 0) & (c == 0))
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def body(qb_i, carry):
         dk, dv = carry
+        qb = q_ref[0, 0, pl.ds(qb_i * block_q, block_q), :].astype(jnp.float32)
+        dob = do_ref[0, 0, pl.ds(qb_i * block_q, block_q), :].astype(jnp.float32)
+        lse = lse_ref[0, 0, pl.ds(qb_i * block_q, block_q), 0]
+        delta = delta_ref[0, 0, pl.ds(qb_i * block_q, block_q), 0]
+        s = jnp.dot(qb * sm_scale, kb_mat.T,
+                    preferred_element_type=jnp.float32)      # [bq, bk]
+        if causal:
+            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
+            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
+            mask = (rows + qb_i * block_q + row0) >= (cols + k_idx * bk)
+            s = jnp.where(mask, s, NEG_INF)
+        p = jnp.exp(s - lse[:, None])
+        dv = dv + jnp.dot(p.T, dob, preferred_element_type=jnp.float32)
+        dp = jnp.dot(dob, vb_mat.T, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta[:, None]) * sm_scale
+        dk = dk + jnp.dot(ds.T, qb, preferred_element_type=jnp.float32)
+        return dk, dv
 
-        def body(qb_i, c):
-            dk, dv = c
-            qb = q_ref[0, g, pl.ds(qb_i * block_q, block_q), :].astype(jnp.float32)
-            dob = do_ref[0, g, pl.ds(qb_i * block_q, block_q), :].astype(jnp.float32)
-            lse = lse_ref[0, g, pl.ds(qb_i * block_q, block_q), 0]
-            delta = delta_ref[0, g, pl.ds(qb_i * block_q, block_q), 0]
-            s = jnp.dot(qb * sm_scale, kb_mat.T,
-                        preferred_element_type=jnp.float32)  # [bqq, bk]
-            if causal:
-                rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 0)
-                cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, bk), 1)
-                mask = (rows + qb_i * block_q + q_off) >= (cols + k_idx * bk)
-                s = jnp.where(mask, s, NEG_INF)
-            p = jnp.exp(s - lse[:, None])
-            dv = dv + jnp.dot(p.T, dob, preferred_element_type=jnp.float32)
-            dp = jnp.dot(dob, vb_mat.T, preferred_element_type=jnp.float32)
-            ds = p * (dp - delta[:, None]) * sm_scale
-            dk = dk + jnp.dot(ds.T, qb, preferred_element_type=jnp.float32)
-            return dk, dv
+    n_qb = chunk // block_q
+    if causal:
+        # dK/dV for this k block only sees q blocks whose last row reaches
+        # the block's first column: start at the diagonal (a chunk wholly
+        # above it runs zero iterations).
+        lo = jnp.clip(jax.lax.div(k_idx * bk - row0, block_q), 0, n_qb)
+    else:
+        lo = 0
+    dk, dv = jax.lax.fori_loop(lo, n_qb, body, (dk_acc[...], dv_acc[...]))
+    dk_acc[...] = dk
+    dv_acc[...] = dv
 
-        n_qb = Sq // block_q
-        if causal and n_qb >= 2:
-            # dK/dV for this k block only sees q blocks whose last row
-            # reaches the block's first column: start at the diagonal.
-            lo = jnp.clip(
-                jax.lax.div(k_idx * bk - q_off, block_q), 0, n_qb
-            )
-        else:
-            lo = 0
-        return jax.lax.fori_loop(lo, n_qb, body, (dk, dv))
+    @pl.when((g == pl.num_programs(3) - 1) & (c == pl.num_programs(4) - 1))
+    def _():
+        dk_ref[0, 0] = dk.astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv.astype(dv_ref.dtype)
 
-    dk0 = jnp.zeros((bk, D), jnp.float32)
-    dv0 = jnp.zeros((bk, D), jnp.float32)
-    dk, dv = jax.lax.fori_loop(0, group, qhead, (dk0, dv0))
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+
+#: Rows of Q/dO/lse/delta one dK/dV grid step keeps in VMEM.  2048 rows at
+#: head_dim 128 are ~6 MB double-buffered (the lse/delta lane pad is half of
+#: it) — inside the 16 MB scoped default of every TPU generation, and the
+#: whole sequence for b1-shaped training (S=2048), where the q blocks are
+#: then fetched once per head, not once per k block.
+DKV_CHUNK_ROWS = 2048
+
+
+def _dkv_chunk(Sq: int, bq: int) -> int:
+    """Largest multiple of ``bq`` dividing ``Sq`` within DKV_CHUNK_ROWS."""
+    n = Sq // bq
+    per = max(1, min(n, DKV_CHUNK_ROWS // bq))
+    while n % per:
+        per -= 1
+    return per * bq
 
 
 def _flash_bwd(res, g, *, sm_scale, causal, q_offset, block_q, block_k,
@@ -298,30 +357,37 @@ def _flash_bwd(res, g, *, sm_scale, causal, q_offset, block_q, block_k,
         interpret=interpret,
     )(q_off, q, k, v, do, lse, delta)
 
-    # dk/dv: grid over kv heads; each kernel instance loops the q-heads in its
-    # GQA group and all q blocks.
+    # dk/dv: grid over (kv head, k block) with the GQA group's q heads and
+    # the q chunks as the two innermost, accumulating dims.
+    cq = _dkv_chunk(Sq, bq)
+
+    def q_map(b, h, i, g, c, *_):
+        return (b, h * group + g, c, 0)
+
+    def kv_map(b, h, i, g, c, *_):
+        return (b, h, i, 0)
+
     dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=bq, group=group,
+            _bwd_dkv_kernel, sm_scale=sm_scale, causal=causal, block_q=bq,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, Hkv, Sk // bk),
+            grid=(B, Hkv, Sk // bk, group, Sq // cq),
             in_specs=[
-                pl.BlockSpec((1, group, Sq, D), lambda b, h, i, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, bk, D), lambda b, h, i, *_: (b, h, i, 0)),
-                pl.BlockSpec((1, 1, bk, D), lambda b, h, i, *_: (b, h, i, 0)),
-                pl.BlockSpec((1, group, Sq, D), lambda b, h, i, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, group, Sq, LSE_LANES),
-                             lambda b, h, i, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, group, Sq, LSE_LANES),
-                             lambda b, h, i, *_: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, cq, D), q_map),
+                pl.BlockSpec((1, 1, bk, D), kv_map),
+                pl.BlockSpec((1, 1, bk, D), kv_map),
+                pl.BlockSpec((1, 1, cq, D), q_map),
+                pl.BlockSpec((1, 1, cq, LSE_LANES), q_map),
+                pl.BlockSpec((1, 1, cq, LSE_LANES), q_map),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, bk, D), lambda b, h, i, *_: (b, h, i, 0)),
-                pl.BlockSpec((1, 1, bk, D), lambda b, h, i, *_: (b, h, i, 0)),
+                pl.BlockSpec((1, 1, bk, D), kv_map),
+                pl.BlockSpec((1, 1, bk, D), kv_map),
             ],
+            scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
+                            pltpu.VMEM((bk, D), jnp.float32)],
         ),
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -390,23 +456,13 @@ def flash_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Flash attention over [batch, heads, seq, head_dim] (GQA: k/v may have
-    fewer heads).  Pallas on TPU; jnp reference elsewhere."""
-    B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    scale = sm_scale if sm_scale is not None else D ** -0.5
-    # The kernels need block-divisible sequence lengths: shrink by powers of
-    # two until the block divides (768 -> 256, etc.); truly odd lengths take
-    # the XLA reference path rather than reading/writing garbage tails.
-    bq, bk = min(block_q, Sq), min(block_k, Sk)
-    while bq > 16 and Sq % bq:
-        bq //= 2
-    while bk > 16 and Sk % bk:
-        bk //= 2
-    use_pallas = force_pallas or _on_tpu()
-    if Sq % bq or Sk % bk:
-        use_pallas = False
-    if not use_pallas:
+    fewer heads).  Pallas on TPU, where a shape the kernels cannot take
+    raises; the jnp reference elsewhere (``force_pallas`` with ``interpret``
+    runs the kernels off the chip, for tests)."""
+    scale = sm_scale if sm_scale is not None else q.shape[-1] ** -0.5
+    if not (force_pallas or _on_tpu()):
         return mha_reference(
             q, k, v, causal=causal, sm_scale=scale, q_offset=q_offset
         )
+    bq, bk = kernel_blocks(q, k, block_q, block_k)
     return _flash(q, k, v, scale, causal, q_offset, bq, bk, interpret)

@@ -9,8 +9,8 @@ full S×S score matrix never materializes and per-chip memory is
 O(S_local²).  XLA overlaps the ppermute with the chunk compute (ICI
 collective-permute).
 
-Call inside shard_map with sequence dim sharded over `axis_name`; falls back
-to plain flash attention when the axis has size 1.
+Call inside shard_map with sequence dim sharded over `axis_name`; with an
+axis of size 1 it is plain flash attention.
 
 On TPU the per-chunk math runs the Pallas flash kernels under one JOINT
 custom VJP over the whole ring: the forward combines per-chunk (out, lse)
@@ -20,7 +20,8 @@ decomposition is exact across chunks), with dK/dV accumulators riding the
 ring home to their owner shard.  Causal masking across chunks uses the
 kernels' q_offset (a prefetch scalar, so it may be rank-dependent): future
 chunks mask fully, past chunks fully visible, the diagonal chunk is causal.
-Off-TPU the blockwise jnp form remains as the differentiable fallback.
+Off the TPU the blockwise jnp form is the differentiable reference; on it a
+per-shard length the kernels cannot take raises.
 """
 
 from __future__ import annotations
@@ -39,6 +40,7 @@ from .attention import (
     _flash_fwd,
     _on_tpu,
     flash_attention,
+    kernel_blocks,
 )
 
 
@@ -94,18 +96,13 @@ def _chunk_attn(q, k, v, scale, mode):
 # ------------------------------------------------- fused ring+flash (TPU)
 
 
-def _ring_blocks(S: int) -> tuple:
-    bq = min(256, S)
-    bk = min(256, S)
-    if S % bq or S % bk:
-        raise ValueError(f"ring kernel needs block-divisible S, got {S}")
-    return bq, bk
+RING_BLOCK = 256  # q and k block cap of the per-chunk kernels
 
 
 def _ring_flash_fwd_impl(q, k, v, scale, axis_name, n, interpret):
     rank = lax.axis_index(axis_name)
     B, H, S, D = q.shape
-    bq, bk = _ring_blocks(S)
+    bq, bk = kernel_blocks(q, k, RING_BLOCK, RING_BLOCK)
     perm = [(i, (i + 1) % n) for i in range(n)]
     acc = jnp.zeros((B, H, S, D), jnp.float32)
     m_run = jnp.full((B, H, S), NEG_INF, jnp.float32)
@@ -138,8 +135,8 @@ def _ring_flash_fwd_impl(q, k, v, scale, axis_name, n, interpret):
 def _ring_flash_bwd_impl(q, k, v, out, lse_total, do, scale, axis_name, n,
                          interpret):
     rank = lax.axis_index(axis_name)
-    B, H, S, D = q.shape
-    bq, bk = _ring_blocks(S)
+    S = q.shape[2]
+    bq, bk = kernel_blocks(q, k, RING_BLOCK, RING_BLOCK)
     perm = [(i, (i + 1) % n) for i in range(n)]
     lse4 = jnp.broadcast_to(
         lse_total[..., None], lse_total.shape + (LSE_LANES,)
@@ -227,14 +224,10 @@ def ring_attention(
         vg = lax.all_gather(v, axis_name, axis=2, tiled=True)
         return flash_attention(q, kg, vg, causal=False, sm_scale=scale)
 
-    S = q.shape[2]
-    # The fused kernels need TPU-tileable per-shard lengths (multiples of
-    # the 256 block); anything else takes the blockwise jnp path below.
-    use_kernel = (force_kernel or _on_tpu()) and S >= 256 and S % 256 == 0
-    if use_kernel:
-        # Fused ring+flash: Pallas kernels inside one joint custom VJP.
-        return _ring_flash(q, k, v, scale, axis_name, n,
-                           interpret or not _on_tpu())
+    if force_kernel or _on_tpu():
+        # Fused ring+flash: Pallas kernels inside one joint custom VJP.  A
+        # per-shard length they cannot take raises (kernel_blocks).
+        return _ring_flash(q, k, v, scale, axis_name, n, interpret)
 
     rank = lax.axis_index(axis_name)
     perm = [(i, (i + 1) % n) for i in range(n)]

@@ -7,36 +7,33 @@ bootstrap (reference: python/ray/train/torch/config.py:66
 _setup_torch_process_group): the collective *data plane* is XLA ICI/DCN
 collectives inside compiled programs; the host-level rendezvous is
 jax.distributed keyed from cluster metadata.
+
+Names resolve on first use (PEP 562): a driver that only declares a
+``MeshConfig`` for its workers — and must stay off JAX so that the worker
+it starts can own the chip — imports no JAX through this package.
 """
 
-from .mesh import (
-    AXIS_DP,
-    AXIS_EP,
-    AXIS_FSDP,
-    AXIS_PP,
-    AXIS_SP,
-    AXIS_TP,
-    MeshConfig,
-    batch_spec,
-    data_sharding,
-    make_mesh,
-    set_mesh,
-)
-from .pipeline import make_pp_loss, stack_layers, unstack_layers
-from .sharding import (
-    ShardingRules,
-    infer_param_specs,
-    named_sharding,
-    shard_pytree,
-    with_sharding_constraint,
-)
-from .distributed import initialize_process_group, process_group_barrier
+import importlib
 
-__all__ = [
-    "AXIS_DP", "AXIS_FSDP", "AXIS_TP", "AXIS_SP", "AXIS_EP", "AXIS_PP",
-    "MeshConfig", "make_mesh", "set_mesh", "batch_spec", "data_sharding",
-    "make_pp_loss", "stack_layers", "unstack_layers",
-    "ShardingRules", "infer_param_specs", "named_sharding", "shard_pytree",
-    "with_sharding_constraint",
-    "initialize_process_group", "process_group_barrier",
-]
+_EXPORTS = {
+    "AXIS_DP": "mesh", "AXIS_EP": "mesh", "AXIS_FSDP": "mesh",
+    "AXIS_PP": "mesh", "AXIS_SP": "mesh", "AXIS_TP": "mesh",
+    "MeshConfig": "mesh", "batch_spec": "mesh", "data_sharding": "mesh",
+    "make_mesh": "mesh", "set_mesh": "mesh",
+    "make_pp_loss": "pipeline", "stack_layers": "pipeline",
+    "unstack_layers": "pipeline",
+    "ShardingRules": "sharding", "infer_param_specs": "sharding",
+    "named_sharding": "sharding", "shard_pytree": "sharding",
+    "with_sharding_constraint": "sharding",
+    "initialize_process_group": "distributed",
+    "process_group_barrier": "distributed",
+}
+
+__all__ = list(_EXPORTS)
+
+
+def __getattr__(name):
+    module = _EXPORTS.get(name)
+    if module is None:
+        raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+    return getattr(importlib.import_module(f"{__name__}.{module}"), name)

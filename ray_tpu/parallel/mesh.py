@@ -17,11 +17,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import TYPE_CHECKING, Dict, Optional, Sequence
 
-import jax
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+# JAX is imported where a mesh is BUILT, not where one is declared: a driver
+# hands ``MeshConfig`` to its workers without importing JAX itself (the
+# process that touches JAX holds the chip).
+if TYPE_CHECKING:
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 AXIS_DP = "dp"
 AXIS_FSDP = "fsdp"
@@ -80,6 +82,10 @@ def make_mesh(
     torus-adjacent order — innermost mesh axes therefore land on ICI
     neighbors.
     """
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
     devices = list(devices if devices is not None else jax.devices())
     sizes = axis_sizes or (config or MeshConfig()).resolve(len(devices))
     shape = tuple(sizes.get(a, 1) for a in MESH_AXES)
@@ -88,22 +94,24 @@ def make_mesh(
 
 
 def set_mesh(mesh: Mesh):
-    """Version-tolerant ``jax.set_mesh``: newer jax installs the mesh as
-    the ambient (sharding-in-types) mesh; older jax lacks set_mesh, where
-    entering the Mesh context provides the equivalent ambient-mesh scope
-    for pjit-style programs."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Install ``mesh`` as the ambient mesh (``jax.set_mesh``): the scope in
+    which the model's Pallas kernels run per shard (models/llama.py)."""
+    import jax
+
+    return jax.set_mesh(mesh)
 
 
 def batch_spec(sp_shard_seq: bool = False) -> P:
     """PartitionSpec for a [batch, seq, ...] input batch: batch over dp+fsdp,
     optionally sequence over sp (context parallelism)."""
+    from jax.sharding import PartitionSpec as P
+
     return P((AXIS_DP, AXIS_FSDP), AXIS_SP if sp_shard_seq else None)
 
 
 def data_sharding(mesh: Mesh, sp_shard_seq: bool = False) -> NamedSharding:
+    from jax.sharding import NamedSharding
+
     return NamedSharding(mesh, batch_spec(sp_shard_seq))
 
 

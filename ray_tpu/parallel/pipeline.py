@@ -33,19 +33,7 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-
-try:  # jax >= 0.5 exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def shard_map(f, mesh, in_specs, out_specs, check_vma=None, **kw):
-        # Older jax spells check_vma as check_rep.
-        if check_vma is not None:
-            kw["check_rep"] = check_vma
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, **kw)
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from .mesh import AXIS_PP, mesh_axis_size

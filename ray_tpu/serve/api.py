@@ -103,7 +103,10 @@ def deployment(_cls=None, *, name: Optional[str] = None,
 def run(app: Application, *, name: Optional[str] = None,
         wait_ready: bool = True, timeout: float = 120.0) -> DeploymentHandle:
     """Deploy an application (and every application bound into its init
-    args) and return the ingress handle (reference: serve/api.py:510
+    args) and return the ingress handle once every replica's constructor
+    has returned; one that raises fails the call with its error.  A
+    constructor that compiles a model needs a ``timeout`` to match
+    (reference: serve/api.py:510
     serve.run; nested binds mirror the deployment-graph build at
     serve/_private/deployment_graph_build.py — each node becomes its own
     deployment and downstream nodes receive DeploymentHandles)."""
@@ -153,9 +156,16 @@ def run(app: Application, *, name: Optional[str] = None,
             while time.monotonic() < deadline:
                 if ray_tpu.get(controller.ready.remote(dep_name), timeout=30):
                     break
+                err = ray_tpu.get(controller.start_error.remote(dep_name),
+                                  timeout=30)
+                if err:
+                    raise RuntimeError(
+                        f"deployment {dep_name!r}: a replica failed to "
+                        f"start:\n{err}")
                 time.sleep(0.1)
             else:
-                raise TimeoutError(f"deployment {dep_name!r} not ready")
+                raise TimeoutError(
+                    f"deployment {dep_name!r} not ready after {timeout}s")
     return handle
 
 

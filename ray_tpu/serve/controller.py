@@ -83,8 +83,16 @@ class ServeController:
     def __init__(self):
         # name -> target spec dict
         self.targets: Dict[str, dict] = {}
-        # name -> list of {"handle": ActorHandle, "id": int}
+        # name -> list of {"handle": ActorHandle, "id": int, "version"}
         self.replicas: Dict[str, List[dict]] = {}
+        # name -> replicas whose constructor is still running (the same
+        # dicts plus "ready", the ref of their first ping).  They are
+        # promoted into ``replicas`` when it resolves: a constructor may
+        # compile a model for minutes, so readiness is waited on, not
+        # bounded by a constant.  Loop-thread only.
+        self.starting: Dict[str, List[dict]] = {}
+        # name -> newest constructor failure, for serve.run to raise.
+        self.start_errors: Dict[str, str] = {}
         self._next_replica_id = 0
         self._lock = threading.Lock()
         self._version = 0
@@ -104,6 +112,7 @@ class ServeController:
             spec = dict(spec)
             spec["version"] = (old["version"] + 1) if old else 1
             self.targets[name] = spec
+            self.start_errors.pop(name, None)
             self._version += 1
         _publish_slo(name, spec)
         return True
@@ -158,6 +167,12 @@ class ServeController:
             ]
             return len(current) >= max(1, self._target_replicas(name))
 
+    def start_error(self, name: str) -> Optional[str]:
+        """The newest failure of a replica constructor of ``name``'s
+        current version, or None."""
+        with self._lock:
+            return self.start_errors.get(name)
+
     def shutdown(self) -> bool:
         with self._lock:
             self._shutdown = True
@@ -181,16 +196,17 @@ class ServeController:
         while True:
             time.sleep(0.2)
             with self._lock:
-                if self._shutdown and not any(self.replicas.values()):
+                if self._shutdown and not any(self.replicas.values()) \
+                        and not any(self.starting.values()):
                     break
                 targets = dict(self.targets)
             # Drop deployments no longer targeted.
-            for name in list(self.replicas):
+            for name in set(self.replicas) | set(self.starting):
                 if name not in targets:
                     with self._lock:
                         dropped = self.replicas.pop(name, [])
                         self._version += 1
-                    for r in dropped:
+                    for r in dropped + self.starting.pop(name, []):
                         self._stop_replica(r)
             for name, spec in targets.items():
                 with self._lock:
@@ -210,22 +226,55 @@ class ServeController:
                     else:
                         live.append(r)
                 reps = live
+                started, pending = self._poll_starting(name, spec)
+                if started:
+                    reps.extend(started)
+                    changed = True
                 self._autoscale(name, spec, reps)
                 want = self._target_replicas(name)
-                while len(reps) < want:
+                while len(reps) + len(pending) < want:
                     try:
-                        reps.append(self._start_replica(name, spec))
-                        changed = True
+                        pending.append(self._start_replica(name, spec))
                     except Exception:
                         break
+                while pending and len(reps) + len(pending) > want:
+                    self._stop_replica(pending.pop())
                 while len(reps) > want:
                     self._stop_replica(reps.pop())
                     changed = True
+                self.starting[name] = pending
                 with self._lock:
                     if name in self.targets:
                         self.replicas[name] = reps
                     if changed:
                         self._version += 1
+
+    def _poll_starting(self, name: str, spec: dict):
+        """Sort ``name``'s constructing replicas into (started, pending):
+        a resolved first ping promotes the replica, a failed one (the
+        constructor raised, the worker died) records why and drops it, an
+        outdated version is stopped."""
+        started, pending = [], []
+        for r in self.starting.get(name, ()):
+            if r["version"] != spec["version"]:
+                self._stop_replica(r)
+                continue
+            done, _ = ray_tpu.wait([r["ready"]], timeout=0)
+            if not done:
+                pending.append(r)
+                continue
+            try:
+                ray_tpu.get(r["ready"], timeout=5)
+            except Exception as e:  # noqa: BLE001 — reported, then retried
+                self._stop_replica(r)
+                with self._lock:
+                    self.start_errors[name] = f"{type(e).__name__}: {e}"
+                continue
+            del r["ready"]
+            started.append(r)
+            with self._lock:
+                self.start_errors.pop(name, None)
+        return started, pending
 
     def _alive_many(self, reps: List[dict]) -> List[bool]:
         if not reps:
@@ -263,9 +312,10 @@ class ServeController:
         handle = ServeReplica.options(**opts).remote(
             name, spec["cls_blob"], spec["init_args_blob"]
         )
-        ray_tpu.get(handle.ping.remote(), timeout=120)  # wait ready
+        # The first ping resolves once the constructor returns (or fails
+        # with its error): _poll_starting waits on it.
         return {"handle": handle, "id": self._next_replica_id,
-                "version": spec["version"]}
+                "version": spec["version"], "ready": handle.ping.remote()}
 
     def _stop_replica(self, r: dict):
         try:

@@ -662,26 +662,40 @@ class InferenceEngine:
             # compile every suffix bucket and the COW copy explicitly
             # (dummy tokens into the scratch page; page 0 onto itself)
             # so no real prefix hit after warmup pays a trace.
-            def _warm_prefix_path():
+            def _warm_suffix_bucket(b):
                 import jax.numpy as jnp
 
-                from ..models.paged import copy_page, paged_prefill_prefix
-                adapters = self.adapter_pool.arrays
+                from ..models.paged import paged_prefill_prefix
                 pt = jnp.full((self.maxp,), self.scratch, jnp.int32)
                 zero = jnp.asarray(0, jnp.int32)
-                temp = jnp.asarray(0.0, jnp.float32)
-                for b in self.config.prefill_buckets():
-                    _, self._d_key, self.pools = paged_prefill_prefix(
-                        self.model_config, self.params, self.pools,
-                        adapters, jnp.zeros((1, b), jnp.int32), zero,
-                        jnp.asarray(1, jnp.int32), pt, zero, temp,
-                        self._d_key)
+                _, self._d_key, self.pools = paged_prefill_prefix(
+                    self.model_config, self.params, self.pools,
+                    self.adapter_pool.arrays, jnp.zeros((1, b), jnp.int32),
+                    zero, jnp.asarray(1, jnp.int32), pt, zero,
+                    jnp.asarray(0.0, jnp.float32), self._d_key)
+
+            def _warm_cow_copy():
+                import jax.numpy as jnp
+
+                from ..models.paged import copy_page
+                zero = jnp.asarray(0, jnp.int32)
                 self.pools = copy_page(self.pools, zero, zero)
-            self._run_on_loop(_warm_prefix_path)
+
+            # One control op per program: each waits for ONE cold compile,
+            # under the bound a cold prefill gets through its stream (at
+            # 1B-parameter size a bucket compiles for tens of seconds).
+            import functools
+
+            compile_s = self.config.stream_timeout_s
+            for b in self.config.prefill_buckets():
+                self._run_on_loop(
+                    functools.partial(_warm_suffix_bucket, b), compile_s)
+            self._run_on_loop(_warm_cow_copy, compile_s)
         # Compile the adapter-load path too (zero payload into the zero
         # slot): the first real LoRA registration after warmup must be an
         # execution, not a fresh trace.
-        self._run_on_loop(self.adapter_pool.warmup_compile)
+        self._run_on_loop(self.adapter_pool.warmup_compile,
+                          self.config.stream_timeout_s)
         # Recompile sentinel (RT_DEBUG_JIT=1): freeze every program's
         # trace count — decode, each prefill bucket, the COW/suffix path,
         # adapter loads — so any post-warmup trace raises RecompileError
@@ -1236,16 +1250,33 @@ class LLMServer:
                  adapters: Optional[Dict[str, Any]] = None):
         import jax
 
+        from .. import accelerators
         from ..models import llama_init
 
+        backend = jax.default_backend()
+        chips = accelerators.num_chips()
+        if model != "tiny" and backend != "tpu" and chips:
+            raise RuntimeError(
+                f"model {model!r} would be served from the {backend!r} "
+                f"backend on a host with {chips} TPU chip(s): the replica "
+                f"was granted none.  Ask for one through the deployment's "
+                f"actor options: llm_app(..., ray_actor_options="
+                f"{{'num_tpus': 1}})")
+        t0 = time.perf_counter()
         cfg = _MODEL_BUILDERS[model]()
-        params = llama_init(cfg, jax.random.PRNGKey(seed))
+        params = jax.block_until_ready(
+            llama_init(cfg, jax.random.PRNGKey(seed)))
         self.engine = InferenceEngine(
             cfg, params, EngineConfig(**(engine or {})), seed=seed)
         for name, spec in (adapters or {}).items():
             self.load_adapter(name, spec)
+        t1 = time.perf_counter()
         if warmup:
             self.engine.warmup()
+        #: Set-up seconds: params and pools onto the device, then (with
+        #: ``warmup``) the compile of every program the engine will run.
+        self._init_s = t1 - t0
+        self._warmup_s = time.perf_counter() - t1
 
     def load_adapter(self, name: str, source: Any = None) -> str:
         """Register a LoRA adapter on this replica's engine.  ``source``
@@ -1284,7 +1315,46 @@ class LLMServer:
             stream.cancel()
 
     def stats(self) -> Dict[str, Any]:
-        return self.engine.stats()
+        """Engine counters plus what this replica's process runs on, as
+        JAX reports it here."""
+        import os
+
+        import jax
+
+        from ..devtools import jitguard
+
+        dev = jax.devices()[0]
+        return dict(
+            self.engine.stats(),
+            pid=os.getpid(),
+            vocab_size=self.engine.model_config.vocab_size,
+            device={"platform": dev.platform, "kind": dev.device_kind,
+                    "count": len(jax.devices())},
+            init_s=self._init_s, warmup_s=self._warmup_s,
+            sentinel_armed=jitguard.armed())
+
+    def clear_prefix_cache(self) -> int:
+        """Drop every cached prefix page (returns how many the cache let
+        go): afterwards an idle engine's free list holds the whole pool."""
+        return self.engine.clear_prefix_cache()
+
+    def reference_logits(self, prompt_tokens,
+                         candidates=()) -> Dict[str, Any]:
+        """Next-token logits after ``prompt_tokens`` from the plain full
+        forward pass (``llama_apply``: no cache, no pages, no buckets) over
+        this replica's weights: the best token, its logit, and the logits
+        of ``candidates``.  What a deployment check holds the engine's
+        greedy token to, within the dtype's rounding."""
+        import jax
+        import jax.numpy as jnp
+
+        from ..models import llama_apply
+
+        toks = jnp.asarray(prompt_tokens, jnp.int32)[None]
+        logits = np.asarray(jax.jit(llama_apply, static_argnums=0)(
+            self.engine.model_config, self.engine.params, toks)[0, -1])
+        return {"argmax": int(logits.argmax()), "max": float(logits.max()),
+                "logits": [float(logits[int(c)]) for c in candidates]}
 
     def engine_metrics(self) -> Dict[str, Any]:
         """SLO signal snapshot for the controller's autoscaler."""
@@ -1294,15 +1364,19 @@ class LLMServer:
 def llm_app(model: str = "tiny", engine: Optional[dict] = None,
             num_replicas: int = 1, name: str = "llm", seed: int = 0,
             warmup: bool = False,
-            adapters: Optional[Dict[str, Any]] = None):
+            adapters: Optional[Dict[str, Any]] = None,
+            ray_actor_options: Optional[dict] = None):
     """Build a servable LLM application:
     ``serve.run(llm_app(...))`` then stream tokens via
     ``handle.options(stream=True).remote([1, 2, 3], 16)`` or POST with
     ``Accept: text/event-stream``.  ``adapters`` maps adapter name to an
     int seed (random adapter) or weight source, registered on every
-    replica at startup."""
+    replica at startup.  ``ray_actor_options`` are the deployment's:
+    ``{"num_tpus": 1}`` gives each replica a chip, and on a host with
+    chips every model but ``tiny`` refuses to start without one."""
     from .api import Deployment
 
-    dep = Deployment(LLMServer, name, num_replicas=num_replicas)
+    dep = Deployment(LLMServer, name, num_replicas=num_replicas,
+                     ray_actor_options=ray_actor_options)
     return dep.bind(model=model, engine=engine, seed=seed, warmup=warmup,
                     adapters=adapters)

@@ -12,9 +12,9 @@ devices the step ran on).  FLOPs per step come from XLA's own cost model
 (``jax.jit(fn).lower(*args).cost_analysis()["flops"]``) when available,
 else from the classic dense-transformer estimate ``6 * params * tokens``
 (``transformer_flops``), else from an explicit number the caller provides.
-CPU backends get a nominal peak so MFU stays finite and tests run
-everywhere — the absolute value is meaningless off-accelerator, the
-*trend* is still useful.
+The peak comes from the one table in ``ray_tpu.accelerators``, keyed by
+``device_kind``.  On the CPU backend there is no peak and MFU is not
+reported (``None``): a utilization against an invented peak is not one.
 """
 
 from __future__ import annotations
@@ -23,46 +23,19 @@ import contextlib
 import time
 from typing import Any, Dict, Optional
 
-#: Per-device peak dense FLOP/s (bf16) by device-kind substring, checked in
-#: order.  Sources: published TPU/GPU spec sheets.
-PEAK_FLOPS_TABLE = (
-    # jax device_kind spells the lite parts "TPU v5 lite" / "TPU v6 lite".
-    ("v6 lite", 918e12),  # TPU v6e (Trillium)
-    ("v6e", 918e12),
-    ("v5 lite", 197e12),  # TPU v5e
-    ("v5litepod", 197e12),
-    ("v5e", 197e12),
-    ("v5p", 459e12),
-    ("v5", 459e12),  # bare "TPU v5" device_kind: the p part
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-    ("h100", 989e12),
-    ("a100", 312e12),
-)
+def device_peak_flops(device: Optional[Any] = None) -> Optional[float]:
+    """Peak bf16 FLOP/s of one device (``jax.devices()[0]`` when omitted):
+    None on the CPU backend, the ``ray_tpu.accelerators`` table's entry on
+    an accelerator.  An accelerator the table does not know raises."""
+    from ..accelerators import peak_flops
 
-#: Nominal per-core peak for CPU backends: keeps MFU finite in CPU-only
-#: smoke runs (the stub the issue calls for); not a real utilization.
-CPU_NOMINAL_PEAK_FLOPS = 1e11
+    if device is None:
+        import jax
 
-
-def device_peak_flops(device: Optional[Any] = None) -> float:
-    """Peak FLOP/s of one device (``jax.devices()[0]`` when omitted).
-    Unknown accelerators fall back to the CPU nominal rather than raising —
-    a telemetry path must never kill a train step."""
-    kind = ""
-    try:
-        if device is None:
-            import jax
-
-            device = jax.devices()[0]
-        kind = (getattr(device, "device_kind", "") or "").lower()
-    except Exception:
-        return CPU_NOMINAL_PEAK_FLOPS
-    for sub, peak in PEAK_FLOPS_TABLE:
-        if sub in kind:
-            return peak
-    return CPU_NOMINAL_PEAK_FLOPS
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return None
+    return peak_flops(device.device_kind)
 
 
 def flops_per_step(fn, *args, **kwargs) -> Optional[float]:
@@ -80,8 +53,6 @@ def flops_per_step(fn, *args, **kwargs) -> Optional[float]:
             analysis = lowered.cost_analysis()  # no compile needed
         except Exception:
             analysis = lowered.compile().cost_analysis()
-        if isinstance(analysis, (list, tuple)):  # older jax returns [dict]
-            analysis = analysis[0] if analysis else None
         if analysis:
             f = analysis.get("flops")
             if isinstance(f, (int, float)) and f > 0:
@@ -142,19 +113,19 @@ class TrainTelemetry:
     def set_flops_per_step(self, flops: Optional[float]) -> None:
         self.flops_per_step = flops
 
-    def peak_flops_total(self) -> float:
-        """Aggregate peak FLOP/s across the devices this step runs on."""
+    def peak_flops_total(self) -> Optional[float]:
+        """Aggregate peak FLOP/s across the devices this step runs on, or
+        None where the device has no peak on record (the CPU backend)."""
         peak = self._peak_flops
         if peak is None:
             peak = device_peak_flops()
+        if peak is None:
+            return None
         n = self._num_devices
         if n is None:
-            try:
-                import jax
+            import jax
 
-                n = jax.local_device_count()
-            except Exception:
-                n = 1
+            n = jax.local_device_count()
         return peak * max(1, n)
 
     # -- recording -------------------------------------------------------------
@@ -170,7 +141,8 @@ class TrainTelemetry:
                     compile_time_s: Optional[float] = None
                     ) -> Dict[str, float]:
         """Record one finished step; returns the derived metrics
-        ({step_time_s, tokens_per_sec?, mfu?, compile_time_s?})."""
+        ({step_time_s, tokens_per_sec?, mfu?, compile_time_s?}).  ``mfu``
+        is there only where the device's peak is known."""
         out: Dict[str, float] = {"step_time_s": float(step_time_s)}
         self._g_step.set(step_time_s, tags=self._tags)
         if compile_time_s is not None:
@@ -181,8 +153,9 @@ class TrainTelemetry:
             out["tokens_per_sec"] = tokens / step_time_s
             self._g_tps.set(out["tokens_per_sec"], tags=self._tags)
         flops = flops if flops is not None else self.flops_per_step
-        if flops and step_time_s > 0:
-            mfu = flops / step_time_s / self.peak_flops_total()
+        peak = self.peak_flops_total() if flops and step_time_s > 0 else None
+        if peak:
+            mfu = flops / step_time_s / peak
             out["mfu"] = mfu
             self._g_mfu.set(mfu, tags=self._tags)
         self.last = dict(out)

@@ -65,12 +65,10 @@ def device_trace(log_dir: str, *,
         _active_dir = log_dir
     kwargs = {}
     if host_tracer_level is not None:
-        try:
-            kwargs["profiler_options"] = jax.profiler.ProfileOptions(
-                host_tracer_level=host_tracer_level
-            )
-        except (AttributeError, TypeError):
-            pass  # older jax: default options
+        # ProfileOptions takes no constructor arguments (jax 0.9).
+        options = jax.profiler.ProfileOptions()
+        options.host_tracer_level = host_tracer_level
+        kwargs["profiler_options"] = options
     try:
         jax.profiler.start_trace(log_dir, **kwargs)
         try:

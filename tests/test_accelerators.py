@@ -84,24 +84,136 @@ class TestVisibilityEnv:
         assert env["TPU_VISIBLE_CHIPS"] == "1,3"
         assert env["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,2,1"
 
-    def test_all_chips_clears_bounds(self, tpu_host):
+    def test_all_chips_keeps_host_bounds(self, tpu_host):
+        # A whole-host grant clears only the per-process view: unsetting the
+        # host's bounds sends libtpu to the metadata server.
         env = accelerators.visibility_env([0, 1, 2, 3], host_chips=4)
-        assert env["TPU_VISIBLE_CHIPS"] == ""
-        assert env["TPU_CHIPS_PER_HOST_BOUNDS"] == ""
+        assert env == {"TPU_VISIBLE_CHIPS": ""}
 
-    def test_apply_sets_and_clears(self, tpu_host, monkeypatch):
+    def test_apply_pins_tpu_and_refuses_a_cpu_jax(self, tpu_host, monkeypatch):
         # Register every var apply_visibility mutates so monkeypatch
-        # restores them — a leaked JAX_PLATFORMS=tpu,cpu would poison every
+        # restores them — a leaked JAX_PLATFORMS=tpu would poison every
         # worker spawned by later tests in this process.
         monkeypatch.setenv("JAX_PLATFORMS", "cpu")
         monkeypatch.setenv("TPU_VISIBLE_CHIPS", "stale")
-        monkeypatch.setenv("TPU_HOST_BOUNDS", "stale")
-        monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "stale")
-        accelerators.apply_visibility([0, 1, 2, 3], host_chips=4)
-        assert "TPU_CHIPS_PER_HOST_BOUNDS" not in os.environ
-        accelerators.apply_visibility([1], host_chips=4)
+        monkeypatch.setenv("TPU_HOST_BOUNDS", "1,1,1")
+        monkeypatch.setenv("TPU_CHIPS_PER_HOST_BOUNDS", "2,2,1")
+        # This process's jax already runs on the CPU (conftest): a grant
+        # cannot re-point it, so it raises instead of computing there.
+        with pytest.raises(RuntimeError, match="before the grant"):
+            accelerators.apply_visibility([0, 1, 2, 3], host_chips=4)
+        assert "TPU_VISIBLE_CHIPS" not in os.environ
+        assert os.environ["TPU_CHIPS_PER_HOST_BOUNDS"] == "2,2,1"
+        assert os.environ["JAX_PLATFORMS"] == "tpu"  # and nothing after it
+        with pytest.raises(RuntimeError, match="before the grant"):
+            accelerators.apply_visibility([1], host_chips=4)
         assert os.environ["TPU_VISIBLE_CHIPS"] == "1"
-        assert os.environ["JAX_PLATFORMS"] == "tpu,cpu"
+        assert os.environ["TPU_CHIPS_PER_HOST_BOUNDS"] == "1,1,1"
+        assert os.environ["JAX_PLATFORMS"] == "tpu"
+
+    def test_no_grant_leaves_platform_alone(self, monkeypatch):
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        accelerators.apply_visibility([], host_chips=4)
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+
+
+class TestWorkerEnv:
+    #: What the one-chip v5e machine's environment held.
+    HOST = {
+        "JAX_PLATFORMS": "tpu,cpu",
+        "JAX_COMPILATION_CACHE_DIR": "/somewhere/jax",
+        "TPU_ACCELERATOR_TYPE": "v5litepod-4",
+        "TPU_CHIPS_PER_HOST_BOUNDS": "2,2,1",
+        "TPU_HOST_BOUNDS": "1,1,1",
+        "TPU_SKIP_MDS_QUERY": "true",
+        "TPU_WORKER_HOSTNAMES": "localhost",
+        "TPU_WORKER_ID": "0",
+    }
+
+    def test_keeps_host_config_drops_process_view(self):
+        base = dict(self.HOST, TPU_VISIBLE_CHIPS="2", TPU_PROCESS_BOUNDS="1,1,1")
+        env = accelerators.worker_env(base)
+        for k, v in self.HOST.items():
+            if k != "JAX_PLATFORMS":
+                assert env[k] == v, k
+        assert "TPU_VISIBLE_CHIPS" not in env
+        assert "TPU_PROCESS_BOUNDS" not in env
+
+    def test_worker_is_pinned_to_cpu_whatever_the_driver_says(self):
+        assert accelerators.worker_env(self.HOST)["JAX_PLATFORMS"] == "cpu"
+        assert accelerators.worker_env({})["JAX_PLATFORMS"] == "cpu"
+
+    def test_pythonpath_leads_with_the_checkout(self):
+        root = os.path.dirname(os.path.dirname(accelerators.__file__))
+        assert accelerators.worker_env({})["PYTHONPATH"] == root
+        env = accelerators.worker_env({"PYTHONPATH": "/x"})
+        assert env["PYTHONPATH"] == root + os.pathsep + "/x"
+
+    def test_zygote_forwards_the_cache_dir(self):
+        """A zygote-forked worker gets only RT_/JAX_/PYTHON* of the spawn
+        env: the compile-cache variable is among them."""
+        from ray_tpu.core import zygote
+
+        class Fake:
+            def alive(self):
+                return True
+
+            def spawn(self, env, log=None):
+                self.env = env
+                return 1
+
+        z = Fake()
+        zygote.spawn_with_fallback(
+            z, accelerators.worker_env(self.HOST), "/tmp/x.log")
+        assert z.env["JAX_COMPILATION_CACHE_DIR"] == "/somewhere/jax"
+        assert z.env["JAX_PLATFORMS"] == "cpu"
+
+
+class TestCompileCache:
+    def test_operator_directory_wins(self, monkeypatch):
+        import jax
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/operator/dir")
+        before = jax.config.jax_compilation_cache_dir
+        accelerators.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == before
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == "/operator/dir"
+        assert accelerators.compile_cache_dir() == "/operator/dir"
+
+    def test_default_is_fixed_under_the_checkout(self, monkeypatch):
+        import jax
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = jax.config.jax_compilation_cache_dir
+        try:
+            accelerators.enable_compile_cache()
+            root = os.path.dirname(os.path.dirname(accelerators.__file__))
+            path = os.path.join(root, ".jax_cache")
+            assert accelerators.compile_cache_dir() == path
+            assert jax.config.jax_compilation_cache_dir == path
+            assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
+
+    def test_before_jax_import_it_is_the_variable(self, monkeypatch):
+        import sys
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delitem(sys.modules, "jax")
+        path = accelerators.compile_cache_dir()
+        accelerators.enable_compile_cache()
+        assert os.environ["JAX_COMPILATION_CACHE_DIR"] == path
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+
+
+class TestPeakTable:
+    def test_v5e_by_device_kind(self):
+        assert accelerators.peak_flops("TPU v5 lite") == 197e12
+
+    @pytest.mark.parametrize("kind", ["cpu", "TPU v9", "", "tpu v5 lite"])
+    def test_unknown_device_raises(self, kind):
+        with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+            accelerators.peak_flops(kind)
 
 
 class TestChipPool:
@@ -169,14 +281,13 @@ class TestEndToEnd:
             @ray_tpu.remote(resources={"TPU": 1}, num_cpus=0)
             class ChipHolder:
                 def chips(self):
-                    # Full-host grant: visibility stays default (reference
-                    # clears the bounds when all chips are granted), but the
-                    # worker flips JAX back onto the TPU platform.
+                    # Full-host grant: visibility stays default, and the
+                    # worker pins JAX to the TPU platform and nothing else.
                     return (os.environ.get("TPU_VISIBLE_CHIPS"),
                             os.environ.get("JAX_PLATFORMS"))
 
             holder = ChipHolder.remote()
-            assert ray_tpu.get(holder.chips.remote()) == (None, "tpu,cpu")
+            assert ray_tpu.get(holder.chips.remote()) == (None, "tpu")
 
             # The sole chip is held: a second TPU task must not schedule.
             @ray_tpu.remote(resources={"TPU": 1}, num_cpus=0)

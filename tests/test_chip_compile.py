@@ -1,0 +1,367 @@
+"""What only the chip's compiler, or only a run, can show — without the chip.
+
+1. Compiles for a *described* TPU v5e (``jax.experimental.topologies``): the
+   TPU compiler is installed here and compiles for a chip that is not
+   attached, raising what the chip's compiler would raise.  Interpret-mode
+   tests cannot see a kernel that runs out of VMEM or a Mosaic kernel that
+   XLA refuses to partition; these can.  Kernel-level cases (about a second
+   each) run in tier-1; whole-step compiles (10-90 s each) are ``slow`` and
+   are run before a chip call.  Nothing executes, so nothing here is a
+   measurement.
+2. A CPU rehearsal of ``chip_smoke.py``: its phase functions at
+   ``model="tiny"`` on the CPU backend, through this test-only entry (the
+   script itself has no such option and refuses to run off the chip).
+
+Code that asks ``jax.default_backend()`` sees the CPU here, so the tests that
+compile a whole model step steer ``ops.attention._on_tpu`` themselves.
+"""
+
+import functools
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from ray_tpu.ops import attention as att
+from ray_tpu.ops.norms import rms_norm_pallas
+from ray_tpu.ops.ring_attention import ring_attention
+from ray_tpu.parallel.mesh import MESH_AXES, batch_spec
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+import chip_smoke  # noqa: E402 — the script at the root; it imports no jax
+
+HBM_BYTES = 16909336064  # bytes_limit of one v5e chip, as memory_stats() gave it
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """A described 2x2 host of TPU v5e, with the persistent compilation
+    cache off around the module: a compile for a described chip is written
+    to the cache but cannot be read back without one, and warns."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu, or it cannot describe
+        pytest.skip(f"cannot describe a TPU v5e here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _on(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _mesh(topo, **sizes) -> Mesh:
+    shape = tuple(sizes.get(a, 1) for a in MESH_AXES)
+    return Mesh(np.array(topo.devices).reshape(shape), MESH_AXES)
+
+
+# ------------------------------------------------------------------ kernels
+
+#: (q heads, kv heads, sequence): LlamaConfig.b1's attention, then
+#: LlamaConfig.llama3_8b's (GQA 32/8) at the lengths the preset is used at.
+#: 32/8 at 4096 and 8192 is what the backward used to be refused at: the
+#: dK/dV kernel held Q, dO, lse and delta of the whole GQA group in VMEM.
+FLASH_SHAPES = [(16, 16, 2048), (32, 8, 2048), (32, 8, 4096), (32, 8, 8192)]
+
+
+def _flash_args(v5e, heads, kv_heads, seq, dtype=jnp.bfloat16, d=128):
+    one = SingleDeviceSharding(v5e.devices[0])
+    return (_on(one, (1, heads, seq, d), dtype),
+            _on(one, (1, kv_heads, seq, d), dtype),
+            _on(one, (1, kv_heads, seq, d), dtype))
+
+
+@pytest.mark.parametrize("heads,kv_heads,seq", FLASH_SHAPES)
+def test_flash_forward_compiles(v5e, heads, kv_heads, seq):
+    fwd = functools.partial(att.flash_attention, force_pallas=True)
+    text = jax.jit(fwd).lower(
+        *_flash_args(v5e, heads, kv_heads, seq)).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("heads,kv_heads,seq", FLASH_SHAPES)
+def test_flash_backward_compiles(v5e, heads, kv_heads, seq):
+    def loss(q, k, v):
+        out = att.flash_attention(q, k, v, force_pallas=True)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *_flash_args(v5e, heads, kv_heads, seq)).compile().as_text()
+    assert text.count("tpu_custom_call") == 3  # forward, dq, dk+dv
+
+
+@pytest.mark.parametrize("seq,dtype,fits", [
+    (12288, jnp.bfloat16, True), (16384, jnp.bfloat16, False),
+    (6144, jnp.float32, True), (8192, jnp.float32, False),
+])
+def test_flash_refuses_what_the_compiler_would(v5e, seq, dtype, fits):
+    """K and V of one head stay whole in VMEM for the forward and dq
+    kernels: at the edge of KV_RESIDENT_BYTES the kernels still compile,
+    past it flash_attention raises its own error before tracing one."""
+    args = _flash_args(v5e, 4, 4, seq, dtype)
+    fwd = jax.jit(functools.partial(att.flash_attention, force_pallas=True))
+    if fits:
+        assert "tpu_custom_call" in fwd.lower(*args).compile().as_text()
+    else:
+        with pytest.raises(ValueError, match="keeps K and V of one head"):
+            fwd.lower(*args)
+
+
+def test_flash_refuses_a_length_no_block_divides():
+    q = jnp.zeros((1, 2, 1000, 64))  # 1000 = 8 * 125: no block down to 16
+    with pytest.raises(ValueError, match="blocks divide"):
+        att.flash_attention(q, q, q, force_pallas=True)
+    # Off the chip, and not forced, the same call is the XLA reference.
+    assert att.flash_attention(q, q, q).shape == q.shape
+
+
+def test_rms_norm_kernel_compiles(v5e):
+    one = SingleDeviceSharding(v5e.devices[0])
+    text = jax.jit(rms_norm_pallas).lower(
+        _on(one, (8192, 2048)), _on(one, (2048,))).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
+def test_ring_flash_compiles_on_four_devices(v5e, grad):
+    """The fused ring+flash pair over sp=4: b1's heads, 2048 tokens a shard."""
+    mesh = _mesh(v5e, sp=4)
+    spec = P(None, None, "sp", None)
+    ring = jax.shard_map(
+        functools.partial(ring_attention, axis_name="sp", force_kernel=True),
+        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False)
+    fn = ring
+    if grad:
+        fn = jax.grad(lambda q, k, v: ring(q, k, v).astype(jnp.float32).sum(),
+                      argnums=(0, 1, 2))
+    arg = _on(NamedSharding(mesh, spec), (1, 16, 4 * 2048, 128))
+    text = jax.jit(fn).lower(arg, arg, arg).compile().as_text()
+    # One kernel per ring step forward; forward + 2 per step with the grad.
+    assert text.count("tpu_custom_call") == (12 if grad else 4)
+    assert chip_smoke.count_collectives(text)["collective-permute"] > 0
+
+
+# ------------------------------------------------------- chip_smoke, on CPU
+
+
+@pytest.fixture
+def smoke(monkeypatch):
+    """chip_smoke.py as a module, and a cluster whose workers get four
+    virtual CPU devices (the sharded phase's fsdp=2 x tp=2)."""
+    import ray_tpu
+
+    monkeypatch.setenv(
+        "XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+    monkeypatch.setenv("RT_DEBUG_JIT", "1")
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=4)
+    yield chip_smoke
+    ray_tpu.shutdown()
+
+
+TINY_ENGINE = dict(batch_slots=4, page_size=8, max_prompt_len=16,
+                   max_new_tokens_cap=16)
+TINY_TRAIN = dict(model="tiny", batch=4, seq=64, steps=3, lr=1e-2)
+
+
+def test_chip_smoke_phases_rehearsed_on_cpu(smoke):
+    """Both default phases end to end at model="tiny": same entry points,
+    same checks, the CPU backend."""
+    serve = smoke.serve_phase(
+        model="tiny", engine=TINY_ENGINE, prompt_lens=(3, 12, 16),
+        new_tokens=6, platform="cpu", num_tpus=0)
+    assert serve["platform"] == "cpu" and serve["greedy_repeats"]
+    assert serve["decode_traces"] == 1 and serve["sentinel_armed"]
+    assert serve["free_pages"] == serve["total_pages"]
+    assert serve["tokens_returned"] == 5 * 6
+    assert max(serve["reference_logit_gap"].values()) < 1e-4  # float32
+    assert serve["replica_dead_after_s"] < 60  # gone (or a zombie) before
+    train = smoke.train_phase(name="train", platform="cpu", chips=0,
+                              **TINY_TRAIN)
+    assert train["platform"] == "cpu" and train["last_loss"] < train["first_loss"]
+    # The same result held to the chip's contract is a failure.
+    with pytest.raises(smoke.SmokeFailure, match="expected 1 x 'tpu'"):
+        smoke.check_device(train, "tpu", 1)
+
+
+def test_chip_smoke_failed_phase_is_an_exit_code(monkeypatch, capsys):
+    """With no chip, or JAX held to the CPU, the script refuses at once; a
+    phase that fails on a host with one goes up through main() as an
+    exception (a non-zero exit).  Neither prints ``"ok": true``."""
+    import ray_tpu
+
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
+    assert chip_smoke.main([]) == 2  # conftest: JAX_PLATFORMS=cpu
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert chip_smoke.main([]) == 2  # and no chip either (RT_TPU_CHIPS=0)
+    assert chip_smoke.main(["--chips", "4"]) == 2
+
+    def failing_phase(**kwargs):
+        raise chip_smoke.SmokeFailure("forced")
+
+    monkeypatch.setenv("RT_TPU_CHIPS", "1")  # pretend: main() gets to a phase
+    monkeypatch.setenv("RT_DEBUG_JIT", "")  # main() sets both: restored after
+    monkeypatch.setenv("RT_LOG_TO_DRIVER", "0")
+    monkeypatch.setattr(chip_smoke, "serve_phase", failing_phase)
+    with pytest.raises(chip_smoke.SmokeFailure, match="forced"):
+        chip_smoke.main([])
+    assert not ray_tpu.is_initialized()
+    out = capsys.readouterr().out
+    assert '"phase": "start"' in out and '"ok"' not in out
+
+
+def test_chip_smoke_parent_stays_off_jax():
+    """Everything the script's parent imports, in a fresh interpreter."""
+    import subprocess
+
+    code = ("import sys, chip_smoke, ray_tpu.serve.engine, ray_tpu.train, "
+            "ray_tpu.cluster_utils\n"
+            "from ray_tpu.parallel import MeshConfig\n"
+            "MeshConfig(fsdp=2, tp=2)\n"
+            "print('jax' in sys.modules)")
+    out = subprocess.run([sys.executable, "-c", code], cwd=ROOT, check=True,
+                         capture_output=True, text=True, timeout=60)
+    assert out.stdout.strip() == "False"
+
+
+@pytest.mark.slow  # two gangs, two compiles of the sharded step: ~60 s
+def test_chip_smoke_sharded_phases_rehearsed_on_cpu(smoke):
+    sharded, single = smoke.sharded_phases(platform="cpu", chips=0,
+                                           **TINY_TRAIN)
+    assert sharded["mesh"]["fsdp"] == 2 and sharded["mesh"]["tp"] == 2
+    assert sharded["count"] == 4 and single["mesh"] is None
+    assert sharded["max_loss_diff_vs_one_device"] < 1e-3
+
+
+# ------------------------------------------------- whole steps, before a call
+
+
+@pytest.fixture
+def kernels_as_on_chip(monkeypatch):
+    """Whole model steps ask the backend which attention to take."""
+    monkeypatch.setattr(att, "_on_tpu", lambda: True)
+
+
+def _smoke_train_step(mesh=None):
+    from ray_tpu.models import (TrainState, llama_init, llama_loss,
+                                llama_sharding_rules)
+    from ray_tpu.models.train_state import default_optimizer, make_train_step
+
+    t = chip_smoke.TRAIN
+    cfg = chip_smoke.train_model_config(t["model"], t["seq"])
+    tx = default_optimizer(lr=t["lr"], grad_clip=1.0)
+    state = jax.eval_shape(lambda: TrainState.create(
+        llama_init(cfg, jax.random.PRNGKey(0)), tx))
+    rules = llama_sharding_rules() if mesh is not None else None
+    step = make_train_step(
+        lambda p, b: llama_loss(cfg, p, b["tokens"], b["targets"]),
+        tx, mesh, rules)
+    return step, state, rules, (t["batch"], t["seq"])
+
+
+@pytest.mark.slow  # ~30 s
+def test_b1_train_step_fits_one_chip(v5e, kernels_as_on_chip):
+    step, state, _, shape = _smoke_train_step()
+    one = SingleDeviceSharding(v5e.devices[0])
+    state = jax.tree.map(lambda x: _on(one, x.shape, x.dtype), state)
+    batch = {k: _on(one, shape, jnp.int32) for k in ("tokens", "targets")}
+    compiled = step.lower(state, batch).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 60  # 20 x 3
+    ma = compiled.memory_analysis()
+    # Weights and both Adam moments (donated, so counted once) plus the
+    # step's temporaries: within the chip, and not by much.
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.slow  # ~90 s
+def test_b1_train_step_partitions_over_four_chips(v5e, kernels_as_on_chip):
+    """fsdp=2 x tp=2: XLA cannot partition a Mosaic kernel by itself, so
+    the model runs the flash kernel per shard (llama._flash_per_shard)."""
+    from ray_tpu.parallel.sharding import named_sharding
+
+    mesh = _mesh(v5e, fsdp=2, tp=2)
+    step, state, rules, shape = _smoke_train_step(mesh)
+    state = jax.tree.map(
+        lambda x, s: _on(s, x.shape, x.dtype), state,
+        named_sharding(mesh, rules.tree_specs(state)))
+    data = NamedSharding(mesh, batch_spec())
+    batch = {k: _on(data, shape, jnp.int32) for k in ("tokens", "targets")}
+    with jax.set_mesh(mesh):
+        compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 60
+    assert chip_smoke.count_collectives(text)["all-gather"] > 0
+    ma = compiled.memory_analysis()  # bytes on EACH device
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES / 2
+
+
+def _smoke_engine_args(v5e):
+    from ray_tpu.models import llama_init
+    from ray_tpu.models.paged import init_adapter_pool, init_paged_pools
+    from ray_tpu.serve.engine import EngineConfig, _b1_config
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    place = functools.partial(
+        jax.tree.map, lambda x: _on(one, x.shape, x.dtype))
+    cfg, ec = _b1_config(), EngineConfig(**chip_smoke.SERVE_ENGINE)
+    params = place(jax.eval_shape(
+        lambda: llama_init(cfg, jax.random.PRNGKey(0))))
+    pools = place(jax.eval_shape(
+        lambda: init_paged_pools(cfg, ec.pool_pages, ec.page_size)))
+    adapters = place(jax.eval_shape(
+        lambda: init_adapter_pool(cfg, ec.max_adapters, ec.lora_rank)))
+    key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+    return cfg, ec, params, pools, adapters, key, functools.partial(_on, one)
+
+
+@pytest.mark.slow  # ~10 s
+def test_b1_decode_step_fits_one_chip(v5e):
+    from ray_tpu.models.paged import paged_decode_step
+
+    cfg, ec, params, pools, adapters, key, on = _smoke_engine_args(v5e)
+    b = ec.batch_slots
+    ma = paged_decode_step.lower(
+        cfg, params, pools, adapters, on((b,), jnp.int32),
+        on((b, ec.pages_per_seq), jnp.int32), on((b,), jnp.int32),
+        on((b,), bool), on((b,), jnp.float32), on((b,), jnp.int32), key,
+    ).compile().memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.slow  # ~15 s each
+@pytest.mark.parametrize("prefix", [False, True], ids=["cold", "suffix"])
+def test_b1_largest_prefill_bucket_fits_one_chip(v5e, prefix):
+    from ray_tpu.models.paged import paged_prefill, paged_prefill_prefix
+
+    cfg, ec, params, pools, adapters, key, on = _smoke_engine_args(v5e)
+    scalar = on((), jnp.int32)
+    toks = on((1, ec.prefill_buckets()[-1]), jnp.int32)
+    table = on((ec.pages_per_seq,), jnp.int32)
+    if prefix:
+        lowered = paged_prefill_prefix.lower(
+            cfg, params, pools, adapters, toks, scalar, scalar, table,
+            scalar, on((), jnp.float32), key)
+    else:
+        lowered = paged_prefill.lower(
+            cfg, params, pools, adapters, toks, scalar, table, scalar,
+            on((), jnp.float32), key)
+    ma = lowered.compile().memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES

@@ -201,6 +201,24 @@ def test_device_trace_busy_is_typed(tmp_path):
     assert profiling.active_trace_dir() is None
 
 
+def test_device_trace_passes_the_host_tracer_level(tmp_path, monkeypatch):
+    """jax 0.9's ProfileOptions takes no constructor arguments: the level
+    is set on the object and reaches start_trace (it used to be dropped
+    behind an except)."""
+    import jax
+
+    from ray_tpu.util import profiling
+
+    seen = {}
+    monkeypatch.setattr(
+        jax.profiler, "start_trace",
+        lambda log_dir, **kw: seen.update(kw, log_dir=log_dir))
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    with profiling.device_trace(str(tmp_path), host_tracer_level=3):
+        pass
+    assert seen["profiler_options"].host_tracer_level == 3
+
+
 # ---------------------------------------------------------------------------
 # Engine integration: records carry the full schema, slo_signals gains
 # stall/jitter, controller reacts to stall pressure.

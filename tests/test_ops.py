@@ -86,6 +86,37 @@ class TestFlashAttention:
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
 
+    @pytest.mark.parametrize("q_offset", [0, 64])
+    def test_gqa_backward_in_q_chunks(self, monkeypatch, q_offset):
+        """dK/dV accumulate over q chunks and over the GQA group's heads
+        (the two innermost grid dims), as they do past DKV_CHUNK_ROWS at
+        long sequences: 32-row chunks forced onto 128 tokens."""
+        from ray_tpu.ops import attention as att
+
+        monkeypatch.setattr(att, "DKV_CHUNK_ROWS", 32)
+        assert att._dkv_chunk(128, 16) == 32
+        q, k, v = _qkv(B=1, H=4, Hkv=2, S=128, D=32, seed=9)
+
+        def loss(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v) ** 2)
+
+        flash_fn = lambda q, k, v: _flash(
+            q, k, v, q.shape[-1] ** -0.5, True, q_offset, 16, 32, True)
+        ref_fn = lambda q, k, v: mha_reference(
+            q, k, v, causal=True, q_offset=q_offset)
+        g1 = jax.grad(loss(flash_fn), argnums=(0, 1, 2))(q, k, v)
+        g2 = jax.grad(loss(ref_fn), argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(g1, g2):
+            np.testing.assert_allclose(a, b, atol=5e-4, rtol=5e-4)
+
+    def test_dkv_chunk_divides_the_sequence(self):
+        from ray_tpu.ops.attention import _dkv_chunk
+
+        assert _dkv_chunk(2048, 512) == 2048   # b1: the whole sequence
+        assert _dkv_chunk(8192, 512) == 2048
+        assert _dkv_chunk(3072, 512) == 1536   # largest divisor <= 2048
+        assert _dkv_chunk(128, 128) == 128
+
     def test_dispatch_cpu_fallback(self):
         q, k, v = _qkv(S=64)
         out = flash_attention(q, k, v)  # CPU -> reference path
@@ -101,7 +132,7 @@ class TestRingAttention:
         q, k, v = _qkv(B=B, H=H, S=S, D=D, seed=3)
         ref = mha_reference(q, k, v, causal=True)
 
-        from ray_tpu.parallel.pipeline import shard_map  # version-tolerant
+        from ray_tpu.parallel.pipeline import shard_map
 
         ring = shard_map(
             functools.partial(ring_attention, axis_name="sp", causal=True),
@@ -117,7 +148,7 @@ class TestRingAttention:
     def test_grad_flows(self):
         mesh = make_mesh(MeshConfig(fsdp=1, sp=8))
         q, k, v = _qkv(B=1, H=2, S=128, D=32)
-        from ray_tpu.parallel.pipeline import shard_map  # version-tolerant
+        from ray_tpu.parallel.pipeline import shard_map
 
         ring = shard_map(
             functools.partial(ring_attention, axis_name="sp", causal=True),
@@ -146,7 +177,7 @@ class TestRingAttention:
         B, H, S, D = 1, 2, 256, 32
         q, k, v = _qkv(B=B, H=H, S=S, D=D, seed=5)
         ref = mha_reference(q, k, v, causal=True)
-        from ray_tpu.parallel.pipeline import shard_map  # version-tolerant
+        from ray_tpu.parallel.pipeline import shard_map
 
         mesh4 = _Mesh(_np.array(jax.devices()[:4]).reshape(1, 1, 1, 4),
                       ("dp", "fsdp", "tp", "sp"))
@@ -169,7 +200,7 @@ class TestRingAttention:
         mesh4 = _Mesh(_np.array(jax.devices()[:4]).reshape(1, 1, 1, 4),
                       ("dp", "fsdp", "tp", "sp"))
         q, k, v = _qkv(B=1, H=2, S=256, D=32, seed=6)
-        from ray_tpu.parallel.pipeline import shard_map  # version-tolerant
+        from ray_tpu.parallel.pipeline import shard_map
 
         ring = shard_map(
             functools.partial(ring_attention, axis_name="sp", causal=True,
@@ -204,7 +235,7 @@ class TestRingAttention:
         q = jax.random.normal(kq, (1, 4, 256, 32), jnp.float32)
         k = jax.random.normal(kk, (1, 2, 256, 32), jnp.float32)
         v = jax.random.normal(kv, (1, 2, 256, 32), jnp.float32)
-        from ray_tpu.parallel.pipeline import shard_map  # version-tolerant
+        from ray_tpu.parallel.pipeline import shard_map
 
         ring = shard_map(
             functools.partial(ring_attention, axis_name="sp", causal=True,

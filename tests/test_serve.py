@@ -46,6 +46,42 @@ def test_deploy_and_route_across_replicas(rt):
     assert st["Echo"]["running_replicas"] == 2
 
 
+def test_replicas_construct_in_parallel_and_run_waits_for_them(rt):
+    """serve.run waits on the constructors (a model replica compiles for
+    minutes), not on a constant inside the controller, and replicas of one
+    deployment construct side by side."""
+    @serve.deployment(num_replicas=2)
+    class Slow:
+        def __init__(self):
+            time.sleep(1.5)
+            self.t = time.time()
+
+        def __call__(self):
+            return self.t
+
+    t0 = time.time()
+    handle = serve.run(Slow.bind())
+    assert time.time() - t0 < 2.9  # two 1.5 s constructors, side by side
+    assert serve.status()["Slow"]["running_replicas"] == 2
+    assert handle.remote().result() > t0
+
+
+def test_failing_constructor_fails_run_with_its_error(rt):
+    @serve.deployment
+    class Broken:
+        def __init__(self):
+            raise ValueError("no chip for this replica")
+
+        def __call__(self):
+            return 1
+
+    t0 = time.time()
+    with pytest.raises(RuntimeError, match="no chip for this replica"):
+        serve.run(Broken.bind(), timeout=60)
+    assert time.time() - t0 < 30  # the error, not the timeout
+    serve.delete("Broken")  # or the controller keeps retrying it
+
+
 def test_rolling_update_changes_code(rt):
     @serve.deployment(num_replicas=1)
     def v1(x):

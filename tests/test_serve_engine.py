@@ -193,6 +193,20 @@ def test_prefill_bucket_wider_than_worst_case_footprint():
         eng.shutdown()
 
 
+def test_llm_server_refuses_the_cpu_on_a_host_with_chips(monkeypatch):
+    """Serving a real model from the CPU backend on a TPU host is an error,
+    not a default; `tiny` (tests, demos) serves anywhere, and llm_app hands
+    the chip request to the deployment's ordinary actor options."""
+    from ray_tpu.serve.engine import LLMServer, llm_app
+
+    monkeypatch.setenv("RT_TPU_CHIPS", "4")
+    with pytest.raises(RuntimeError, match=r"ray_actor_options=.*num_tpus"):
+        LLMServer(model="b1")  # raises before a single weight is made
+    app = llm_app(model="b1", ray_actor_options={"num_tpus": 1})
+    assert app.deployment.to_spec(app)["resources"] == {"TPU": 1}
+    assert llm_app().deployment.to_spec(llm_app())["resources"] == {}
+
+
 def test_whole_request_mode_gang_admission():
     """The baseline mode admits only into an EMPTY batch: a request
     arriving mid-gang waits for the gang to fully drain."""

@@ -145,7 +145,9 @@ def test_flusher_config_knobs():
     assert cfg.metrics_history_min_interval_s > 0
 
 
-def test_train_telemetry_cpu_mfu():
+def test_train_telemetry_cpu_reports_no_mfu():
+    """On the CPU backend there is no peak on record: step time and
+    tokens/sec are derived, MFU is not reported against an invented one."""
     import jax.numpy as jnp
 
     from ray_tpu.train import telemetry
@@ -159,8 +161,27 @@ def test_train_telemetry_cpu_mfu():
     out = tel.record_step(0.01, tokens=512)
     assert out["step_time_s"] == pytest.approx(0.01)
     assert out["tokens_per_sec"] == pytest.approx(51200.0)
-    assert math.isfinite(out["mfu"]) and out["mfu"] > 0
-    assert telemetry.device_peak_flops() > 0  # CPU stub is finite
+    assert "mfu" not in out
+    assert telemetry.device_peak_flops() is None
+    assert tel.peak_flops_total() is None
+
+
+def test_train_telemetry_mfu_against_the_peak_table():
+    """MFU = flops / step seconds / (chips x the table's peak for the
+    device_kind); an accelerator the table does not know raises."""
+    import types
+
+    from ray_tpu.train import telemetry
+
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert telemetry.device_peak_flops(v5e) == 197e12
+    tel = telemetry.TrainTelemetry(
+        flops_per_step=197e12, num_devices=4,
+        peak_flops=telemetry.device_peak_flops(v5e))
+    assert tel.record_step(0.5)["mfu"] == pytest.approx(0.5)
+    unknown = types.SimpleNamespace(platform="tpu", device_kind="TPU v9")
+    with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+        telemetry.device_peak_flops(unknown)
 
 
 def test_train_telemetry_step_context():
@@ -204,7 +225,8 @@ def test_session_report_augments_goodput():
     assert "step_time_s" not in results[0]["metrics"]  # no previous round
     m1 = results[1]["metrics"]
     assert m1["step_time_s"] == 123.0  # user key wins
-    assert m1["tokens_per_sec"] > 0 and math.isfinite(m1["mfu"])
+    assert m1["tokens_per_sec"] > 0
+    assert "mfu" not in m1  # CPU backend: no peak on record
     m2 = results[2]["metrics"]
     assert 0 < m2["step_time_s"] < 60
 
@@ -232,7 +254,8 @@ def test_cluster_telemetry_smoke(tel_cluster):
     """The acceptance scenario: a few tasks + one jitted train step; then
     the history endpoint has >=2 timestamped samples for a built-in
     scheduler metric, /metrics exposes a spec-compliant histogram, and
-    ray_tpu_train_mfu is finite."""
+    ray_tpu_train_mfu (against an explicit peak: the CPU backend has none
+    on record) is finite."""
     import jax
     import jax.numpy as jnp
 
@@ -252,7 +275,7 @@ def test_cluster_telemetry_smoke(tel_cluster):
     x = jnp.ones((64, 64))
     flops = telemetry.flops_per_step(step, x) \
         or telemetry.transformer_flops(64 * 64, 64)
-    tel = telemetry.TrainTelemetry(flops_per_step=flops)
+    tel = telemetry.TrainTelemetry(flops_per_step=flops, peak_flops=1e12)
     with tel.step(tokens=64 * 64):
         step(x).block_until_ready()
     assert math.isfinite(tel.last["mfu"])
