@@ -198,6 +198,7 @@ def _flash_fwd(q, k, v, sm_scale, causal, q_offset, block_q, block_k, interpret)
             jax.ShapeDtypeStruct((B, H, Sq, LSE_LANES), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q_off, q, k, v)
     return out, lse
 
@@ -355,6 +356,7 @@ def _flash_bwd(res, g, *, sm_scale, causal, q_offset, block_q, block_k,
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q_off, q, k, v, do, lse, delta)
 
     # dk/dv: grid over (kv head, k block) with the GQA group's q heads and
@@ -394,6 +396,7 @@ def _flash_bwd(res, g, *, sm_scale, causal, q_offset, block_q, block_k,
             jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q_off, q, k, v, do, lse, delta)
     return dq, dk, dv
 

@@ -43,6 +43,7 @@ def rms_norm_pallas(x: jax.Array, w: jax.Array, eps: float = 1e-6,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(x2.shape, x.dtype),
         interpret=interpret,
+        name="rms_norm",
     )(x2, w)
     return out.reshape(orig_shape)
 

@@ -152,7 +152,9 @@ def cmd_status(args) -> int:
             for row in _engine_rows(engines, devmem):
                 print(f"  engine {row['engine']}: slots {row['slots']}  "
                       f"queued {row['queued']}  pages {row['pages']}  "
-                      f"stall {row['stall%']}%  "
+                      f"stall {row['stall%']}%  host {row['host%']}%  "
+                      f"queue wait {row['qwait_ms']} ms  "
+                      f"loop {row['loop%']}%  compiles {row['compiles']}  "
                       f"adapters pinned {row['adapters']}"
                       + (f"  hbm {row['hbm']}" if row["hbm"] else ""))
         except Exception:
@@ -171,8 +173,25 @@ def _engine_rows(engines, devmem_items) -> list:
     for e in engines:
         recs = e.get("records") or []
         latest = e.get("latest") or {}
-        wall = sum(float(r.get("wall_s") or 0) for r in recs)
-        stall = sum(float(r.get("stall_s") or 0) for r in recs)
+
+        def total(*keys, recs=recs):
+            return sum(float(r.get(k) or 0) for r in recs for k in keys)
+
+        wall, stall = total("wall_s"), total("stall_s")
+        # The loop's own account (records of an engine that keeps one):
+        # the host's share of a step, how long a request waited for
+        # admission to look at it, the share of the loop thread's time
+        # the retained records tile (under 100: records were lost), and
+        # the programs JAX built inside the window's steps.
+        loop = total("wall_s", "between_s")
+        host = total("between_s", "upload_s", "dispatch_s", "emit_s")
+        waits = sorted(e["queue_s"] for r in recs
+                       for e in r.get("first_tokens") or ())
+        accounted = "t0" in latest
+        if accounted:
+            first = recs[0]
+            span = latest["t0"] + latest["wall_s"] - (
+                first["t0"] - first["between_s"] - first["idle_s"])
         try:
             pid = int(str(e.get("engine", "")).split(".", 1)[0])
         except ValueError:
@@ -185,6 +204,13 @@ def _engine_rows(engines, devmem_items) -> list:
                      f"{latest.get('slots', 0)}",
             "queued": latest.get("queued", 0),
             "stall%": f"{100.0 * stall / wall:.1f}" if wall > 0 else "0.0",
+            "host%": f"{100.0 * host / loop:.1f}"
+                     if accounted and loop > 0 else "-",
+            "qwait_ms": f"{1e3 * waits[len(waits) // 2]:.1f}"
+                        if waits else "-",
+            "loop%": f"{100.0 * (loop + total('idle_s')) / span:.1f}"
+                     if accounted and span > 0 else "-",
+            "compiles": int(total("compiles")),
             "pages": f"{latest.get('pages_used', 0)}u/"
                      f"{latest.get('pages_free', 0)}f",
             "adapters": latest.get("adapter_pins", 0),
@@ -332,8 +358,8 @@ def _render_top(cl) -> str:
         "",
         _format_table(
             _engine_rows(engines, devmem),
-            ["engine", "slots", "queued", "stall%", "pages", "adapters",
-             "hbm", "tenants"],
+            ["engine", "slots", "queued", "stall%", "host%", "qwait_ms",
+             "loop%", "compiles", "pages", "adapters", "hbm", "tenants"],
             empty="(no engines reporting — flight recorder off or no "
                   "serve traffic yet)",
         ),

@@ -34,6 +34,7 @@ import dataclasses
 import itertools
 import math
 import queue as _queue
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
@@ -44,6 +45,19 @@ import numpy as np
 #: ``"<pid>.<seq>"`` so the head's per-engine rings stay distinct when a
 #: process hosts several engines (bench harnesses, tests).
 _ENGINE_SEQ = itertools.count()
+
+#: Phases of the loop.  Each is one ``util.profiling.annotation``: a host
+#: span on the profiler's clock under this name, and (where the table in
+#: ``_record_step`` gives it a key) seconds on the step record.
+PH_ADMIT = "rt:engine/admit"        # the locked section of _loop
+PH_IDLE = "rt:engine/idle"          # _wake.wait with nothing to do
+PH_PREFILL = "rt:engine/prefill"    # all of _prefill, one request
+PH_PREFILL_WAIT = "rt:engine/prefill_wait"  # blocked in int(first)
+PH_UPLOAD = "rt:engine/upload"      # host mirrors to the device
+PH_DISPATCH = "rt:engine/dispatch"  # enqueue of the decode program
+PH_READBACK = "rt:engine/readback"  # blocked on the step's tokens
+PH_EMIT = "rt:engine/emit"          # the per-slot pass after it
+PH_RECORD = "rt:engine/record"      # _record_step
 
 
 class EngineOverloadedError(Exception):
@@ -122,8 +136,7 @@ class _Request:
         "out_q", "cancelled", "finished", "pages", "page_table",
         "length", "generated", "submit_t", "first_token_t",
         "last_token_t", "itls", "slot",
-        "trace_ctx", "submit_wall", "admit_wall", "first_wall",
-        "prefill_bucket",
+        "trace_ctx", "submit_wall", "admit_t",
         "tenant", "weight", "adapter", "adapter_slot", "match",
         "cow_ref", "cache_hit_len",
     )
@@ -142,7 +155,14 @@ class _Request:
         self.page_table: Optional[np.ndarray] = None
         self.length = 0
         self.generated = 0
+        # Stage stamps, all time.perf_counter(): submit, admission into a
+        # slot, first token.  The step record's first_tokens entry and the
+        # request's spans are both computed from these; submit_wall is the
+        # one wall-clock anchor that places the spans beside other
+        # processes' (see wall()).
         self.submit_t = time.perf_counter()
+        self.submit_wall = time.time()
+        self.admit_t = 0.0
         self.first_token_t: Optional[float] = None
         self.last_token_t: Optional[float] = None
         # Engine-side inter-token latencies: measured at emission, so
@@ -150,15 +170,8 @@ class _Request:
         self.itls: List[float] = []
         self.slot = -1
         # Tracing: the submitter's span context (None when the request
-        # arrived untraced/unsampled — then the engine emits nothing) plus
-        # wall-clock transition stamps for the queue/prefill/decode spans
-        # (submit_t/first_token_t are perf_counter and can't be shared
-        # with wall-clocked spans from other processes).
+        # arrived untraced/unsampled — then the engine emits nothing).
         self.trace_ctx: Optional[Dict[str, str]] = None
-        self.submit_wall = 0.0
-        self.admit_wall = 0.0
-        self.first_wall = 0.0
-        self.prefill_bucket = 0
         # Multi-tenant plane: fair-queue identity, the adapter this
         # sequence decodes with (None = base model), and the prefix-cache
         # plan pinned at admission (match + the extra COW-source ref held
@@ -170,6 +183,10 @@ class _Request:
         self.match = None
         self.cow_ref: Optional[int] = None
         self.cache_hit_len = 0
+
+    def wall(self, t: float) -> float:
+        """The wall clock at this request's perf_counter stamp ``t``."""
+        return self.submit_wall + (t - self.submit_t)
 
 
 class TokenStream:
@@ -356,8 +373,15 @@ class InferenceEngine:
         self._step_walls = collections.deque(maxlen=max(16, cfg.step_window))
         self._stall_events = collections.deque(
             maxlen=max(16, cfg.step_window))  # (wall_time, stall_s)
-        self._pc_hits_total = 0
         self._evicted_total = 0
+        # The loop's own time account (rides on the step record, so
+        # step_record switches it): seconds by phase between two records,
+        # the first_tokens entries of the step being run, and where the
+        # previous record's step ended (t0 + wall_s, as recorded).
+        self._gap_acct: Optional[Dict[str, float]] = (
+            {} if cfg.step_record else None)
+        self._first_tokens: List[Dict[str, Any]] = []  # fresh each step
+        self._prev_end = round(time.perf_counter(), 6)
         # Device-memory attribution: the engine owns the big allocations,
         # so it names them for util/devmem snapshots.  Weights bytes are
         # static; pool/adapter lambdas chase the live arrays (donation
@@ -428,7 +452,6 @@ class InferenceEngine:
             from ..util import tracing
 
             req.trace_ctx = tracing.context_for_submit()
-            req.submit_wall = time.time()
             self._queues.setdefault(tenant, []).append(req)
             victim: Optional[_Request] = None
             if self._queued_total() > self.config.max_queue:
@@ -624,38 +647,64 @@ class InferenceEngine:
         return self._run_on_loop(
             lambda: self._cache.clear(self.allocator))
 
-    def warmup(self) -> None:
+    def warmup(self) -> List[Dict[str, Any]]:
         """Compile the decode program and every prefill bucket up front
         (one dummy sequence per bucket) so serving traffic never pays a
-        trace."""
+        trace.  Returns one row a program warmed: its wall seconds and,
+        from JAX's own monitoring events (``devmem.compile_totals``
+        differenced around it), the seconds tracing, lowering and in the
+        backend's compile, and whether the persistent cache served it."""
+        from ..util import devmem
+
+        rows: List[Dict[str, Any]] = []
+
+        def warm(program: str, bucket: Optional[int], fn) -> None:
+            before, t0 = devmem.compile_totals(), time.perf_counter()
+            fn()
+            wall_s = time.perf_counter() - t0
+            d = {k: v - before[k]
+                 for k, v in devmem.compile_totals().items()}
+            rows.append({
+                "program": program, "bucket": bucket,
+                "wall_s": round(wall_s, 3),
+                "trace_s": round(d["trace_s"], 3),
+                "lower_s": round(d["lower_s"], 3),
+                "compile_s": round(d["compile_s"], 3),
+                "cache_hit": bool(d["cache_hits"]
+                                  and not d["cache_misses"]),
+                "cache_read_s": round(d["cache_read_s"], 3),
+            })
+
+        def run(prompt, max_new: int) -> None:
+            for _ in self.submit(prompt, max_new_tokens=max_new):
+                pass
+
         # A fresh engine's warmup is a legitimate compile phase: stand
         # the sentinel down while it traces (a previous engine in this
         # process may have armed with different geometry), re-arm below.
         from ..devtools import jitguard
         jitguard.disarm()
-        # max_new_tokens=2: the first token comes from PREFILL — the
-        # decode program only compiles once a second token is needed.
-        probe = self.submit([1], max_new_tokens=2)
-        for _ in probe:
-            pass
-        for bucket in self.config.prefill_buckets()[1:]:
+        buckets = self.config.prefill_buckets()
+        # The first token comes from PREFILL — the decode program only
+        # compiles once a second token is needed.
+        warm("prefill", buckets[0], lambda: run([1], 1))
+        warm("decode", None, lambda: run([1], 2))
+        for bucket in buckets[1:]:
             n = min(bucket, self.config.max_prompt_len)
             if self._cache is not None:
                 # The previous bucket's ones-prompt cached its pages; a
                 # hit here would route to the suffix path and skip the
                 # cold prefill compile this bucket exists to pay.
                 self.clear_prefix_cache()
-            s = self.submit(np.ones((n,), np.int32), max_new_tokens=1)
-            for _ in s:
-                pass
+            warm("prefill", bucket,
+                 lambda n=n: run(np.ones((n,), np.int32), 1))
         if self._cache is not None \
                 and self.config.max_prompt_len >= self.config.page_size:
             # Re-run the largest prompt: it hits the pages the line above
             # cached, compiling the COW copy + suffix-prefill path too.
             n = self.config.max_prompt_len
-            for _ in self.submit(np.ones((n,), np.int32),
-                                 max_new_tokens=1):
-                pass
+            warm("prefix_hit", n,
+                 lambda: run(np.ones((n,), np.int32), 1))
             self.clear_prefix_cache()
             # The re-run traces the prefix path only for the ONE suffix
             # bucket (and COW divergence) its geometry happens to hit —
@@ -687,21 +736,23 @@ class InferenceEngine:
             import functools
 
             compile_s = self.config.stream_timeout_s
-            for b in self.config.prefill_buckets():
-                self._run_on_loop(
-                    functools.partial(_warm_suffix_bucket, b), compile_s)
-            self._run_on_loop(_warm_cow_copy, compile_s)
+            for b in buckets:
+                warm("prefill_prefix", b, lambda b=b: self._run_on_loop(
+                    functools.partial(_warm_suffix_bucket, b), compile_s))
+            warm("copy_page", None,
+                 lambda: self._run_on_loop(_warm_cow_copy, compile_s))
         # Compile the adapter-load path too (zero payload into the zero
         # slot): the first real LoRA registration after warmup must be an
         # execution, not a fresh trace.
-        self._run_on_loop(self.adapter_pool.warmup_compile,
-                          self.config.stream_timeout_s)
+        warm("adapter_load", None, lambda: self._run_on_loop(
+            self.adapter_pool.warmup_compile, self.config.stream_timeout_s))
         # Recompile sentinel (RT_DEBUG_JIT=1): freeze every program's
         # trace count — decode, each prefill bucket, the COW/suffix path,
         # adapter loads — so any post-warmup trace raises RecompileError
         # at the stray call site instead of silently paying a compile in
         # the step loop.  No-op when the env flag is off.
         jitguard.arm()
+        return rows
 
     # ---------------------------------------------------------------- loop
 
@@ -777,7 +828,7 @@ class InferenceEngine:
             w = max(req.weight, 1e-9)
             self._vtime[tenant] = v_start + self._req_cost(req) / w
             self._vclock = v_start
-            req.admit_wall = time.time()
+            req.admit_t = time.perf_counter()
             req.pages = shared + pages
             req.match = match
             if match is not None and match.cow_src is not None:
@@ -811,10 +862,10 @@ class InferenceEngine:
         # Decode-lifetime span: first token -> eviction.  Token count,
         # TTFT, and mean ITL ride as attrs so per-request latency
         # attribution is derivable from the span tree alone.
-        now_wall = time.time()
         self._emit_req_span(
-            req, "engine:decode", req.first_wall or req.admit_wall,
-            now_wall, tokens=req.generated, reason=reason,
+            req, "engine:decode",
+            req.wall(req.first_token_t or req.admit_t),
+            time.time(), tokens=req.generated, reason=reason,
             ttft_s=round(req.first_token_t - req.submit_t, 6)
             if req.first_token_t is not None else None,
             mean_itl_s=round(sum(req.itls) / len(req.itls), 6)
@@ -858,10 +909,42 @@ class InferenceEngine:
         prefill program and emit its first token (TTFT point).  A
         prefix-cache hit copies the COW page (mid-page divergence) and
         prefills only the uncached suffix."""
+        from ..util.profiling import annotation
+
+        # This request's own account: the prefill's phases go on its
+        # first_tokens entry, not among the step's.
+        acct: Dict[str, float] = {}
+        with annotation(PH_PREFILL, acct) as phase:
+            bucket = self._prefill_body(req, acct)
+        n, prefix_len = int(req.prompt.size), int(req.cache_hit_len)
+        entry = {
+            "queue_s": round(req.admit_t - req.submit_t, 6),
+            "prefill_s": round(acct[PH_PREFILL], 6),
+            "prefill_wait_s": round(acct[PH_PREFILL_WAIT], 6),
+            "ttft_s": round(req.first_token_t - req.submit_t, 6),
+            "prompt": n, "bucket": bucket, "cached": prefix_len,
+        }
+        self._first_tokens.append(entry)
+        # The request's spans, from the same stamps as the entry: queue
+        # wait (submit -> admission into a batch slot) and the prefill;
+        # bucket and cached-prefix attrs make padding waste and cache
+        # effectiveness readable straight off the trace.
+        if req.trace_ctx is not None:
+            self._emit_req_span(req, "engine:queue", req.submit_wall,
+                                req.submit_wall + entry["queue_s"],
+                                prompt_len=n)
+            start = req.wall(phase.t0)
+            self._emit_req_span(req, "engine:prefill", start,
+                                start + entry["prefill_s"], bucket=bucket,
+                                prompt_len=n, cached_prefix=prefix_len)
+
+    def _prefill_body(self, req: _Request, acct: Dict[str, float]) -> int:
+        """The work of :meth:`_prefill`; returns the padded length run."""
         import jax.numpy as jnp
 
         from ..models.paged import (copy_page, paged_prefill,
                                     paged_prefill_prefix)
+        from ..util.profiling import annotation
 
         # Admission reserved (pinned) the slot; materialize the weights
         # if this is the adapter's first use since eviction.
@@ -871,11 +954,6 @@ class InferenceEngine:
             self._m_adapter_evict.inc(ev - self._adapter_evictions_seen)
             self._adapter_evictions_seen = ev
         n = req.prompt.size
-        # Queue-wait span (submit -> admission into a batch slot).
-        self._emit_req_span(req, "engine:queue", req.submit_wall,
-                            req.admit_wall or req.submit_wall,
-                            prompt_len=int(n))
-        pf_start = time.time()
         prefix_len = req.cache_hit_len
         aid = jnp.asarray(req.adapter_slot, jnp.int32)
         adapters = self.adapter_pool.arrays
@@ -892,7 +970,6 @@ class InferenceEngine:
                 req.cow_ref = None
             suffix = req.prompt[prefix_len:]
             s_pad = self._bucket_len(suffix.size)
-            req.prefill_bucket = s_pad
             toks = np.zeros((1, s_pad), np.int32)
             toks[0, :suffix.size] = suffix
             first, self._d_key, self.pools = paged_prefill_prefix(
@@ -902,11 +979,9 @@ class InferenceEngine:
                 aid, jnp.asarray(req.temperature, jnp.float32),
                 self._d_key)
             self._m_pc_hits.inc(1)
-            self._pc_hits_total += 1
             self._m_prefill.inc(suffix.size)  # only the work actually done
         else:
             s_pad = self._bucket_len(n)
-            req.prefill_bucket = s_pad
             toks = np.zeros((1, s_pad), np.int32)
             toks[0, :n] = req.prompt
             first, self._d_key, self.pools = paged_prefill(
@@ -915,7 +990,8 @@ class InferenceEngine:
                 jnp.asarray(req.page_table), aid,
                 jnp.asarray(req.temperature, jnp.float32), self._d_key)
             self._m_prefill.inc(n)
-        first = int(first)  # rt-sync-ok: THE prefill readback — the first token must reach the host to stream it
+        with annotation(PH_PREFILL_WAIT, acct):
+            first = int(first)  # rt-sync-ok: THE prefill readback — the first token must reach the host to stream it
         # Cache every fully-frozen prompt page (decode appends past the
         # prompt, so pages wholly inside it never change again).
         if self._cache is not None:
@@ -931,13 +1007,6 @@ class InferenceEngine:
         req.length = n
         req.first_token_t = now
         req.last_token_t = now
-        req.first_wall = time.time()
-        # Prefill span: bucket + cached-prefix attrs make padding waste
-        # and cache effectiveness readable straight off the trace.
-        self._emit_req_span(req, "engine:prefill", pf_start, req.first_wall,
-                            bucket=int(req.prefill_bucket),
-                            prompt_len=int(n),
-                            cached_prefix=int(prefix_len))
         ttft = now - req.submit_t
         self._m_ttft.observe(ttft)
         self._ttft_recent.append(ttft)
@@ -950,6 +1019,7 @@ class InferenceEngine:
         self._adapter_slots[slot] = req.adapter_slot
         self._dirty = True
         self._emit_token(req, first)
+        return int(s_pad)
 
     def _emit_token(self, req: _Request, token: int) -> None:
         req.generated += 1
@@ -974,7 +1044,7 @@ class InferenceEngine:
                 continue
             self._emit_req_span(
                 req, "engine:decode",
-                req.first_wall or req.admit_wall or req.submit_wall,
+                req.wall(req.first_token_t or req.admit_t or req.submit_t),
                 now_wall, tokens=req.generated, reason="error",
                 error=repr(exc)[:200])
             self.allocator.free(req.pages)
@@ -1007,8 +1077,10 @@ class InferenceEngine:
             self.config.page_size)
 
     def _loop(self) -> None:
+        from ..util.profiling import annotation
+
         while True:
-            with self._lock:
+            with annotation(PH_ADMIT), self._lock:
                 if self._stop:
                     break
                 control, self._control = self._control, []
@@ -1041,7 +1113,8 @@ class InferenceEngine:
                     self._m_active.set(0, tags=self._pid_tags)
                     self._m_pages.set(self.allocator.used_count,
                                       tags=self._pid_tags)
-                    self._wake.wait(timeout=0.05)
+                    with annotation(PH_IDLE, self._gap_acct):
+                        self._wake.wait(timeout=0.05)
                     continue
             # Model work runs OUTSIDE the lock: pools/slot arrays belong
             # to this thread; submit() only appends to the wait queue.
@@ -1074,16 +1147,21 @@ class InferenceEngine:
         import jax.numpy as jnp
 
         from ..models.paged import paged_decode_step, trace_counts
+        from ..util import devmem
+        from ..util.profiling import annotation
 
         # Flight recorder entry state: step wall, admission-stall span,
-        # and per-step deltas come from host counters only — no device
-        # sync, no lock beyond what the loop already holds.
+        # the step's phase account and per-step deltas come from host
+        # counters only — no device sync, no lock beyond what the loop
+        # already holds.
         rec_on = self.config.step_record
         t0 = time.perf_counter()
+        acct: Optional[Dict[str, float]] = {} if rec_on else None
+        self._first_tokens = []
         stall_s = 0.0
         evicted0 = self._evicted_total
         shed0 = self.shed
-        pc_hits0 = self._pc_hits_total
+        compiles0 = devmem.compile_count()
         traces0 = trace_counts() if rec_on else None
         for req in admitted:
             pf0 = time.perf_counter()
@@ -1091,58 +1169,74 @@ class InferenceEngine:
             stall_s += time.perf_counter() - pf0
         if not any(s is not None for s in self.slots):
             if rec_on and admitted:
-                self._record_step(t0, stall_s, len(admitted), evicted0,
-                                  shed0, pc_hits0, traces0, decoded=False)
+                with annotation(PH_RECORD):
+                    self._record_step(t0, acct, stall_s, len(admitted),
+                                      evicted0, shed0, compiles0, traces0,
+                                      decoded=False)
             return
         self.step_count += 1
         if self._dirty:
             # Membership changed since the last step: re-upload the
             # host mirrors.  Steady-state decode skips this — tokens,
             # lengths, and the PRNG key advance on device.
-            self._d_tokens = jnp.asarray(self._tokens)
-            self._d_page_tables = jnp.asarray(self._page_tables)
-            self._d_seq_lens = jnp.asarray(self._seq_lens)
-            self._d_active = jnp.asarray(self._active)
-            self._d_temps = jnp.asarray(self._temps)
-            self._d_adapter_slots = jnp.asarray(self._adapter_slots)
-            self._dirty = False
-        (self._d_tokens, self._d_seq_lens, self._d_key,
-         self.pools) = paged_decode_step(
-            self.model_config, self.params, self.pools,
-            self.adapter_pool.arrays,
-            self._d_tokens, self._d_page_tables, self._d_seq_lens,
-            self._d_active, self._d_temps, self._d_adapter_slots,
-            self._d_key)
-        toks = np.asarray(self._d_tokens)  # rt-sync-ok: THE decode-step readback — one batched token fetch per step
-        now = time.perf_counter()
-        for slot, req in enumerate(self.slots):
-            if req is None:
-                continue
-            self._seq_lens[slot] += 1
-            req.length += 1
-            self._tokens[slot] = toks[slot]
-            if req.last_token_t is not None:
-                itl = now - req.last_token_t
-                req.itls.append(itl)
-                self._m_itl.observe(itl)
-            req.last_token_t = now
-            self._emit_token(req, int(toks[slot]))
+            with annotation(PH_UPLOAD, acct):
+                self._d_tokens = jnp.asarray(self._tokens)
+                self._d_page_tables = jnp.asarray(self._page_tables)
+                self._d_seq_lens = jnp.asarray(self._seq_lens)
+                self._d_active = jnp.asarray(self._active)
+                self._d_temps = jnp.asarray(self._temps)
+                self._d_adapter_slots = jnp.asarray(self._adapter_slots)
+                self._dirty = False
+        with annotation(PH_DISPATCH, acct):
+            (self._d_tokens, self._d_seq_lens, self._d_key,
+             self.pools) = paged_decode_step(
+                self.model_config, self.params, self.pools,
+                self.adapter_pool.arrays,
+                self._d_tokens, self._d_page_tables, self._d_seq_lens,
+                self._d_active, self._d_temps, self._d_adapter_slots,
+                self._d_key)
+        with annotation(PH_READBACK, acct):
+            toks = np.asarray(self._d_tokens)  # rt-sync-ok: THE decode-step readback — one batched token fetch per step
+        with annotation(PH_EMIT, acct) as phase:
+            now = phase.t0
+            for slot, req in enumerate(self.slots):
+                if req is None:
+                    continue
+                self._seq_lens[slot] += 1
+                req.length += 1
+                self._tokens[slot] = toks[slot]
+                if req.last_token_t is not None:
+                    itl = now - req.last_token_t
+                    req.itls.append(itl)
+                    self._m_itl.observe(itl)
+                req.last_token_t = now
+                self._emit_token(req, int(toks[slot]))
         self._m_active.set(
             sum(1 for s in self.slots if s is not None),
             tags=self._pid_tags)
         self._m_pages.set(self.allocator.used_count,
                           tags=self._pid_tags)
         if rec_on:
-            self._record_step(t0, stall_s, len(admitted), evicted0,
-                              shed0, pc_hits0, traces0, decoded=True)
+            with annotation(PH_RECORD):
+                self._record_step(t0, acct, stall_s, len(admitted),
+                                  evicted0, shed0, compiles0, traces0,
+                                  decoded=True)
 
-    def _record_step(self, t0: float, stall_s: float, admitted: int,
-                     evicted0: int, shed0: int, pc_hits0: int,
+    def _record_step(self, t0: float, acct: Dict[str, float],
+                     stall_s: float, admitted: int, evicted0: int,
+                     shed0: int, compiles0: int,
                      traces0: Optional[Dict[str, int]],
                      decoded: bool) -> None:
         """Append one flight-recorder record for the step that just ran.
         Called on the loop thread; everything here is host bookkeeping
-        (the decode result was already synced for token emission)."""
+        (the decode result was already synced for token emission).
+
+        The records tile the loop's time: ``t0`` (``time.perf_counter()``
+        at the top of ``_run_step``) less the previous record's ``t0 +
+        wall_s`` is ``between_s + idle_s``, and inside ``wall_s`` the
+        phases ``stall_s`` (the admission prefills, itemised a request in
+        ``first_tokens``), ``upload_s``, ``dispatch_s``, ``readback_s`` and
+        ``emit_s`` leave only the gauges."""
         from ..models.paged import trace_counts
         from ..util import devmem, steprec
 
@@ -1163,12 +1257,27 @@ class InferenceEngine:
         with self._lock:
             queued = self._queued_total()
             tenants = {t: len(q) for t, q in self._queues.items() if q}
-        steprec.record_step({
+        # Rounded first, then differenced: the tiling identity holds on
+        # the record's own numbers, not only on the clock's.
+        t0, wall_s = round(t0, 6), round(wall_s, 6)
+        idle_s = round(self._gap_acct.pop(PH_IDLE, 0.0), 6)
+        between_s = round(t0 - self._prev_end - idle_s, 6)
+        self._prev_end = t0 + wall_s
+        first_tokens = self._first_tokens
+        rec = {
             "t": round(now, 3),
             "engine": self.engine_id,
             "step": self.step_count,
-            "wall_s": round(wall_s, 6),
+            "t0": t0,
+            "wall_s": wall_s,
             "stall_s": round(stall_s, 6),
+            "between_s": between_s,
+            "idle_s": idle_s,
+            "upload_s": round(acct.get(PH_UPLOAD, 0.0), 6),
+            "dispatch_s": round(acct.get(PH_DISPATCH, 0.0), 6),
+            "readback_s": round(acct.get(PH_READBACK, 0.0), 6),
+            "emit_s": round(acct.get(PH_EMIT, 0.0), 6),
+            "first_tokens": first_tokens,
             "occupancy": sum(1 for s in self.slots if s is not None),
             "slots": self.config.batch_slots,
             "admitted": admitted,
@@ -1178,10 +1287,16 @@ class InferenceEngine:
             "pages_used": self.allocator.used_count,
             "pages_free": self.allocator.free_count,
             "pages_shared": self.allocator.shared_count,
-            "prefix_hits": self._pc_hits_total - pc_hits0,
+            "prefix_hits": sum(1 for e in first_tokens if e["cached"]),
             "adapter_pins": self.adapter_pool.pinned_count,
             "tenants": tenants,
-        })
+        }
+        # Which step recompiled, for every jitted program of the process
+        # (trace_counts above knows the three paged ones).
+        compiles = devmem.compile_count() - compiles0
+        if compiles:
+            rec["compiles"] = compiles
+        steprec.record_step(rec)
 
 
 # ------------------------------------------------------------ serve binding
@@ -1233,6 +1348,17 @@ def random_lora(model_config, seed: int, rank: int = 8,
     return lora
 
 
+def _setup_table(setup: Dict[str, Any]) -> str:
+    """``LLMServer.stats()["setup"]`` as lines for the replica's log."""
+    cols = ("program", "bucket", "wall_s", "trace_s", "lower_s",
+            "compile_s", "cache_hit", "cache_read_s")
+    lines = [f"weights_s={setup['weights_s']} pools_s={setup['pools_s']}",
+             " ".join(cols)]
+    lines += [" ".join(str(row[c]) for c in cols)
+              for row in setup["programs"]]
+    return "\n  ".join(lines)
+
+
 class LLMServer:
     """The deployment callable: one engine per replica, tokens streamed
     through serve's per-item streaming path (handle iterators, HTTP SSE,
@@ -1266,17 +1392,26 @@ class LLMServer:
         cfg = _MODEL_BUILDERS[model]()
         params = jax.block_until_ready(
             llama_init(cfg, jax.random.PRNGKey(seed)))
+        tw = time.perf_counter()
         self.engine = InferenceEngine(
             cfg, params, EngineConfig(**(engine or {})), seed=seed)
+        jax.block_until_ready(self.engine.pools)
+        tp = time.perf_counter()
         for name, spec in (adapters or {}).items():
             self.load_adapter(name, spec)
         t1 = time.perf_counter()
-        if warmup:
-            self.engine.warmup()
+        programs = self.engine.warmup() if warmup else []
         #: Set-up seconds: params and pools onto the device, then (with
         #: ``warmup``) the compile of every program the engine will run.
         self._init_s = t1 - t0
         self._warmup_s = time.perf_counter() - t1
+        #: The same, itemised: weights, the engine with its KV pools, and
+        #: one row a program warmed (InferenceEngine.warmup).
+        self._setup = {"weights_s": round(tw - t0, 3),
+                       "pools_s": round(tp - tw, 3), "programs": programs}
+        if programs:  # once, into the replica's log
+            print(f"set-up of {model}: {_setup_table(self._setup)}",
+                  file=sys.stderr, flush=True)
 
     def load_adapter(self, name: str, source: Any = None) -> str:
         """Register a LoRA adapter on this replica's engine.  ``source``
@@ -1331,7 +1466,7 @@ class LLMServer:
             device={"platform": dev.platform, "kind": dev.device_kind,
                     "count": len(jax.devices())},
             init_s=self._init_s, warmup_s=self._warmup_s,
-            sentinel_armed=jitguard.armed())
+            setup=self._setup, sentinel_armed=jitguard.armed())
 
     def clear_prefix_cache(self) -> int:
         """Drop every cached prefix page (returns how many the cache let

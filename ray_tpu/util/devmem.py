@@ -66,6 +66,96 @@ def compile_stats() -> Dict[str, Dict[str, float]]:
         return {k: dict(v) for k, v in _compiles.items()}
 
 
+#: jax.monitoring events totalled per process, by the key they are
+#: reported under.  The three compile phases arrive as time spans and are
+#: totalled as the union of their intervals: tracing one jitted function
+#: traces the jitted functions it calls, each with a span of its own, and
+#: summing those would count the inner seconds twice.  BACKEND_COMPILE
+#: wraps the persistent cache's lookup, so it fires once an executable
+#: whether compiled or read back: ``compiles`` counts every program this
+#: process built.
+_JAX_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "compile_s",
+}
+_JAX_DURATIONS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+}
+_JAX_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_jax_totals: Optional[Dict[str, float]] = None
+
+
+def _add_span(counted: list, start: float, end: float) -> float:
+    """Seconds of [start, end] that no interval in ``counted`` (disjoint,
+    ascending; spans arrive in the order they end) covers yet; the list
+    then holds their union."""
+    new = end - start
+    while counted and counted[-1][0] >= start:  # nested in this one
+        s, e = counted.pop()
+        new -= e - s
+    if counted and counted[-1][1] > start:  # overlaps (another thread)
+        new -= counted[-1][1] - start
+        start = counted[-1][1]
+    if end > start:
+        counted.append((start, end))
+        del counted[:-1024]
+    return max(new, 0.0)
+
+
+def compile_totals() -> Dict[str, float]:
+    """Running totals of what JAX itself reports about tracing, lowering,
+    compiling and the persistent cache in this process (the first call
+    registers the ``jax.monitoring`` listeners).  Difference two calls to
+    account a phase: ``InferenceEngine.warmup`` does so a program, the
+    step record does so a step (``compiles``)."""
+    global _jax_totals
+    if _jax_totals is None:
+        with _lock:
+            if _jax_totals is None:
+                import jax.monitoring
+
+                totals = dict.fromkeys(
+                    ["compiles", *_JAX_SPANS.values(),
+                     *_JAX_DURATIONS.values(), *_JAX_EVENTS.values()], 0)
+                counted: Dict[str, list] = {
+                    k: [] for k in _JAX_SPANS.values()}
+
+                def on_span(event: str, start: float, end: float,
+                            **_kw) -> None:
+                    key = _JAX_SPANS.get(event)
+                    if key is not None:
+                        totals[key] += _add_span(counted[key], start, end)
+                        if key == "compile_s":
+                            totals["compiles"] += 1
+
+                def on_duration(event: str, duration: float, **_kw) -> None:
+                    key = _JAX_DURATIONS.get(event)
+                    if key is not None:
+                        totals[key] += duration
+
+                def on_event(event: str, **_kw) -> None:
+                    key = _JAX_EVENTS.get(event)
+                    if key is not None:
+                        totals[key] += 1
+
+                jax.monitoring.register_event_time_span_listener(on_span)
+                jax.monitoring.register_event_duration_secs_listener(
+                    on_duration)
+                jax.monitoring.register_event_listener(on_event)
+                _jax_totals = totals
+    return dict(_jax_totals)
+
+
+def compile_count() -> int:
+    """Programs built so far (0 before :func:`compile_totals` first ran):
+    one dict read, for the decode loop."""
+    return _jax_totals["compiles"] if _jax_totals is not None else 0
+
+
 def _device_stats() -> list:
     """Per-device allocator counters; [] on backends without them (CPU)."""
     import jax

@@ -258,7 +258,7 @@ def detect_stall_pressure(steps: List[dict], now: float, window_s: float,
         wall_sum = sum(walls)
         stall_sum = sum(float(r.get("stall_s", 0.0)) for r in recs)
         if len(recs) >= p["stall_min_steps"] and wall_sum > 0:
-            frac = stall_sum / (wall_sum + stall_sum)
+            frac = stall_sum / wall_sum  # wall_s contains stall_s
             if frac >= p["stall_frac_warn"]:
                 out.append(firing(
                     "stall_pressure", f"stall:{eid}", SEV_WARN,

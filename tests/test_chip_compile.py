@@ -138,6 +138,27 @@ def test_rms_norm_kernel_compiles(v5e):
     assert text.count("tpu_custom_call") == 1
 
 
+@pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
+                                    "flash_bwd_dkv", "rms_norm"])
+def test_kernels_carry_their_names_into_the_compiled_program(v5e, kernel):
+    """``pallas_call(name=...)``: what a device trace (and a reduction of
+    it) can tell the Mosaic calls apart by."""
+    one = SingleDeviceSharding(v5e.devices[0])
+    if kernel == "rms_norm":
+        fn, args = rms_norm_pallas, (_on(one, (8192, 2048)),
+                                     _on(one, (2048,)))
+    else:
+        def loss(q, k, v):
+            out = att.flash_attention(q, k, v, force_pallas=True)
+            return out.astype(jnp.float32).sum()
+
+        fn, args = (jax.grad(loss, argnums=(0, 1, 2)),
+                    _flash_args(v5e, 16, 8, 2048))
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert any(kernel in ln for ln in calls), calls
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["forward", "backward"])
 def test_ring_flash_compiles_on_four_devices(v5e, grad):
     """The fused ring+flash pair over sp=4: b1's heads, 2048 tokens a shard."""

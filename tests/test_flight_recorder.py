@@ -169,6 +169,31 @@ def test_devmem_pools_sum_to_live_bytes():
     assert stats["t_prog"]["wall_s"] == pytest.approx(0.75)
 
 
+def test_compile_spans_are_totalled_as_a_union():
+    """Tracing a jitted function traces the ones it calls, each with a span
+    of its own that ends first: the total counts every second once."""
+    from ray_tpu.util import devmem
+
+    counted, total = [], 0.0
+    for start, end in [(1, 2), (3, 4), (0, 5),   # two nested in a third
+                       (6, 7), (6.5, 8),         # overlap from a thread
+                       (10, 11)]:
+        total += devmem._add_span(counted, start, end)
+    assert total == 8.0  # [0, 5] + [6, 8] + [10, 11]
+    assert counted == [(0, 5), (6, 7), (7, 8), (10, 11)]
+    # On the real listener: a jit that calls a jit, traced and built once.
+    import jax
+    import jax.numpy as jnp
+
+    before = devmem.compile_totals()
+    inner = jax.jit(lambda x: x * 2 + 1)
+    jax.jit(lambda x: inner(x) - 3)(jnp.arange(5.0)).block_until_ready()
+    after = devmem.compile_totals()
+    assert after["compiles"] - before["compiles"] >= 1
+    assert after["trace_s"] > before["trace_s"]
+    assert devmem.compile_count() == after["compiles"]
+
+
 def test_maybe_snapshot_never_forces_jax_import():
     """A worker that hasn't touched jax must report nothing (importing
     XLA into every worker is exactly what maybe_snapshot avoids) — probed
@@ -528,3 +553,261 @@ def test_headless_step_records_hold_and_replay(tmp_path, monkeypatch):
         except Exception:
             pass
         head.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# The loop's own time account: phases and request stages on the step
+# record, the same phases on the profiler's clock, the set-up table.
+# ---------------------------------------------------------------------------
+
+PHASE_FIELDS = {"t0": float, "between_s": float, "idle_s": float,
+                "upload_s": float, "dispatch_s": float, "readback_s": float,
+                "emit_s": float, "first_tokens": list}
+ENTRY_FIELDS = {"queue_s": float, "prefill_s": float,
+                "prefill_wait_s": float, "ttft_s": float, "prompt": int,
+                "bucket": int, "cached": int}
+RT_PHASES = {"admit", "prefill", "prefill_wait", "upload", "dispatch",
+             "readback", "emit", "record"}
+REPEATED = [9, 8, 7, 6, 5, 4, 3, 2, 1, 2, 3]  # one full page of 8, then 3
+
+
+def _engine_records(eng, until, timeout_s=10.0):
+    """This engine's step records, drained until ``until(records)``."""
+    recs, deadline = [], time.time() + timeout_s
+    while time.time() < deadline:
+        recs += [r for r in steprec.drain_buffered()
+                 if r.get("engine") == eng.engine_id]
+        if until(recs):
+            break
+        time.sleep(0.05)
+    return recs
+
+
+@pytest.fixture(scope="module")
+def served():
+    """A tiny engine that served eight concurrent requests on four slots,
+    then one prompt twice (a prefix hit) and one request under a rooted
+    trace: (records, spans of the trace, number of requests)."""
+    import threading
+
+    from ray_tpu.util import tracing
+
+    steprec.drain_buffered()
+    eng = _tiny_engine()
+    try:
+        threads = [threading.Thread(target=lambda i=i: list(eng.submit(
+            [1 + i, 2, 3, 4 + i], max_new_tokens=12))) for i in range(8)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join()
+        for _ in range(2):
+            assert len(list(eng.submit(REPEATED, max_new_tokens=3))) == 3
+        tracing.drain_buffered()
+        with tracing.trace("req_root", force=True) as root:
+            assert len(list(eng.submit([5, 7, 11, 13, 17],
+                                       max_new_tokens=3))) == 3
+        n = 11
+        recs = _engine_records(
+            eng, lambda rs: sum(r["evicted"] for r in rs) >= n)
+        spans = [s for s in tracing.drain_buffered()
+                 if s.get("trace_id") == root["trace_id"]]
+    finally:
+        eng.shutdown()
+    assert sum(r["evicted"] for r in recs) == n
+    return recs, spans, n
+
+
+def test_step_records_carry_the_loops_account(served):
+    recs, _, _ = served
+    for r in recs:
+        assert STEP_FIELDS <= set(r)
+        for key, kind in PHASE_FIELDS.items():
+            assert isinstance(r[key], kind), (key, r[key])
+            assert kind is list or r[key] >= 0, (key, r[key])
+        for e in r["first_tokens"]:
+            assert {k: type(v) for k, v in e.items()} == ENTRY_FIELDS
+
+
+def test_step_records_tile_the_loops_time(served):
+    """t0[k] - t0[k-1] = wall_s[k-1] + between_s[k] + idle_s[k]: no moment
+    of the loop thread belongs to no record."""
+    recs, _, _ = served
+    assert len(recs) > 20
+    assert [r["step"] for r in recs] == sorted(r["step"] for r in recs)
+    for prev, cur in zip(recs, recs[1:]):
+        assert cur["t0"] - prev["t0"] == pytest.approx(
+            prev["wall_s"] + cur["between_s"] + cur["idle_s"], abs=2e-6)
+    # The waits between the three rounds of requests are idle, not work.
+    assert sum(r["idle_s"] for r in recs) > 0
+
+
+def test_phases_leave_only_the_gauges_of_the_step_wall(served):
+    import statistics
+
+    recs, _, _ = served
+    phases = [r["stall_s"] + r["upload_s"] + r["dispatch_s"]
+              + r["readback_s"] + r["emit_s"] for r in recs]
+    for r, inside in zip(recs, phases):
+        assert inside <= r["wall_s"] + 5e-6
+    assert statistics.median(phases) >= 0.9 * statistics.median(
+        r["wall_s"] for r in recs)
+
+
+def test_first_tokens_has_one_entry_for_each_admitted_request(served):
+    recs, _, n = served
+    for r in recs:
+        assert len(r["first_tokens"]) == r["admitted"]
+        assert r["prefix_hits"] == sum(
+            1 for e in r["first_tokens"] if e["cached"])
+    entries = [e for r in recs for e in r["first_tokens"]]
+    assert len(entries) == n
+    for e in entries:
+        assert 0 <= e["prefill_wait_s"] <= e["prefill_s"]
+        assert e["queue_s"] >= 0 and e["ttft_s"] >= e["queue_s"]
+        assert e["bucket"] >= e["prompt"] - e["cached"] > 0
+    # The repeated prompt: a cold prefill, then a hit on its one full page.
+    twice = [e for e in entries if e["prompt"] == len(REPEATED)]
+    assert [e["cached"] for e in twice] == [0, GEOMETRY["page_size"]]
+    assert [e["cached"] for e in entries if e not in twice] == [0] * (n - 2)
+
+
+def test_request_spans_and_entry_come_from_the_same_stamps(served):
+    recs, spans, _ = served
+    by_name = {s["name"]: s for s in spans}
+    entry, = [e for r in recs for e in r["first_tokens"]
+              if e["prompt"] == 5]
+    queue, prefill = by_name["engine:queue"], by_name["engine:prefill"]
+    assert queue["end"] - queue["start"] == pytest.approx(
+        entry["queue_s"], abs=2e-6)
+    assert prefill["end"] - prefill["start"] == pytest.approx(
+        entry["prefill_s"], abs=2e-6)
+    assert prefill["attrs"] == {"bucket": entry["bucket"], "prompt_len": 5,
+                                "cached_prefix": entry["cached"]}
+    assert queue["end"] <= prefill["start"] + 2e-6
+    assert by_name["engine:decode"]["attrs"]["ttft_s"] == entry["ttft_s"]
+
+
+def test_a_device_trace_holds_every_phase_once_a_step(tmp_path):
+    """The same phases as ``rt:engine/*`` host spans in a profiler capture,
+    nested in an annotation bound onto the instance from outside (as the
+    benchmark binds ``bench:engine_step``): each step's interval holds one
+    dispatch, readback, emit and record, and each prefill its wait."""
+    import jax
+    from jax.profiler import ProfileData
+
+    from ray_tpu.util import profiling
+
+    eng = _tiny_engine()
+    try:
+        list(eng.submit([3, 5, 7], max_new_tokens=3))  # compiled before
+        inner = eng._run_step
+
+        def outer(admitted):
+            with jax.profiler.TraceAnnotation("test:step"):
+                return inner(admitted)
+
+        eng._run_step = outer
+        with profiling.device_trace(str(tmp_path), host_tracer_level=2):
+            assert len(list(eng.submit([2, 4, 6, 8], max_new_tokens=6))) == 6
+    finally:
+        eng.shutdown()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events
+              if e.name.startswith(("rt:engine/", "test:step"))]
+    assert {n[len("rt:engine/"):] for n, _, _ in events
+            if n.startswith("rt:")} >= RT_PHASES
+    steps = sorted((s, e) for n, s, e in events if n == "test:step")
+    assert len(steps) >= 5  # one prefill step, then decode steps
+
+    def inside(name, span):
+        return [(s, e) for n, s, e in events
+                if n == "rt:engine/" + name and span[0] <= s and e <= span[1]]
+
+    for step in steps:
+        for name in ("dispatch", "readback", "emit", "record"):
+            assert len(inside(name, step)) == 1, (name, step)
+        assert len(inside("upload", step)) <= 1
+        order = [inside(n, step)[0] for n in ("dispatch", "readback", "emit",
+                                              "record")]
+        assert all(a[1] <= b[0] for a, b in zip(order, order[1:]))
+    prefill, = [(s, e) for n, s, e in events if n == "rt:engine/prefill"]
+    assert len(inside("prefill_wait", prefill)) == 1
+    assert len(inside("prefill", steps[0])) == 1
+    # Admission's locked section lies between the steps, once before each.
+    for before, step in zip(steps, steps[1:]):
+        assert len(inside("admit", (before[1], step[0]))) == 1
+
+
+def test_setup_table_has_a_row_for_each_program_warmed():
+    """``LLMServer.stats()["setup"]``: weights, pools and one row a program
+    of ``InferenceEngine.warmup`` from JAX's own compile events; the step
+    that paid a compile says so (``compiles``), a warmed step does not."""
+    from ray_tpu.serve.engine import LLMServer
+
+    # A geometry no other test compiles, so the programs are really built.
+    geometry = dict(batch_slots=2, page_size=8, max_prompt_len=16,
+                    max_new_tokens_cap=16)
+    steprec.drain_buffered()
+    srv = LLMServer("tiny", engine=geometry, warmup=True)
+    try:
+        cold = _engine_records(srv.engine, lambda rs: len(rs) >= 4, 2.0)
+        assert len(list(srv.engine.submit([3, 5, 7], max_new_tokens=4))) == 4
+        warm = _engine_records(
+            srv.engine, lambda rs: sum(r["evicted"] for r in rs) >= 1)
+        st = srv.stats()
+    finally:
+        srv.engine.shutdown()
+    setup = st["setup"]
+    assert setup["weights_s"] >= 0 and setup["pools_s"] >= 0
+    rows = setup["programs"]
+    assert [(r["program"], r["bucket"]) for r in rows] == [
+        ("prefill", 8), ("decode", None), ("prefill", 16),
+        ("prefix_hit", 16), ("prefill_prefix", 8), ("prefill_prefix", 16),
+        ("copy_page", None), ("adapter_load", None)]
+    for r in rows:
+        assert set(r) == {"program", "bucket", "wall_s", "trace_s",
+                          "lower_s", "compile_s", "cache_hit",
+                          "cache_read_s"}
+        # Each a union of intervals inside the row's wall (they may overlap
+        # one another: an eager operation compiles while a program traces).
+        for key in ("trace_s", "lower_s", "compile_s", "cache_read_s"):
+            assert 0 <= r[key] <= r["wall_s"] + 0.002, (key, r)
+    assert sum(r["wall_s"] for r in rows) <= st["warmup_s"] + 0.0005 * len(rows)
+    built = {r["program"] for r in rows if r["compile_s"] > 0}
+    assert {"prefill", "decode", "prefill_prefix"} <= built
+    # Which step recompiled: the warm-up's first steps did, a served one
+    # after it did not.
+    assert cold[0]["compiles"] >= 1
+    assert warm and all("compiles" not in r for r in warm)
+
+
+def test_status_rows_show_host_share_and_queue_wait():
+    """`ray_tpu status` / `top`: the window's host share of a step and the
+    median queue wait, from the same records; '-' for records without."""
+    from ray_tpu.scripts import _engine_rows
+
+    def rec(**kw):
+        return dict({"wall_s": 0.03, "stall_s": 0.0, "occupancy": 2,
+                     "slots": 4}, **kw)
+
+    accounted = [rec(t0=10.0 + 0.54 * i, between_s=0.01, upload_s=0.001,
+                     dispatch_s=0.002, readback_s=0.02, emit_s=0.007,
+                     idle_s=0.5, first_tokens=[{"queue_s": q}])
+                 for i, q in enumerate((0.004, 0.030, 0.012))]
+    accounted[1]["compiles"] = 2
+    engines = [{"engine": "1.0", "records": accounted,
+                "latest": accounted[-1]},
+               {"engine": "2.0", "records": [rec()], "latest": rec()}]
+    row, old = _engine_rows(engines, [])
+    assert row["host%"] == "50.0"  # (10+1+2+7) of (30+10) ms
+    assert row["qwait_ms"] == "12.0"
+    assert (row["loop%"], row["compiles"]) == ("100.0", 2)
+    assert (old["host%"], old["qwait_ms"], old["loop%"]) == ("-", "-", "-")
+    # A record lost from the middle of the window shows as untiled time.
+    engines[0]["records"] = [accounted[0], accounted[2]]
+    assert _engine_rows(engines, [])[0]["loop%"] == "66.7"

@@ -120,6 +120,18 @@ def test_stall_pressure_fires_and_clean_silent():
     assert detect_stall_pressure(stalled, now + 500.0, 30.0) == []
 
 
+def test_stall_share_is_of_the_step_wall_which_contains_the_stall():
+    """``wall_s`` already holds ``stall_s`` (the engine reads both off one
+    clock): half of every step stalled is 50%, as `ray_tpu status` and the
+    benchmark's ``prefill_stall_share`` have it, not a third."""
+    now = 100.0
+    half = [{"t": now - i, "engine": "e0", "wall_s": 0.2, "stall_s": 0.1}
+            for i in range(10)]
+    hit, = detect_stall_pressure(half, now, 30.0)
+    assert hit["data"]["stall_frac"] == 0.5
+    assert DEFAULTS["stall_frac_warn"] == 0.5  # at the threshold, not under
+
+
 def test_step_jitter_fires_and_clean_silent():
     now = 100.0
     walls = [0.001] * 28 + [0.1, 0.1]
