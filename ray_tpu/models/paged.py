@@ -5,17 +5,28 @@ stack (reference: the Ray Serve LLM APIs run a continuous-batching engine
 whose KV cache is a pool of fixed-size pages).  TPU-first shape, same
 recipe as `generate.py` but paged:
 
-- ONE preallocated KV pool per replica: ``[L, P+1, H_kv, page, D]`` per
-  k/v; page ``P`` is a scratch page that absorbs writes from inactive
+- ONE preallocated KV pool per replica: ``[L, P+1, page, H_kv, D]`` per
+  k/v — a token's ``[H_kv, D]`` row is contiguous, which is how it is
+  written (one row per token) and how it is read (a table's pages
+  reshape to ``[MAXP*page, H_kv, D]`` with no transpose), so the donated
+  pools keep one layout from argument to result and no program copies
+  them.  Page ``P`` is a scratch page that absorbs writes from inactive
   batch slots and padded prompt tail positions, so every program runs
-  with fully static shapes and no data-dependent control flow.
+  with fully static shapes and no data-dependent control flow.  The
+  page axis is the second; what lies inside a page only
+  ``init_paged_pools``, ``_page_size``, ``_write_rows`` and
+  ``_attend_pages`` know.
 - A host-side free-list allocator hands pages to sequences; per-sequence
   PAGE TABLES (``[MAX_PAGES]`` int32, scratch-filled past the allocated
   prefix) are plain arrays, so ONE compiled decode program serves any
   admission mix — slot occupancy, page placement, and lengths are data.
 - The decode step gathers each slot's pages into a linear view and masks
   by sequence length (the standard static-shape TPU decode recipe: score
-  the whole gather, mask the unwritten tail — no dynamic slicing).
+  the whole gather, mask the unwritten tail — no dynamic slicing).  The
+  gather is the only copy of K/V a layer makes: queries are grouped by
+  KV head and contracted against the gathered view in the pool's dtype
+  with float32 accumulation (no upcast, transposed or GQA-repeated K/V
+  is ever materialised).
 
 Compile counts are observable via ``trace_count()`` — the jitted bodies
 bump a counter when TRACED (python executes only at trace time), which is
@@ -35,7 +46,7 @@ from ..ops.rotary import apply_rotary, rope_frequencies
 from .llama import LlamaConfig, _mlp
 
 Params = Any
-PagedPools = Dict[str, jax.Array]  # {"k": [L, P+1, H_kv, page, D], "v": ...}
+PagedPools = Dict[str, jax.Array]  # {"k": [L, P+1, page, H_kv, D], "v": ...}
 
 # jit-trace counters per program name; a bump means XLA compiled a new
 # specialization (python bodies only run while tracing).  The counters
@@ -71,10 +82,51 @@ def init_paged_pools(config: LlamaConfig, num_pages: int,
                      page_size: int) -> PagedPools:
     """One pool pair for the whole replica; index ``num_pages`` is the
     scratch page (writes routed there are never read)."""
-    shape = (config.n_layers, num_pages + 1, config.n_kv_heads,
-             page_size, config.head_dim)
+    shape = (config.n_layers, num_pages + 1, page_size,
+             config.n_kv_heads, config.head_dim)
     return {"k": jnp.zeros(shape, config.dtype),
             "v": jnp.zeros(shape, config.dtype)}
+
+
+def _page_size(pools: PagedPools) -> int:
+    return pools["k"].shape[2]
+
+
+def _write_rows(pool: jax.Array, layer: int, page_idx: jax.Array,
+                off: jax.Array, rows: jax.Array) -> jax.Array:
+    """Write one token's K or V per row: rows [N, H_kv, D] land at
+    ``(page_idx[n], off[n])`` of ``layer``.  The index arrays lead and are
+    adjacent, so each row is one contiguous ``[H_kv, D]`` update and the
+    donated pool is updated in place."""
+    return pool.at[layer, page_idx, off].set(rows.astype(pool.dtype))
+
+
+def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
+                  v_pool: jax.Array, layer: int, tables: jax.Array,
+                  visible: jax.Array) -> jax.Array:
+    """Attention of q [B, Q, H, D] over the pages of tables [B, MAXP]:
+    visible [B, Q, MAXP*page] bool says which gathered positions a query
+    may see (scratch and unwritten ones never).  Returns [B, Q, H*D].
+
+    A table's pages reshape to a linear ``[B, MAXP*page, H_kv, D]`` view
+    as gathered; queries are grouped ``[.., H_kv, n_rep, D]`` and
+    contracted against it directly.  K and V stay in the pool's dtype and
+    the products accumulate in float32: a bf16 x bf16 product is exact in
+    float32, so this is what upcasting the gathered K first computed,
+    without the float32 copy."""
+    B, Q = q.shape[:2]
+    n_rep = config.n_heads // config.n_kv_heads
+    k_seq = k_pool[layer, tables].reshape(
+        B, -1, config.n_kv_heads, config.head_dim)
+    v_seq = v_pool[layer, tables].reshape(k_seq.shape)
+    qg = q.reshape(B, Q, config.n_kv_heads, n_rep, config.head_dim)
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_seq,
+                        preferred_element_type=jnp.float32) \
+        * (config.head_dim ** -0.5)
+    scores = jnp.where(visible[:, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v_seq.dtype)
+    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_seq)
+    return out.reshape(B, Q, -1)
 
 
 # ------------------------------------------------------- adapter pool
@@ -250,7 +302,10 @@ def paged_decode_step(config: LlamaConfig, params: Params,
     so one compiled program serves any adapter mix).  Inactive slots
     pass seq_lens=0 and an all-scratch page table: their writes land on
     the scratch page and their sampled token is ignored host-side.
-    Pools are donated — steady-state decode never copies the cache.
+    Pools are donated and keep their layout through the program
+    (``tests/test_chip_compile.py`` holds the compiled step to it), so
+    steady-state decode never copies the cache: a layer writes B rows in
+    place and reads one gather of the page tables.
 
     The PRNG key and the slot lengths advance ON DEVICE (returned
     alongside the tokens), so the serving loop's only per-step host
@@ -261,8 +316,7 @@ def paged_decode_step(config: LlamaConfig, params: Params,
           seq_lens=seq_lens, temps=temps, adapter_ids=adapter_ids, key=key)
     B = tokens.shape[0]
     maxp = page_tables.shape[1]
-    ps = pools["k"].shape[3]
-    n_rep = config.n_heads // config.n_kv_heads
+    ps = _page_size(pools)
     x = params["embed"][tokens].astype(config.dtype)  # [B, d]
     cos, sin = rope_frequencies(config.head_dim, maxp * ps,
                                 config.rope_theta)
@@ -270,7 +324,9 @@ def paged_decode_step(config: LlamaConfig, params: Params,
     b_idx = jnp.arange(B)
     page_idx = page_tables[b_idx, seq_lens // ps]  # [B]
     off = seq_lens % ps
-    pos_grid = jnp.arange(maxp * ps)[None, None, :]  # [1, 1, MAXP*ps]
+    # The length mask removes scratch/unwritten positions: [B, 1, MAXP*ps].
+    visible = jnp.arange(maxp * ps)[None, None, :] \
+        <= seq_lens[:, None, None]
     # One gather per adapter array for the whole step: [B, L, ...].
     qa_g, qb_g = adapters["qa"][adapter_ids], adapters["qb"][adapter_ids]
     va_g, vb_g = adapters["va"][adapter_ids], adapters["vb"][adapter_ids]
@@ -287,27 +343,11 @@ def paged_decode_step(config: LlamaConfig, params: Params,
         v = v_flat.reshape(B, config.n_kv_heads, config.head_dim)
         q = _rotary_single(q, cos, sin, seq_lens)
         k = _rotary_single(k, cos, sin, seq_lens)
-        k_pool = k_pool.at[i, page_idx, :, off, :].set(
-            k.astype(k_pool.dtype))
-        v_pool = v_pool.at[i, page_idx, :, off, :].set(
-            v.astype(v_pool.dtype))
-        # Gather each slot's pages into a linear [B, H_kv, MAXP*ps, D]
-        # view; the length mask removes scratch/unwritten positions.
-        k_seq = k_pool[i, page_tables].transpose(0, 2, 1, 3, 4).reshape(
-            B, config.n_kv_heads, maxp * ps, config.head_dim)
-        v_seq = v_pool[i, page_tables].transpose(0, 2, 1, 3, 4).reshape(
-            B, config.n_kv_heads, maxp * ps, config.head_dim)
-        if n_rep > 1:  # GQA: repeat kv heads query-side
-            k_seq = jnp.repeat(k_seq, n_rep, axis=1)
-            v_seq = jnp.repeat(v_seq, n_rep, axis=1)
-        scores = jnp.einsum("bhd,bhkd->bhk", q.astype(jnp.float32),
-                            k_seq.astype(jnp.float32)) \
-            * (config.head_dim ** -0.5)
-        scores = jnp.where(pos_grid <= seq_lens[:, None, None],
-                           scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(v_seq.dtype)
-        out = jnp.einsum("bhk,bhkd->bhd", probs, v_seq)
-        x = x + out.reshape(B, -1) @ a["wo"]
+        k_pool = _write_rows(k_pool, i, page_idx, off, k)
+        v_pool = _write_rows(v_pool, i, page_idx, off, v)
+        out = _attend_pages(config, q[:, None], k_pool, v_pool, i,
+                            page_tables, visible)
+        x = x + out[:, 0] @ a["wo"]
         h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
         x = x + _mlp(layer, h)
     x = rms_norm(x, params["final_norm"], config.norm_eps)
@@ -337,7 +377,7 @@ def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
     _bump("prefill", tokens=tokens, page_table=page_table, temp=temp,
           key=key)
     _, s_pad = tokens.shape
-    ps = pools["k"].shape[3]
+    ps = _page_size(pools)
     n_rep = config.n_heads // config.n_kv_heads
     x = params["embed"][tokens[0]].astype(config.dtype)  # [S_pad, d]
     cos, sin = rope_frequencies(config.head_dim, s_pad, config.rope_theta)
@@ -364,10 +404,8 @@ def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
                        ).transpose(1, 0, 2)
         q = apply_rotary(q[None], cos, sin)[0]
         k = apply_rotary(k[None], cos, sin)[0]
-        k_pool = k_pool.at[i, page_idx, :, off, :].set(
-            k.transpose(1, 0, 2).astype(k_pool.dtype))
-        v_pool = v_pool.at[i, page_idx, :, off, :].set(
-            v.transpose(1, 0, 2).astype(v_pool.dtype))
+        k_pool = _write_rows(k_pool, i, page_idx, off, k.transpose(1, 0, 2))
+        v_pool = _write_rows(v_pool, i, page_idx, off, v.transpose(1, 0, 2))
         kr, vr = k, v
         if n_rep > 1:
             kr = jnp.repeat(kr, n_rep, axis=0)
@@ -414,9 +452,8 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
           temp=temp, key=key)
     _, s_pad = tokens.shape
     maxp = page_table.shape[0]
-    ps = pools["k"].shape[3]
+    ps = _page_size(pools)
     scratch = pools["k"].shape[1] - 1
-    n_rep = config.n_heads // config.n_kv_heads
     x = params["embed"][tokens[0]].astype(config.dtype)  # [S_pad, d]
     cos, sin = rope_frequencies(config.head_dim, maxp * ps,
                                 config.rope_theta)
@@ -426,8 +463,9 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
     page_idx = jnp.where(
         valid, page_table[jnp.clip(positions // ps, 0, maxp - 1)], scratch)
     off = jnp.where(valid, positions % ps, 0)
-    kpos = jnp.arange(maxp * ps)[None, None, :]  # [1, 1, MAXP*ps]
-    qpos = positions[None, :, None]              # [1, S_pad, 1]
+    # Causal in global positions: [1, S_pad, MAXP*ps].
+    visible = jnp.arange(maxp * ps)[None, None, :] \
+        <= positions[None, :, None]
     qa_g, qb_g = adapters["qa"][adapter_id], adapters["qb"][adapter_id]
     va_g, vb_g = adapters["va"][adapter_id], adapters["vb"][adapter_id]
     lscale = adapters["scale"][adapter_id]
@@ -435,35 +473,20 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
         h = rms_norm(x, layer["attn_norm"], config.norm_eps)
         a = layer["attn"]
         q = (h @ a["wq"] + _lora_delta_seq(h, qa_g[i], qb_g[i], lscale)
-             ).reshape(s_pad, config.n_heads, config.head_dim
-                       ).transpose(1, 0, 2)  # [H, S, D]
+             ).reshape(s_pad, config.n_heads, config.head_dim)
         k = (h @ a["wk"]).reshape(s_pad, config.n_kv_heads, config.head_dim)
         v = (h @ a["wv"] + _lora_delta_seq(h, va_g[i], vb_g[i], lscale)
              ).reshape(s_pad, config.n_kv_heads, config.head_dim)
         # Per-row RoPE at global positions (suffix rows are not at 0).
-        q = _rotary_single(q.transpose(1, 0, 2), cos, sin,
-                           positions).transpose(1, 0, 2)
+        q = _rotary_single(q, cos, sin, positions)
         k = _rotary_single(k, cos, sin, positions)
-        k_pool = k_pool.at[i, page_idx, :, off, :].set(
-            k.astype(k_pool.dtype))
-        v_pool = v_pool.at[i, page_idx, :, off, :].set(
-            v.astype(v_pool.dtype))
-        # Gather the WHOLE table (cached prefix + fresh suffix) like the
-        # decode step; causal mask in global positions.
-        k_seq = k_pool[i, page_table].transpose(1, 0, 2, 3).reshape(
-            config.n_kv_heads, maxp * ps, config.head_dim)
-        v_seq = v_pool[i, page_table].transpose(1, 0, 2, 3).reshape(
-            config.n_kv_heads, maxp * ps, config.head_dim)
-        if n_rep > 1:
-            k_seq = jnp.repeat(k_seq, n_rep, axis=0)
-            v_seq = jnp.repeat(v_seq, n_rep, axis=0)
-        scores = jnp.einsum("hqd,hkd->hqk", q.astype(jnp.float32),
-                            k_seq.astype(jnp.float32)) \
-            * (config.head_dim ** -0.5)
-        scores = jnp.where(kpos <= qpos, scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(v_seq.dtype)
-        out = jnp.einsum("hqk,hkd->hqd", probs, v_seq)
-        x = x + out.transpose(1, 0, 2).reshape(s_pad, -1) @ a["wo"]
+        k_pool = _write_rows(k_pool, i, page_idx, off, k)
+        v_pool = _write_rows(v_pool, i, page_idx, off, v)
+        # Attend the WHOLE table (cached prefix + fresh suffix) like the
+        # decode step, as a batch of one.
+        out = _attend_pages(config, q[None], k_pool, v_pool, i,
+                            page_table[None], visible)
+        x = x + out[0] @ a["wo"]
         h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
         x = x + _mlp(layer, h)
     x = rms_norm(x, params["final_norm"], config.norm_eps)

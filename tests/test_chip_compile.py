@@ -334,15 +334,14 @@ def test_b1_train_step_partitions_over_four_chips(v5e, kernels_as_on_chip):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES / 2
 
 
-def _smoke_engine_args(v5e):
+def _engine_args(v5e, cfg, ec):
+    """Shapes of everything the paged programs take, on one described chip."""
     from ray_tpu.models import llama_init
     from ray_tpu.models.paged import init_adapter_pool, init_paged_pools
-    from ray_tpu.serve.engine import EngineConfig, _b1_config
 
     one = SingleDeviceSharding(v5e.devices[0])
     place = functools.partial(
         jax.tree.map, lambda x: _on(one, x.shape, x.dtype))
-    cfg, ec = _b1_config(), EngineConfig(**chip_smoke.SERVE_ENGINE)
     params = place(jax.eval_shape(
         lambda: llama_init(cfg, jax.random.PRNGKey(0))))
     pools = place(jax.eval_shape(
@@ -353,36 +352,87 @@ def _smoke_engine_args(v5e):
     return cfg, ec, params, pools, adapters, key, functools.partial(_on, one)
 
 
+def _lower_paged(program, args, bucket=None):
+    """One program of ``models/paged.py`` lowered on ``_engine_args``'s
+    shapes (``bucket``: the padded prompt length of the two prefills)."""
+    from ray_tpu.models import paged
+
+    cfg, ec, params, pools, adapters, key, on = args
+    b, i32 = ec.batch_slots, jnp.int32
+    scalar, temp = on((), i32), on((), jnp.float32)
+    toks, table = on((1, bucket or 1), i32), on((ec.pages_per_seq,), i32)
+    if program == "paged_decode_step":
+        return paged.paged_decode_step.lower(
+            cfg, params, pools, adapters, on((b,), i32),
+            on((b, ec.pages_per_seq), i32), on((b,), i32), on((b,), bool),
+            on((b,), jnp.float32), on((b,), i32), key)
+    if program == "paged_prefill":
+        return paged.paged_prefill.lower(
+            cfg, params, pools, adapters, toks, scalar, table, scalar, temp,
+            key)
+    if program == "paged_prefill_prefix":
+        return paged.paged_prefill_prefix.lower(
+            cfg, params, pools, adapters, toks, scalar, scalar, table,
+            scalar, temp, key)
+    assert program == "copy_page"
+    return paged.copy_page.lower(pools, scalar, scalar)
+
+
+def _smoke_engine_args(v5e):
+    from ray_tpu.serve.engine import EngineConfig, _b1_config
+
+    return _engine_args(v5e, _b1_config(),
+                        EngineConfig(**chip_smoke.SERVE_ENGINE))
+
+
 @pytest.mark.slow  # ~10 s
 def test_b1_decode_step_fits_one_chip(v5e):
-    from ray_tpu.models.paged import paged_decode_step
-
-    cfg, ec, params, pools, adapters, key, on = _smoke_engine_args(v5e)
-    b = ec.batch_slots
-    ma = paged_decode_step.lower(
-        cfg, params, pools, adapters, on((b,), jnp.int32),
-        on((b, ec.pages_per_seq), jnp.int32), on((b,), jnp.int32),
-        on((b,), bool), on((b,), jnp.float32), on((b,), jnp.int32), key,
-    ).compile().memory_analysis()
+    ma = _lower_paged("paged_decode_step", _smoke_engine_args(v5e)
+                      ).compile().memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
 
 
 @pytest.mark.slow  # ~15 s each
-@pytest.mark.parametrize("prefix", [False, True], ids=["cold", "suffix"])
-def test_b1_largest_prefill_bucket_fits_one_chip(v5e, prefix):
-    from ray_tpu.models.paged import paged_prefill, paged_prefill_prefix
-
-    cfg, ec, params, pools, adapters, key, on = _smoke_engine_args(v5e)
-    scalar = on((), jnp.int32)
-    toks = on((1, ec.prefill_buckets()[-1]), jnp.int32)
-    table = on((ec.pages_per_seq,), jnp.int32)
-    if prefix:
-        lowered = paged_prefill_prefix.lower(
-            cfg, params, pools, adapters, toks, scalar, scalar, table,
-            scalar, on((), jnp.float32), key)
-    else:
-        lowered = paged_prefill.lower(
-            cfg, params, pools, adapters, toks, scalar, table, scalar,
-            on((), jnp.float32), key)
-    ma = lowered.compile().memory_analysis()
+@pytest.mark.parametrize("program", ["paged_prefill", "paged_prefill_prefix"],
+                         ids=["cold", "suffix"])
+def test_b1_largest_prefill_bucket_fits_one_chip(v5e, program):
+    args = _smoke_engine_args(v5e)
+    ma = _lower_paged(program, args, bucket=args[1].prefill_buckets()[-1]
+                      ).compile().memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("program", [
+    "paged_decode_step", "paged_prefill", "paged_prefill_prefix",
+    "copy_page"])
+def test_paged_programs_never_copy_the_pool(v5e, program):
+    """The donated pools keep the layout they are declared in from argument
+    to aliased result, and attention holds no second copy of a table's K/V:
+    no instruction of the optimized HLO with a pool-shaped result is a
+    ``copy`` (a layout the write or the read does not want costs a
+    transpose of the whole pool in and one out, every call), and the
+    temporaries stay under a quarter of one pool (the gathered pages,
+    upcast, transposed and GQA-repeated, were three pools' worth).  A
+    2-layer model at internlm2-1.8b's widths (GQA 16/8, heads of 128) on
+    the serving cells' engine geometry, bucket 128: ~3 s a program."""
+    import re
+
+    from ray_tpu.models import LlamaConfig
+    from ray_tpu.serve.engine import EngineConfig
+
+    ec = EngineConfig(batch_slots=16, page_size=128, max_prompt_len=1024,
+                      max_new_tokens_cap=256)
+    cfg = LlamaConfig(vocab_size=92544, d_model=2048, n_layers=2,
+                      n_heads=16, n_kv_heads=8, d_ff=8192, remat=False,
+                      max_seq=ec.pages_per_seq * ec.page_size)
+    args = _engine_args(v5e, cfg, ec)
+    pool = args[3]["k"]
+    compiled = _lower_paged(program, args, bucket=128).compile()
+    dims = ",".join(str(d) for d in pool.shape)
+    pool_shaped = re.findall(
+        r"^\s*(?:ROOT\s+)?\S+ = \w+\[" + re.escape(dims) + r"\]\S* "
+        r"([\w-]+)\(", compiled.as_text(), re.M)
+    assert "parameter" in pool_shaped  # the pattern still reads this HLO
+    assert "copy" not in pool_shaped, pool_shaped
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4

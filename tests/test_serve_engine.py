@@ -66,6 +66,74 @@ def test_paged_decode_matches_reference_generate(engine):
     assert list(engine.submit(prompt, max_new_tokens=6)) == toks
 
 
+@pytest.mark.parametrize("n_heads,n_kv_heads", [(4, 4), (4, 2), (4, 1)],
+                         ids=["mha", "gqa", "mqa"])
+def test_paged_parity_for_every_kv_grouping(monkeypatch, n_heads,
+                                            n_kv_heads):
+    """One pool layout and one grouped contraction serve MHA (a group of
+    one), GQA and MQA: in float32 the engine's tokens equal
+    models.generate's through a cold prefill, a full-page prefix hit, a
+    prefix hit that diverges mid-page (copy-on-write), and decode across a
+    page edge; and the first token of each path has the plain full
+    forward's best logit to within 1e-5."""
+    import jax
+    import jax.numpy as jnp
+
+    import ray_tpu.models.paged as paged_mod
+    from ray_tpu.models import LlamaConfig, llama_apply, llama_init
+    from ray_tpu.models.generate import generate
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    cfg = LlamaConfig(vocab_size=512, d_model=128, n_layers=2,
+                      n_heads=n_heads, n_kv_heads=n_kv_heads, d_ff=256,
+                      max_seq=256, remat=False, dtype=jnp.float32)
+    params = llama_init(cfg, jax.random.PRNGKey(1))
+    copies, real_copy = [], paged_mod.copy_page
+
+    def counted_copy(pools, src, dst):
+        copies.append(int(dst))
+        return real_copy(pools, src, dst)
+
+    monkeypatch.setattr(paged_mod, "copy_page", counted_copy)
+
+    def ref(prompt, n):
+        return np.asarray(generate(
+            cfg, params, np.asarray([prompt], np.int32),
+            max_new_tokens=n))[0, len(prompt):].tolist()
+
+    def best_logit_gap(context, token):
+        logits = np.asarray(llama_apply(
+            cfg, params, jnp.asarray([context], jnp.int32)))[0, -1]
+        return float(logits.max() - logits[token])
+
+    eng = InferenceEngine(cfg, params,
+                          EngineConfig(max_queue=16, **GEOMETRY))
+    try:
+        # 12 tokens on 8-token pages: one page frozen into the prefix
+        # cache, and 6 new tokens write positions 12..17, over the edge
+        # at 16 into a third page.
+        prompt = list(range(2, 14))
+        cold = list(eng.submit(prompt, max_new_tokens=6))
+        assert cold == ref(prompt, 6)
+        assert best_logit_gap(prompt, cold[0]) <= 1e-5         # prefill
+        assert best_logit_gap(prompt + cold[:1], cold[1]) <= 1e-5  # decode
+        hits = eng.stats()["prefix_cache"]["hits"]
+        assert list(eng.submit(prompt, max_new_tokens=6)) == cold
+        assert eng.stats()["prefix_cache"]["hits"] == hits + 1 and not copies
+        # Five tokens shared with the cached page, then another tail: the
+        # page is copied and only its first five positions are kept.
+        fork = prompt[:5] + [91, 92, 93, 94, 95, 96, 97]
+        forked = list(eng.submit(fork, max_new_tokens=6))
+        assert forked == ref(fork, 6) and forked != cold
+        assert best_logit_gap(fork, forked[0]) <= 1e-5   # suffix prefill
+        assert eng.stats()["prefix_cache"]["hits"] == hits + 2
+        assert len(copies) == 1
+        eng.clear_prefix_cache()
+        assert eng.allocator.free_count == eng.allocator.total
+    finally:
+        eng.shutdown()
+
+
 def test_admission_mid_stream_stalls_at_most_one_step(engine):
     """A sequence admitted mid-stream joins the running batch between
     decode steps: the running sequence keeps emitting one token per step
