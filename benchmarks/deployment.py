@@ -1,13 +1,11 @@
 """The benchmark's deployment: a thin subclass of the program's
 ``LLMServer``, pickled to the replica like any user's deployment.  Inside
-the replica it registers the ``LlamaConfig`` built from the cell's
-configuration file under the configuration's name, runs
-``LLMServer.__init__`` unchanged, and carries the benchmark's replica-side
-methods: the window's marks, the device trace (only the process that holds
-the chip can trace it), and the comparison with the plain reference.
-
-A public ``register_model`` in ``ray_tpu/serve/engine.py`` would replace the
-write to ``_MODEL_BUILDERS``: listed in PERF.md for the ``tracing`` issue.
+the replica it has the configuration's family (``spec.family``) register
+the cell's configuration with the engine, runs ``LLMServer.__init__``
+unchanged under the name the family gives back, and carries the benchmark's
+replica-side methods: the window's marks, the device trace (only the
+process that holds the chip can trace it), and the comparison with the
+family's plain reference.
 """
 
 from __future__ import annotations
@@ -82,14 +80,12 @@ class BenchLLMServer(LLMServer):
                 raise RuntimeError("forced failure")
             from ray_tpu.serve import engine as eng
 
-            from .modelcfg import llama_config
+            from .spec import family
 
             ec = eng.EngineConfig(**engine)
-            cfg = llama_config(
-                model, remat=False,
-                max_seq=ec.pages_per_seq * ec.page_size)
-            eng._MODEL_BUILDERS[model["name"]] = lambda: cfg
-            super().__init__(model=model["name"], engine=engine,
+            name = family(model).register(
+                model, max_seq=ec.pages_per_seq * ec.page_size)
+            super().__init__(model=name, engine=engine,
                              seed=seed % (2 ** 31 - 1), warmup=True)
         except Exception as e:  # noqa: BLE001
             raise phase_error("compile", e) from e
@@ -171,8 +167,10 @@ class BenchLLMServer(LLMServer):
                        ) -> List[List[float]]:
         """For each {"prompt", "output"}: per generated token, the plain
         reference's best logit minus its logit of the emitted token."""
-        from .reference.llama_ref import Reference, teacher_forced_gaps
+        from .reference import teacher_forced_gaps
+        from .spec import family
 
-        ref = Reference(self._bench_model, self.engine.params)
+        ref = family(self._bench_model).reference(
+            self._bench_model, self.engine.params)
         return [teacher_forced_gaps(ref, s["prompt"], s["output"])
                 for s in samples]

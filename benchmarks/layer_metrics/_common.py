@@ -8,8 +8,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
-from ..arith import (flash_train_step_ops_bytes, load_peaks, median, mfu,
-                     roofline, train_flops_per_token)
+from ..arith import load_peaks, median, mfu, roofline
+from ..spec import family
 from ..trace_reduce import ops_time
 
 #: The Mosaic (Pallas) calls, as trace_reduce.label names them.  The Pallas
@@ -63,8 +63,10 @@ def train_mfu(ctx: Dict[str, Any]) -> Optional[float]:
         return None  # a CPU has no peak on record: no device number
     rate = ctx["tokens_per_step"] / median(ctx["step_s"])
     peaks = load_peaks(ctx["device"]["kind"])
-    return 100.0 * mfu(rate, train_flops_per_token(ctx["model"], ctx["seq"]),
-                       ctx["device"]["count"], peaks["bf16_flops"])
+    flops = family(ctx["model"]).train_flops_per_token(ctx["model"],
+                                                       ctx["seq"])
+    return 100.0 * mfu(rate, flops, ctx["device"]["count"],
+                       peaks["bf16_flops"])
 
 
 def flash_roofline(ctx: Dict[str, Any]) -> Optional[float]:
@@ -74,10 +76,8 @@ def flash_roofline(ctx: Dict[str, Any]) -> Optional[float]:
     seconds = ops_time(tr, *MOSAIC_NEEDLES)
     if not seconds or ctx["device"]["platform"] != "tpu":
         return None
-    layers = ctx["model"]["num_hidden_layers"]
-    forwards = max(1, ctx["tpu_custom_calls"] // layers - 2)
-    need = flash_train_step_ops_bytes(ctx["model"], ctx["batch"], ctx["seq"],
-                                      forwards)
+    need = family(ctx["model"]).train_step_kernel_ops_bytes(
+        ctx["model"], ctx["batch"], ctx["seq"], ctx["tpu_custom_calls"])
     peaks = load_peaks(ctx["device"]["kind"])
     steps = tr["traced_steps"]
     return 100.0 * roofline(need["ops"] * steps, need["bytes"] * steps,

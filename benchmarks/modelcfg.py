@@ -1,6 +1,8 @@
-"""From a configuration file (the published ``config.json`` keys) to the
-program's ``LlamaConfig``.  Imports JAX: only the process that holds the
-chip (replica, train worker) and the tests call it."""
+"""The llama family's way from a configuration file (the published
+``config.json`` keys) to the program's ``LlamaConfig``, reached by the
+harness through ``families/llama.py``.  ``llama_config`` imports JAX: only
+the process that holds the chip (replica, train worker) and the tests call
+it."""
 
 from __future__ import annotations
 
@@ -17,7 +19,7 @@ def check_supported(model: Dict[str, Any]) -> None:
         if key in model and model[key] != want:
             raise ValueError(
                 f"configuration {model.get('name')!r} has {key}="
-                f"{model[key]!r}; llama.py computes only {want!r}")
+                f"{model[key]!r}; the llama family computes only {want!r}")
     if model["hidden_size"] % model["num_attention_heads"]:
         raise ValueError("hidden_size is not a multiple of the head count")
 

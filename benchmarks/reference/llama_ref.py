@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import teacher_forced_gaps  # noqa: F401 (tests import it from here)
+
 Q_BLOCK = 1024
 
 
@@ -165,14 +167,3 @@ class Reference:
                 g_emb = g_emb.at[jnp.asarray(tokens[i])].add(cts[i])
             sq += float(jnp.sum(g_emb * g_emb))
         return total / n, math.sqrt(sq)
-
-
-def teacher_forced_gaps(ref: Reference, prompt: Sequence[int],
-                        output: Sequence[int]) -> List[float]:
-    """For each generated token: the reference's best logit at that
-    position minus the reference's logit of the token the system emitted
-    (0 when the system's token is the reference's argmax)."""
-    seq = np.asarray(list(prompt) + list(output[:-1]), np.int32)
-    positions = range(len(prompt) - 1, len(seq))
-    logits = ref.logits(seq, positions)
-    return [float(row.max() - row[tok]) for row, tok in zip(logits, output)]

@@ -1,10 +1,11 @@
 """Reads BENCHMARK.json and the files it names.  Nothing here knows a cell,
-a configuration, a traffic mix or a metric by name: a ``workloads`` entry
-names its ``config`` and ``traffic``, and each is a file found by that name.
-No JAX."""
+a configuration, a traffic mix, a metric or an architecture by name: a
+``workloads`` entry names its ``config`` and ``traffic``, a configuration
+file names its ``family``, and each is a file found by that name.  No JAX."""
 
 from __future__ import annotations
 
+import importlib
 import json
 import os
 import re
@@ -17,6 +18,8 @@ NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TRAFFIC_KINDS = ("serve_closed", "serve_open", "train")
+#: The family of a configuration file that names none.
+DEFAULT_FAMILY = "llama"
 
 
 class SpecError(ValueError):
@@ -140,6 +143,20 @@ def applies(metric: Dict[str, Any], cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
 
+def family(model: Dict[str, Any]):
+    """The module that knows ``model``'s architecture:
+    ``benchmarks/families/<family with . and - as _>.py``, by the ``family``
+    key of the loaded configuration file."""
+    name = model.get("family", DEFAULT_FAMILY)
+    module = str(name).replace(".", "_").replace("-", "_")
+    path = os.path.join(BENCH_DIR, "families", module + ".py")
+    if not (isinstance(name, str) and NAME_RE.match(name)
+            and os.path.isfile(path)):
+        raise SpecError(f"configuration {model.get('name')!r} is of family "
+                        f"{name!r}, and there is no {path}")
+    return importlib.import_module(f"benchmarks.families.{module}")
+
+
 def load_cell(name: str, root: str = ROOT) -> Dict[str, Any]:
     """Everything one run needs, from the names in BENCHMARK.json."""
     doc = load_benchmark(root)
@@ -155,10 +172,11 @@ def load_cell(name: str, root: str = ROOT) -> Dict[str, Any]:
     if traffic.get("kind") not in TRAFFIC_KINDS:
         raise SpecError(f"{traffic_path}: kind {traffic.get('kind')!r} is "
                         f"not one of {TRAFFIC_KINDS}")
+    model = load_json(os.path.join(root, cfg_entry["file"]))
+    family(model)  # a family with no file fails here, before any process
     return {
         "name": name, "chips": w["chips"], "why": w["why"],
-        "config_name": w["config"],
-        "model": load_json(os.path.join(root, cfg_entry["file"])),
+        "config_name": w["config"], "model": model,
         "traffic_name": w["traffic"], "traffic": traffic,
         "run_seconds": doc["run_seconds"],
         "end_to_end": [m for m in doc["end_to_end"] if applies(m, name)],
@@ -167,11 +185,13 @@ def load_cell(name: str, root: str = ROOT) -> Dict[str, Any]:
 
 
 def rehearsal_cell(cell: Dict[str, Any], root: str = ROOT) -> Dict[str, Any]:
-    """The same cell for the CPU rehearsal: the tiny configuration, and the
-    traffic file's own ``rehearsal`` overrides (sizes a CPU can run)."""
+    """The same cell for the CPU rehearsal: its family's tiny configuration,
+    and the traffic file's own ``rehearsal`` overrides (sizes a CPU can
+    run)."""
     out = dict(cell)
     out["model"] = load_json(os.path.join(
-        root, "benchmarks", "configs", "rehearsal-tiny.json"))
+        root, "benchmarks", "configs",
+        family(cell["model"]).REHEARSAL_CONFIG + ".json"))
     traffic = dict(cell["traffic"])
     for key, value in traffic.pop("rehearsal", {}).items():
         if isinstance(value, dict) and isinstance(traffic.get(key), dict):

@@ -43,12 +43,9 @@ def train_loop(config: Dict[str, Any]) -> None:
                 f"cell needs {config['platform']!r}: no fallback")
         devices_ready_wall = time.time()
 
-        from benchmarks import trace_reduce, traffic as gen
-        from benchmarks.modelcfg import llama_config
-        from benchmarks.reference.llama_ref import Reference
+        from benchmarks import spec, trace_reduce, traffic as gen
         from ray_tpu import train as rt_train
-        from ray_tpu.models import (TrainState, llama_init, llama_loss,
-                                    llama_sharding_rules)
+        from ray_tpu.models import TrainState
         from ray_tpu.models.train_state import (default_optimizer,
                                                 make_train_step)
         from ray_tpu.parallel.sharding import named_sharding
@@ -56,7 +53,8 @@ def train_loop(config: Dict[str, Any]) -> None:
         model, tr = config["model"], config["traffic"]
         batch_size, seq = tr["batch"], tr["seq"]
         seed = gen.seed32(config["seed"])
-        cfg = llama_config(model, max_seq=seq, **tr["model_options"])
+        fam = spec.family(model)
+        cfg = fam.program_config(model, max_seq=seq, **tr["model_options"])
         tx = default_optimizer(lr=tr["lr"], grad_clip=tr["grad_clip"])
         mesh = rt_train.get_mesh()
         if (mesh is None) != (tr["mesh"] is None):
@@ -64,15 +62,15 @@ def train_loop(config: Dict[str, Any]) -> None:
                                f"session gave {mesh}")
 
         def loss_fn(p, b):
-            return llama_loss(cfg, p, b["tokens"], b["targets"])
+            return fam.loss(cfg, p, b["tokens"], b["targets"])
 
         def host_batch(i: int):
             return gen.train_batch(config["seed"], i, batch_size, seq,
-                                   cfg.vocab_size)
+                                   model["vocab_size"])
 
         enter("compile")
         t_c = time.perf_counter()
-        init = lambda key: llama_init(cfg, key)  # noqa: E731
+        init = lambda key: fam.init(cfg, key)  # noqa: E731
         if mesh is None:
             rules, scope = None, contextlib.nullcontext
             make_params = jax.jit(init)
@@ -82,7 +80,7 @@ def train_loop(config: Dict[str, Any]) -> None:
         else:
             # A fresh context each time: jax.set_mesh's is spent after one
             # use, and entering it again sets no mesh, without a word.
-            rules = llama_sharding_rules()
+            rules = fam.sharding_rules(cfg)
             scope = lambda: jax.set_mesh(mesh)  # noqa: E731
             shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
             make_params = jax.jit(init, out_shardings=named_sharding(
@@ -103,12 +101,12 @@ def train_loop(config: Dict[str, Any]) -> None:
                 make_params(jax.random.PRNGKey(seed)))
         weights_s = time.perf_counter() - t_c
 
-        # The plain reference on the same weights and the first batch,
-        # before the optimizer state takes its room.
+        # The family's plain reference on the same weights and the first
+        # batch, before the optimizer state takes its room.
         enter("compare")
         t_r = time.perf_counter()
         b0 = host_batch(0)
-        ref_loss, ref_gnorm = Reference(
+        ref_loss, ref_gnorm = fam.reference(
             model, params, devices[0]).loss_and_grad_norm(
                 b0["tokens"], b0["targets"])
         reference_s = time.perf_counter() - t_r

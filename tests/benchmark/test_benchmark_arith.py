@@ -7,7 +7,7 @@ import os
 
 import pytest
 
-from bench_testlib import ROOT
+from bench_testlib import ROOT, repo_copy  # noqa: F401
 
 from benchmarks import arith, spec, trace_reduce, traffic
 
@@ -271,3 +271,107 @@ def test_validation_refuses(edit, message):
     edit(doc)
     with pytest.raises(spec.SpecError, match=message):
         spec.validate(doc)
+
+
+# ----------------------------------------------------------------- families
+
+#: What the dense counts gave before the harness asked a family for them
+#: (PR 26), at the cells' own sequence lengths and batches; a compiled step
+#: with three or four ``tpu_custom_call`` a layer ran the flash forward once
+#: or twice.
+COUNTS = {
+    "internlm2-1.8b": {
+        "param_count": 1_889_110_016, "matmul_params": 1_699_479_552,
+        "flops": {4096: 11_404_836_864.0, 2048: 10_800_857_088.0},
+        "kernels": {(4, 4096, 3): (23_089_744_183_296.0, 14_571_012_096.0),
+                    (4, 4096, 4): (29_686_813_949_952.0, 19_428_016_128.0),
+                    (8, 2048, 3): (11_544_872_091_648.0, 14_571_012_096.0),
+                    (8, 2048, 4): (14_843_406_974_976.0, 19_428_016_128.0)}},
+    "mistral-7b-v0.3-L4": {
+        "param_count": 1_140_887_552, "matmul_params": 1_006_632_960,
+        "flops": {4096: 6_442_450_944.0, 2048: 6_241_124_352.0},
+        "kernels": {(4, 4096, 3): (7_696_581_394_432.0, 4_051_697_664.0),
+                    (4, 4096, 4): (9_895_604_649_984.0, 5_402_263_552.0),
+                    (8, 2048, 3): (3_848_290_697_216.0, 4_051_697_664.0),
+                    (8, 2048, 4): (4_947_802_324_992.0, 5_402_263_552.0)}},
+}
+
+
+def _config(name):
+    return spec.load_json(os.path.join(
+        ROOT, "benchmarks", "configs", name + ".json"))
+
+
+@pytest.mark.parametrize("name", sorted(COUNTS))
+def test_a_file_without_the_key_is_of_the_llama_family(name):
+    model = _config(name)
+    assert "family" not in model
+    assert spec.family(model).__name__ == "benchmarks.families.llama"
+    assert spec.family(model).REHEARSAL_CONFIG == "rehearsal-tiny"
+
+
+@pytest.mark.parametrize("name", sorted(COUNTS))
+def test_the_familys_parameter_counts_did_not_move(name):
+    model = _config(name)
+    fam = spec.family(model)
+    assert fam.param_count(model) == COUNTS[name]["param_count"]
+    assert fam.matmul_params(model) == COUNTS[name]["matmul_params"]
+
+
+@pytest.mark.parametrize("seq", [4096, 2048])
+@pytest.mark.parametrize("name", sorted(COUNTS))
+def test_the_familys_train_flops_did_not_move(name, seq):
+    model = _config(name)
+    got = spec.family(model).train_flops_per_token(model, seq)
+    assert got == COUNTS[name]["flops"][seq]
+    assert got == arith.train_flops_per_token(model, seq)
+
+
+@pytest.mark.parametrize("calls_per_layer", [3, 4])
+@pytest.mark.parametrize("batch,seq", [(4, 4096), (8, 2048)])
+@pytest.mark.parametrize("name", sorted(COUNTS))
+def test_the_familys_kernel_counts_did_not_move(name, batch, seq,
+                                                calls_per_layer):
+    model = _config(name)
+    calls = calls_per_layer * model["num_hidden_layers"]
+    got = spec.family(model).train_step_kernel_ops_bytes(
+        model, batch, seq, calls)
+    assert (got["ops"], got["bytes"]) \
+        == COUNTS[name]["kernels"][batch, seq, calls_per_layer]
+    assert got == arith.flash_train_step_ops_bytes(
+        model, batch, seq, calls_per_layer - 2)
+
+
+def test_a_step_with_no_kernel_count_reads_as_one_forward():
+    """``max(1, ...)``: as before, a step whose text was not counted does
+    not read as a flash-free step."""
+    model = _config("mistral-7b-v0.3-L4")
+    assert spec.family(model).train_step_kernel_ops_bytes(
+        model, 4, 4096, 0) == arith.flash_train_step_ops_bytes(
+            model, 4, 4096, 1)
+
+
+@pytest.mark.parametrize("name", ["no-such", "../spec", 7])
+def test_a_family_with_no_file_is_refused_by_name_and_path(name):
+    model = dict(_config("internlm2-1.8b"), family=name)
+    with pytest.raises(spec.SpecError) as e:
+        spec.family(model)
+    assert repr(name) in str(e.value) and "internlm2-1.8b" in str(e.value)
+    assert os.path.join("benchmarks", "families") in str(e.value)
+
+
+def test_a_cell_of_a_family_with_no_file_fails_before_any_process(
+        repo_copy):  # noqa: F811
+    path = repo_copy / "benchmarks" / "configs" / "internlm2-1.8b.json"
+    model = dict(json.load(open(path)), family="olmoe")
+    json.dump(model, open(path, "w"))
+    with pytest.raises(spec.SpecError, match="'olmoe'") as e:
+        spec.load_cell("internlm2-1.8b.serve-mixed", str(repo_copy))
+    assert os.path.join(ROOT, "benchmarks", "families", "olmoe.py") \
+        in str(e.value)
+
+
+def test_the_llama_family_refuses_what_it_does_not_compute():
+    model = dict(_config("internlm2-1.8b"), tie_word_embeddings=True)
+    with pytest.raises(ValueError, match="the llama family computes only"):
+        spec.family(model).check_supported(model)

@@ -3,10 +3,13 @@ rehearsal mode), once for each traffic kind: the last line of stdout has
 exactly the contract's keys, and a run leaves nothing behind.  A rehearsal
 says ``"platform": "cpu"`` and is never a measurement."""
 
+import hashlib
 import json
+import os
+import shutil
 
-from bench_testlib import (RESULT_KEYS, assert_nothing_left,  # noqa: F401
-                           repo_copy, run_bench)
+from bench_testlib import (RESULT_KEYS, ROOT,  # noqa: F401
+                           assert_nothing_left, repo_copy, run_bench)
 
 SAT = "internlm2-1.8b.serve-saturated"
 MIX = "internlm2-1.8b.serve-mixed"
@@ -118,3 +121,81 @@ def test_a_cell_a_configuration_a_mix_and_a_metric_are_added_as_files(
     samples = next(l for l in lines if l.get("phase") == "samples")
     assert samples["loss_rel_err"] < 1e-4
     assert samples["grad_norm_rel_err"] < 1e-3
+
+
+def _digests(root):
+    """{relative path: sha256} of every file under ``root`` but
+    BENCHMARK.json (which takes the entries) and what a run leaves."""
+    out = {}
+    for d, _, names in os.walk(root):
+        if "__pycache__" in d:
+            continue
+        for n in names:
+            p = os.path.join(d, n)
+            if os.path.isfile(p) and n != "BENCHMARK.json":
+                with open(p, "rb") as f:
+                    out[os.path.relpath(p, root)] = hashlib.sha256(
+                        f.read()).hexdigest()
+    return out
+
+
+def test_a_family_is_added_as_files(repo_copy):  # noqa: F811
+    """In a temporary copy: a family whose head is tied to the embedding
+    (``tied_family/``: its module, its reference, its tiny configuration),
+    one configuration of it and two cells, as files and entries only.  The
+    training cell is held to that family's reference.  The serving cell
+    gets as far as the program lets it: the family's registration serves
+    the configuration, and the family's reference then finds the engine's
+    head untied, because ``LLMServer`` makes its weights with ``llama_init``
+    whatever the family (ROADMAP D2)."""
+    before = _digests(repo_copy)
+    b = repo_copy / "benchmarks"
+    src = os.path.join(ROOT, "tests", "benchmark", "tied_family")
+    shutil.copy(os.path.join(src, "family.py"), b / "families" / "tied.py")
+    shutil.copy(os.path.join(src, "reference.py"),
+                b / "reference" / "tied_ref.py")
+    shutil.copy(os.path.join(src, "tied-tiny.json"), b / "configs")
+    added = json.load(open(b / "configs" / "tied-tiny.json"))
+    added["name"] = "added-tied"
+    json.dump(added, open(b / "configs" / "added-tied.json", "w"))
+    doc = json.load(open(repo_copy / "BENCHMARK.json"))
+    doc["configs"].append({
+        "name": "added-tied", "source": "a test",
+        "file": "benchmarks/configs/added-tied.json", "reduced": [],
+        "why": "a test"})
+    for traffic, like in (("train-1chip", T1), ("serve-mixed", MIX)):
+        name = "added-tied." + traffic
+        doc["workloads"].append({
+            "name": name, "config": "added-tied", "traffic": traffic,
+            "chips": 1, "why": "a test"})
+        for m in doc["end_to_end"] + doc["per_layer"]:
+            if like in m.get("workloads", ()):
+                m["workloads"].append(name)
+    json.dump(doc, open(repo_copy / "BENCHMARK.json", "w"))
+
+    rc, lines, err = run_bench(
+        "--workload", "added-tied.train-1chip", "--seed", "3000000013",
+        "--seconds", "2", "--trace", "0", "--rehearse", root=str(repo_copy))
+    assert rc == 0, err[-2000:]
+    check_result(lines, {"train_tok_s", "setup_s"})
+    samples = next(l for l in lines if l.get("phase") == "samples")
+    assert samples["loss_rel_err"] < 1e-4
+    assert samples["grad_norm_rel_err"] < 1e-3
+    assert_nothing_left(lines)
+
+    rc, lines, err = run_bench(
+        "--workload", "added-tied.serve-mixed", "--seed", "8", "--seconds",
+        "3", "--trace", "0", "--rehearse", root=str(repo_copy))
+    assert rc == 0, err[-2000:]
+    out = lines[-1]
+    assert set(out["metrics"]) == {"itl_p95_ms", "setup_s"}
+    assert out["attempted"] > 0 and out["failed"] == 0
+    # The llama family refuses a tied head and its reference agrees with
+    # whatever tree it is given, so this verdict is the tied family's.
+    assert out["correct"] is False
+    reasons = [l["reason"] for l in lines if l.get("phase") == "incorrect"]
+    assert len(reasons) == 1 and "reference logit" in reasons[0], reasons
+    assert_nothing_left(lines)
+
+    after = _digests(repo_copy)
+    assert {p: h for p, h in after.items() if p in before} == before
