@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rotary, rope_frequencies
-from .llama import LlamaConfig, _mlp
+from .llama import LlamaConfig, _mlp, _qk_norm
 
 Params = Any
 KVCache = Dict[str, jax.Array]  # {"k": [L,B,H_kv,S,D], "v": ...}
@@ -42,10 +42,11 @@ def init_kv_cache(config: LlamaConfig, batch: int,
 def _qkv(config: LlamaConfig, layer, x):
     B, S, _ = x.shape
     a = layer["attn"]
-    q = (x @ a["wq"]).reshape(B, S, config.n_heads, config.head_dim
-                              ).transpose(0, 2, 1, 3)
-    k = (x @ a["wk"]).reshape(B, S, config.n_kv_heads, config.head_dim
-                              ).transpose(0, 2, 1, 3)
+    q, k = _qk_norm(config, a, x @ a["wq"], x @ a["wk"])
+    q = q.reshape(B, S, config.n_heads, config.head_dim
+                  ).transpose(0, 2, 1, 3)
+    k = k.reshape(B, S, config.n_kv_heads, config.head_dim
+                  ).transpose(0, 2, 1, 3)
     v = (x @ a["wv"]).reshape(B, S, config.n_kv_heads, config.head_dim
                               ).transpose(0, 2, 1, 3)
     return q, k, v

@@ -43,6 +43,9 @@ class LlamaConfig:
     max_seq: int = 4096
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
+    # QK-norm (OLMo-2 / OLMoE): RMSNorm with a learned weight over the whole
+    # q and k projection widths, before the split into heads and RoPE.
+    qk_norm: bool = False
     dtype: Any = jnp.bfloat16
     # remat: rematerialize each block in backward (HBM <-> FLOPs trade)
     remat: bool = True
@@ -80,6 +83,8 @@ class LlamaConfig:
             + 3 * d * f  # w1, w2, w3 (w2 transposed)
             + 2 * d  # norms
         )
+        if self.qk_norm:
+            per_layer += d + self.n_kv_heads * self.head_dim
         return v * d + self.n_layers * per_layer + d + d * v
 
     # ---- stock sizes ------------------------------------------------------
@@ -148,7 +153,27 @@ def llama_init(config: LlamaConfig, key: jax.Array) -> Params:
                 "w2": dense(ks[6], (f, d), f ** -0.5),  # down
             },
         })
+        if config.qk_norm:
+            params["layers"][-1]["attn"].update(qk_norm_init(config))
     return params
+
+
+def qk_norm_init(config) -> Params:
+    """The two QK-norm weights of one layer's ``attn`` (see ``_qk_norm``)."""
+    return {"q_norm": jnp.ones((config.d_model,), config.dtype),
+            "k_norm": jnp.ones((config.n_kv_heads * config.head_dim,),
+                               config.dtype)}
+
+
+def _qk_norm(config, a: Params, q: jax.Array, k: jax.Array):
+    """QK-norm where the configuration has it: q [..., H*D] and
+    k [..., H_kv*D] are the flat projections, normalised over their whole
+    width before they are split into heads and rotated.  A trace-time
+    branch: a model without it compiles to the program it always did."""
+    if not config.qk_norm:
+        return q, k
+    return (rms_norm(q, a["q_norm"], config.norm_eps),
+            rms_norm(k, a["k_norm"], config.norm_eps))
 
 
 def llama_sharding_rules() -> ShardingRules:
@@ -203,6 +228,7 @@ def _attention(config: LlamaConfig, x, layer, cos, sin, lora_layer=None):
         scale = lora_layer["scale"]
         q = q + ((x @ lora_layer["wq_lora_a"]) @ lora_layer["wq_lora_b"]) * scale
         v = v + ((x @ lora_layer["wv_lora_a"]) @ lora_layer["wv_lora_b"]) * scale
+    q, k = _qk_norm(config, a, q, k)
     q = q.reshape(B, S, config.n_heads, hd).transpose(0, 2, 1, 3)
     k = k.reshape(B, S, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
     v = v.reshape(B, S, config.n_kv_heads, hd).transpose(0, 2, 1, 3)

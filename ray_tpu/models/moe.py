@@ -1,32 +1,33 @@
-"""Mixture-of-Experts decoder (Mixtral-family), TPU-first with expert
+"""Mixture-of-Experts decoder (Mixtral, OLMoE), TPU-first with expert
 parallelism.
 
 The reference framework has no MoE/EP feature (SURVEY §2.4: expert parallel
 "absent as a framework feature") — this is a net-new, first-class TPU
 capability, like sequence parallelism: the `ep` mesh axis shards the expert
-dimension, and the dispatch/combine einsums against one-hot routing masks
-let XLA insert the all_to_all collectives (the GShard/Switch formulation —
-hand-rolled NCCL alltoall is exactly what a TPU build must NOT do).
+dimension.
 
-Design (token-choice top-k with capacity):
-- router: logits [.., E]; top-k experts per token, probabilities renormalized
-- dispatch: one-hot [G, E, C] mask (G tokens/group, C capacity slots);
-  expert inputs gather to [E, C, d] — a single einsum, MXU-friendly
-- experts: batched SwiGLU over the leading E dim ([E, C, d] @ [E, d, f]),
-  sharded P(ep, ...) so each ep shard computes only its experts
-- combine: weighted einsum back to [G, d]; tokens over capacity are dropped
-  (their residual path carries them — standard Switch behavior)
+Design (token-choice top-k, no capacity and no drops):
+- router: logits [.., E] in float32; softmax over all experts, the top-k
+  probabilities kept as they are (OLMoE) or renormalised (Mixtral)
+- the tokens x top_k (token, expert) pairs are sorted by expert, so each
+  expert's rows are one contiguous group of a [tokens*k, d] array
+- experts: three grouped products over those groups (``jax.lax.ragged_dot``,
+  which XLA lowers on the TPU to a grouped-matmul kernel of its own): memory
+  and operations are proportional to tokens x top_k whatever the load; an
+  expert with no token costs nothing and one with every token is just a
+  long group
+- combine: the pairs are put back in token order and summed with their
+  router weights in float32
 - aux loss: Switch load-balancing loss (mean expert fraction x mean router
   probability x E), returned separately so the trainer can weight it.
 
-`n_experts=1, top_k=1` with ample capacity reduces exactly to the dense
-SwiGLU MLP — the correctness anchor used in tests.
+`n_experts=1, top_k=1` reduces exactly to the dense SwiGLU MLP — the
+correctness anchor used in tests.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -37,14 +38,18 @@ from ..ops.norms import rms_norm
 from ..ops.rotary import rope_frequencies
 from ..parallel.mesh import AXIS_EP, AXIS_FSDP, AXIS_TP
 from ..parallel.sharding import ShardingRules
-from .llama import LlamaConfig, _attention, llama_sharding_rules
+from .llama import (LlamaConfig, _attention, llama_sharding_rules,
+                    qk_norm_init)
 
 Params = Dict[str, Any]
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    """Mixtral-style: Llama attention + MoE FFN every layer."""
+    """Llama attention + a routed FFN in every layer.  The defaults are
+    Mixtral's; OLMoE keeps its top-k probabilities as the softmax gave them
+    (``norm_topk_prob=False``) and normalises q and k (``qk_norm=True``).
+    ``d_ff`` is the width of ONE expert."""
 
     vocab_size: int = 32000
     d_model: int = 4096
@@ -54,7 +59,8 @@ class MoEConfig:
     d_ff: int = 14336
     n_experts: int = 8
     top_k: int = 2
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True
+    qk_norm: bool = False
     max_seq: int = 4096
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
@@ -66,12 +72,6 @@ class MoEConfig:
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
-    def capacity(self, n_tokens: int) -> int:
-        """Per-expert slot count for a group of ``n_tokens``."""
-        c = math.ceil(n_tokens * self.top_k * self.capacity_factor
-                      / self.n_experts)
-        return max(4, int(c))
-
     def param_count(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         kv = self.n_kv_heads * self.head_dim
@@ -81,6 +81,8 @@ class MoEConfig:
             + self.n_experts * 3 * d * f         # experts
             + 2 * d
         )
+        if self.qk_norm:
+            per_layer += d + kv
         return v * d + self.n_layers * per_layer + d + d * v
 
     def as_llama(self) -> LlamaConfig:
@@ -90,7 +92,7 @@ class MoEConfig:
             n_layers=self.n_layers, n_heads=self.n_heads,
             n_kv_heads=self.n_kv_heads, d_ff=self.d_ff,
             max_seq=self.max_seq, rope_theta=self.rope_theta,
-            norm_eps=self.norm_eps, dtype=self.dtype,
+            norm_eps=self.norm_eps, qk_norm=self.qk_norm, dtype=self.dtype,
         )
 
     # ---- stock sizes ------------------------------------------------------
@@ -144,6 +146,8 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
                 "w2": dense(ks[7], (E, f, d), f ** -0.5),
             },
         })
+        if config.qk_norm:
+            params["layers"][-1]["attn"].update(qk_norm_init(config))
     return params
 
 
@@ -160,58 +164,68 @@ def moe_sharding_rules() -> ShardingRules:
     ])
 
 
-def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array
-             ) -> Tuple[jax.Array, jax.Array]:
-    """Top-k expert FFN over [B, S, d].  Returns (out, aux_loss)."""
-    B, S, d = x.shape
-    E, k = config.n_experts, config.top_k
-    G = B * S
-    C = config.capacity(G)
-    xf = x.reshape(G, d)
-
-    logits = (xf.astype(jnp.float32) @ moe["router"])          # [G, E]
+def _route(config: MoEConfig, moe: Params, xf: jax.Array
+           ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """The router on tokens xf [G, d], in float32 (a float32 product too:
+    the TPU's default would round the router's weights to bfloat16, and
+    which experts are the top k turns on differences that small): the
+    softmax over all experts [G, E], and each token's top-k probabilities
+    and experts [G, k] (renormalised to sum to 1 only where the
+    architecture does)."""
+    logits = jnp.matmul(xf.astype(jnp.float32), moe["router"],
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)                     # [G, k]
-    top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    top_p, top_e = jax.lax.top_k(probs, config.top_k)
+    if config.norm_topk_prob:
+        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    return probs, top_p, top_e
 
-    # Capacity assignment: for each (expert, slot) pair, position of this
-    # token among the expert's claimants in token order (GShard's
-    # position_in_expert via masked cumsum).
-    onehot = jax.nn.one_hot(top_e, E, dtype=jnp.float32)       # [G, k, E]
-    # priority: earlier k-choices claim slots first, then token order.
-    flat = onehot.transpose(1, 0, 2).reshape(k * G, E)         # [k*G, E]
-    pos = jnp.cumsum(flat, axis=0) - flat                      # claim index
-    keep = (pos < C) * flat
-    slot = pos.reshape(k, G, E).transpose(1, 0, 2)             # [G, k, E]
-    keep = keep.reshape(k, G, E).transpose(1, 0, 2)
 
-    # dispatch[G, E, C]: token -> (expert, slot) one-hot (dropped tokens all
-    # zero); combine adds the renormalized router weight.
-    slot_oh = jax.nn.one_hot(
-        slot.astype(jnp.int32), C, dtype=jnp.float32
-    ) * keep[..., None]
-    dispatch = slot_oh.sum(1)                                  # [G, E, C]
-    combine = jnp.einsum("gk,gkec->gec", top_p, slot_oh)       # [G, E, C]
+def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
+             valid: Optional[jax.Array] = None
+             ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Top-k expert FFN over x [..., d]: every token reaches all ``top_k``
+    of its experts at any load.  Returns (out, aux_loss, counts), counts
+    [E] int32 = the tokens each expert got.
 
-    expert_in = jnp.einsum(
-        "gec,gd->ecd", dispatch.astype(config.dtype), xf
-    )                                                          # [E, C, d]
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", expert_in, moe["w1"]))
-    h = h * jnp.einsum("ecd,edf->ecf", expert_in, moe["w3"])
-    expert_out = jnp.einsum("ecf,efd->ecd", h, moe["w2"])      # [E, C, d]
+    ``valid`` [...] bool marks the real tokens of a statically shaped batch
+    (the serving programs' padded prompt rows and empty slots): the others
+    are sorted behind every group, so no expert multiplies them, they are
+    in no count, and their output is zero."""
+    lead, d = x.shape[:-1], x.shape[-1]
+    E, k = config.n_experts, config.top_k
+    xf = x.reshape(-1, d)
+    G = xf.shape[0]
+    with jax.named_scope("moe_ffn"):
+        probs, top_p, top_e = _route(config, moe, xf)
+        if valid is not None:
+            top_e = jnp.where(valid.reshape(G, 1), top_e, E)
+        # Sort the G*k pairs by expert (stable: token order inside a
+        # group); ``rank`` is where each pair went.
+        pair_e = top_e.reshape(G * k)
+        order = jnp.argsort(pair_e, stable=True)
+        rank = jnp.zeros_like(order).at[order].set(jnp.arange(G * k))
+        counts = jnp.sum(pair_e[:, None] == jnp.arange(E)[None, :], axis=0,
+                         dtype=jnp.int32)
+        xs = xf[order // k]                                    # [G*k, d]
 
-    out = jnp.einsum(
-        "gec,ecd->gd", combine.astype(config.dtype), expert_out
-    )
+        def grouped(rows, w):
+            return jax.lax.ragged_dot(rows, w, counts,
+                                      preferred_element_type=jnp.float32)
 
-    # Switch load-balancing loss: E * sum_e f_e * P_e, where f_e is the
-    # fraction of tokens whose TOP-1 choice is e and P_e the mean router
-    # probability for e.
-    top1 = jax.nn.one_hot(top_e[:, 0], E, dtype=jnp.float32)
-    frac_tokens = top1.mean(0)
-    frac_prob = probs.mean(0)
-    aux = E * jnp.sum(frac_tokens * frac_prob)
-    return out.reshape(B, S, d), aux
+        h = (jax.nn.silu(grouped(xs, moe["w1"])) * grouped(xs, moe["w3"])
+             ).astype(config.dtype)
+        ys = grouped(h, moe["w2"])[rank].reshape(G, k, d)      # float32
+        if valid is not None:  # rows behind the last group are not written
+            ys = jnp.where((top_e < E)[..., None], ys, 0.0)
+        out = jnp.einsum("gk,gkd->gd", top_p, ys).astype(config.dtype)
+
+        # Switch load-balancing loss: E * sum_e f_e * P_e, where f_e is the
+        # fraction of tokens whose TOP-1 choice is e and P_e the mean router
+        # probability for e.
+        top1 = jax.nn.one_hot(top_e[:, 0], E, dtype=jnp.float32)
+        aux = E * jnp.sum(top1.mean(0) * probs.mean(0))
+    return out.reshape(*lead, d), aux, counts
 
 
 def _moe_block(config: MoEConfig, x, layer, cos, sin):
@@ -219,7 +233,7 @@ def _moe_block(config: MoEConfig, x, layer, cos, sin):
     h = rms_norm(x, layer["attn_norm"], config.norm_eps)
     x = x + _attention(lconf, h, layer, cos, sin)
     h = rms_norm(x, layer["moe_norm"], config.norm_eps)
-    ffn, aux = _moe_ffn(config, layer["moe"], h)
+    ffn, aux, _ = _moe_ffn(config, layer["moe"], h)
     return x + ffn, aux
 
 

@@ -43,7 +43,8 @@ import jax.numpy as jnp
 
 from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rotary, rope_frequencies
-from .llama import LlamaConfig, _mlp
+from .llama import LlamaConfig, _mlp, _qk_norm
+from .moe import MoEConfig, _moe_ffn
 
 Params = Any
 PagedPools = Dict[str, jax.Array]  # {"k": [L, P+1, page, H_kv, D], "v": ...}
@@ -127,6 +128,50 @@ def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
     probs = jax.nn.softmax(scores, axis=-1).astype(v_seq.dtype)
     out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_seq)
     return out.reshape(B, Q, -1)
+
+
+def _ffn(config, layer: Params, x: jax.Array, valid: jax.Array):
+    """The second half of the block, x + FFN(norm(x)): dense or routed by
+    what the configuration object is (a trace-time branch, so a dense model
+    compiles to the program it always did).  ``valid`` marks the rows that
+    hold a real token; only a routed FFN looks at it.  Returns (x, the
+    routed layer's per-expert token counts [E], or None)."""
+    if isinstance(config, MoEConfig):
+        h = rms_norm(x, layer["moe_norm"], config.norm_eps)
+        out, _, counts = _moe_ffn(config, layer["moe"], h, valid)
+        return x + out, counts
+    h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
+    return x + _mlp(layer, h), None
+
+
+#: What ``_with_routing`` appends, in its order: the step record's keys.
+ROUTING_KEYS = ("experts_hit", "expert_pairs", "expert_load_max")
+
+
+def routing_width(config) -> int:
+    """How many int32 counters a program of ``config`` appends to the
+    tokens it returns: ``len(ROUTING_KEYS)`` where the FFN is routed."""
+    return len(ROUTING_KEYS) if isinstance(config, MoEConfig) else 0
+
+
+def _with_routing(toks: jax.Array, counts: List[Optional[jax.Array]]
+                  ) -> jax.Array:
+    """``toks`` [N] int32, followed (where the layers were routed) by the
+    program's ``ROUTING_KEYS``: over all layers, the experts that got a
+    token, the (token, expert) pairs routed, and the most tokens on one
+    expert of one layer.  They ride in the array the engine reads back
+    anyway, so they cost it no transfer of their own."""
+    if counts[0] is None:
+        return toks
+    c = jnp.stack(counts)  # [L, E]
+    return jnp.concatenate([toks, jnp.stack(
+        [jnp.sum(c > 0, dtype=jnp.int32), jnp.sum(c), jnp.max(c)])])
+
+
+def _first_token(tok: jax.Array, counts) -> jax.Array:
+    """A prefill's result: the scalar token, or [token, *ROUTING_KEYS]
+    where the layers were routed."""
+    return tok[0] if counts[0] is None else _with_routing(tok, counts)
 
 
 # ------------------------------------------------------- adapter pool
@@ -284,6 +329,54 @@ def _sample_tokens(logits: jax.Array, temps: jax.Array,
     return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
 
 
+def decode_logits(config, params: Params, pools: PagedPools,
+                  adapters: AdapterArrays, tokens: jax.Array,
+                  page_tables: jax.Array, seq_lens: jax.Array,
+                  active: jax.Array, adapter_ids: jax.Array):
+    """``paged_decode_step`` up to its sampling: (logits [B, V] float32,
+    pools, per-layer expert counts)."""
+    B, maxp = page_tables.shape
+    ps = _page_size(pools)
+    x = params["embed"][tokens[:B]].astype(config.dtype)  # [B, d]
+    cos, sin = rope_frequencies(config.head_dim, maxp * ps,
+                                config.rope_theta)
+    k_pool, v_pool = pools["k"], pools["v"]
+    b_idx = jnp.arange(B)
+    page_idx = page_tables[b_idx, seq_lens // ps]  # [B]
+    off = seq_lens % ps
+    # The length mask removes scratch/unwritten positions: [B, 1, MAXP*ps].
+    visible = jnp.arange(maxp * ps)[None, None, :] \
+        <= seq_lens[:, None, None]
+    # One gather per adapter array for the whole step: [B, L, ...].
+    qa_g, qb_g = adapters["qa"][adapter_ids], adapters["qb"][adapter_ids]
+    va_g, vb_g = adapters["va"][adapter_ids], adapters["vb"][adapter_ids]
+    lscale = adapters["scale"][adapter_ids]  # [B]
+    counts = []
+    for i, layer in enumerate(params["layers"]):
+        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
+        a = layer["attn"]
+        q_flat = h @ a["wq"] + _lora_delta_batched(
+            h, qa_g[:, i], qb_g[:, i], lscale)
+        v_flat = h @ a["wv"] + _lora_delta_batched(
+            h, va_g[:, i], vb_g[:, i], lscale)
+        q_flat, k_flat = _qk_norm(config, a, q_flat, h @ a["wk"])
+        q = q_flat.reshape(B, config.n_heads, config.head_dim)
+        k = k_flat.reshape(B, config.n_kv_heads, config.head_dim)
+        v = v_flat.reshape(B, config.n_kv_heads, config.head_dim)
+        q = _rotary_single(q, cos, sin, seq_lens)
+        k = _rotary_single(k, cos, sin, seq_lens)
+        k_pool = _write_rows(k_pool, i, page_idx, off, k)
+        v_pool = _write_rows(v_pool, i, page_idx, off, v)
+        out = _attend_pages(config, q[:, None], k_pool, v_pool, i,
+                            page_tables, visible)
+        x = x + out[:, 0] @ a["wo"]
+        x, c = _ffn(config, layer, x, active)
+        counts.append(c)
+    x = rms_norm(x, params["final_norm"], config.norm_eps)
+    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    return logits, {"k": k_pool, "v": v_pool}, counts
+
+
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
 def paged_decode_step(config: LlamaConfig, params: Params,
                       pools: PagedPools, adapters: AdapterArrays,
@@ -293,7 +386,9 @@ def paged_decode_step(config: LlamaConfig, params: Params,
                       key: jax.Array):
     """One decode step for every batch slot at once.
 
-    tokens [B] int32 (last sampled token per slot), page_tables [B, MAXP]
+    tokens int32, the last sampled token of each slot in its first B
+    entries (what this program returned last step goes back in as it is,
+    so anything behind them is ignored), page_tables [B, MAXP]
     int32 (scratch index past each sequence's allocated prefix), seq_lens
     [B] int32 = tokens already cached (the new token is WRITTEN at
     position seq_lens and attends positions <= seq_lens), active [B]
@@ -311,71 +406,26 @@ def paged_decode_step(config: LlamaConfig, params: Params,
     alongside the tokens), so the serving loop's only per-step host
     traffic is downloading the [B] sampled tokens — host-side key
     folding measurably dominates step time otherwise.  Returns
-    (next_tokens [B], new_seq_lens [B], new_key, pools)."""
+    (next_tokens [B], new_seq_lens [B], new_key, pools); a model whose FFN
+    is routed appends its ``ROUTING_KEYS`` counters to next_tokens
+    (``_with_routing``)."""
     _bump("decode", tokens=tokens, page_tables=page_tables,
           seq_lens=seq_lens, temps=temps, adapter_ids=adapter_ids, key=key)
-    B = tokens.shape[0]
-    maxp = page_tables.shape[1]
-    ps = _page_size(pools)
-    x = params["embed"][tokens].astype(config.dtype)  # [B, d]
-    cos, sin = rope_frequencies(config.head_dim, maxp * ps,
-                                config.rope_theta)
-    k_pool, v_pool = pools["k"], pools["v"]
-    b_idx = jnp.arange(B)
-    page_idx = page_tables[b_idx, seq_lens // ps]  # [B]
-    off = seq_lens % ps
-    # The length mask removes scratch/unwritten positions: [B, 1, MAXP*ps].
-    visible = jnp.arange(maxp * ps)[None, None, :] \
-        <= seq_lens[:, None, None]
-    # One gather per adapter array for the whole step: [B, L, ...].
-    qa_g, qb_g = adapters["qa"][adapter_ids], adapters["qb"][adapter_ids]
-    va_g, vb_g = adapters["va"][adapter_ids], adapters["vb"][adapter_ids]
-    lscale = adapters["scale"][adapter_ids]  # [B]
-    for i, layer in enumerate(params["layers"]):
-        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        a = layer["attn"]
-        q_flat = h @ a["wq"] + _lora_delta_batched(
-            h, qa_g[:, i], qb_g[:, i], lscale)
-        v_flat = h @ a["wv"] + _lora_delta_batched(
-            h, va_g[:, i], vb_g[:, i], lscale)
-        q = q_flat.reshape(B, config.n_heads, config.head_dim)
-        k = (h @ a["wk"]).reshape(B, config.n_kv_heads, config.head_dim)
-        v = v_flat.reshape(B, config.n_kv_heads, config.head_dim)
-        q = _rotary_single(q, cos, sin, seq_lens)
-        k = _rotary_single(k, cos, sin, seq_lens)
-        k_pool = _write_rows(k_pool, i, page_idx, off, k)
-        v_pool = _write_rows(v_pool, i, page_idx, off, v)
-        out = _attend_pages(config, q[:, None], k_pool, v_pool, i,
-                            page_tables, visible)
-        x = x + out[:, 0] @ a["wo"]
-        h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-        x = x + _mlp(layer, h)
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    logits, pools, counts = decode_logits(
+        config, params, pools, adapters, tokens, page_tables, seq_lens,
+        active, adapter_ids)
     key, sub = jax.random.split(key)
     toks = _sample_tokens(logits, temps, sub)
     new_lens = jnp.where(active, seq_lens + 1, 0)
-    return toks, new_lens, key, {"k": k_pool, "v": v_pool}
+    return _with_routing(toks, counts), new_lens, key, pools
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
-def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
-                  adapters: AdapterArrays, tokens: jax.Array,
-                  length: jax.Array, page_table: jax.Array,
-                  adapter_id: jax.Array, temp: jax.Array, key: jax.Array):
-    """Prefill ONE sequence's prompt into its pages and sample the first
-    token.
-
-    tokens [1, S_pad] int32 (prompt padded to a bucket length — one
-    compile per bucket, see the engine's bucket table), length scalar =
-    real prompt length, page_table [MAXP], adapter_id scalar pool-slot
-    index (data, like the decode step's).  Padded tail positions write
-    through the page table like real ones (their garbage K/V is masked by
-    length until decode overwrites it) or to the scratch page past the
-    allocated prefix.  The key advances on device like the decode step's.
-    Returns (first_token scalar, new_key, pools)."""
-    _bump("prefill", tokens=tokens, page_table=page_table, temp=temp,
-          key=key)
+def prefill_logits(config, params: Params, pools: PagedPools,
+                   adapters: AdapterArrays, tokens: jax.Array,
+                   length: jax.Array, page_table: jax.Array,
+                   adapter_id: jax.Array):
+    """``paged_prefill`` up to its sampling: (logits [1, V] float32 after
+    the last real position, pools, per-layer expert counts)."""
     _, s_pad = tokens.shape
     ps = _page_size(pools)
     n_rep = config.n_heads // config.n_kv_heads
@@ -388,17 +438,22 @@ def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
     row = positions[:, None]
     col = positions[None, :]
     causal = col <= row  # [S_pad, S_pad]
+    valid = positions < length
+    counts = []
     qa_g, qb_g = adapters["qa"][adapter_id], adapters["qb"][adapter_id]
     va_g, vb_g = adapters["va"][adapter_id], adapters["vb"][adapter_id]
     lscale = adapters["scale"][adapter_id]
     for i, layer in enumerate(params["layers"]):
         h = rms_norm(x, layer["attn_norm"], config.norm_eps)
         a = layer["attn"]
-        q = (h @ a["wq"] + _lora_delta_seq(h, qa_g[i], qb_g[i], lscale)
-             ).reshape(s_pad, config.n_heads, config.head_dim
-                       ).transpose(1, 0, 2)  # [H, S, D]
-        k = (h @ a["wk"]).reshape(s_pad, config.n_kv_heads, config.head_dim
-                                  ).transpose(1, 0, 2)
+        q, k = _qk_norm(
+            config, a,
+            h @ a["wq"] + _lora_delta_seq(h, qa_g[i], qb_g[i], lscale),
+            h @ a["wk"])
+        q = q.reshape(s_pad, config.n_heads, config.head_dim
+                      ).transpose(1, 0, 2)  # [H, S, D]
+        k = k.reshape(s_pad, config.n_kv_heads, config.head_dim
+                      ).transpose(1, 0, 2)
         v = (h @ a["wv"] + _lora_delta_seq(h, va_g[i], vb_g[i], lscale)
              ).reshape(s_pad, config.n_kv_heads, config.head_dim
                        ).transpose(1, 0, 2)
@@ -417,14 +472,94 @@ def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
         probs = jax.nn.softmax(scores, axis=-1).astype(vr.dtype)
         out = jnp.einsum("hqk,hkd->hqd", probs, vr)
         x = x + out.transpose(1, 0, 2).reshape(s_pad, -1) @ a["wo"]
-        h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-        x = x + _mlp(layer, h)
+        x, c = _ffn(config, layer, x, valid)
+        counts.append(c)
     x = rms_norm(x, params["final_norm"], config.norm_eps)
     x_last = jnp.take(x, length - 1, axis=0)  # last REAL position
     logits = (x_last @ params["lm_head"]).astype(jnp.float32)[None]
+    return logits, {"k": k_pool, "v": v_pool}, counts
+
+
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
+                  adapters: AdapterArrays, tokens: jax.Array,
+                  length: jax.Array, page_table: jax.Array,
+                  adapter_id: jax.Array, temp: jax.Array, key: jax.Array):
+    """Prefill ONE sequence's prompt into its pages and sample the first
+    token.
+
+    tokens [1, S_pad] int32 (prompt padded to a bucket length — one
+    compile per bucket, see the engine's bucket table), length scalar =
+    real prompt length, page_table [MAXP], adapter_id scalar pool-slot
+    index (data, like the decode step's).  Padded tail positions write
+    through the page table like real ones (their garbage K/V is masked by
+    length until decode overwrites it) or to the scratch page past the
+    allocated prefix.  The key advances on device like the decode step's.
+    Returns (first_token scalar, new_key, pools); a model whose FFN is
+    routed returns [first_token, *ROUTING_KEYS] in its place."""
+    _bump("prefill", tokens=tokens, page_table=page_table, temp=temp,
+          key=key)
+    logits, pools, counts = prefill_logits(
+        config, params, pools, adapters, tokens, length, page_table,
+        adapter_id)
     key, sub = jax.random.split(key)
-    tok = _sample_tokens(logits, temp[None], sub)[0]
-    return tok, key, {"k": k_pool, "v": v_pool}
+    tok = _sample_tokens(logits, temp[None], sub)
+    return _first_token(tok, counts), key, pools
+
+
+def prefill_prefix_logits(config, params: Params, pools: PagedPools,
+                          adapters: AdapterArrays, tokens: jax.Array,
+                          prefix_len: jax.Array, length: jax.Array,
+                          page_table: jax.Array, adapter_id: jax.Array):
+    """``paged_prefill_prefix`` up to its sampling; returns what
+    ``prefill_logits`` returns."""
+    _, s_pad = tokens.shape
+    maxp = page_table.shape[0]
+    ps = _page_size(pools)
+    scratch = pools["k"].shape[1] - 1
+    x = params["embed"][tokens[0]].astype(config.dtype)  # [S_pad, d]
+    cos, sin = rope_frequencies(config.head_dim, maxp * ps,
+                                config.rope_theta)
+    k_pool, v_pool = pools["k"], pools["v"]
+    positions = prefix_len + jnp.arange(s_pad)  # global positions
+    valid = positions < length
+    page_idx = jnp.where(
+        valid, page_table[jnp.clip(positions // ps, 0, maxp - 1)], scratch)
+    off = jnp.where(valid, positions % ps, 0)
+    # Causal in global positions: [1, S_pad, MAXP*ps].
+    visible = jnp.arange(maxp * ps)[None, None, :] \
+        <= positions[None, :, None]
+    qa_g, qb_g = adapters["qa"][adapter_id], adapters["qb"][adapter_id]
+    va_g, vb_g = adapters["va"][adapter_id], adapters["vb"][adapter_id]
+    lscale = adapters["scale"][adapter_id]
+    counts = []
+    for i, layer in enumerate(params["layers"]):
+        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
+        a = layer["attn"]
+        q, k = _qk_norm(
+            config, a,
+            h @ a["wq"] + _lora_delta_seq(h, qa_g[i], qb_g[i], lscale),
+            h @ a["wk"])
+        q = q.reshape(s_pad, config.n_heads, config.head_dim)
+        k = k.reshape(s_pad, config.n_kv_heads, config.head_dim)
+        v = (h @ a["wv"] + _lora_delta_seq(h, va_g[i], vb_g[i], lscale)
+             ).reshape(s_pad, config.n_kv_heads, config.head_dim)
+        # Per-row RoPE at global positions (suffix rows are not at 0).
+        q = _rotary_single(q, cos, sin, positions)
+        k = _rotary_single(k, cos, sin, positions)
+        k_pool = _write_rows(k_pool, i, page_idx, off, k)
+        v_pool = _write_rows(v_pool, i, page_idx, off, v)
+        # Attend the WHOLE table (cached prefix + fresh suffix) like the
+        # decode step, as a batch of one.
+        out = _attend_pages(config, q[None], k_pool, v_pool, i,
+                            page_table[None], visible)
+        x = x + out[0] @ a["wo"]
+        x, c = _ffn(config, layer, x, valid)
+        counts.append(c)
+    x = rms_norm(x, params["final_norm"], config.norm_eps)
+    x_last = jnp.take(x, length - prefix_len - 1, axis=0)  # last real row
+    logits = (x_last @ params["lm_head"]).astype(jnp.float32)[None]
+    return logits, {"k": k_pool, "v": v_pool}, counts
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
@@ -447,54 +582,15 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
     suffix route to the scratch page (they may not even own a page).
     Queries then attend the full gathered table like the decode step —
     cached prefix plus fresh suffix — masked by global causal position.
-    Returns (first_token scalar, new_key, pools)."""
+    Returns what ``paged_prefill`` returns."""
     _bump("prefill_prefix", tokens=tokens, page_table=page_table,
           temp=temp, key=key)
-    _, s_pad = tokens.shape
-    maxp = page_table.shape[0]
-    ps = _page_size(pools)
-    scratch = pools["k"].shape[1] - 1
-    x = params["embed"][tokens[0]].astype(config.dtype)  # [S_pad, d]
-    cos, sin = rope_frequencies(config.head_dim, maxp * ps,
-                                config.rope_theta)
-    k_pool, v_pool = pools["k"], pools["v"]
-    positions = prefix_len + jnp.arange(s_pad)  # global positions
-    valid = positions < length
-    page_idx = jnp.where(
-        valid, page_table[jnp.clip(positions // ps, 0, maxp - 1)], scratch)
-    off = jnp.where(valid, positions % ps, 0)
-    # Causal in global positions: [1, S_pad, MAXP*ps].
-    visible = jnp.arange(maxp * ps)[None, None, :] \
-        <= positions[None, :, None]
-    qa_g, qb_g = adapters["qa"][adapter_id], adapters["qb"][adapter_id]
-    va_g, vb_g = adapters["va"][adapter_id], adapters["vb"][adapter_id]
-    lscale = adapters["scale"][adapter_id]
-    for i, layer in enumerate(params["layers"]):
-        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        a = layer["attn"]
-        q = (h @ a["wq"] + _lora_delta_seq(h, qa_g[i], qb_g[i], lscale)
-             ).reshape(s_pad, config.n_heads, config.head_dim)
-        k = (h @ a["wk"]).reshape(s_pad, config.n_kv_heads, config.head_dim)
-        v = (h @ a["wv"] + _lora_delta_seq(h, va_g[i], vb_g[i], lscale)
-             ).reshape(s_pad, config.n_kv_heads, config.head_dim)
-        # Per-row RoPE at global positions (suffix rows are not at 0).
-        q = _rotary_single(q, cos, sin, positions)
-        k = _rotary_single(k, cos, sin, positions)
-        k_pool = _write_rows(k_pool, i, page_idx, off, k)
-        v_pool = _write_rows(v_pool, i, page_idx, off, v)
-        # Attend the WHOLE table (cached prefix + fresh suffix) like the
-        # decode step, as a batch of one.
-        out = _attend_pages(config, q[None], k_pool, v_pool, i,
-                            page_table[None], visible)
-        x = x + out[0] @ a["wo"]
-        h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-        x = x + _mlp(layer, h)
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
-    x_last = jnp.take(x, length - prefix_len - 1, axis=0)  # last real row
-    logits = (x_last @ params["lm_head"]).astype(jnp.float32)[None]
+    logits, pools, counts = prefill_prefix_logits(
+        config, params, pools, adapters, tokens, prefix_len, length,
+        page_table, adapter_id)
     key, sub = jax.random.split(key)
-    tok = _sample_tokens(logits, temp[None], sub)[0]
-    return tok, key, {"k": k_pool, "v": v_pool}
+    tok = _sample_tokens(logits, temp[None], sub)
+    return _first_token(tok, counts), key, pools
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

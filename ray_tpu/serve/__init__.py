@@ -28,6 +28,7 @@ from .engine import (
     LLMServer,
     llm_app,
     random_lora,
+    register_model,
 )
 from .grpc_ingress import start_grpc, stop_grpc
 from .handle import DeploymentHandle, DeploymentResponse
@@ -45,6 +46,6 @@ __all__ = [
     "multiplexed", "get_multiplexed_model_id", "pick_replica_for_model",
     "deploy_config", "start_grpc", "stop_grpc",
     "EngineConfig", "EngineOverloadedError", "InferenceEngine",
-    "LLMServer", "llm_app", "random_lora",
+    "LLMServer", "llm_app", "random_lora", "register_model",
     "AdapterPool", "AdapterNotFoundError", "RadixPrefixCache",
 ]

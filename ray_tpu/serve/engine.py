@@ -37,7 +37,7 @@ import queue as _queue
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -237,7 +237,7 @@ class InferenceEngine:
 
         from ..devtools import jitguard
         from ..models.paged import (PAGED_PROGRAMS, PageAllocator,
-                                    init_paged_pools)
+                                    init_paged_pools, routing_width)
         from ..util.metrics import get_counter, get_gauge, get_histogram
 
         # A fresh engine means fresh geometry: re-registering stands the
@@ -280,7 +280,11 @@ class InferenceEngine:
         # tokens/lengths ON DEVICE and never re-uploads.
         self._page_tables = np.full((b, self.maxp), self.scratch, np.int32)
         self._seq_lens = np.zeros((b,), np.int32)
-        self._tokens = np.zeros((b,), np.int32)
+        # A routed model's decode program appends its routing counters to
+        # the tokens it returns (paged.ROUTING_KEYS): the mirror has their
+        # room, so that what goes up has the shape of what comes back.
+        self._tokens = np.zeros((b + routing_width(model_config),), np.int32)
+        self._routing: Dict[str, int] = {}  # of the step being run
         self._active = np.zeros((b,), bool)
         self._temps = np.zeros((b,), np.float32)
         self._adapter_slots = np.full((b,), self.adapter_pool.zero_slot,
@@ -915,7 +919,7 @@ class InferenceEngine:
         # first_tokens entry, not among the step's.
         acct: Dict[str, float] = {}
         with annotation(PH_PREFILL, acct) as phase:
-            bucket = self._prefill_body(req, acct)
+            bucket, routing = self._prefill_body(req, acct)
         n, prefix_len = int(req.prompt.size), int(req.cache_hit_len)
         entry = {
             "queue_s": round(req.admit_t - req.submit_t, 6),
@@ -923,6 +927,7 @@ class InferenceEngine:
             "prefill_wait_s": round(acct[PH_PREFILL_WAIT], 6),
             "ttft_s": round(req.first_token_t - req.submit_t, 6),
             "prompt": n, "bucket": bucket, "cached": prefix_len,
+            **routing,  # a routed model's counters of this prefill
         }
         self._first_tokens.append(entry)
         # The request's spans, from the same stamps as the entry: queue
@@ -938,11 +943,13 @@ class InferenceEngine:
                                 start + entry["prefill_s"], bucket=bucket,
                                 prompt_len=n, cached_prefix=prefix_len)
 
-    def _prefill_body(self, req: _Request, acct: Dict[str, float]) -> int:
-        """The work of :meth:`_prefill`; returns the padded length run."""
+    def _prefill_body(self, req: _Request, acct: Dict[str, float]
+                      ) -> Tuple[int, Dict[str, int]]:
+        """The work of :meth:`_prefill`; returns the padded length run and
+        (of a model whose FFN is routed) the prefill's routing counters."""
         import jax.numpy as jnp
 
-        from ..models.paged import (copy_page, paged_prefill,
+        from ..models.paged import (ROUTING_KEYS, copy_page, paged_prefill,
                                     paged_prefill_prefix)
         from ..util.profiling import annotation
 
@@ -991,7 +998,8 @@ class InferenceEngine:
                 jnp.asarray(req.temperature, jnp.float32), self._d_key)
             self._m_prefill.inc(n)
         with annotation(PH_PREFILL_WAIT, acct):
-            first = int(first)  # rt-sync-ok: THE prefill readback — the first token must reach the host to stream it
+            out = np.asarray(first).reshape(-1)  # rt-sync-ok: THE prefill readback — the first token must reach the host to stream it
+        first = int(out[0])
         # Cache every fully-frozen prompt page (decode appends past the
         # prompt, so pages wholly inside it never change again).
         if self._cache is not None:
@@ -1019,7 +1027,7 @@ class InferenceEngine:
         self._adapter_slots[slot] = req.adapter_slot
         self._dirty = True
         self._emit_token(req, first)
-        return int(s_pad)
+        return int(s_pad), dict(zip(ROUTING_KEYS, out[1:].tolist()))
 
     def _emit_token(self, req: _Request, token: int) -> None:
         req.generated += 1
@@ -1146,7 +1154,8 @@ class InferenceEngine:
     def _run_step(self, admitted: List[_Request]) -> None:
         import jax.numpy as jnp
 
-        from ..models.paged import paged_decode_step, trace_counts
+        from ..models.paged import (ROUTING_KEYS, paged_decode_step,
+                                    trace_counts)
         from ..util import devmem
         from ..util.profiling import annotation
 
@@ -1158,6 +1167,7 @@ class InferenceEngine:
         t0 = time.perf_counter()
         acct: Optional[Dict[str, float]] = {} if rec_on else None
         self._first_tokens = []
+        self._routing = {}
         stall_s = 0.0
         evicted0 = self._evicted_total
         shed0 = self.shed
@@ -1199,6 +1209,8 @@ class InferenceEngine:
             toks = np.asarray(self._d_tokens)  # rt-sync-ok: THE decode-step readback — one batched token fetch per step
         with annotation(PH_EMIT, acct) as phase:
             now = phase.t0
+            self._routing = dict(zip(
+                ROUTING_KEYS, toks[self.config.batch_slots:].tolist()))
             for slot, req in enumerate(self.slots):
                 if req is None:
                     continue
@@ -1290,6 +1302,9 @@ class InferenceEngine:
             "prefix_hits": sum(1 for e in first_tokens if e["cached"]),
             "adapter_pins": self.adapter_pool.pinned_count,
             "tenants": tenants,
+            # Only a model whose FFN is routed has these (of the decode
+            # step; a prefill's are on its first_tokens entry).
+            **self._routing,
         }
         # Which step recompiled, for every jitted program of the process
         # (trace_counts above knows the three paged ones).
@@ -1306,6 +1321,25 @@ _MODEL_BUILDERS = {
     "tiny": lambda: _tiny_config(),
     "b1": lambda: _b1_config(),
 }
+
+
+def register_model(name: str, builder) -> None:
+    """Make ``llm_app(model=name)`` serve the model whose configuration
+    object (a ``LlamaConfig`` or a ``MoEConfig``) the zero-argument
+    ``builder`` returns.  Called in the process that builds the
+    ``LLMServer``: the replica, e.g. from a deployment subclass's
+    ``__init__``."""
+    _MODEL_BUILDERS[name] = builder
+
+
+def _model_functions(cfg):
+    """(init, apply) of the registered model: how its weights are made and
+    its full forward pass to logits, by the type of its configuration."""
+    from .. import models
+
+    if isinstance(cfg, models.MoEConfig):
+        return models.moe_init, lambda c, p, t: models.moe_apply(c, p, t)[0]
+    return models.llama_init, models.llama_apply
 
 
 def _tiny_config():
@@ -1377,7 +1411,6 @@ class LLMServer:
         import jax
 
         from .. import accelerators
-        from ..models import llama_init
 
         backend = jax.default_backend()
         chips = accelerators.num_chips()
@@ -1390,8 +1423,8 @@ class LLMServer:
                 f"{{'num_tpus': 1}})")
         t0 = time.perf_counter()
         cfg = _MODEL_BUILDERS[model]()
-        params = jax.block_until_ready(
-            llama_init(cfg, jax.random.PRNGKey(seed)))
+        init, self._apply = _model_functions(cfg)
+        params = jax.block_until_ready(init(cfg, jax.random.PRNGKey(seed)))
         tw = time.perf_counter()
         self.engine = InferenceEngine(
             cfg, params, EngineConfig(**(engine or {})), seed=seed)
@@ -1476,17 +1509,15 @@ class LLMServer:
     def reference_logits(self, prompt_tokens,
                          candidates=()) -> Dict[str, Any]:
         """Next-token logits after ``prompt_tokens`` from the plain full
-        forward pass (``llama_apply``: no cache, no pages, no buckets) over
+        forward pass (the model's own: no cache, no pages, no buckets) over
         this replica's weights: the best token, its logit, and the logits
         of ``candidates``.  What a deployment check holds the engine's
         greedy token to, within the dtype's rounding."""
         import jax
         import jax.numpy as jnp
 
-        from ..models import llama_apply
-
         toks = jnp.asarray(prompt_tokens, jnp.int32)[None]
-        logits = np.asarray(jax.jit(llama_apply, static_argnums=0)(
+        logits = np.asarray(jax.jit(self._apply, static_argnums=0)(
             self.engine.model_config, self.engine.params, toks)[0, -1])
         return {"argmax": int(logits.argmax()), "max": float(logits.max()),
                 "logits": [float(logits[int(c)]) for c in candidates]}

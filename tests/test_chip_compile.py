@@ -336,14 +336,16 @@ def test_b1_train_step_partitions_over_four_chips(v5e, kernels_as_on_chip):
 
 def _engine_args(v5e, cfg, ec):
     """Shapes of everything the paged programs take, on one described chip."""
-    from ray_tpu.models import llama_init
     from ray_tpu.models.paged import init_adapter_pool, init_paged_pools
+    from ray_tpu.serve.engine import _model_functions
+
+    init = _model_functions(cfg)[0]  # the model's own
 
     one = SingleDeviceSharding(v5e.devices[0])
     place = functools.partial(
         jax.tree.map, lambda x: _on(one, x.shape, x.dtype))
     params = place(jax.eval_shape(
-        lambda: llama_init(cfg, jax.random.PRNGKey(0))))
+        lambda: init(cfg, jax.random.PRNGKey(0))))
     pools = place(jax.eval_shape(
         lambda: init_paged_pools(cfg, ec.pool_pages, ec.page_size)))
     adapters = place(jax.eval_shape(
@@ -359,11 +361,12 @@ def _lower_paged(program, args, bucket=None):
 
     cfg, ec, params, pools, adapters, key, on = args
     b, i32 = ec.batch_slots, jnp.int32
+    n_tok = b + paged.routing_width(cfg)  # a routed model's counters ride
     scalar, temp = on((), i32), on((), jnp.float32)
     toks, table = on((1, bucket or 1), i32), on((ec.pages_per_seq,), i32)
     if program == "paged_decode_step":
         return paged.paged_decode_step.lower(
-            cfg, params, pools, adapters, on((b,), i32),
+            cfg, params, pools, adapters, on((n_tok,), i32),
             on((b, ec.pages_per_seq), i32), on((b,), i32), on((b,), bool),
             on((b,), jnp.float32), on((b,), i32), key)
     if program == "paged_prefill":
@@ -402,29 +405,39 @@ def test_b1_largest_prefill_bucket_fits_one_chip(v5e, program):
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES
 
 
+@pytest.mark.parametrize("model", ["llama", "olmoe"])
 @pytest.mark.parametrize("program", [
     "paged_decode_step", "paged_prefill", "paged_prefill_prefix",
     "copy_page"])
-def test_paged_programs_never_copy_the_pool(v5e, program):
+def test_paged_programs_never_copy_the_pool(v5e, program, model):
     """The donated pools keep the layout they are declared in from argument
     to aliased result, and attention holds no second copy of a table's K/V:
     no instruction of the optimized HLO with a pool-shaped result is a
     ``copy`` (a layout the write or the read does not want costs a
     transpose of the whole pool in and one out, every call), and the
     temporaries stay under a quarter of one pool (the gathered pages,
-    upcast, transposed and GQA-repeated, were three pools' worth).  A
-    2-layer model at internlm2-1.8b's widths (GQA 16/8, heads of 128) on
-    the serving cells' engine geometry, bucket 128: ~3 s a program."""
+    upcast, transposed and GQA-repeated, were three pools' worth), beside
+    one layer's gathered pair where that is too large for fast memory.  A
+    2-layer model at internlm2-1.8b's widths (GQA 16/8, heads of 128), and
+    one at OLMoE-1B-7B's (MHA 16, QK-norm, 64 experts of 1024, 8 a token:
+    the same block with the routed FFN), on the serving cells' engine
+    geometry, bucket 128: ~3 s a program."""
     import re
 
-    from ray_tpu.models import LlamaConfig
+    from ray_tpu.models import LlamaConfig, MoEConfig
     from ray_tpu.serve.engine import EngineConfig
 
     ec = EngineConfig(batch_slots=16, page_size=128, max_prompt_len=1024,
                       max_new_tokens_cap=256)
-    cfg = LlamaConfig(vocab_size=92544, d_model=2048, n_layers=2,
-                      n_heads=16, n_kv_heads=8, d_ff=8192, remat=False,
-                      max_seq=ec.pages_per_seq * ec.page_size)
+    if model == "llama":
+        cfg = LlamaConfig(vocab_size=92544, d_model=2048, n_layers=2,
+                          n_heads=16, n_kv_heads=8, d_ff=8192, remat=False,
+                          max_seq=ec.pages_per_seq * ec.page_size)
+    else:
+        cfg = MoEConfig(vocab_size=50304, d_model=2048, n_layers=2,
+                        n_heads=16, n_kv_heads=16, d_ff=1024, n_experts=64,
+                        top_k=8, norm_topk_prob=False, qk_norm=True,
+                        remat=False, max_seq=ec.pages_per_seq * ec.page_size)
     args = _engine_args(v5e, cfg, ec)
     pool = args[3]["k"]
     compiled = _lower_paged(program, args, bucket=128).compile()
@@ -435,4 +448,13 @@ def test_paged_programs_never_copy_the_pool(v5e, program):
     assert "parameter" in pool_shaped  # the pattern still reads this HLO
     assert "copy" not in pool_shaped, pool_shaped
     pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
-    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes / 4
+    allowed = pool_bytes / 4
+    if model == "olmoe" and program in ("paged_decode_step",
+                                        "paged_prefill_prefix"):
+        # MHA's gathered K and V of one layer's 16 tables (2 x 84 MB) do not
+        # fit the fast memory GQA 16/8's pair stays in, so the compiler
+        # keeps that one pair in HBM (178 MB of temporaries in the decode
+        # step): allowed once, and no upcast or transposed view of it.
+        allowed += 2 * ec.batch_slots * ec.pages_per_seq \
+            * int(np.prod(pool.shape[2:])) * pool.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < allowed

@@ -190,14 +190,13 @@ class TestMoE:
         assert 0.5 < float(aux) < 4.0
 
     def test_single_expert_matches_dense_mlp(self):
-        """n_experts=1, top_k=1, ample capacity: the MoE FFN must reduce to
-        the plain SwiGLU MLP with the same weights."""
+        """n_experts=1, top_k=1: the MoE FFN must reduce to the plain
+        SwiGLU MLP with the same weights."""
         from ray_tpu.models import MoEConfig
         from ray_tpu.models.moe import _moe_ffn
 
         cfg = MoEConfig.tiny(dtype=jnp.float32, remat=False)
-        cfg = dataclasses.replace(cfg, n_experts=1, top_k=1,
-                                  capacity_factor=2.0)
+        cfg = dataclasses.replace(cfg, n_experts=1, top_k=1)
         d, f = cfg.d_model, cfg.d_ff
         key = jax.random.PRNGKey(3)
         k1, k2, k3, kx = jax.random.split(key, 4)
@@ -208,7 +207,8 @@ class TestMoE:
             "w2": jax.random.normal(k3, (1, f, d)) * 0.05,
         }
         x = jax.random.normal(kx, (2, 16, d))
-        out, _ = _moe_ffn(cfg, moe, x)
+        out, _, counts = _moe_ffn(cfg, moe, x)
+        assert counts.tolist() == [2 * 16]
         dense = (jax.nn.silu(x @ moe["w1"][0]) * (x @ moe["w3"][0])) @ moe["w2"][0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                    rtol=2e-4, atol=2e-5)
