@@ -13,7 +13,7 @@ Three rows:
    the record append live vs patched out.  The recorder's contract is
    <= 2% of step wall (one dict append per round; no locks beyond the
    ring's, no device work); ``overhead_frac`` is the tracked number.
-   The hard gate is deliberately loose (25%, bench_serve precedent) —
+   The hard gate is deliberately loose (25%) —
    a noisy 2-vCPU CI box cannot hold a 2% assertion without flaking,
    but a blowup means the record path grew a sync or lock contention
    and must fail loudly.

@@ -6,12 +6,6 @@ parallelism is a ShardingRules table consumed by pjit: DP/FSDP/TP/SP are
 configurations, not code paths.
 """
 
-from .generate import (
-    generate,
-    init_kv_cache,
-    llama_decode_step,
-    llama_prefill,
-)
 from .llama import (
     LlamaConfig,
     llama_apply,
@@ -30,15 +24,16 @@ from .paged import (
     paged_prefill,
 )
 from .moe import MoEConfig, moe_apply, moe_init, moe_loss, moe_sharding_rules
+from .block import init_and_apply
 from .train_state import TrainState, make_train_step
 
 __all__ = [
     "LlamaConfig", "llama_init", "llama_apply", "llama_loss",
-    "generate", "init_kv_cache", "llama_prefill", "llama_decode_step",
     "PageAllocator", "init_paged_pools", "paged_prefill",
     "paged_decode_step",
     "llama_sharding_rules", "lora_init", "lora_merge", "lora_sharding_rules",
     "MLPConfig", "mlp_init", "mlp_apply",
     "MoEConfig", "moe_init", "moe_apply", "moe_loss", "moe_sharding_rules",
+    "init_and_apply",
     "TrainState", "make_train_step",
 ]

@@ -191,15 +191,23 @@ def llama_sharding_rules() -> ShardingRules:
     ])
 
 
-def _flash_per_shard(config: LlamaConfig, q, k, v):
+def _option(config, name: str):
+    """A training option of ``LlamaConfig`` (``sp_ring``, the flash tiles,
+    ``remat_policy``); a configuration object without the field, a
+    ``MoEConfig``, takes ``LlamaConfig``'s default."""
+    return getattr(config, name, getattr(LlamaConfig, name))
+
+
+def _flash_per_shard(config, q, k, v):
     """Flash attention under pjit.  XLA cannot partition a Pallas kernel
     ("Mosaic kernels cannot be automatically partitioned"), so under an
     ambient mesh (``jax.set_mesh``) the kernel runs per shard: batch over
     dp/fsdp and heads over tp, both independent in attention.  A dim its
     axes do not divide stays replicated."""
     flash = functools.partial(
-        flash_attention, causal=True, block_q=config.flash_block_q,
-        block_k=config.flash_block_k)
+        flash_attention, causal=True,
+        block_q=_option(config, "flash_block_q"),
+        block_k=_option(config, "flash_block_k"))
     mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty or mesh.manual_axes:  # no mesh / already per-shard
         return flash(q, k, v)
@@ -216,27 +224,18 @@ def _flash_per_shard(config: LlamaConfig, q, k, v):
                          check_vma=False)(q, k, v)
 
 
-def _attention(config: LlamaConfig, x, layer, cos, sin, lora_layer=None):
-    B, S, d = x.shape
-    hd = config.head_dim
-    a = layer["attn"]
-    q = x @ a["wq"]
-    k = x @ a["wk"]
-    v = x @ a["wv"]
-    if lora_layer is not None:
-        # LoRA on wq/wv (standard recipe): delta = x @ A @ B * (alpha/r).
-        scale = lora_layer["scale"]
-        q = q + ((x @ lora_layer["wq_lora_a"]) @ lora_layer["wq_lora_b"]) * scale
-        v = v + ((x @ lora_layer["wv_lora_a"]) @ lora_layer["wv_lora_b"]) * scale
-    q, k = _qk_norm(config, a, q, k)
-    q = q.reshape(B, S, config.n_heads, hd).transpose(0, 2, 1, 3)
-    k = k.reshape(B, S, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
-    v = v.reshape(B, S, config.n_kv_heads, hd).transpose(0, 2, 1, 3)
+def _attend(config, cos, sin, q, k, v):
+    """The training programs' ``attend`` (see ``block``): causal
+    self-attention of q [B, S, H, D] over k, v [B, S, H_kv, D] with RoPE,
+    through the flash kernel or, sequence-sharded, the ring.  Returns
+    [B, S, H*D]."""
+    B, S = q.shape[:2]
+    q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
     # Ring attention engages only when tracing inside shard_map over `sp`
     # (local-chunk view).  Under plain pjit the tensors are the global view:
     # positions start at 0 and XLA partitions full attention itself.
     ring_mode = False
-    if config.sp_ring:
+    if _option(config, "sp_ring"):
         from ..collective.xla_ops import axis_size
 
         try:
@@ -255,8 +254,28 @@ def _attention(config: LlamaConfig, x, layer, cos, sin, lora_layer=None):
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
         out = _flash_per_shard(config, q, k, v)
-    out = out.transpose(0, 2, 1, 3).reshape(B, S, d)
-    return out @ a["wo"]
+    return out.transpose(0, 2, 1, 3).reshape(B, S, -1)
+
+
+def _lora(lora_layer: Optional[Params]):
+    """``block``'s ``lora`` closure over one layer of a ``lora_init``
+    tree: delta = h @ A @ B * (alpha/r)."""
+    if lora_layer is None:
+        return None
+    return lambda name, h: (
+        (h @ lora_layer[name + "_lora_a"]) @ lora_layer[name + "_lora_b"]
+    ) * lora_layer["scale"]
+
+
+def _attention(config, x, layer, cos, sin, lora_layer=None):
+    """A layer's attention of normalised x [B, S, d], through ``wo``.  No
+    program calls it (``_layer`` runs the whole layer);
+    ``benchmarks/reference/olmoe_compare.py`` does."""
+    from . import block
+
+    return block.attention(
+        config, layer["attn"], x,
+        functools.partial(_attend, config, cos, sin), _lora(lora_layer))
 
 
 def _mlp(layer, x):
@@ -264,11 +283,20 @@ def _mlp(layer, x):
     return (jax.nn.silu(x @ m["w1"]) * (x @ m["w3"])) @ m["w2"]
 
 
+def _layer(config, x, layer, cos, sin, lora_layer=None):
+    """One decoder layer of a training program, dense or routed, with its
+    arrays as arguments (what ``jax.checkpoint`` wraps): (x, the routed
+    FFN's aux loss or None)."""
+    from . import block
+
+    x, aux, _ = block.decoder_layer(
+        config, layer, x, functools.partial(_attend, config, cos, sin),
+        lora=_lora(lora_layer))
+    return x, aux
+
+
 def _block(config: LlamaConfig, x, layer, cos, sin, lora_layer=None):
-    h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-    x = x + _attention(config, h, layer, cos, sin, lora_layer)
-    h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-    return x + _mlp(layer, h)
+    return _layer(config, x, layer, cos, sin, lora_layer)[0]
 
 
 def llama_apply(
@@ -278,22 +306,21 @@ def llama_apply(
     lora_params: Optional[Params] = None,
 ) -> jax.Array:
     """Returns logits [B, S, vocab]."""
-    x = llama_hidden(config, params, tokens, lora_params)
+    x, _ = hidden_and_aux(config, params, tokens, lora_params)
     return (x @ params["lm_head"]).astype(jnp.float32)
 
 
-def llama_hidden(
-    config: LlamaConfig,
-    params: Params,
-    tokens: jax.Array,
-    lora_params: Optional[Params] = None,
-) -> jax.Array:
-    """Final-norm hidden states [B, S, d] (logits = hidden @ lm_head)."""
-    x = params["embed"][tokens].astype(config.dtype)
+def hidden_and_aux(config, params: Params, tokens: jax.Array,
+                   lora_params: Optional[Params] = None):
+    """The training forward pass of either architecture: final-norm hidden
+    states [B, S, d] (logits = hidden @ lm_head) and the layers' aux losses
+    (None where the FFN is dense)."""
+    from . import block
+
     cos, sin = rope_frequencies(
         config.head_dim, config.max_seq, config.rope_theta
     )
-    block = _block
+    layer_fn = _layer
     if config.remat:
         # Two independent axes compose here:
         # - prevent_cse: True keeps forward/backward recompute separate
@@ -317,15 +344,18 @@ def llama_hidden(
             "save_dots": (cps.checkpoint_dots, True),
             "save_dots_no_batch":
                 (cps.checkpoint_dots_with_no_batch_dims, True),
-        }[config.remat_policy]
-        block = jax.checkpoint(
-            _block, static_argnums=(0,), policy=policy,
+        }[_option(config, "remat_policy")]
+        layer_fn = jax.checkpoint(
+            _layer, static_argnums=(0,), policy=policy,
             prevent_cse=prevent_cse,
         )
-    for i, layer in enumerate(params["layers"]):
+
+    def run(i, layer, x):
         ll = lora_params["layers"][i] if lora_params is not None else None
-        x = block(config, x, layer, cos, sin, ll)
-    return rms_norm(x, params["final_norm"], config.norm_eps)
+        return (*layer_fn(config, x, layer, cos, sin, ll), None)
+
+    hidden, auxes, _ = block.decoder_stack(config, params, tokens, run)
+    return hidden, auxes
 
 
 def llama_loss(
@@ -340,7 +370,7 @@ def llama_loss(
     full fp32 logits tensor ([B, S, vocab] — 2 GiB at 8x2048x32k, plus its
     gradient) never materializes; each chunk's logits are rematerialized in
     the backward pass (jax.checkpoint over the chunk loss)."""
-    hidden = llama_hidden(config, params, tokens, lora_params)
+    hidden, _ = hidden_and_aux(config, params, tokens, lora_params)
     B, S, d = hidden.shape
     w = params["lm_head"]
 

@@ -34,11 +34,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.norms import rms_norm
-from ..ops.rotary import rope_frequencies
 from ..parallel.mesh import AXIS_EP, AXIS_FSDP, AXIS_TP
 from ..parallel.sharding import ShardingRules
-from .llama import (LlamaConfig, _attention, llama_sharding_rules,
+from .llama import (LlamaConfig, hidden_and_aux, llama_sharding_rules,
                     qk_norm_init)
 
 Params = Dict[str, Any]
@@ -86,7 +84,9 @@ class MoEConfig:
         return v * d + self.n_layers * per_layer + d + d * v
 
     def as_llama(self) -> LlamaConfig:
-        """Attention-config view (reuses the Llama attention path)."""
+        """The attention fields as a ``LlamaConfig``.  No program needs it
+        (``block`` reads either configuration object);
+        ``benchmarks/reference/olmoe_compare.py`` still calls it."""
         return LlamaConfig(
             vocab_size=self.vocab_size, d_model=self.d_model,
             n_layers=self.n_layers, n_heads=self.n_heads,
@@ -228,32 +228,13 @@ def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
     return out.reshape(*lead, d), aux, counts
 
 
-def _moe_block(config: MoEConfig, x, layer, cos, sin):
-    lconf = config.as_llama()
-    h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-    x = x + _attention(lconf, h, layer, cos, sin)
-    h = rms_norm(x, layer["moe_norm"], config.norm_eps)
-    ffn, aux, _ = _moe_ffn(config, layer["moe"], h)
-    return x + ffn, aux
-
-
 def moe_apply(config: MoEConfig, params: Params, tokens: jax.Array
               ) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits [B, S, vocab] fp32, aux_loss scalar)."""
-    x = params["embed"][tokens].astype(config.dtype)
-    cos, sin = rope_frequencies(
-        config.head_dim, config.max_seq, config.rope_theta
-    )
-    block = _moe_block
-    if config.remat:
-        block = jax.checkpoint(_moe_block, static_argnums=(0,))
-    aux_total = jnp.zeros((), jnp.float32)
-    for layer in params["layers"]:
-        x, aux = block(config, x, layer, cos, sin)
-        aux_total = aux_total + aux
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
+    x, auxes = hidden_and_aux(config, params, tokens)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, aux_total / max(config.n_layers, 1)
+    return logits, sum(auxes, jnp.zeros((), jnp.float32)) \
+        / max(config.n_layers, 1)
 
 
 def moe_loss(config: MoEConfig, params: Params, tokens: jax.Array,

@@ -2,8 +2,7 @@
 
 Role-equivalent to vLLM-style PagedAttention as surfaced by Ray Serve's LLM
 stack (reference: the Ray Serve LLM APIs run a continuous-batching engine
-whose KV cache is a pool of fixed-size pages).  TPU-first shape, same
-recipe as `generate.py` but paged:
+whose KV cache is a pool of fixed-size pages).  TPU-first shape:
 
 - ONE preallocated KV pool per replica: ``[L, P+1, page, H_kv, D]`` per
   k/v — a token's ``[H_kv, D]`` row is contiguous, which is how it is
@@ -41,10 +40,9 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from ..ops.norms import rms_norm
 from ..ops.rotary import apply_rotary, rope_frequencies
-from .llama import LlamaConfig, _mlp, _qk_norm
-from .moe import MoEConfig, _moe_ffn
+from . import block
+from .llama import LlamaConfig
 
 Params = Any
 PagedPools = Dict[str, jax.Array]  # {"k": [L, P+1, page, H_kv, D], "v": ...}
@@ -130,20 +128,6 @@ def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
     return out.reshape(B, Q, -1)
 
 
-def _ffn(config, layer: Params, x: jax.Array, valid: jax.Array):
-    """The second half of the block, x + FFN(norm(x)): dense or routed by
-    what the configuration object is (a trace-time branch, so a dense model
-    compiles to the program it always did).  ``valid`` marks the rows that
-    hold a real token; only a routed FFN looks at it.  Returns (x, the
-    routed layer's per-expert token counts [E], or None)."""
-    if isinstance(config, MoEConfig):
-        h = rms_norm(x, layer["moe_norm"], config.norm_eps)
-        out, _, counts = _moe_ffn(config, layer["moe"], h, valid)
-        return x + out, counts
-    h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-    return x + _mlp(layer, h), None
-
-
 #: What ``_with_routing`` appends, in its order: the step record's keys.
 ROUTING_KEYS = ("experts_hit", "expert_pairs", "expert_load_max")
 
@@ -151,7 +135,7 @@ ROUTING_KEYS = ("experts_hit", "expert_pairs", "expert_load_max")
 def routing_width(config) -> int:
     """How many int32 counters a program of ``config`` appends to the
     tokens it returns: ``len(ROUTING_KEYS)`` where the FFN is routed."""
-    return len(ROUTING_KEYS) if isinstance(config, MoEConfig) else 0
+    return len(ROUTING_KEYS) if block.is_routed(config) else 0
 
 
 def _with_routing(toks: jax.Array, counts: List[Optional[jax.Array]]
@@ -238,6 +222,22 @@ def _lora_delta_seq(h: jax.Array, a: jax.Array, b: jax.Array,
                     scale: jax.Array) -> jax.Array:
     """One adapter over a sequence: h [S, d], a [d, r], b [r, out]."""
     return ((h @ a) @ b) * scale.astype(h.dtype)
+
+
+def _adapter_lora(adapters: AdapterArrays, ids: jax.Array):
+    """``lora(i, name, h)`` for layer ``i`` over the pool slots ``ids``:
+    [B], a slot for each row of h [B, d] (the decode step), or a scalar,
+    one adapter over a sequence h [S, d] (the prefills).  One gather per
+    adapter array for the whole program: [B, L, ...] or [L, ...]."""
+    qa, qb = adapters["qa"][ids], adapters["qb"][ids]
+    va, vb = adapters["va"][ids], adapters["vb"][ids]
+    scale = adapters["scale"][ids]
+    delta = _lora_delta_batched if jnp.ndim(ids) else _lora_delta_seq
+
+    def lora(i, name, h):
+        a, b = (qa, qb) if name == "wq" else (va, vb)
+        return delta(h, a[..., i, :, :], b[..., i, :, :], scale)
+    return lora
 
 
 class PageAllocator:
@@ -329,6 +329,28 @@ def _sample_tokens(logits: jax.Array, temps: jax.Array,
     return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
 
 
+def _stack(config, params: Params, tokens: jax.Array, attend, lora,
+           valid: jax.Array):
+    """The decoder stack of a serving program over tokens [N]:
+    ``attend(i, q, k, v)`` and ``lora(i, name, h)`` are ``block``'s
+    closures with the layer's index in front (the pools and the adapter
+    pool are indexed by it).  Returns (hidden [N, d], the layers' expert
+    counts)."""
+    hidden, _, counts = block.decoder_stack(
+        config, params, tokens,
+        lambda i, layer, x: block.decoder_layer(
+            config, layer, x, functools.partial(attend, i),
+            lora=functools.partial(lora, i), valid=valid))
+    return hidden, counts
+
+
+def _write_kv(pools: PagedPools, layer: int, page_idx: jax.Array,
+              off: jax.Array, k: jax.Array, v: jax.Array) -> None:
+    """``_write_rows`` of a layer's K and V, the dict's pools replaced."""
+    pools["k"] = _write_rows(pools["k"], layer, page_idx, off, k)
+    pools["v"] = _write_rows(pools["v"], layer, page_idx, off, v)
+
+
 def decode_logits(config, params: Params, pools: PagedPools,
                   adapters: AdapterArrays, tokens: jax.Array,
                   page_tables: jax.Array, seq_lens: jax.Array,
@@ -337,44 +359,26 @@ def decode_logits(config, params: Params, pools: PagedPools,
     pools, per-layer expert counts)."""
     B, maxp = page_tables.shape
     ps = _page_size(pools)
-    x = params["embed"][tokens[:B]].astype(config.dtype)  # [B, d]
+    pools = dict(pools)
     cos, sin = rope_frequencies(config.head_dim, maxp * ps,
                                 config.rope_theta)
-    k_pool, v_pool = pools["k"], pools["v"]
-    b_idx = jnp.arange(B)
-    page_idx = page_tables[b_idx, seq_lens // ps]  # [B]
+    page_idx = page_tables[jnp.arange(B), seq_lens // ps]  # [B]
     off = seq_lens % ps
     # The length mask removes scratch/unwritten positions: [B, 1, MAXP*ps].
     visible = jnp.arange(maxp * ps)[None, None, :] \
         <= seq_lens[:, None, None]
-    # One gather per adapter array for the whole step: [B, L, ...].
-    qa_g, qb_g = adapters["qa"][adapter_ids], adapters["qb"][adapter_ids]
-    va_g, vb_g = adapters["va"][adapter_ids], adapters["vb"][adapter_ids]
-    lscale = adapters["scale"][adapter_ids]  # [B]
-    counts = []
-    for i, layer in enumerate(params["layers"]):
-        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        a = layer["attn"]
-        q_flat = h @ a["wq"] + _lora_delta_batched(
-            h, qa_g[:, i], qb_g[:, i], lscale)
-        v_flat = h @ a["wv"] + _lora_delta_batched(
-            h, va_g[:, i], vb_g[:, i], lscale)
-        q_flat, k_flat = _qk_norm(config, a, q_flat, h @ a["wk"])
-        q = q_flat.reshape(B, config.n_heads, config.head_dim)
-        k = k_flat.reshape(B, config.n_kv_heads, config.head_dim)
-        v = v_flat.reshape(B, config.n_kv_heads, config.head_dim)
+
+    def attend(i, q, k, v):  # one row a slot: [B, H, D]
         q = _rotary_single(q, cos, sin, seq_lens)
         k = _rotary_single(k, cos, sin, seq_lens)
-        k_pool = _write_rows(k_pool, i, page_idx, off, k)
-        v_pool = _write_rows(v_pool, i, page_idx, off, v)
-        out = _attend_pages(config, q[:, None], k_pool, v_pool, i,
-                            page_tables, visible)
-        x = x + out[:, 0] @ a["wo"]
-        x, c = _ffn(config, layer, x, active)
-        counts.append(c)
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
+        _write_kv(pools, i, page_idx, off, k, v)
+        return _attend_pages(config, q[:, None], pools["k"], pools["v"], i,
+                             page_tables, visible)[:, 0]
+
+    x, counts = _stack(config, params, tokens[:B], attend,
+                       _adapter_lora(adapters, adapter_ids), active)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
-    return logits, {"k": k_pool, "v": v_pool}, counts
+    return logits, pools, counts
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
@@ -428,40 +432,19 @@ def prefill_logits(config, params: Params, pools: PagedPools,
     the last real position, pools, per-layer expert counts)."""
     _, s_pad = tokens.shape
     ps = _page_size(pools)
+    pools = dict(pools)
     n_rep = config.n_heads // config.n_kv_heads
-    x = params["embed"][tokens[0]].astype(config.dtype)  # [S_pad, d]
     cos, sin = rope_frequencies(config.head_dim, s_pad, config.rope_theta)
-    k_pool, v_pool = pools["k"], pools["v"]
     positions = jnp.arange(s_pad)
     page_idx = page_table[positions // ps]  # [S_pad]
     off = positions % ps
-    row = positions[:, None]
-    col = positions[None, :]
-    causal = col <= row  # [S_pad, S_pad]
-    valid = positions < length
-    counts = []
-    qa_g, qb_g = adapters["qa"][adapter_id], adapters["qb"][adapter_id]
-    va_g, vb_g = adapters["va"][adapter_id], adapters["vb"][adapter_id]
-    lscale = adapters["scale"][adapter_id]
-    for i, layer in enumerate(params["layers"]):
-        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        a = layer["attn"]
-        q, k = _qk_norm(
-            config, a,
-            h @ a["wq"] + _lora_delta_seq(h, qa_g[i], qb_g[i], lscale),
-            h @ a["wk"])
-        q = q.reshape(s_pad, config.n_heads, config.head_dim
-                      ).transpose(1, 0, 2)  # [H, S, D]
-        k = k.reshape(s_pad, config.n_kv_heads, config.head_dim
-                      ).transpose(1, 0, 2)
-        v = (h @ a["wv"] + _lora_delta_seq(h, va_g[i], vb_g[i], lscale)
-             ).reshape(s_pad, config.n_kv_heads, config.head_dim
-                       ).transpose(1, 0, 2)
-        q = apply_rotary(q[None], cos, sin)[0]
-        k = apply_rotary(k[None], cos, sin)[0]
-        k_pool = _write_rows(k_pool, i, page_idx, off, k.transpose(1, 0, 2))
-        v_pool = _write_rows(v_pool, i, page_idx, off, v.transpose(1, 0, 2))
-        kr, vr = k, v
+    causal = positions[None, :] <= positions[:, None]  # [S_pad, S_pad]
+
+    def attend(i, q, k, v):  # [S_pad, H, D]: attention among the rows
+        q = apply_rotary(q.transpose(1, 0, 2)[None], cos, sin)[0]
+        k = apply_rotary(k.transpose(1, 0, 2)[None], cos, sin)[0]
+        _write_kv(pools, i, page_idx, off, k.transpose(1, 0, 2), v)
+        kr, vr = k, v.transpose(1, 0, 2)  # [H_kv, S_pad, D]
         if n_rep > 1:
             kr = jnp.repeat(kr, n_rep, axis=0)
             vr = jnp.repeat(vr, n_rep, axis=0)
@@ -471,13 +454,14 @@ def prefill_logits(config, params: Params, pools: PagedPools,
         scores = jnp.where(causal[None], scores, -1e30)
         probs = jax.nn.softmax(scores, axis=-1).astype(vr.dtype)
         out = jnp.einsum("hqk,hkd->hqd", probs, vr)
-        x = x + out.transpose(1, 0, 2).reshape(s_pad, -1) @ a["wo"]
-        x, c = _ffn(config, layer, x, valid)
-        counts.append(c)
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
+        return out.transpose(1, 0, 2).reshape(s_pad, -1)
+
+    x, counts = _stack(config, params, tokens[0], attend,
+                       _adapter_lora(adapters, adapter_id),
+                       positions < length)
     x_last = jnp.take(x, length - 1, axis=0)  # last REAL position
     logits = (x_last @ params["lm_head"]).astype(jnp.float32)[None]
-    return logits, {"k": k_pool, "v": v_pool}, counts
+    return logits, pools, counts
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
@@ -517,10 +501,9 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
     maxp = page_table.shape[0]
     ps = _page_size(pools)
     scratch = pools["k"].shape[1] - 1
-    x = params["embed"][tokens[0]].astype(config.dtype)  # [S_pad, d]
+    pools = dict(pools)
     cos, sin = rope_frequencies(config.head_dim, maxp * ps,
                                 config.rope_theta)
-    k_pool, v_pool = pools["k"], pools["v"]
     positions = prefix_len + jnp.arange(s_pad)  # global positions
     valid = positions < length
     page_idx = jnp.where(
@@ -529,37 +512,22 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
     # Causal in global positions: [1, S_pad, MAXP*ps].
     visible = jnp.arange(maxp * ps)[None, None, :] \
         <= positions[None, :, None]
-    qa_g, qb_g = adapters["qa"][adapter_id], adapters["qb"][adapter_id]
-    va_g, vb_g = adapters["va"][adapter_id], adapters["vb"][adapter_id]
-    lscale = adapters["scale"][adapter_id]
-    counts = []
-    for i, layer in enumerate(params["layers"]):
-        h = rms_norm(x, layer["attn_norm"], config.norm_eps)
-        a = layer["attn"]
-        q, k = _qk_norm(
-            config, a,
-            h @ a["wq"] + _lora_delta_seq(h, qa_g[i], qb_g[i], lscale),
-            h @ a["wk"])
-        q = q.reshape(s_pad, config.n_heads, config.head_dim)
-        k = k.reshape(s_pad, config.n_kv_heads, config.head_dim)
-        v = (h @ a["wv"] + _lora_delta_seq(h, va_g[i], vb_g[i], lscale)
-             ).reshape(s_pad, config.n_kv_heads, config.head_dim)
+
+    def attend(i, q, k, v):  # [S_pad, H, D]
         # Per-row RoPE at global positions (suffix rows are not at 0).
         q = _rotary_single(q, cos, sin, positions)
         k = _rotary_single(k, cos, sin, positions)
-        k_pool = _write_rows(k_pool, i, page_idx, off, k)
-        v_pool = _write_rows(v_pool, i, page_idx, off, v)
+        _write_kv(pools, i, page_idx, off, k, v)
         # Attend the WHOLE table (cached prefix + fresh suffix) like the
         # decode step, as a batch of one.
-        out = _attend_pages(config, q[None], k_pool, v_pool, i,
-                            page_table[None], visible)
-        x = x + out[0] @ a["wo"]
-        x, c = _ffn(config, layer, x, valid)
-        counts.append(c)
-    x = rms_norm(x, params["final_norm"], config.norm_eps)
+        return _attend_pages(config, q[None], pools["k"], pools["v"], i,
+                             page_table[None], visible)[0]
+
+    x, counts = _stack(config, params, tokens[0], attend,
+                       _adapter_lora(adapters, adapter_id), valid)
     x_last = jnp.take(x, length - prefix_len - 1, axis=0)  # last real row
     logits = (x_last @ params["lm_head"]).astype(jnp.float32)[None]
-    return logits, {"k": k_pool, "v": v_pool}, counts
+    return logits, pools, counts
 
 
 @functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))

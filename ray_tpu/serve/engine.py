@@ -22,10 +22,6 @@ The engine turns a replica from a request router into an inference loop:
   generator feeds serve's existing per-item streaming path: handles,
   HTTP SSE, gRPC server-streaming); a consumer that disappears cancels
   the request and frees its pages mid-flight.
-
-``mode="whole_request"`` keeps the same kernels but only admits when the
-batch is EMPTY (gang admission, drain to completion) — the baseline
-``bench_serve.py`` compares against.
 """
 
 from __future__ import annotations
@@ -83,7 +79,6 @@ class EngineConfig:
     max_new_tokens_cap: int = 128
     num_pages: int = 0            # 0 -> batch_slots * pages_per_seq
     max_queue: int = 32           # admission bound: beyond this, shed
-    mode: str = "continuous"      # or "whole_request" (gang admission)
     stream_timeout_s: float = 120.0
     # Multi-tenant plane.  max_adapters/lora_rank shape the device
     # adapter pool and are PART of the decode signature — engines that
@@ -97,8 +92,7 @@ class EngineConfig:
     ttft_window: int = 64
     # Flight recorder (util/steprec.py): one fixed-size record per decode
     # step into the bounded per-process ring.  Off-hot-path by design
-    # (host counters only, no device sync); the bench_serve overhead row
-    # holds it to <= 2% of step wall.  step_window sizes the recent
+    # (host counters only, no device sync).  step_window sizes the recent
     # step-wall / stall deques feeding slo_signals jitter + stall
     # pressure.
     step_record: bool = True
@@ -553,7 +547,6 @@ class InferenceEngine:
             "decode_traces": trace_count("decode"),
             "prefill_traces": trace_count("prefill"),
             "prefill_prefix_traces": trace_count("prefill_prefix"),
-            "mode": self.config.mode,
             "tenants": tenants,
             "prefix_cache": (self._cache.stats()
                              if self._cache is not None else None),
@@ -779,16 +772,12 @@ class InferenceEngine:
 
     def _admit_locked(self) -> List[_Request]:
         """Move queued requests into free slots (called under the lock).
-        Continuous mode admits whenever a slot AND pages are free;
-        whole-request mode admits a full gang only into an EMPTY batch.
+        A request is admitted whenever a slot AND its pages are free.
         Tenants are drained in weighted-fair order; each admission pins
         its prefix-cache match (refcounted shares) and allocates only the
         pages the cache can't cover, evicting cold cache leaves first
         when the pool runs dry."""
         admitted: List[_Request] = []
-        whole = self.config.mode == "whole_request"
-        if whole and any(s is not None for s in self.slots):
-            return admitted
         for slot in range(self.config.batch_slots):
             if self.slots[slot] is not None:
                 continue
@@ -1332,16 +1321,6 @@ def register_model(name: str, builder) -> None:
     _MODEL_BUILDERS[name] = builder
 
 
-def _model_functions(cfg):
-    """(init, apply) of the registered model: how its weights are made and
-    its full forward pass to logits, by the type of its configuration."""
-    from .. import models
-
-    if isinstance(cfg, models.MoEConfig):
-        return models.moe_init, lambda c, p, t: models.moe_apply(c, p, t)[0]
-    return models.llama_init, models.llama_apply
-
-
 def _tiny_config():
     import jax.numpy as jnp
 
@@ -1422,8 +1401,10 @@ class LLMServer:
                 f"actor options: llm_app(..., ray_actor_options="
                 f"{{'num_tpus': 1}})")
         t0 = time.perf_counter()
+        from ..models import init_and_apply
+
         cfg = _MODEL_BUILDERS[model]()
-        init, self._apply = _model_functions(cfg)
+        init, self._apply = init_and_apply(cfg)
         params = jax.block_until_ready(init(cfg, jax.random.PRNGKey(seed)))
         tw = time.perf_counter()
         self.engine = InferenceEngine(

@@ -18,8 +18,8 @@ ever blocking the loop:
    every flush (throttled by ``step_dump_interval_s``).  A SIGKILLed
    worker can run no exit hook, so the sidecar is written *ahead of*
    death; ``ray_tpu logs --post-mortem`` globs it up with the log tails.
-3. **Tests/bench** — ``drain_buffered()`` hands back unflushed records
-   for client-less harnesses (bench_serve's ``assert_step_records``).
+3. **Tests** — ``drain_buffered()`` hands back unflushed records to a
+   harness that has no client (``tests/test_flight_recorder.py``).
 """
 
 from __future__ import annotations
@@ -154,8 +154,7 @@ def flush_steps(client=None) -> int:
 
 def drain_buffered() -> List[Dict[str, Any]]:
     """Remove and return every buffered (not-yet-flushed) record — for
-    tests and client-less harnesses (bench_serve asserts step-record
-    completeness this way)."""
+    tests and harnesses that have no client to flush to."""
     with _ring_lock:
         out = list(_ring)
         _ring.clear()

@@ -113,9 +113,10 @@ if [ "$MODE" = "netfault" ]; then
     # smoke plus a clean cluster under live traffic must open ZERO
     # incidents — the detectors page on faults, not on ordinary load.
     echo "=== netfault false-positive gate (clean run, no injection) ==="
-    if ! env JAX_PLATFORMS=cpu timeout -k 10 300 \
-        python bench_serve.py --smoke >/dev/null 2>&1; then
-        echo "!!! false-positive gate: clean bench_serve --smoke failed"
+    if ! env JAX_PLATFORMS=cpu timeout -k 10 300 python -m pytest -q \
+        tests/test_serve_engine.py -k "span_tree or one_compiled" \
+        -p no:cacheprovider -p no:randomly >/dev/null 2>&1; then
+        echo "!!! false-positive gate: clean serve engine smoke failed"
         exit 1
     fi
     if ! env JAX_PLATFORMS=cpu timeout -k 10 300 python -m pytest -q \

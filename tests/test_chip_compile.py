@@ -336,10 +336,10 @@ def test_b1_train_step_partitions_over_four_chips(v5e, kernels_as_on_chip):
 
 def _engine_args(v5e, cfg, ec):
     """Shapes of everything the paged programs take, on one described chip."""
+    from ray_tpu.models import init_and_apply
     from ray_tpu.models.paged import init_adapter_pool, init_paged_pools
-    from ray_tpu.serve.engine import _model_functions
 
-    init = _model_functions(cfg)[0]  # the model's own
+    init = init_and_apply(cfg)[0]  # the model's own
 
     one = SingleDeviceSharding(v5e.devices[0])
     place = functools.partial(

@@ -28,8 +28,8 @@ from ray_tpu.util import steprec
 GEOMETRY = dict(batch_slots=4, page_size=8, max_prompt_len=16,
                 max_new_tokens_cap=32)
 
-# Every field the bench gate (bench_serve.assert_step_records) and the
-# `top`/`status` renderers rely on.
+# Every field the `top`/`status` renderers and the benchmark's readers
+# (benchmarks/layer_metrics/) rely on.
 STEP_FIELDS = {
     "t", "engine", "step", "wall_s", "stall_s", "occupancy", "slots",
     "admitted", "evicted", "shed", "queued", "pages_used", "pages_free",

@@ -79,16 +79,14 @@ def test_page_allocator_refcounts():
 
 def test_prefix_cache_hit_and_cow_parity(engine):
     """Cached-prefix decode (full-page hit AND mid-page COW divergence)
-    must be token-exact against the reference generate — reusing frozen
-    KV pages is an optimization, never an approximation."""
-    from ray_tpu.models.generate import generate
+    must be token-exact against greedy decoding by the full forward pass
+    — reusing frozen KV pages is an optimization, never an
+    approximation."""
+    from greedy_ref import greedy_tokens
     from ray_tpu.models.paged import trace_count
 
     def ref(prompt, n):
-        return np.asarray(generate(
-            engine.model_config, engine.params,
-            np.asarray([prompt], np.int32),
-            max_new_tokens=n))[0, len(prompt):].tolist()
+        return greedy_tokens(engine.model_config, engine.params, prompt, n)
 
     engine.clear_prefix_cache()
     cache_before = engine.stats()["prefix_cache"]
@@ -173,7 +171,7 @@ def test_adapter_mix_parity_and_one_decode_program(engine):
     """Requests on different adapters decode IN THE SAME BATCH and each
     matches the reference with that adapter's weights merged into the
     base — and the whole mix reuses the one compiled decode program."""
-    from ray_tpu.models.generate import generate
+    from greedy_ref import greedy_tokens
     from ray_tpu.models.llama import lora_merge
     from ray_tpu.models.paged import trace_count
     from ray_tpu.serve.engine import random_lora
@@ -195,14 +193,8 @@ def test_adapter_mix_parity_and_one_decode_program(engine):
     for name, seed in (("a1", 1), ("a2", 2)):
         merged = lora_merge(cfg, engine.params,
                             random_lora(cfg, seed, rank=rank))
-        ref = np.asarray(generate(
-            cfg, merged, np.asarray([prompt], np.int32),
-            max_new_tokens=6))[0, len(prompt):].tolist()
-        assert got[name] == ref, name
-    base_ref = np.asarray(generate(
-        cfg, engine.params, np.asarray([prompt], np.int32),
-        max_new_tokens=6))[0, len(prompt):].tolist()
-    assert got[None] == base_ref
+        assert got[name] == greedy_tokens(cfg, merged, prompt, 6), name
+    assert got[None] == greedy_tokens(cfg, engine.params, prompt, 6)
     # Adapter identity is per-slot DATA: no retrace for any mix.
     assert trace_count("decode") == decode_before
     st = engine.stats()["adapters"]

@@ -291,21 +291,25 @@ def test_remote_node_log_routing():
         def say():
             print("REMOTE-NODE-LOG-LINE")
             sys.stdout.flush()
-            return "said"
+            return os.getpid()
 
         strat = ray_tpu.NodeAffinitySchedulingStrategy(node.hex)
-        assert ray_tpu.get(
-            say.options(scheduling_strategy=strat).remote(), timeout=60
-        ) == "said"
+        said_pid = ray_tpu.get(
+            say.options(scheduling_strategy=strat).remote(), timeout=60)
         from ray_tpu.core.context import ctx
 
+        # The node runs a worker for each of its CPUs and their logs join
+        # the index in either order: wait for the entry of the worker that
+        # printed, by its pid, and for the line to reach its file (a
+        # deadline for a loaded host; a quiet one is done in under 1 s).
         text = ""
-        deadline = time.time() + 20
+        deadline = time.time() + 90
         while time.time() < deadline:
             entries = ctx.client.call(
                 "list_state", {"kind": "logs"})["items"]
             remote = [e for e in entries if e["kind"] == "worker"
-                      and e["node_id"] == node.hex]
+                      and e["node_id"] == node.hex
+                      and e["pid"] == said_pid]
             if remote:
                 text = ray_tpu.get_log(remote[0]["proc_id"])
                 if "REMOTE-NODE-LOG-LINE" in text:

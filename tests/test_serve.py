@@ -395,11 +395,15 @@ def test_streaming_grpc_ingress(rt):
 
 
 def test_llm_token_streaming_deployment(rt):
-    """The full LLM-serving story: a deployment holds Llama weights + the
-    KV-cache decode loop and STREAMS tokens as they decode — handle-level
+    """The full LLM-serving story: a deployment holds Llama weights + a
+    greedy decode loop and STREAMS tokens as they decode — handle-level
     and SSE (reference: Ray Serve's LLM APIs stream autoregressive
     tokens; here decode-step latency hides behind the serve streaming
     path)."""
+
+    import cloudpickle
+    import greedy_ref
+    from greedy_ref import greedy_tokens
 
     @serve.deployment(num_replicas=1)
     class TinyLlama:
@@ -413,19 +417,14 @@ def test_llm_token_streaming_deployment(rt):
             self.params = llama_init(self.cfg, jax.random.PRNGKey(0))
 
         def __call__(self, prompt_tokens, max_new_tokens=4):
-            import numpy as np
-
-            from ray_tpu.models import generate
-
             import queue as _q
-            out_q: "_q.Queue" = _q.Queue()
             import threading
 
+            out_q: "_q.Queue" = _q.Queue()
+
             def run():
-                generate(self.cfg, self.params,
-                         np.asarray([prompt_tokens], np.int32),
-                         max_new_tokens=max_new_tokens,
-                         stream=lambda t: out_q.put(int(t[0])))
+                greedy_tokens(self.cfg, self.params, list(prompt_tokens),
+                              max_new_tokens, stream=out_q.put)
                 out_q.put(None)
 
             threading.Thread(target=run, daemon=True).start()
@@ -435,7 +434,13 @@ def test_llm_token_streaming_deployment(rt):
                     return
                 yield tok
 
-    handle = serve.run(TinyLlama.bind())
+    # The replica's process has no tests/ on its path: the helper travels
+    # with the deployment.
+    cloudpickle.register_pickle_by_value(greedy_ref)
+    try:
+        handle = serve.run(TinyLlama.bind())
+    finally:
+        cloudpickle.unregister_pickle_by_value(greedy_ref)
     toks = list(handle.options(stream=True).remote([1, 2, 3], 5))
     assert len(toks) == 5 and all(isinstance(t, int) for t in toks)
 

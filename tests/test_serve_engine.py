@@ -8,8 +8,6 @@ workload, and one compiled program serves every admission mix.
 """
 
 import json
-import subprocess
-import sys
 import time
 import urllib.request
 
@@ -48,20 +46,16 @@ def engine():
     eng.shutdown()
 
 
-def test_paged_decode_matches_reference_generate(engine):
-    """The paged engine's greedy decode must match models.generate token
-    for token (same params, same math, pages instead of a linear cache)."""
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models.generate import generate
+def test_paged_decode_matches_the_full_forward_pass(engine):
+    """The paged engine's greedy decode must match greedy decoding by the
+    plain full forward pass token for token (same params, same layer,
+    pages instead of recomputing the sequence)."""
+    from greedy_ref import greedy_tokens
 
     prompt = [5, 7, 11]
     toks = list(engine.submit(prompt, max_new_tokens=6))
-    ref = np.asarray(generate(
-        engine.model_config, engine.params,
-        np.asarray([prompt], np.int32), max_new_tokens=6))[0, len(prompt):]
-    assert toks == ref.tolist()
+    assert toks == greedy_tokens(engine.model_config, engine.params,
+                                 prompt, 6)
     # Greedy decode is deterministic across engine runs.
     assert list(engine.submit(prompt, max_new_tokens=6)) == toks
 
@@ -71,8 +65,8 @@ def test_paged_decode_matches_reference_generate(engine):
 def test_paged_parity_for_every_kv_grouping(monkeypatch, n_heads,
                                             n_kv_heads):
     """One pool layout and one grouped contraction serve MHA (a group of
-    one), GQA and MQA: in float32 the engine's tokens equal
-    models.generate's through a cold prefill, a full-page prefix hit, a
+    one), GQA and MQA: in float32 the engine's tokens equal the full
+    forward pass's (``greedy_ref``) through a cold prefill, a full-page prefix hit, a
     prefix hit that diverges mid-page (copy-on-write), and decode across a
     page edge; and the first token of each path has the plain full
     forward's best logit to within 1e-5."""
@@ -80,8 +74,8 @@ def test_paged_parity_for_every_kv_grouping(monkeypatch, n_heads,
     import jax.numpy as jnp
 
     import ray_tpu.models.paged as paged_mod
+    from greedy_ref import greedy_tokens
     from ray_tpu.models import LlamaConfig, llama_apply, llama_init
-    from ray_tpu.models.generate import generate
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
     cfg = LlamaConfig(vocab_size=512, d_model=128, n_layers=2,
@@ -97,9 +91,7 @@ def test_paged_parity_for_every_kv_grouping(monkeypatch, n_heads,
     monkeypatch.setattr(paged_mod, "copy_page", counted_copy)
 
     def ref(prompt, n):
-        return np.asarray(generate(
-            cfg, params, np.asarray([prompt], np.int32),
-            max_new_tokens=n))[0, len(prompt):].tolist()
+        return greedy_tokens(cfg, params, prompt, n)
 
     def best_logit_gap(context, token):
         logits = np.asarray(llama_apply(
@@ -238,8 +230,8 @@ def test_prefill_bucket_wider_than_worst_case_footprint():
     import jax
     import jax.numpy as jnp
 
+    from greedy_ref import greedy_tokens
     from ray_tpu.models import LlamaConfig, llama_init
-    from ray_tpu.models.generate import generate
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
     cfg = LlamaConfig.tiny(remat=False, dtype=jnp.float32)
@@ -251,10 +243,7 @@ def test_prefill_bucket_wider_than_worst_case_footprint():
         assert eng.maxp == 4
         prompt = list(range(2, 20))  # 18 tokens -> the 32 bucket
         toks = list(eng.submit(prompt, max_new_tokens=4))
-        ref = np.asarray(generate(
-            cfg, params, np.asarray([prompt], np.int32),
-            max_new_tokens=4))[0, len(prompt):]
-        assert toks == ref.tolist()
+        assert toks == greedy_tokens(cfg, params, prompt, 4)
         eng.clear_prefix_cache()  # drop cached prompt pages
         assert eng.allocator.free_count == eng.allocator.total
     finally:
@@ -273,24 +262,6 @@ def test_llm_server_refuses_the_cpu_on_a_host_with_chips(monkeypatch):
     app = llm_app(model="b1", ray_actor_options={"num_tpus": 1})
     assert app.deployment.to_spec(app)["resources"] == {"TPU": 1}
     assert llm_app().deployment.to_spec(llm_app())["resources"] == {}
-
-
-def test_whole_request_mode_gang_admission():
-    """The baseline mode admits only into an EMPTY batch: a request
-    arriving mid-gang waits for the gang to fully drain."""
-    eng = _tiny_engine(mode="whole_request")
-    try:
-        a = eng.submit([1, 2], max_new_tokens=12)
-        next(a)
-        b = eng.submit([3, 4], max_new_tokens=2)
-        b_toks = list(b)
-        list(a)
-        assert len(b_toks) == 2
-        # B's first token comes only after A's last step (gang barrier) —
-        # the exact opposite of the continuous-mode assertion above.
-        assert b.steps[0] >= a.steps[-1]
-    finally:
-        eng.shutdown()
 
 
 def test_model_failure_fails_streams_not_the_loop(monkeypatch):
@@ -385,9 +356,8 @@ def test_serve_request_connected_trace_tree(rt):
     ingress -> handle -> replica -> engine (queue/prefill/decode),
     reconstructable from the head's span plane by trace id — the
     X-RT-Trace-Id the HTTP ingress returns.  Engine-stage completeness is
-    ALSO gated by bench_serve --smoke (assert_trace_completeness), so
-    tier-1 keeps the cheap propagation tests while this covers the full
-    serve path."""
+    held in tier-1 by test_engine_request_span_tree, so tier-1 keeps the
+    cheap propagation tests while this covers the full serve path."""
     from ray_tpu.core.context import ctx
     from ray_tpu.util import trace_analysis
 
@@ -480,28 +450,3 @@ def test_llm_app_streams_and_cancels_through_serve(rt):
     assert st["cancelled"] >= 1
     # One compiled decode program served the whole test.
     assert st["decode_traces"] == 1
-
-
-@pytest.mark.slow
-def test_bench_serve_smoke():
-    """The traffic generator and BOTH batching modes stay exercised: the
-    bench's smoke mode must produce a full summary with balanced free
-    lists and single-compile decode rows."""
-    import os
-    import tempfile
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench_serve.py")
-    with tempfile.NamedTemporaryFile(suffix=".json") as f:
-        subprocess.run(
-            [sys.executable, bench, "--smoke", "--out", f.name],
-            check=True, timeout=540, cwd=os.path.dirname(bench),
-            env={**os.environ, "JAX_PLATFORMS": "cpu"})
-        report = json.load(open(f.name))
-    s = report["summary"]
-    assert s["continuous_tokens_per_s"] > 0
-    assert s["whole_request_tokens_per_s"] > 0
-    assert "continuous_over_whole_request" in s
-    for rows in report["modes"].values():
-        assert rows and all(r["free_list_balanced"] for r in rows)
-        assert all(r["decode_traces"] == 1 for r in rows)
