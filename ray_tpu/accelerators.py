@@ -20,6 +20,10 @@ tpu.py:71 TPUAcceleratorManager) — re-designed for this framework:
   chip/host-bounds variables that make libtpu carve out a sub-host topology
   (reference: tpu.py:155-196; the 1-chip and 2-chip bounds come from the
   jax#14977 recipe).  n == all chips keeps the host's own bounds.
+- **Chip hand-back** (`wait_for_chips`): a chip's device file opens for one
+  holder at a time, and the host hands it back some seconds after the last
+  holder died.  A granted worker waits for each of its device files to open
+  (bounded) before JAX is imported, so that libtpu does not find one busy.
 - **Worker environment** (`worker_env`): what every spawner hands a worker
   process — pinned to the CPU backend until a chip grant, and carrying the
   host's libtpu configuration so that a granted worker starts without a
@@ -36,11 +40,13 @@ task's function, i.e. before user code first imports jax.
 
 from __future__ import annotations
 
+import errno
 import glob
 import os
 import re
 import sys
-from typing import Dict, List, Mapping, Optional
+import time
+from typing import Callable, Dict, List, Mapping, Optional
 
 TPU_VALID_CHIP_OPTIONS = (1, 2, 4, 8)
 
@@ -226,6 +232,72 @@ def apply_visibility(chip_ids: List[int], host_chips: Optional[int] = None) -> N
             f"granted TPU chips {sorted(chip_ids)}, but JAX was imported in "
             f"this process before the grant and runs on {backend!r}; chip "
             f"grants need a fresh worker")
+
+
+#: How long a granted worker waits for a chip's device file to open.
+CHIP_WAIT_S = 60.0
+
+
+def chip_device_paths(chip_ids: List[int]) -> List[str]:
+    """The device file of each granted chip: ``/dev/accel<n>``, or the n-th
+    numbered entry of ``/dev/vfio`` (as `num_chips` counts them).  A chip
+    this node has no such file for is left out."""
+    if glob.glob("/dev/accel*"):
+        paths = [f"/dev/accel{n}" for n in chip_ids]
+        return [p for p in paths if os.path.exists(p)]
+    try:
+        groups = sorted((e for e in os.listdir("/dev/vfio") if e.isdigit()),
+                        key=int)
+    except (FileNotFoundError, NotADirectoryError, PermissionError):
+        return []
+    return [f"/dev/vfio/{groups[n]}" for n in chip_ids if n < len(groups)]
+
+
+def _holder(path: str) -> str:
+    """Who has ``path`` open, as far as ``/proc`` lets this process see."""
+    found = []
+    for fd in glob.glob("/proc/[0-9]*/fd/*"):
+        try:
+            if os.readlink(fd) != path:
+                continue
+            pid = fd.split("/")[2]
+            with open(f"/proc/{pid}/comm") as f:
+                found.append(f"pid {pid} ({f.read().strip()})")
+        except OSError:
+            continue
+    return ", ".join(sorted(set(found))) or "no holder visible in /proc"
+
+
+def wait_for_chips(paths: List[str], timeout_s: float = CHIP_WAIT_S, *,
+                   opener: Optional[Callable[[str], int]] = None,
+                   sleep: Callable[[float], None] = time.sleep) -> float:
+    """Wait until each device file in ``paths`` can be opened, and close it
+    again: one open and close a chip where the chips are free.  A file that
+    is still busy (``EBUSY``: its last holder's death has not reached the
+    host's driver yet) is polled for ``timeout_s`` seconds in all, then a
+    ``RuntimeError`` names it and its holder.  Any other error is libtpu's
+    to report in its own words.  Returns the seconds waited."""
+    if opener is None:
+        def opener(path: str) -> int:
+            return os.open(path, os.O_RDWR | os.O_CLOEXEC)
+    t0, delay = time.monotonic(), 0.05
+    for path in paths:
+        while True:
+            try:
+                os.close(opener(path))
+                break
+            except OSError as e:
+                if e.errno != errno.EBUSY:
+                    break
+                waited = time.monotonic() - t0
+                if waited >= timeout_s:
+                    raise RuntimeError(
+                        f"TPU device {path} is still busy {waited:.0f} s "
+                        f"after it was granted to this worker; held by "
+                        f"{_holder(path)}") from e
+            sleep(delay)
+            delay = min(1.0, 2 * delay)
+    return time.monotonic() - t0
 
 
 #: Variables that carve chips out for ONE process.  A worker never inherits

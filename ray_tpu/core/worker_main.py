@@ -923,6 +923,11 @@ class Worker:
                 for k in tpu_keys:
                     saved_env.setdefault(k, os.environ.get(k))
                 accelerators.apply_visibility(spec["tpu_chips"])
+                # The host hands a chip back some seconds after its last
+                # holder died: outwait that here, before libtpu opens the
+                # device and gives up on the first EBUSY.
+                accelerators.wait_for_chips(
+                    accelerators.chip_device_paths(spec["tpu_chips"]))
                 # This process compiles for the chip from here on.
                 accelerators.enable_compile_cache()
             if renv.get("working_dir_key"):

@@ -11,6 +11,19 @@ The engine turns a replica from a request router into an inference loop:
 - Queued sequences are admitted into free batch slots BETWEEN decode
   steps; a prefill runs as its own (bucketed) program, so running
   sequences stall by at most one step per admission.
+- The loop keeps ONE decode step in flight.  A step's tokens, lengths
+  and PRNG key advance on the device and every page a sequence can
+  reach was reserved at admission, so while membership stands still
+  step n+1 needs nothing the host learns from step n: the loop
+  dispatches n+1 first and then reads, emits and records n while the
+  chip runs n+1 (``_run_step``).  It engages by what the loop observes
+  (nothing admitted, no control op, mirrors clean, no token budget
+  ending at step n) and otherwise reads the step in flight before
+  anything else is dispatched: prefills, uploads, control ops and
+  evictions by budget only ever meet a quiet device.  A stop the host
+  cannot foresee (a stop token, a cancel) wastes the one step already
+  dispatched: its token is dropped and its K/V row lies past the
+  sequence's length in a page that slot had reserved.
 - Finished/cancelled sequences are evicted between steps and their pages
   return to the free list; the page pool's worst-case footprint is
   reserved at admission, so decode can never die of page exhaustion
@@ -33,7 +46,7 @@ import queue as _queue
 import sys
 import threading
 import time
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -183,6 +196,39 @@ class _Request:
         return self.submit_wall + (t - self.submit_t)
 
 
+class _Step(NamedTuple):
+    """A decode step that was dispatched and whose tokens the host has not
+    read: its number, the device array of its tokens, the request each
+    slot decoded for when it was dispatched, and whether it went out
+    before the step ahead of it was read."""
+
+    number: int
+    tokens: Any
+    owners: List[Optional[_Request]]
+    ahead: bool
+
+
+class _Account:
+    """The step record being filled: opened by the first ``_run_step``
+    after the last record closed, closed by ``_record_step``."""
+
+    __slots__ = ("t0", "phases", "stall_s", "first_tokens", "evicted0",
+                 "shed0", "compiles0", "traces0")
+
+    def __init__(self, engine: "InferenceEngine"):
+        from ..models.paged import trace_counts
+        from ..util import devmem
+
+        self.t0 = time.perf_counter()
+        self.phases: Dict[str, float] = {}
+        self.stall_s = 0.0
+        self.first_tokens: List[Dict[str, Any]] = []
+        self.evicted0 = engine._evicted_total
+        self.shed0 = engine.shed
+        self.compiles0 = devmem.compile_count()
+        self.traces0 = trace_counts()
+
+
 class TokenStream:
     """Per-request token iterator; the consumer side of the engine's
     emission queue.  ``cancel()`` (or closing the iterating generator)
@@ -278,12 +324,13 @@ class InferenceEngine:
         # the tokens it returns (paged.ROUTING_KEYS): the mirror has their
         # room, so that what goes up has the shape of what comes back.
         self._tokens = np.zeros((b + routing_width(model_config),), np.int32)
-        self._routing: Dict[str, int] = {}  # of the step being run
         self._active = np.zeros((b,), bool)
         self._temps = np.zeros((b,), np.float32)
         self._adapter_slots = np.full((b,), self.adapter_pool.zero_slot,
                                       np.int32)
         self._dirty = True
+        #: The decode step dispatched and not yet read (one at most).
+        self._inflight: Optional[_Step] = None
         self._d_tokens = self._d_page_tables = None
         self._d_seq_lens = self._d_active = self._d_temps = None
         self._d_adapter_slots = None
@@ -373,12 +420,12 @@ class InferenceEngine:
             maxlen=max(16, cfg.step_window))  # (wall_time, stall_s)
         self._evicted_total = 0
         # The loop's own time account (rides on the step record, so
-        # step_record switches it): seconds by phase between two records,
-        # the first_tokens entries of the step being run, and where the
-        # previous record's step ended (t0 + wall_s, as recorded).
+        # step_record switches it): the idle seconds between two records,
+        # the record being filled (_rec), and where the previous record
+        # ended (t0 + wall_s, as recorded).
         self._gap_acct: Optional[Dict[str, float]] = (
             {} if cfg.step_record else None)
-        self._first_tokens: List[Dict[str, Any]] = []  # fresh each step
+        self._acct: Optional[_Account] = None
         self._prev_end = round(time.perf_counter(), 6)
         # Device-memory attribution: the engine owns the big allocations,
         # so it names them for util/devmem snapshots.  Weights bytes are
@@ -918,7 +965,9 @@ class InferenceEngine:
             "prompt": n, "bucket": bucket, "cached": prefix_len,
             **routing,  # a routed model's counters of this prefill
         }
-        self._first_tokens.append(entry)
+        rec = self._rec()
+        if rec is not None:
+            rec.first_tokens.append(entry)
         # The request's spans, from the same stamps as the entry: queue
         # wait (submit -> admission into a batch slot) and the prefill;
         # bucket and cached-prefix attrs make padding waste and cache
@@ -1015,13 +1064,13 @@ class InferenceEngine:
         self._temps[slot] = req.temperature
         self._adapter_slots[slot] = req.adapter_slot
         self._dirty = True
-        self._emit_token(req, first)
+        self._emit_token(req, first, self.step_count)
         return int(s_pad), dict(zip(ROUTING_KEYS, out[1:].tolist()))
 
-    def _emit_token(self, req: _Request, token: int) -> None:
+    def _emit_token(self, req: _Request, token: int, step: int) -> None:
         req.generated += 1
         self._m_tokens.inc(1)
-        req.out_q.put(("tok", token, self.step_count))
+        req.out_q.put(("tok", token, step))
         if req.stop_token is not None and token == req.stop_token:
             self._evict(req.slot, "stop")
         elif req.generated >= req.max_new:
@@ -1035,6 +1084,8 @@ class InferenceEngine:
         queued — they retry against the fresh pool."""
         from ..models.paged import init_paged_pools
 
+        self._quiesce()
+        self._acct = None  # its seconds fall to the next record's between_s
         now_wall = time.time()
         for slot, req in enumerate(self.slots):
             if req is None:
@@ -1104,9 +1155,15 @@ class InferenceEngine:
                 for slot, req in enumerate(self.slots):
                     if req is not None and req.cancelled.is_set():
                         self._evict(slot, "cancelled")
-                admitted = self._admit_locked()
+                # A slot freed under the step in flight (a stop token, a
+                # cancel) is still written for its previous owner by that
+                # step: nothing is admitted this round, _run_step reads
+                # the step, and the next round admits.
+                admitted = ([] if self._inflight is not None and self._dirty
+                            else self._admit_locked())
                 active = sum(1 for s in self.slots if s is not None)
-                if not admitted and active == 0 and not control:
+                if not admitted and active == 0 and not control \
+                        and self._inflight is None:
                     self._m_active.set(0, tags=self._pid_tags)
                     self._m_pages.set(self.allocator.used_count,
                                       tags=self._pid_tags)
@@ -1116,15 +1173,19 @@ class InferenceEngine:
             # Model work runs OUTSIDE the lock: pools/slot arrays belong
             # to this thread; submit() only appends to the wait queue.
             # Control ops (adapter registration, cache clear) run here
-            # for the same reason.
-            for task in control:
-                task()
+            # for the same reason, and with no step in flight.
             try:
+                if control and self._inflight is not None:
+                    step, self._inflight = self._inflight, None
+                    self._finish_step(step)
+                for task in control:
+                    task()
                 self._run_step(admitted)
             except Exception as e:  # noqa: BLE001 — fail streams, not
                 self._fail_inflight(e)  # the loop thread
         # Shutdown: fail queued + in-flight requests loudly, and unblock
-        # any control-op waiters.
+        # any control-op waiters.  The device is quiet before a slot goes.
+        self._quiesce()
         with self._lock:
             pending = [r for q in self._queues.values() for r in q]
             for q in self._queues.values():
@@ -1140,45 +1201,84 @@ class InferenceEngine:
             if req is not None:
                 self._evict(slot, "shutdown")
 
-    def _run_step(self, admitted: List[_Request]) -> None:
-        import jax.numpy as jnp
+    def _rec(self) -> Optional[_Account]:
+        """The step record being filled (None with ``step_record`` off),
+        opened here if the last one was closed."""
+        if self._acct is None and self.config.step_record:
+            self._acct = _Account(self)
+        return self._acct
 
-        from ..models.paged import (ROUTING_KEYS, paged_decode_step,
-                                    trace_counts)
-        from ..util import devmem
+    def _quiesce(self) -> None:
+        """Let go of the step in flight, once the device is done with it;
+        nobody gets its tokens."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            try:
+                np.asarray(step.tokens)  # rt-sync-ok: shutdown or failure, the device must be quiet before slots and pools go
+            except Exception:  # noqa: BLE001 — its failure is being handled
+                pass
+
+    def _may_run_ahead(self) -> bool:
+        """Whether the step after the one in flight can be dispatched
+        before that one is read: membership stands as the device has it
+        (``_dirty`` false) and no slot's token budget ends at the step in
+        flight, which the host knows by count.  Its pages were reserved at
+        admission, so the step needs nothing else from the host."""
+        return not self._dirty and all(
+            req is None or req.max_new - req.generated > 1
+            for req in self.slots)
+
+    def _run_step(self, admitted: List[_Request]) -> None:
+        """One turn of the loop: at most one decode step dispatched and at
+        most one read.  With a step in flight and membership standing
+        still, the next step goes out first and the chip runs it while
+        this turn reads, emits and records the one before.  Otherwise the
+        step in flight is read first; what it evicted is admission's to
+        refill, so with nothing to prefill the turn ends there.  With
+        nothing in flight (the serial case) the turn prefills what was
+        admitted, uploads the mirrors and dispatches, and the next turn
+        reads."""
         from ..util.profiling import annotation
 
-        # Flight recorder entry state: step wall, admission-stall span,
-        # the step's phase account and per-step deltas come from host
-        # counters only — no device sync, no lock beyond what the loop
-        # already holds.
-        rec_on = self.config.step_record
-        t0 = time.perf_counter()
-        acct: Optional[Dict[str, float]] = {} if rec_on else None
-        self._first_tokens = []
-        self._routing = {}
-        stall_s = 0.0
-        evicted0 = self._evicted_total
-        shed0 = self.shed
-        compiles0 = devmem.compile_count()
-        traces0 = trace_counts() if rec_on else None
+        self._rec()  # a record opens where its first turn begins
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            if not admitted and self._may_run_ahead():
+                self._inflight = self._dispatch_step(ahead=True)
+            self._finish_step(step)
+            if not admitted:
+                return
+        rec = self._rec()
         for req in admitted:
             pf0 = time.perf_counter()
             self._prefill(req)
-            stall_s += time.perf_counter() - pf0
-        if not any(s is not None for s in self.slots):
-            if rec_on and admitted:
-                with annotation(PH_RECORD):
-                    self._record_step(t0, acct, stall_s, len(admitted),
-                                      evicted0, shed0, compiles0, traces0,
-                                      decoded=False)
-            return
+            if rec is not None:
+                rec.stall_s += time.perf_counter() - pf0
+        if any(s is not None for s in self.slots):
+            self._inflight = self._dispatch_step(ahead=False)
+        elif rec is not None and admitted:  # prefills that ended at once
+            with annotation(PH_RECORD):
+                self._record_step(rec, None, {})
+        else:
+            self._acct = None  # an empty turn (a control op's) is no record
+
+    def _dispatch_step(self, ahead: bool) -> _Step:
+        """Enqueue one decode step for every slot; its tokens stay on the
+        device until ``_finish_step``.  ``ahead``: the step before it has
+        not been read (then nothing is dirty and nothing is uploaded)."""
+        import jax.numpy as jnp
+
+        from ..models.paged import paged_decode_step
+        from ..util.profiling import annotation
+
+        rec = self._rec()
+        phases = rec.phases if rec is not None else None
         self.step_count += 1
         if self._dirty:
             # Membership changed since the last step: re-upload the
             # host mirrors.  Steady-state decode skips this — tokens,
             # lengths, and the PRNG key advance on device.
-            with annotation(PH_UPLOAD, acct):
+            with annotation(PH_UPLOAD, phases):
                 self._d_tokens = jnp.asarray(self._tokens)
                 self._d_page_tables = jnp.asarray(self._page_tables)
                 self._d_seq_lens = jnp.asarray(self._seq_lens)
@@ -1186,7 +1286,7 @@ class InferenceEngine:
                 self._d_temps = jnp.asarray(self._temps)
                 self._d_adapter_slots = jnp.asarray(self._adapter_slots)
                 self._dirty = False
-        with annotation(PH_DISPATCH, acct):
+        with annotation(PH_DISPATCH, phases):
             (self._d_tokens, self._d_seq_lens, self._d_key,
              self.pools) = paged_decode_step(
                 self.model_config, self.params, self.pools,
@@ -1194,15 +1294,26 @@ class InferenceEngine:
                 self._d_tokens, self._d_page_tables, self._d_seq_lens,
                 self._d_active, self._d_temps, self._d_adapter_slots,
                 self._d_key)
-        with annotation(PH_READBACK, acct):
-            toks = np.asarray(self._d_tokens)  # rt-sync-ok: THE decode-step readback — one batched token fetch per step
-        with annotation(PH_EMIT, acct) as phase:
+        return _Step(self.step_count, self._d_tokens, list(self.slots),
+                     ahead)
+
+    def _finish_step(self, step: _Step) -> None:
+        """Read a dispatched step's tokens, hand each to the request its
+        slot decoded for, and close the record."""
+        from ..models.paged import ROUTING_KEYS
+        from ..util.profiling import annotation
+
+        rec = self._rec()
+        phases = rec.phases if rec is not None else None
+        with annotation(PH_READBACK, phases):
+            toks = np.asarray(step.tokens)  # rt-sync-ok: THE decode-step readback — one batched token fetch per step
+        with annotation(PH_EMIT, phases) as phase:
             now = phase.t0
-            self._routing = dict(zip(
+            routing = dict(zip(
                 ROUTING_KEYS, toks[self.config.batch_slots:].tolist()))
-            for slot, req in enumerate(self.slots):
-                if req is None:
-                    continue
+            for slot, req in enumerate(step.owners):
+                if req is None or self.slots[slot] is not req:
+                    continue  # stopped under this step: the token is dropped
                 self._seq_lens[slot] += 1
                 req.length += 1
                 self._tokens[slot] = toks[slot]
@@ -1211,50 +1322,54 @@ class InferenceEngine:
                     req.itls.append(itl)
                     self._m_itl.observe(itl)
                 req.last_token_t = now
-                self._emit_token(req, int(toks[slot]))
+                self._emit_token(req, int(toks[slot]), step.number)
         self._m_active.set(
             sum(1 for s in self.slots if s is not None),
             tags=self._pid_tags)
         self._m_pages.set(self.allocator.used_count,
                           tags=self._pid_tags)
-        if rec_on:
+        if rec is not None:
             with annotation(PH_RECORD):
-                self._record_step(t0, acct, stall_s, len(admitted),
-                                  evicted0, shed0, compiles0, traces0,
-                                  decoded=True)
+                self._record_step(rec, step, routing)
 
-    def _record_step(self, t0: float, acct: Dict[str, float],
-                     stall_s: float, admitted: int, evicted0: int,
-                     shed0: int, compiles0: int,
-                     traces0: Optional[Dict[str, int]],
-                     decoded: bool) -> None:
-        """Append one flight-recorder record for the step that just ran.
-        Called on the loop thread; everything here is host bookkeeping
-        (the decode result was already synced for token emission).
+    def _record_step(self, acct: _Account, step: Optional[_Step],
+                     routing: Dict[str, int]) -> None:
+        """Close ``acct`` into one flight-recorder record: of ``step``,
+        which was just read, or (None) of prefills that left no sequence to
+        decode.  Called on the loop thread; everything here is host
+        bookkeeping (the decode result was already synced for token
+        emission).
 
         The records tile the loop's time: ``t0`` (``time.perf_counter()``
-        at the top of ``_run_step``) less the previous record's ``t0 +
-        wall_s`` is ``between_s + idle_s``, and inside ``wall_s`` the
-        phases ``stall_s`` (the admission prefills, itemised a request in
-        ``first_tokens``), ``upload_s``, ``dispatch_s``, ``readback_s`` and
-        ``emit_s`` leave only the gauges."""
+        where the record's first ``_run_step`` began) less the previous
+        record's ``t0 + wall_s`` is ``between_s + idle_s``, and inside
+        ``wall_s`` the phases ``stall_s`` (the admission prefills, itemised
+        a request in ``first_tokens``), ``upload_s``, ``dispatch_s``,
+        ``readback_s`` and ``emit_s`` leave only the gauges and, where the
+        step was dispatched in one turn and read in the next, the locked
+        section between them.  A phase's seconds go to the record that was
+        open when it ran: with a step in flight, ``dispatch_s`` is the
+        next step's dispatch, ``readback_s`` the time still blocked on the
+        chip, and ``wall_s + between_s`` the step's period.  ``ahead`` is
+        1 when the step was dispatched before the one ahead of it was
+        read."""
         from ..models.paged import trace_counts
         from ..util import devmem, steprec
 
+        self._acct = None
+        t0, stall_s = acct.t0, acct.stall_s
         wall_s = time.perf_counter() - t0
         now = time.time()
-        if decoded:
+        if step is not None:
             self._step_walls.append(wall_s)
         if stall_s > 0:
             self._stall_events.append((now, stall_s))
             self._m_stall.inc(stall_s)
         # Compile observability: a trace-count bump inside this step means
         # this step's wall paid the compile — attribute it by program.
-        if traces0 is not None:
-            traces1 = trace_counts()
-            for prog, n in traces1.items():
-                if n > traces0.get(prog, 0):
-                    devmem.record_compile(prog, wall_s)
+        for prog, n in trace_counts().items():
+            if n > acct.traces0.get(prog, 0):
+                devmem.record_compile(prog, wall_s)
         with self._lock:
             queued = self._queued_total()
             tenants = {t: len(q) for t, q in self._queues.items() if q}
@@ -1264,26 +1379,27 @@ class InferenceEngine:
         idle_s = round(self._gap_acct.pop(PH_IDLE, 0.0), 6)
         between_s = round(t0 - self._prev_end - idle_s, 6)
         self._prev_end = t0 + wall_s
-        first_tokens = self._first_tokens
+        first_tokens, phases = acct.first_tokens, acct.phases
         rec = {
             "t": round(now, 3),
             "engine": self.engine_id,
-            "step": self.step_count,
+            "step": step.number if step is not None else self.step_count,
+            "ahead": int(step is not None and step.ahead),
             "t0": t0,
             "wall_s": wall_s,
             "stall_s": round(stall_s, 6),
             "between_s": between_s,
             "idle_s": idle_s,
-            "upload_s": round(acct.get(PH_UPLOAD, 0.0), 6),
-            "dispatch_s": round(acct.get(PH_DISPATCH, 0.0), 6),
-            "readback_s": round(acct.get(PH_READBACK, 0.0), 6),
-            "emit_s": round(acct.get(PH_EMIT, 0.0), 6),
+            "upload_s": round(phases.get(PH_UPLOAD, 0.0), 6),
+            "dispatch_s": round(phases.get(PH_DISPATCH, 0.0), 6),
+            "readback_s": round(phases.get(PH_READBACK, 0.0), 6),
+            "emit_s": round(phases.get(PH_EMIT, 0.0), 6),
             "first_tokens": first_tokens,
             "occupancy": sum(1 for s in self.slots if s is not None),
             "slots": self.config.batch_slots,
-            "admitted": admitted,
-            "evicted": self._evicted_total - evicted0,
-            "shed": self.shed - shed0,
+            "admitted": len(first_tokens),
+            "evicted": self._evicted_total - acct.evicted0,
+            "shed": self.shed - acct.shed0,
             "queued": queued,
             "pages_used": self.allocator.used_count,
             "pages_free": self.allocator.free_count,
@@ -1293,11 +1409,11 @@ class InferenceEngine:
             "tenants": tenants,
             # Only a model whose FFN is routed has these (of the decode
             # step; a prefill's are on its first_tokens entry).
-            **self._routing,
+            **routing,
         }
         # Which step recompiled, for every jitted program of the process
         # (trace_counts above knows the three paged ones).
-        compiles = devmem.compile_count() - compiles0
+        compiles = devmem.compile_count() - acct.compiles0
         if compiles:
             rec["compiles"] = compiles
         steprec.record_step(rec)

@@ -5,6 +5,7 @@ Mirrors the reference's accelerator-manager tests
 mocked via RT_TPU_CHIPS.
 """
 
+import errno
 import os
 
 import pytest
@@ -204,6 +205,54 @@ class TestCompileCache:
         accelerators.enable_compile_cache()
         assert os.environ["JAX_COMPILATION_CACHE_DIR"] == path
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+
+
+class TestChipHandBack:
+    """`wait_for_chips`: a granted worker outwaits the host's late hand-back
+    of a chip's device file (EBUSY) before libtpu opens it."""
+
+    @staticmethod
+    def _opener(busy):
+        """Opens /dev/null; each path is EBUSY ``busy[path]`` times first."""
+        calls = []
+
+        def opener(path):
+            calls.append(path)
+            if busy.get(path, 0) > 0:
+                busy[path] -= 1
+                raise OSError(errno.EBUSY, "Device or resource busy", path)
+            return os.open(os.devnull, os.O_RDONLY)
+
+        return opener, calls
+
+    def test_a_device_busy_twice_and_then_free_is_waited_for(self):
+        opener, calls = self._opener({"/dev/vfio/1": 2})
+        naps = []
+        accelerators.wait_for_chips(
+            ["/dev/vfio/0", "/dev/vfio/1", "/dev/vfio/2"], opener=opener,
+            sleep=naps.append)
+        assert calls == ["/dev/vfio/0"] + ["/dev/vfio/1"] * 3 + ["/dev/vfio/2"]
+        assert len(naps) == 2 and all(0 < n <= 1.0 for n in naps)
+
+    def test_it_gives_up_with_the_devices_name_and_its_holder(self):
+        opener, calls = self._opener({"/dev/vfio/3": 10 ** 6})
+        with pytest.raises(RuntimeError,
+                           match=r"/dev/vfio/3 is still busy .* held by "):
+            accelerators.wait_for_chips(["/dev/vfio/3"], timeout_s=0.2,
+                                        opener=opener)
+        assert len(calls) >= 2
+
+    def test_a_node_without_device_files_waits_for_nothing(self):
+        # This sandbox has neither /dev/accel* nor /dev/vfio: no path, no
+        # open.  Any error but EBUSY is left for libtpu to report.
+        assert accelerators.chip_device_paths([0, 1, 2, 3]) == [] or \
+            os.path.exists("/dev/vfio") or os.path.exists("/dev/accel0")
+
+        def denied(path):
+            raise PermissionError(errno.EACCES, "Permission denied", path)
+
+        assert accelerators.wait_for_chips(["/dev/vfio/0"],
+                                           opener=denied) < 1.0
 
 
 class TestPeakTable:

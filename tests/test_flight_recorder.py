@@ -562,7 +562,7 @@ def test_headless_step_records_hold_and_replay(tmp_path, monkeypatch):
 
 PHASE_FIELDS = {"t0": float, "between_s": float, "idle_s": float,
                 "upload_s": float, "dispatch_s": float, "readback_s": float,
-                "emit_s": float, "first_tokens": list}
+                "emit_s": float, "first_tokens": list, "ahead": int}
 ENTRY_FIELDS = {"queue_s": float, "prefill_s": float,
                 "prefill_wait_s": float, "ttft_s": float, "prompt": int,
                 "bucket": int, "cached": int}
@@ -631,15 +631,52 @@ def test_step_records_carry_the_loops_account(served):
 
 def test_step_records_tile_the_loops_time(served):
     """t0[k] - t0[k-1] = wall_s[k-1] + between_s[k] + idle_s[k]: no moment
-    of the loop thread belongs to no record."""
+    of the loop thread belongs to no record, also where the loop dispatches
+    a step before it reads the one in flight (most of this window)."""
     recs, _, _ = served
     assert len(recs) > 20
+    assert sum(r["ahead"] for r in recs) > len(recs) // 3
     assert [r["step"] for r in recs] == sorted(r["step"] for r in recs)
     for prev, cur in zip(recs, recs[1:]):
         assert cur["t0"] - prev["t0"] == pytest.approx(
             prev["wall_s"] + cur["between_s"] + cur["idle_s"], abs=2e-6)
     # The waits between the three rounds of requests are idle, not work.
     assert sum(r["idle_s"] for r in recs) > 0
+
+
+def test_ahead_is_one_on_steady_steps_and_zero_where_membership_changed():
+    """``ahead`` says the step went out before the one ahead of it was read:
+    so on every step but the first after an admission or after a token
+    budget's end, both of which the loop meets with nothing in flight.  The
+    record carries the number of the step it read, whatever was dispatched
+    since."""
+    import threading
+
+    steprec.drain_buffered()
+    eng = _tiny_engine()
+    try:
+        assert len(list(eng.submit([3, 5, 7], max_new_tokens=8))) == 8
+        alone = _engine_records(
+            eng, lambda rs: sum(r["evicted"] for r in rs) >= 1)
+        a = eng.submit([2, 4, 6, 8], max_new_tokens=24)
+        head = [next(a) for _ in range(6)]
+        b = threading.Thread(
+            target=lambda: list(eng.submit([9, 1], max_new_tokens=5)))
+        b.start()
+        b.join()
+        assert len(head + list(a)) == 24
+        mixed = _engine_records(
+            eng, lambda rs: sum(r["evicted"] for r in rs) >= 2)
+    finally:
+        eng.shutdown()
+    assert [r["ahead"] for r in alone] == [0] + [1] * 6
+    assert [r["step"] for r in alone] == list(range(alone[0]["step"],
+                                                    alone[0]["step"] + 7))
+    assert sum(r["admitted"] for r in mixed) == 2
+    for prev, cur in zip([{"evicted": 1}] + mixed, mixed):
+        changed = cur["admitted"] > 0 or prev["evicted"] > 0
+        assert cur["ahead"] == (0 if changed else 1), (prev, cur)
+    assert sum(r["ahead"] for r in mixed) >= len(mixed) - 4
 
 
 def test_phases_leave_only_the_gauges_of_the_step_wall(served):
@@ -691,8 +728,10 @@ def test_request_spans_and_entry_come_from_the_same_stamps(served):
 def test_a_device_trace_holds_every_phase_once_a_step(tmp_path):
     """The same phases as ``rt:engine/*`` host spans in a profiler capture,
     nested in an annotation bound onto the instance from outside (as the
-    benchmark binds ``bench:engine_step``): each step's interval holds one
-    dispatch, readback, emit and record, and each prefill its wait."""
+    benchmark binds ``bench:engine_step``): a turn of the loop holds at
+    most one dispatch and one readback, emit and record, the dispatch
+    first (the step ahead goes out before the one in flight is read), and
+    each prefill its wait."""
     import jax
     from jax.profiler import ProfileData
 
@@ -722,23 +761,30 @@ def test_a_device_trace_holds_every_phase_once_a_step(tmp_path):
     assert {n[len("rt:engine/"):] for n, _, _ in events
             if n.startswith("rt:")} >= RT_PHASES
     steps = sorted((s, e) for n, s, e in events if n == "test:step")
-    assert len(steps) >= 5  # one prefill step, then decode steps
 
     def inside(name, span):
         return [(s, e) for n, s, e in events
                 if n == "rt:engine/" + name and span[0] <= s and e <= span[1]]
 
+    # Five decode steps after the prefill's token, over six turns: the
+    # first dispatches and reads nothing, four dispatch the step ahead and
+    # then read the one in flight, the last (the budget ends at its step)
+    # dispatches nothing.
+    shapes = []
     for step in steps:
-        for name in ("dispatch", "readback", "emit", "record"):
-            assert len(inside(name, step)) == 1, (name, step)
-        assert len(inside("upload", step)) <= 1
-        order = [inside(n, step)[0] for n in ("dispatch", "readback", "emit",
-                                              "record")]
+        held = [(name, inside(name, step))
+                for name in ("dispatch", "readback", "emit", "record")]
+        assert all(len(spans) <= 1 for _, spans in held), (held, step)
+        order = [spans[0] for _, spans in held if spans]
         assert all(a[1] <= b[0] for a, b in zip(order, order[1:]))
+        assert len(inside("upload", step)) <= 1
+        shapes.append("".join(name[0] for name, spans in held if spans))
+    assert shapes == ["d"] + ["drer"] * 4 + ["rer"], shapes
     prefill, = [(s, e) for n, s, e in events if n == "rt:engine/prefill"]
     assert len(inside("prefill_wait", prefill)) == 1
     assert len(inside("prefill", steps[0])) == 1
-    # Admission's locked section lies between the steps, once before each.
+    assert len(inside("upload", steps[0])) == 1
+    # Admission's locked section lies between the turns, once before each.
     for before, step in zip(steps, steps[1:]):
         assert len(inside("admit", (before[1], step[0]))) == 1
 
@@ -811,3 +857,21 @@ def test_status_rows_show_host_share_and_queue_wait():
     # A record lost from the middle of the window shows as untiled time.
     engines[0]["records"] = [accounted[0], accounted[2]]
     assert _engine_rows(engines, [])[0]["loop%"] == "66.7"
+
+
+def test_status_rows_show_the_share_of_steps_dispatched_ahead():
+    """`ray_tpu status` / `top`: beside the host's share, the share of the
+    window's decode steps that went out before the step ahead of them was
+    read; '-' for records without the key."""
+    from ray_tpu.scripts import _engine_rows
+
+    def rec(ahead, occupancy=2):
+        return {"wall_s": 0.01, "stall_s": 0.0, "occupancy": occupancy,
+                "slots": 4, "ahead": ahead}
+
+    steps = [rec(0), rec(1), rec(1), rec(1), rec(0, occupancy=0)]
+    old = [{k: v for k, v in rec(0).items() if k != "ahead"}]
+    rows = _engine_rows(
+        [{"engine": "1.0", "records": steps, "latest": steps[-1]},
+         {"engine": "2.0", "records": old, "latest": old[-1]}], [])
+    assert [row["ahead%"] for row in rows] == ["75.0", "-"]

@@ -146,6 +146,154 @@ def test_admission_mid_stream_stalls_at_most_one_step(engine):
     assert a.steps[0] <= b.steps[0] <= b.steps[-1] < a.steps[-1]
 
 
+# --- One decode step in flight: the loop dispatches step n+1 before it reads
+# step n while membership stands still.  Whatever the loop overlaps, every
+# request's tokens are the full forward pass's.
+
+
+def _ref(engine, prompt, n):
+    from greedy_ref import greedy_tokens
+
+    return greedy_tokens(engine.model_config, engine.params, prompt, n)
+
+
+def _settle(engine, timeout_s=10.0):
+    """Wait until the loop is idle: nothing in a slot, nothing in flight."""
+    deadline = time.time() + timeout_s
+    while time.time() < deadline:
+        if engine._inflight is None and not any(engine.slots):
+            return
+        time.sleep(0.02)
+    raise AssertionError("the engine loop did not come to rest")
+
+
+def test_run_ahead_wastes_no_step_at_a_token_budgets_end(engine):
+    """A request that ends by ``max_tokens``: the host knows the count, so
+    no step is dispatched behind the last one (n tokens: one from the
+    prefill, n - 1 decode steps) and the tokens are the reference's."""
+    _settle(engine)
+    before, prompt = engine.step_count, [5, 7, 11, 13]
+    assert list(engine.submit(prompt, max_new_tokens=10)) \
+        == _ref(engine, prompt, 10)
+    _settle(engine)
+    assert engine.step_count - before == 9
+
+
+def test_stop_token_with_a_step_in_flight_drops_that_steps_token(engine):
+    """A stop the host cannot foresee: the step behind the stop token was
+    already dispatched, its token goes to nobody, and the request beside it
+    reads on undisturbed."""
+    _settle(engine)
+    prompt, other = [1, 3, 5, 7], [8, 6, 2, 12, 10]
+    want = _ref(engine, prompt, 12)
+    stop_at = 4
+    assert want[stop_at] not in want[:stop_at]
+    before = engine.step_count
+    got = list(engine.submit(prompt, max_new_tokens=12,
+                             stop_token=want[stop_at]))
+    assert got == want[:stop_at + 1]
+    _settle(engine)
+    # One token from the prefill, stop_at from decode steps, one step wasted.
+    assert engine.step_count - before == stop_at + 1
+    a = engine.submit(other, max_new_tokens=14)
+    b = engine.submit(prompt, max_new_tokens=12, stop_token=want[stop_at])
+    assert list(b) == want[:stop_at + 1]
+    assert list(a) == _ref(engine, other, 14)
+    engine.clear_prefix_cache()
+    assert engine.allocator.free_count == engine.allocator.total
+
+
+def test_cancel_with_a_step_in_flight_leaves_the_others_tokens(engine):
+    """A cancel frees its slot under the step in flight; the request beside
+    it and the one admitted into the freed slot read the reference's."""
+    _settle(engine)
+    keep, gone, late = [2, 4, 6, 8, 10], [9, 7, 5], [1, 3, 5, 7]
+    a = engine.submit(keep, max_new_tokens=20)
+    b = engine.submit(gone, max_new_tokens=30)
+    assert [next(b) for _ in range(3)] == _ref(engine, gone, 3)
+    b.cancel()
+    c = engine.submit(late, max_new_tokens=8)
+    assert list(c) == _ref(engine, late, 8)
+    assert list(a) == _ref(engine, keep, 20)
+    rest = list(b)  # what was emitted before the cancel took effect
+    assert len(rest) < 27 and rest == _ref(engine, gone, 30)[3:3 + len(rest)]
+    _settle(engine)
+    engine.clear_prefix_cache()
+    assert engine.allocator.free_count == engine.allocator.total
+
+
+def test_admission_while_running_ahead_reads_the_step_in_flight_first(engine):
+    """A request arriving while the loop runs ahead: the step in flight is
+    read before the newcomer's prefill is dispatched, and both streams are
+    the reference's."""
+    _settle(engine)
+    first, second = [4, 8, 12, 3], [11, 2]
+    a = engine.submit(first, max_new_tokens=24)
+    head = [next(a) for _ in range(5)]  # decoding, a step in flight
+    b = engine.submit(second, max_new_tokens=9)
+    assert list(b) == _ref(engine, second, 9)
+    assert head + list(a) == _ref(engine, first, 24)
+
+
+def test_page_boundary_crossed_on_a_step_dispatched_ahead(engine):
+    """Pages are reserved at admission, so a step dispatched ahead writes
+    over a page edge (8-token pages: positions 5..20 cross 8 and 16) with
+    nothing from the host."""
+    _settle(engine)
+    prompt = [6, 1, 9, 2, 7]
+    assert list(engine.submit(prompt, max_new_tokens=16)) \
+        == _ref(engine, prompt, 16)
+
+
+def test_sampled_tokens_equal_the_serial_loops_under_one_key():
+    """The PRNG key advances on the device once a dispatch, so the order of
+    keys is the order of dispatch: with temperature > 0 the loop that runs
+    ahead samples what a loop that never does (``_may_run_ahead`` held
+    false from the test) samples under the same seed."""
+    runs = []
+    for serial in (False, True):
+        eng = _tiny_engine()
+        if serial:
+            eng._may_run_ahead = lambda: False
+        try:
+            runs.append([list(eng.submit(p, max_new_tokens=12,
+                                         temperature=0.9))
+                         for p in ([5, 7, 11], [2, 3], [13, 1, 4, 9])])
+            assert eng.stats()["steps"] == 3 * 11
+        finally:
+            eng.shutdown()
+    assert runs[0] == runs[1]
+    assert len({tuple(r) for r in runs[0]}) == 3  # sampled, not constant
+
+
+def test_running_ahead_traces_no_new_program(engine):
+    """A step dispatched ahead takes the last step's device outputs where a
+    serial step may take uploaded mirrors: the same shapes and dtypes, so
+    the recompile sentinel's counts do not move."""
+    from ray_tpu.models.paged import trace_counts
+
+    _settle(engine)
+    before = trace_counts()
+    streams = [engine.submit([3 + i, 5, 8], max_new_tokens=6 + 3 * i)
+               for i in range(5)]
+    assert [len(list(s)) for s in streams] == [6, 9, 12, 15, 18]
+    assert trace_counts() == before
+
+
+def test_shutdown_with_a_step_in_flight_frees_every_page():
+    """``shutdown()`` reads or drops the step in flight before slots go:
+    the stream errors loudly, every page is back, no thread is left."""
+    eng = _tiny_engine()
+    s = eng.submit([1, 2, 3], max_new_tokens=32)
+    assert len([next(s) for _ in range(4)]) == 4  # running ahead by now
+    eng.shutdown()
+    assert not eng._thread.is_alive()
+    assert eng._inflight is None
+    assert eng.allocator.free_count == eng.allocator.total
+    with pytest.raises(RuntimeError, match="shut down"):
+        list(s)
+
+
 def test_page_free_list_balances_after_churn(engine):
     """Completion, cancellation, and shutdown-free paths all return pages:
     after N churn rounds the free list must be exactly full."""
