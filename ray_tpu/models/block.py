@@ -53,6 +53,28 @@ def attention(config, a: Params, h: jax.Array, attend: Attend,
     return attend(*project_qkv(config, a, h, lora)) @ a["wo"]
 
 
+def layer_window(config, i: int) -> int:
+    """The window of layer ``i``: it attends positions ``j`` with
+    ``0 <= i_pos - j < window``; 0 where the layer attends its sequence's
+    whole length (every layer of a configuration without a pattern)."""
+    layout = getattr(config, "window_layout", ())
+    return config.window if layout and layout[i] else 0
+
+
+def layer_rotary(config, i: int) -> bool:
+    """Whether layer ``i`` rotates q and k by position (every layer of a
+    configuration without a pattern does)."""
+    layout = getattr(config, "rope_layout", ())
+    return bool(layout[i]) if layout else True
+
+
+def layer_kind(config, i: int) -> Tuple[int, bool]:
+    """(``layer_window``, ``layer_rotary``) of layer ``i``: what a program
+    that traces a layer once a kind (``jax.checkpoint`` in the train step)
+    keys the trace on."""
+    return layer_window(config, i), layer_rotary(config, i)
+
+
 def is_routed(config) -> bool:
     """Whether the FFN of ``config``'s layers is routed (``moe.py``) or
     dense (``llama.py``): the one place under ``ray_tpu/`` that asks what
@@ -70,16 +92,22 @@ def init_and_apply(config):
 
 
 def ffn(config, layer: Params, x: jax.Array,
-        valid: Optional[jax.Array] = None):
+        valid: Optional[jax.Array] = None,
+        logits: Optional[jax.Array] = None):
     """The second half of the block, x + FFN(norm(x)), dense or routed (a
     trace-time branch, so a dense model compiles to the program it always
     did).  ``valid`` marks the rows that hold a real token; only a routed
-    FFN looks at it.  Returns (x, the routed layer's load-balancing loss,
-    its per-expert token counts [E]); the last two are None where the FFN
-    is dense."""
+    FFN looks at it.  ``logits`` are the router's where ``decoder_layer``
+    took them before attention.  Returns (x, the routed layer's
+    load-balancing loss, its per-expert token counts [E]); the last two are
+    None where the FFN is dense."""
     if is_routed(config):
         h = rms_norm(x, layer["moe_norm"], config.norm_eps)
-        out, aux, counts = moe._moe_ffn(config, layer["moe"], h, valid)
+        # Only a layer that took them early passes ``logits``: the tests'
+        # stand-ins for ``_moe_ffn`` keep its older signature.
+        early = {} if logits is None else {"logits": logits}
+        out, aux, counts = moe._moe_ffn(config, layer["moe"], h, valid,
+                                        **early)
         return x + out, aux, counts
     h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
     return x + llama._mlp(layer, h), None, None
@@ -91,8 +119,11 @@ def decoder_layer(config, layer: Params, x: jax.Array, attend: Attend, *,
     """One pre-norm decoder layer on x [..., d]; returns what ``ffn``
     returns."""
     h = rms_norm(x, layer["attn_norm"], config.norm_eps)
+    logits = None
+    if getattr(config, "router_before_attn", False):
+        logits = moe.router_logits(layer["moe"], h)
     x = x + attention(config, layer["attn"], h, attend, lora)
-    return ffn(config, layer, x, valid)
+    return ffn(config, layer, x, valid, logits)
 
 
 def decoder_stack(config, params: Params, tokens: jax.Array,

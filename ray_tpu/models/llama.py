@@ -160,7 +160,8 @@ def llama_init(config: LlamaConfig, key: jax.Array) -> Params:
 
 def qk_norm_init(config) -> Params:
     """The two QK-norm weights of one layer's ``attn`` (see ``_qk_norm``)."""
-    return {"q_norm": jnp.ones((config.d_model,), config.dtype),
+    return {"q_norm": jnp.ones((config.n_heads * config.head_dim,),
+                               config.dtype),
             "k_norm": jnp.ones((config.n_kv_heads * config.head_dim,),
                                config.dtype)}
 
@@ -224,13 +225,38 @@ def _flash_per_shard(config, q, k, v):
                          check_vma=False)(q, k, v)
 
 
-def _attend(config, cos, sin, q, k, v):
+def _attend_window(q, k, v, window: int):
+    """Plain masked attention of q [B, H, S, D] over k, v [B, H_kv, S, D]:
+    position i sees j with ``0 <= i - j < window``.  What a window layer's
+    full forward computes; there is no window flash kernel, so no cell
+    trains such a layer on a chip (ROADMAP)."""
+    B, H, S, D = q.shape
+    g = k.shape[1]
+    qg = q.reshape(B, g, H // g, S, D)
+    scores = jnp.einsum("bgrqd,bgkd->bgrqk", qg, k,
+                        preferred_element_type=jnp.float32) * (D ** -0.5)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    scores = jnp.where((j <= i) & (i - j < window), scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bgrqk,bgkd->bgrqd", probs, v).reshape(B, H, S, D)
+
+
+def _attend(config, cos, sin, q, k, v, kind=(0, True)):
     """The training programs' ``attend`` (see ``block``): causal
     self-attention of q [B, S, H, D] over k, v [B, S, H_kv, D] with RoPE,
     through the flash kernel or, sequence-sharded, the ring.  Returns
-    [B, S, H*D]."""
+    [B, S, H*D].  ``kind`` is the layer's ``block.layer_kind``: its window
+    (0: none) and whether it has rotary, for a configuration with a layer
+    pattern."""
     B, S = q.shape[:2]
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+    window, rotary = kind
+    if window or not rotary:
+        if rotary:
+            q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
+        out = _attend_window(q, k, v, window) if window \
+            else _flash_per_shard(config, q, k, v)
+        return out.transpose(0, 2, 1, 3).reshape(B, S, -1)
     # Ring attention engages only when tracing inside shard_map over `sp`
     # (local-chunk view).  Under plain pjit the tensors are the global view:
     # positions start at 0 and XLA partitions full attention itself.
@@ -283,14 +309,16 @@ def _mlp(layer, x):
     return (jax.nn.silu(x @ m["w1"]) * (x @ m["w3"])) @ m["w2"]
 
 
-def _layer(config, x, layer, cos, sin, lora_layer=None):
+def _layer(config, x, layer, cos, sin, lora_layer=None, kind=(0, True)):
     """One decoder layer of a training program, dense or routed, with its
-    arrays as arguments (what ``jax.checkpoint`` wraps): (x, the routed
-    FFN's aux loss or None)."""
+    arrays as arguments (what ``jax.checkpoint`` wraps; ``kind``, the
+    layer's ``block.layer_kind``, is static, so layers of one kind and
+    shape share a trace): (x, the routed FFN's aux loss or None)."""
     from . import block
 
     x, aux, _ = block.decoder_layer(
-        config, layer, x, functools.partial(_attend, config, cos, sin),
+        config, layer, x,
+        functools.partial(_attend, config, cos, sin, kind=kind),
         lora=_lora(lora_layer))
     return x, aux
 
@@ -346,13 +374,14 @@ def hidden_and_aux(config, params: Params, tokens: jax.Array,
                 (cps.checkpoint_dots_with_no_batch_dims, True),
         }[_option(config, "remat_policy")]
         layer_fn = jax.checkpoint(
-            _layer, static_argnums=(0,), policy=policy,
+            _layer, static_argnums=(0, 6), policy=policy,
             prevent_cse=prevent_cse,
         )
 
     def run(i, layer, x):
         ll = lora_params["layers"][i] if lora_params is not None else None
-        return (*layer_fn(config, x, layer, cos, sin, ll), None)
+        return (*layer_fn(config, x, layer, cos, sin, ll,
+                          block.layer_kind(config, i)), None)
 
     hidden, auxes, _ = block.decoder_stack(config, params, tokens, run)
     return hidden, auxes
@@ -415,7 +444,8 @@ def lora_init(config: LlamaConfig, key: jax.Array, rank: int = 16,
         layers.append({
             "wq_lora_a": (jax.random.normal(k1, (d, rank), jnp.float32)
                           * (d ** -0.5)).astype(config.dtype),
-            "wq_lora_b": jnp.zeros((rank, d), config.dtype),
+            "wq_lora_b": jnp.zeros(
+                (rank, config.n_heads * config.head_dim), config.dtype),
             "wv_lora_a": (jax.random.normal(k2, (d, rank), jnp.float32)
                           * (d ** -0.5)).astype(config.dtype),
             "wv_lora_b": jnp.zeros((rank, kv_out), config.dtype),

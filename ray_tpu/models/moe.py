@@ -1,5 +1,5 @@
-"""Mixture-of-Experts decoder (Mixtral, OLMoE), TPU-first with expert
-parallelism.
+"""Mixture-of-Experts decoder (Mixtral, OLMoE, SmallThinker), TPU-first
+with expert parallelism.
 
 The reference framework has no MoE/EP feature (SURVEY §2.4: expert parallel
 "absent as a framework feature") — this is a net-new, first-class TPU
@@ -47,7 +47,18 @@ class MoEConfig:
     """Llama attention + a routed FFN in every layer.  The defaults are
     Mixtral's; OLMoE keeps its top-k probabilities as the softmax gave them
     (``norm_topk_prob=False``) and normalises q and k (``qk_norm=True``).
-    ``d_ff`` is the width of ONE expert."""
+    ``d_ff`` is the width of ONE expert.
+
+    SmallThinker's fields: ``head_dim`` where the heads are not
+    ``d_model // n_heads`` wide (0: they are); a layer pattern, one entry a
+    layer, read at trace time through ``block.layer_window`` /
+    ``block.layer_rotary`` (``window_layout[i]`` true: layer ``i`` attends
+    only the last ``window`` positions, ``i - j < window``;
+    ``rope_layout[i]`` false: it has no positional term; empty tuples: no
+    window anywhere, rotary everywhere); ``expert_act`` (the gate's
+    non-linearity: ``silu``, or ``relu`` for a gated ReLU); and
+    ``router_before_attn`` (the router reads the layer's normalised INPUT,
+    the activations attention reads, not the FFN's)."""
 
     vocab_size: int = 32000
     d_model: int = 4096
@@ -65,22 +76,40 @@ class MoEConfig:
     aux_loss_coeff: float = 0.01
     dtype: Any = jnp.bfloat16
     remat: bool = True
+    head_dim: int = 0             # 0 -> d_model // n_heads
+    window: int = 0
+    window_layout: Tuple[int, ...] = ()
+    rope_layout: Tuple[int, ...] = ()
+    expert_act: str = "silu"
+    router_before_attn: bool = False
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
+        for name in ("window_layout", "rope_layout"):
+            layout = tuple(int(x) for x in getattr(self, name))
+            if layout and len(layout) != self.n_layers:
+                raise ValueError(f"{name} has {len(layout)} entries for "
+                                 f"{self.n_layers} layers")
+            object.__setattr__(self, name, layout)
+        if any(self.window_layout) and self.window <= 0:
+            raise ValueError("window_layout names window layers and window "
+                             "is not positive")
+        if self.expert_act not in ("silu", "relu"):
+            raise ValueError(f"expert_act {self.expert_act!r}")
 
     def param_count(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
-        kv = self.n_kv_heads * self.head_dim
+        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
         per_layer = (
-            d * d + 2 * d * kv + d * d          # attention
+            2 * d * q + 2 * d * kv               # attention
             + d * self.n_experts                 # router
             + self.n_experts * 3 * d * f         # experts
             + 2 * d
         )
         if self.qk_norm:
-            per_layer += d + kv
+            per_layer += q + kv
         return v * d + self.n_layers * per_layer + d + d * v
 
     def as_llama(self) -> LlamaConfig:
@@ -111,7 +140,7 @@ class MoEConfig:
 def moe_init(config: MoEConfig, key: jax.Array) -> Params:
     d, f, E = config.d_model, config.d_ff, config.n_experts
     hd = config.head_dim
-    kv_out = config.n_kv_heads * hd
+    q_out, kv_out = config.n_heads * hd, config.n_kv_heads * hd
     std = d ** -0.5
     keys = jax.random.split(key, 2 + config.n_layers)
 
@@ -131,10 +160,10 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
         params["layers"].append({
             "attn_norm": jnp.ones((d,), config.dtype),
             "attn": {
-                "wq": dense(ks[0], (d, d), std),
+                "wq": dense(ks[0], (d, q_out), std),
                 "wk": dense(ks[1], (d, kv_out), std),
                 "wv": dense(ks[2], (d, kv_out), std),
-                "wo": dense(ks[3], (d, d), std),
+                "wo": dense(ks[3], (q_out, d), q_out ** -0.5),
             },
             "moe_norm": jnp.ones((d,), config.dtype),
             "moe": {
@@ -164,16 +193,24 @@ def moe_sharding_rules() -> ShardingRules:
     ])
 
 
-def _route(config: MoEConfig, moe: Params, xf: jax.Array
+def router_logits(moe: Params, xf: jax.Array) -> jax.Array:
+    """The router's logits [..., E] on tokens xf [..., d], in float32 (a
+    float32 product too: the TPU's default would round the router's weights
+    to bfloat16, and which experts are the top k turns on differences that
+    small)."""
+    return jnp.matmul(xf.astype(jnp.float32), moe["router"],
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def _route(config: MoEConfig, moe: Params, xf: jax.Array,
+           logits: Optional[jax.Array] = None
            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """The router on tokens xf [G, d], in float32 (a float32 product too:
-    the TPU's default would round the router's weights to bfloat16, and
-    which experts are the top k turns on differences that small): the
-    softmax over all experts [G, E], and each token's top-k probabilities
-    and experts [G, k] (renormalised to sum to 1 only where the
-    architecture does)."""
-    logits = jnp.matmul(xf.astype(jnp.float32), moe["router"],
-                        precision=jax.lax.Precision.HIGHEST)
+    """The router on tokens xf [G, d] (or on ``logits`` [G, E] taken
+    earlier in the layer, ``router_before_attn``): the softmax over all
+    experts [G, E], and each token's top-k probabilities and experts [G, k]
+    (renormalised to sum to 1 only where the architecture does)."""
+    if logits is None:
+        logits = router_logits(moe, xf)
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, config.top_k)
     if config.norm_topk_prob:
@@ -182,7 +219,8 @@ def _route(config: MoEConfig, moe: Params, xf: jax.Array
 
 
 def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
-             valid: Optional[jax.Array] = None
+             valid: Optional[jax.Array] = None,
+             logits: Optional[jax.Array] = None
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k expert FFN over x [..., d]: every token reaches all ``top_k``
     of its experts at any load.  Returns (out, aux_loss, counts), counts
@@ -191,13 +229,16 @@ def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
     ``valid`` [...] bool marks the real tokens of a statically shaped batch
     (the serving programs' padded prompt rows and empty slots): the others
     are sorted behind every group, so no expert multiplies them, they are
-    in no count, and their output is zero."""
+    in no count, and their output is zero.  ``logits`` [..., E] are the
+    router's where the layer took them before attention."""
     lead, d = x.shape[:-1], x.shape[-1]
     E, k = config.n_experts, config.top_k
     xf = x.reshape(-1, d)
     G = xf.shape[0]
     with jax.named_scope("moe_ffn"):
-        probs, top_p, top_e = _route(config, moe, xf)
+        probs, top_p, top_e = _route(
+            config, moe, xf,
+            None if logits is None else logits.reshape(G, E))
         if valid is not None:
             top_e = jnp.where(valid.reshape(G, 1), top_e, E)
         # Sort the G*k pairs by expert (stable: token order inside a
@@ -213,7 +254,8 @@ def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
             return jax.lax.ragged_dot(rows, w, counts,
                                       preferred_element_type=jnp.float32)
 
-        h = (jax.nn.silu(grouped(xs, moe["w1"])) * grouped(xs, moe["w3"])
+        act = jax.nn.silu if config.expert_act == "silu" else jax.nn.relu
+        h = (act(grouped(xs, moe["w1"])) * grouped(xs, moe["w3"])
              ).astype(config.dtype)
         ys = grouped(h, moe["w2"])[rank].reshape(G, k, d)      # float32
         if valid is not None:  # rows behind the last group are not written

@@ -4,8 +4,8 @@ Role-equivalent to vLLM-style PagedAttention as surfaced by Ray Serve's LLM
 stack (reference: the Ray Serve LLM APIs run a continuous-batching engine
 whose KV cache is a pool of fixed-size pages).  TPU-first shape:
 
-- ONE preallocated KV pool per replica: ``[L, P+1, page, H_kv, D]`` per
-  k/v — a token's ``[H_kv, D]`` row is contiguous, which is how it is
+- ONE preallocated KV pool per replica and KIND of layer (below):
+  ``[L, P+1, page, H_kv, D]`` per k/v — a token's ``[H_kv, D]`` row is contiguous, which is how it is
   written (one row per token) and how it is read (a table's pages
   reshape to ``[MAXP*page, H_kv, D]`` with no transpose), so the donated
   pools keep one layout from argument to result and no program copies
@@ -26,6 +26,18 @@ whose KV cache is a pool of fixed-size pages).  TPU-first shape:
   KV head and contracted against the gathered view in the pool's dtype
   with float32 accumulation (no upcast, transposed or GQA-repeated K/V
   is ever materialised).
+
+Two kinds of cache live side by side where the configuration has a layer
+pattern (``block.layer_window``).  A layer that attends its sequence's
+whole length keeps every page of it: pools ``k`` / ``v``, the page table
+``[MAXP]``.  A WINDOW layer keeps a ring: pools ``kw`` / ``vw`` over the
+window layers only, and a ring table of ``ring_entries(...)`` pages
+(window + one prefill chunk) in which position ``p`` lives at entry
+``(p // page) % entries``.  Nothing is freed while a sequence runs, every
+program keeps static shapes, and which absolute position a gathered ring
+slot holds is arithmetic on the last position written
+(``_ring_positions``).  A configuration without a pattern has the one
+kind, the one pool pair and the programs it always had.
 
 Compile counts are observable via ``trace_count()`` — the jitted bodies
 bump a counter when TRACED (python executes only at trace time), which is
@@ -77,14 +89,48 @@ def _bump(name: str, **arrays: Any) -> None:
     _jitguard.bump(name, _jitguard.signature_of(arrays) if arrays else None)
 
 
+def kv_layers(config):
+    """(the layers that keep a sequence's whole length, the window layers):
+    each a list of layer indices, in order.  A layer's place in its list is
+    its index in that kind's pools."""
+    layers = range(config.n_layers)
+    window = [i for i in layers if block.layer_window(config, i)]
+    return [i for i in layers if i not in window], window
+
+
+def _kv_slot(config, i: int):
+    """Where layer ``i`` keeps its K/V: (``""`` or ``"w"``, the suffix of
+    its kind's pool names, and its index in those pools)."""
+    whole, window = kv_layers(config)
+    return ("w", window.index(i)) if i in window else ("", whole.index(i))
+
+
+def ring_entries(config, page_size: int, chunk: int) -> int:
+    """Pages in a window layer's ring: the window, plus what one prefill
+    chunk of ``chunk`` tokens (page-aligned) writes before it attends; 0
+    for a configuration without window layers."""
+    if not kv_layers(config)[1]:
+        return 0
+    return -(-config.window // page_size) + -(-chunk // page_size)
+
+
 def init_paged_pools(config: LlamaConfig, num_pages: int,
-                     page_size: int) -> PagedPools:
-    """One pool pair for the whole replica; index ``num_pages`` is the
-    scratch page (writes routed there are never read)."""
-    shape = (config.n_layers, num_pages + 1, page_size,
-             config.n_kv_heads, config.head_dim)
-    return {"k": jnp.zeros(shape, config.dtype),
-            "v": jnp.zeros(shape, config.dtype)}
+                     page_size: int, window_pages: int = 0) -> PagedPools:
+    """One pool pair a kind of layer for the whole replica; the last index
+    (``num_pages``, ``window_pages``) of each is its scratch page (writes
+    routed there are never read)."""
+    whole, window = kv_layers(config)
+
+    def pair(suffix, n_layers, pages):
+        shape = (n_layers, pages + 1, page_size,
+                 config.n_kv_heads, config.head_dim)
+        return {"k" + suffix: jnp.zeros(shape, config.dtype),
+                "v" + suffix: jnp.zeros(shape, config.dtype)}
+
+    pools = pair("", len(whole), num_pages)
+    if window:
+        pools.update(pair("w", len(window), window_pages))
+    return pools
 
 
 def _page_size(pools: PagedPools) -> int:
@@ -100,6 +146,24 @@ def _write_rows(pool: jax.Array, layer: int, page_idx: jax.Array,
     return pool.at[layer, page_idx, off].set(rows.astype(pool.dtype))
 
 
+#: The most float32 scores ``_attend_pages`` forms at once.  A prefill
+#: chunk's whole matrix on a whole-length layer (28 heads x 2048 queries x
+#: 15360 keys) is 3.5 GB; over this the queries are walked in blocks.
+SCORE_BLOCK_BYTES = 512 * 2 ** 20
+
+
+def _ring_positions(last: jax.Array, entries: int, ps: int) -> jax.Array:
+    """The absolute position each slot of a gathered ring holds once
+    position ``last`` [...] is written: [..., entries * ps].  Entry ``e``
+    holds the newest page at or before ``last``'s whose number is ``e``
+    modulo ``entries``; a negative result was never written, and one past
+    ``last`` is what an older page left there."""
+    j = jnp.arange(entries * ps)
+    last_page = last[..., None] // ps
+    page = last_page - (last_page - j // ps) % entries
+    return page * ps + j % ps
+
+
 def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
                   v_pool: jax.Array, layer: int, tables: jax.Array,
                   visible: jax.Array) -> jax.Array:
@@ -112,30 +176,58 @@ def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
     contracted against it directly.  K and V stay in the pool's dtype and
     the products accumulate in float32: a bf16 x bf16 product is exact in
     float32, so this is what upcasting the gathered K first computed,
-    without the float32 copy."""
+    without the float32 copy.  Where the whole score matrix would pass
+    ``SCORE_BLOCK_BYTES`` (a prefill chunk over a long table) the queries
+    are walked in equal blocks, one at a time (``lax.map``)."""
     B, Q = q.shape[:2]
     n_rep = config.n_heads // config.n_kv_heads
     k_seq = k_pool[layer, tables].reshape(
         B, -1, config.n_kv_heads, config.head_dim)
     v_seq = v_pool[layer, tables].reshape(k_seq.shape)
     qg = q.reshape(B, Q, config.n_kv_heads, n_rep, config.head_dim)
-    scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_seq,
-                        preferred_element_type=jnp.float32) \
-        * (config.head_dim ** -0.5)
-    scores = jnp.where(visible[:, None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v_seq.dtype)
-    out = jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_seq)
-    return out.reshape(B, Q, -1)
+
+    def attend(qg, visible):
+        scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_seq,
+                            preferred_element_type=jnp.float32) \
+            * (config.head_dim ** -0.5)
+        scores = jnp.where(visible[:, None, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v_seq.dtype)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_seq)
+
+    blocks = 1
+    while (B * config.n_heads * Q * k_seq.shape[1] * 4 // blocks
+           > SCORE_BLOCK_BYTES and Q % (2 * blocks) == 0):
+        blocks *= 2
+    if blocks == 1:
+        return attend(qg, visible).reshape(B, Q, -1)
+
+    def split(x):  # [B, Q, ...] -> [blocks, B, Q / blocks, ...]
+        return jnp.moveaxis(
+            x.reshape(B, blocks, Q // blocks, *x.shape[2:]), 1, 0)
+
+    out = jax.lax.map(lambda a: attend(*a), (split(qg), split(visible)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, Q, -1)
 
 
 #: What ``_with_routing`` appends, in its order: the step record's keys.
 ROUTING_KEYS = ("experts_hit", "expert_pairs", "expert_load_max")
+#: What the decode step of a configuration with window layers appends
+#: behind them (``_kv_rows``).
+KV_KEYS = ("kv_rows_read", "kv_rows_live")
+
+
+def counter_keys(config) -> tuple:
+    """The names of the int32 counters the decode program of ``config``
+    appends to the tokens it returns, in their order (a prefill appends
+    the ``ROUTING_KEYS`` among them)."""
+    return (ROUTING_KEYS if block.is_routed(config) else ()) \
+        + (KV_KEYS if kv_layers(config)[1] else ())
 
 
 def routing_width(config) -> int:
-    """How many int32 counters a program of ``config`` appends to the
-    tokens it returns: ``len(ROUTING_KEYS)`` where the FFN is routed."""
-    return len(ROUTING_KEYS) if block.is_routed(config) else 0
+    """How many int32 counters the decode program of ``config`` appends to
+    the tokens it returns: ``len(counter_keys(config))``."""
+    return len(counter_keys(config))
 
 
 def _with_routing(toks: jax.Array, counts: List[Optional[jax.Array]]
@@ -152,6 +244,29 @@ def _with_routing(toks: jax.Array, counts: List[Optional[jax.Array]]
         [jnp.sum(c > 0, dtype=jnp.int32), jnp.sum(c), jnp.max(c)])])
 
 
+def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
+                  ring_tables: Optional[jax.Array], seq_lens: jax.Array,
+                  active: jax.Array, ps: int) -> jax.Array:
+    """``toks`` followed (where ``config`` has window layers) by the decode
+    step's ``KV_KEYS``, summed over layers and slots: the rows of K its
+    gathers brought in (every slot's whole table, live or not: that is
+    what the program reads), and the rows a query could see
+    (``len + 1`` on a whole-length layer, at most the window on a window
+    layer, nothing in an empty slot)."""
+    whole, window = kv_layers(config)
+    if not window:
+        return toks
+    B = seq_lens.shape[0]
+    read = B * ps * (len(whole) * page_tables.shape[1]
+                     + len(window) * ring_tables.shape[1])
+    rows = jnp.where(active, seq_lens + 1, 0)
+    live = len(whole) * jnp.sum(rows) \
+        + len(window) * jnp.sum(jnp.minimum(rows, config.window))
+    return jnp.concatenate(
+        [toks, jnp.stack([jnp.asarray(read, jnp.int32),
+                          live.astype(jnp.int32)])])
+
+
 def _first_token(tok: jax.Array, counts) -> jax.Array:
     """A prefill's result: the scalar token, or [token, *ROUTING_KEYS]
     where the layers were routed."""
@@ -160,7 +275,7 @@ def _first_token(tok: jax.Array, counts) -> jax.Array:
 
 # ------------------------------------------------------- adapter pool
 
-#: {"qa": [A+1, L, d, r], "qb": [A+1, L, r, d], "va": [A+1, L, d, r],
+#: {"qa": [A+1, L, d, r], "qb": [A+1, L, r, q_out], "va": [A+1, L, d, r],
 #:  "vb": [A+1, L, r, kv_out], "scale": [A+1]} — slot A is the permanent
 #: zero adapter (scale 0), so base-model slots are just data too.
 AdapterArrays = Dict[str, jax.Array]
@@ -173,11 +288,12 @@ def init_adapter_pool(config: LlamaConfig, max_adapters: int,
     decode/prefill signature, so loading, evicting, or remixing adapters
     never recompiles — only the per-slot ``adapter_ids`` data changes."""
     d = config.d_model
+    q_out = config.n_heads * config.head_dim
     kv_out = config.n_kv_heads * config.head_dim
     A, L = max_adapters + 1, config.n_layers
     return {
         "qa": jnp.zeros((A, L, d, rank), config.dtype),
-        "qb": jnp.zeros((A, L, rank, d), config.dtype),
+        "qb": jnp.zeros((A, L, rank, q_out), config.dtype),
         "va": jnp.zeros((A, L, d, rank), config.dtype),
         "vb": jnp.zeros((A, L, rank, kv_out), config.dtype),
         "scale": jnp.zeros((A,), jnp.float32),
@@ -344,17 +460,39 @@ def _stack(config, params: Params, tokens: jax.Array, attend, lora,
     return hidden, counts
 
 
-def _write_kv(pools: PagedPools, layer: int, page_idx: jax.Array,
-              off: jax.Array, k: jax.Array, v: jax.Array) -> None:
-    """``_write_rows`` of a layer's K and V, the dict's pools replaced."""
-    pools["k"] = _write_rows(pools["k"], layer, page_idx, off, k)
-    pools["v"] = _write_rows(pools["v"], layer, page_idx, off, v)
+def _write_kv(pools: PagedPools, kind: str, layer: int,
+              page_idx: jax.Array, off: jax.Array, k: jax.Array,
+              v: jax.Array) -> None:
+    """``_write_rows`` of a layer's K and V into its kind's pools (``kind``
+    and ``layer`` as ``_kv_slot`` gives them), the dict's pools
+    replaced."""
+    for name, rows in (("k" + kind, k), ("v" + kind, v)):
+        pools[name] = _write_rows(pools[name], layer, page_idx, off, rows)
+
+
+def _paged_attend(config, pools: PagedPools, i: int, q, k, v, *, whole,
+                  ring):
+    """What the decode step and the suffix prefill do in layer ``i`` with
+    q [B, Q, H, D] and the new rows' k, v [N, H_kv, D] (rotated by the
+    caller where the layer has rotary): the rows written into the layer's
+    kind of pool, and attention over that kind's tables.  ``whole`` and
+    ``ring`` are each ``(page_idx [N], off [N], tables [B, T], visible
+    [B, Q, T*page])``: the write indices and the read side of the
+    whole-length table and of the window layers' ring (None for a
+    configuration that has none)."""
+    kind, slot = _kv_slot(config, i)
+    page_idx, off, tables, visible = ring if kind else whole
+    _write_kv(pools, kind, slot, page_idx, off, k, v)
+    with jax.named_scope("attn_window" if kind else "attn_global"):
+        return _attend_pages(config, q, pools["k" + kind],
+                             pools["v" + kind], slot, tables, visible)
 
 
 def decode_logits(config, params: Params, pools: PagedPools,
                   adapters: AdapterArrays, tokens: jax.Array,
                   page_tables: jax.Array, seq_lens: jax.Array,
-                  active: jax.Array, adapter_ids: jax.Array):
+                  active: jax.Array, adapter_ids: jax.Array,
+                  ring_tables: Optional[jax.Array] = None):
     """``paged_decode_step`` up to its sampling: (logits [B, V] float32,
     pools, per-layer expert counts)."""
     B, maxp = page_tables.shape
@@ -367,13 +505,22 @@ def decode_logits(config, params: Params, pools: PagedPools,
     # The length mask removes scratch/unwritten positions: [B, 1, MAXP*ps].
     visible = jnp.arange(maxp * ps)[None, None, :] \
         <= seq_lens[:, None, None]
+    ring = None
+    if ring_tables is not None:
+        entries = ring_tables.shape[1]
+        held = _ring_positions(seq_lens, entries, ps)  # [B, entries*ps]
+        age = seq_lens[:, None] - held
+        ring = (ring_tables[jnp.arange(B), (seq_lens // ps) % entries], off,
+                ring_tables,
+                ((held >= 0) & (age >= 0) & (age < config.window))[:, None])
 
     def attend(i, q, k, v):  # one row a slot: [B, H, D]
-        q = _rotary_single(q, cos, sin, seq_lens)
-        k = _rotary_single(k, cos, sin, seq_lens)
-        _write_kv(pools, i, page_idx, off, k, v)
-        return _attend_pages(config, q[:, None], pools["k"], pools["v"], i,
-                             page_tables, visible)[:, 0]
+        if block.layer_rotary(config, i):
+            q = _rotary_single(q, cos, sin, seq_lens)
+            k = _rotary_single(k, cos, sin, seq_lens)
+        return _paged_attend(
+            config, pools, i, q[:, None], k, v,
+            whole=(page_idx, off, page_tables, visible), ring=ring)[:, 0]
 
     x, counts = _stack(config, params, tokens[:B], attend,
                        _adapter_lora(adapters, adapter_ids), active)
@@ -387,7 +534,8 @@ def paged_decode_step(config: LlamaConfig, params: Params,
                       tokens: jax.Array, page_tables: jax.Array,
                       seq_lens: jax.Array, active: jax.Array,
                       temps: jax.Array, adapter_ids: jax.Array,
-                      key: jax.Array):
+                      key: jax.Array,
+                      ring_tables: Optional[jax.Array] = None):
     """One decode step for every batch slot at once.
 
     tokens int32, the last sampled token of each slot in its first B
@@ -404,30 +552,38 @@ def paged_decode_step(config: LlamaConfig, params: Params,
     Pools are donated and keep their layout through the program
     (``tests/test_chip_compile.py`` holds the compiled step to it), so
     steady-state decode never copies the cache: a layer writes B rows in
-    place and reads one gather of the page tables.
+    place and reads one gather of the page tables.  ring_tables
+    [B, entries] int32 are the window layers' rings (None for a
+    configuration without them): those layers write and gather through
+    them, that wide and no wider; the branch is taken layer by layer at
+    trace time, so this stays the one decode program.
 
     The PRNG key and the slot lengths advance ON DEVICE (returned
     alongside the tokens), so the serving loop's only per-step host
     traffic is downloading the [B] sampled tokens — host-side key
     folding measurably dominates step time otherwise.  Returns
-    (next_tokens [B], new_seq_lens [B], new_key, pools); a model whose FFN
-    is routed appends its ``ROUTING_KEYS`` counters to next_tokens
-    (``_with_routing``)."""
+    (next_tokens [B], new_seq_lens [B], new_key, pools); behind
+    next_tokens come the configuration's ``counter_keys``: a routed FFN's
+    ``ROUTING_KEYS`` (``_with_routing``), window layers' ``KV_KEYS``
+    (``_with_kv_rows``)."""
     _bump("decode", tokens=tokens, page_tables=page_tables,
           seq_lens=seq_lens, temps=temps, adapter_ids=adapter_ids, key=key)
     logits, pools, counts = decode_logits(
         config, params, pools, adapters, tokens, page_tables, seq_lens,
-        active, adapter_ids)
+        active, adapter_ids, ring_tables)
     key, sub = jax.random.split(key)
     toks = _sample_tokens(logits, temps, sub)
     new_lens = jnp.where(active, seq_lens + 1, 0)
-    return _with_routing(toks, counts), new_lens, key, pools
+    out = _with_kv_rows(config, _with_routing(toks, counts), page_tables,
+                        ring_tables, seq_lens, active, _page_size(pools))
+    return out, new_lens, key, pools
 
 
 def prefill_logits(config, params: Params, pools: PagedPools,
                    adapters: AdapterArrays, tokens: jax.Array,
                    length: jax.Array, page_table: jax.Array,
-                   adapter_id: jax.Array):
+                   adapter_id: jax.Array,
+                   ring_table: Optional[jax.Array] = None):
     """``paged_prefill`` up to its sampling: (logits [1, V] float32 after
     the last real position, pools, per-layer expert counts)."""
     _, s_pad = tokens.shape
@@ -439,21 +595,31 @@ def prefill_logits(config, params: Params, pools: PagedPools,
     page_idx = page_table[positions // ps]  # [S_pad]
     off = positions % ps
     causal = positions[None, :] <= positions[:, None]  # [S_pad, S_pad]
+    if ring_table is not None:  # a bucket never laps the ring
+        ring_idx = ring_table[(positions // ps) % ring_table.shape[0]]
+        in_window = causal & (positions[:, None] - positions[None, :]
+                              < config.window)
 
     def attend(i, q, k, v):  # [S_pad, H, D]: attention among the rows
-        q = apply_rotary(q.transpose(1, 0, 2)[None], cos, sin)[0]
-        k = apply_rotary(k.transpose(1, 0, 2)[None], cos, sin)[0]
-        _write_kv(pools, i, page_idx, off, k.transpose(1, 0, 2), v)
+        q, k = q.transpose(1, 0, 2), k.transpose(1, 0, 2)
+        if block.layer_rotary(config, i):
+            q = apply_rotary(q[None], cos, sin)[0]
+            k = apply_rotary(k[None], cos, sin)[0]
+        kind, slot = _kv_slot(config, i)
+        _write_kv(pools, kind, slot, ring_idx if kind else page_idx, off,
+                  k.transpose(1, 0, 2), v)
         kr, vr = k, v.transpose(1, 0, 2)  # [H_kv, S_pad, D]
         if n_rep > 1:
             kr = jnp.repeat(kr, n_rep, axis=0)
             vr = jnp.repeat(vr, n_rep, axis=0)
-        scores = jnp.einsum("hqd,hkd->hqk", q.astype(jnp.float32),
-                            kr.astype(jnp.float32)) \
-            * (config.head_dim ** -0.5)
-        scores = jnp.where(causal[None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(vr.dtype)
-        out = jnp.einsum("hqk,hkd->hqd", probs, vr)
+        with jax.named_scope("attn_window" if kind else "attn_global"):
+            scores = jnp.einsum("hqd,hkd->hqk", q.astype(jnp.float32),
+                                kr.astype(jnp.float32)) \
+                * (config.head_dim ** -0.5)
+            scores = jnp.where((in_window if kind else causal)[None],
+                               scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(vr.dtype)
+            out = jnp.einsum("hqk,hkd->hqd", probs, vr)
         return out.transpose(1, 0, 2).reshape(s_pad, -1)
 
     x, counts = _stack(config, params, tokens[0], attend,
@@ -468,14 +634,16 @@ def prefill_logits(config, params: Params, pools: PagedPools,
 def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
                   adapters: AdapterArrays, tokens: jax.Array,
                   length: jax.Array, page_table: jax.Array,
-                  adapter_id: jax.Array, temp: jax.Array, key: jax.Array):
+                  adapter_id: jax.Array, temp: jax.Array, key: jax.Array,
+                  ring_table: Optional[jax.Array] = None):
     """Prefill ONE sequence's prompt into its pages and sample the first
     token.
 
     tokens [1, S_pad] int32 (prompt padded to a bucket length — one
     compile per bucket, see the engine's bucket table), length scalar =
     real prompt length, page_table [MAXP], adapter_id scalar pool-slot
-    index (data, like the decode step's).  Padded tail positions write
+    index (data, like the decode step's), ring_table [entries] the window
+    layers' ring (None without them).  Padded tail positions write
     through the page table like real ones (their garbage K/V is masked by
     length until decode overwrites it) or to the scratch page past the
     allocated prefix.  The key advances on device like the decode step's.
@@ -485,7 +653,7 @@ def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
           key=key)
     logits, pools, counts = prefill_logits(
         config, params, pools, adapters, tokens, length, page_table,
-        adapter_id)
+        adapter_id, ring_table)
     key, sub = jax.random.split(key)
     tok = _sample_tokens(logits, temp[None], sub)
     return _first_token(tok, counts), key, pools
@@ -494,7 +662,8 @@ def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
 def prefill_prefix_logits(config, params: Params, pools: PagedPools,
                           adapters: AdapterArrays, tokens: jax.Array,
                           prefix_len: jax.Array, length: jax.Array,
-                          page_table: jax.Array, adapter_id: jax.Array):
+                          page_table: jax.Array, adapter_id: jax.Array,
+                          ring_table: Optional[jax.Array] = None):
     """``paged_prefill_prefix`` up to its sampling; returns what
     ``prefill_logits`` returns."""
     _, s_pad = tokens.shape
@@ -512,16 +681,32 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
     # Causal in global positions: [1, S_pad, MAXP*ps].
     visible = jnp.arange(maxp * ps)[None, None, :] \
         <= positions[None, :, None]
+    ring = None
+    if ring_table is not None:
+        # The rows are written before they are read, so the ring has to
+        # hold the window behind the first row and every row: what
+        # ``ring_entries`` sizes it for, with ``prefix_len`` on a page's
+        # edge.  A slot is seen by the rows at or behind what it holds,
+        # inside their window.
+        entries = ring_table.shape[0]
+        last = jnp.minimum(prefix_len + s_pad, length) - 1
+        held = _ring_positions(last, entries, ps)  # [entries*ps]
+        age = positions[:, None] - held[None, :]
+        ring = (jnp.where(valid, ring_table[(positions // ps) % entries],
+                          pools["kw"].shape[1] - 1), off, ring_table[None],
+                ((held >= 0) & (held <= last) & (age >= 0)
+                 & (age < config.window))[None])
 
     def attend(i, q, k, v):  # [S_pad, H, D]
         # Per-row RoPE at global positions (suffix rows are not at 0).
-        q = _rotary_single(q, cos, sin, positions)
-        k = _rotary_single(k, cos, sin, positions)
-        _write_kv(pools, i, page_idx, off, k, v)
+        if block.layer_rotary(config, i):
+            q = _rotary_single(q, cos, sin, positions)
+            k = _rotary_single(k, cos, sin, positions)
         # Attend the WHOLE table (cached prefix + fresh suffix) like the
         # decode step, as a batch of one.
-        return _attend_pages(config, q[None], pools["k"], pools["v"], i,
-                             page_table[None], visible)[0]
+        return _paged_attend(
+            config, pools, i, q[None], k, v,
+            whole=(page_idx, off, page_table[None], visible), ring=ring)[0]
 
     x, counts = _stack(config, params, tokens[0], attend,
                        _adapter_lora(adapters, adapter_id), valid)
@@ -536,11 +721,14 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
                          tokens: jax.Array, prefix_len: jax.Array,
                          length: jax.Array, page_table: jax.Array,
                          adapter_id: jax.Array, temp: jax.Array,
-                         key: jax.Array):
+                         key: jax.Array,
+                         ring_table: Optional[jax.Array] = None):
     """Prefill only the SUFFIX of a prompt whose first ``prefix_len``
     positions are already cached in this sequence's page table (radix
     prefix-cache hit; shared pages were written by an earlier identical
-    prefill, the COW page by ``copy_page``).
+    prefill, the COW page by ``copy_page``), or one CHUNK of a prompt
+    longer than the largest bucket (the engine calls it with
+    ``prefix_len`` advancing by the chunk and ``length`` the chunk's end).
 
     tokens [1, S_pad] int32 = prompt[prefix_len:] padded to a bucket,
     prefix_len / length scalars (length = FULL prompt length; both are
@@ -549,13 +737,15 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
     table at global positions ``prefix_len + row``; rows past the real
     suffix route to the scratch page (they may not even own a page).
     Queries then attend the full gathered table like the decode step —
-    cached prefix plus fresh suffix — masked by global causal position.
+    cached prefix plus fresh suffix — masked by global causal position;
+    on a window layer, the gathered ring (``ring_table``), masked by what
+    each slot holds.
     Returns what ``paged_prefill`` returns."""
     _bump("prefill_prefix", tokens=tokens, page_table=page_table,
           temp=temp, key=key)
     logits, pools, counts = prefill_prefix_logits(
         config, params, pools, adapters, tokens, prefix_len, length,
-        page_table, adapter_id)
+        page_table, adapter_id, ring_table)
     key, sub = jax.random.split(key)
     tok = _sample_tokens(logits, temp[None], sub)
     return _first_token(tok, counts), key, pools

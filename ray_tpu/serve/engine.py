@@ -103,6 +103,16 @@ class EngineConfig:
     lora_rank: int = 8
     prefix_cache: bool = True
     ttft_window: int = 64
+    # A prompt longer than this is prefilled in chunks of it: consecutive
+    # calls of the suffix program, each over what the ones before it
+    # cached.  It is the largest prefill bucket (the buckets stop there
+    # instead of doubling up to the prompt cap); 0: none, every prompt is
+    # one bucket.  A deployment sets it where a prompt cap's own bucket
+    # would not fit: the one-bucket program scores [H, S, S] in float32
+    # (3.5 GB at 28 heads of 5632 rows), and a window layer's ring is the
+    # window PLUS one chunk, so the chunk is also what the rings cost in
+    # memory (16 slots x 6 layers x 2048 rows: 0.4 GB of the cell's 1.2).
+    prefill_chunk: int = 0
     # Flight recorder (util/steprec.py): one fixed-size record per decode
     # step into the bounded per-process ring.  Off-hot-path by design
     # (host counters only, no device sync).  step_window sizes the recent
@@ -128,12 +138,17 @@ class EngineConfig:
 
     def prefill_buckets(self) -> List[int]:
         """Padded prompt lengths (one compile each): page-size multiples
-        doubling up to the prompt cap."""
+        doubling up to the prompt cap, or to ``prefill_chunk``."""
+        cap = self.max_prompt_len
+        if 0 < self.prefill_chunk < cap:
+            cap = self.prefill_chunk
+            if cap % self.page_size:
+                raise ValueError("prefill_chunk is not whole pages")
         out, b = [], self.page_size
-        while b < self.max_prompt_len:
+        while b < cap:
             out.append(b)
             b *= 2
-        out.append(max(b, self.max_prompt_len))
+        out.append(max(b, cap))
         return out
 
 
@@ -277,7 +292,7 @@ class InferenceEngine:
 
         from ..devtools import jitguard
         from ..models.paged import (PAGED_PROGRAMS, PageAllocator,
-                                    init_paged_pools, routing_width)
+                                    counter_keys, kv_layers, ring_entries)
         from ..util.metrics import get_counter, get_gauge, get_histogram
 
         # A fresh engine means fresh geometry: re-registering stands the
@@ -292,9 +307,21 @@ class InferenceEngine:
         cfg = config
         self.maxp = cfg.pages_per_seq
         self.scratch = cfg.pool_pages  # scratch page index
-        self.pools = init_paged_pools(model_config, cfg.pool_pages,
-                                      cfg.page_size)
+        # Two kinds of cache where the model has window layers: those
+        # keep a ring of the window plus one prefill chunk (never more
+        # than a whole sequence) in a pool of their own, and slot s owns
+        # that pool's pages [s * ring, (s + 1) * ring): a ring is as long
+        # for every sequence, so there is nothing to allocate or to
+        # refuse.  The others keep every page of a sequence, as every
+        # layer of a model without a pattern does.
+        self._kv_layers = tuple(len(k) for k in kv_layers(model_config))
+        self.ring = min(self.maxp, ring_entries(
+            model_config, cfg.page_size, cfg.prefill_buckets()[-1]))
+        self.ring_scratch = cfg.batch_slots * self.ring
         self.allocator = PageAllocator(cfg.pool_pages)
+        self.pools = self._new_pools()
+        #: The names of the counters behind the decode step's tokens.
+        self._counter_keys = counter_keys(model_config)
         # Multi-tenant plane: device-resident LoRA slots + the radix
         # prefix tree over the page pool.  Both are owned by the loop
         # thread like the allocator.
@@ -304,8 +331,15 @@ class InferenceEngine:
         self.adapter_pool = AdapterPool(
             model_config, max_adapters=cfg.max_adapters,
             rank=cfg.lora_rank)
+        # A radix node is one page, valid for every layer: false of a
+        # window layer's ring page, so such a model runs without it.
         self._cache: Optional[RadixPrefixCache] = (
-            RadixPrefixCache(cfg.page_size) if cfg.prefix_cache else None)
+            RadixPrefixCache(cfg.page_size)
+            if cfg.prefix_cache and not self.ring else None)
+        if cfg.prefix_cache and self.ring:
+            print("engine: the model has window layers, whose pages the "
+                  "prefix cache cannot share: prefix_cache is off",
+                  file=sys.stderr, flush=True)
         self._adapter_evictions_seen = 0
         # ONE device-resident PRNG key threads through every prefill and
         # decode call (each program splits and returns the successor):
@@ -319,11 +353,16 @@ class InferenceEngine:
         # mirrors and mark them dirty; steady-state decode advances
         # tokens/lengths ON DEVICE and never re-uploads.
         self._page_tables = np.full((b, self.maxp), self.scratch, np.int32)
+        # The rings never change hands, so their tables go up once.
+        self._ring_tables = np.arange(
+            self.ring_scratch, dtype=np.int32).reshape(b, self.ring)
+        self._d_ring_tables = (jax.numpy.asarray(self._ring_tables)
+                               if self.ring else None)
         self._seq_lens = np.zeros((b,), np.int32)
-        # A routed model's decode program appends its routing counters to
-        # the tokens it returns (paged.ROUTING_KEYS): the mirror has their
-        # room, so that what goes up has the shape of what comes back.
-        self._tokens = np.zeros((b + routing_width(model_config),), np.int32)
+        # The decode program appends its counters to the tokens it returns
+        # (paged.counter_keys): the mirror has their room, so that what
+        # goes up has the shape of what comes back.
+        self._tokens = np.zeros((b + len(self._counter_keys),), np.int32)
         self._active = np.zeros((b,), bool)
         self._temps = np.zeros((b,), np.float32)
         self._adapter_slots = np.full((b,), self.adapter_pool.zero_slot,
@@ -444,6 +483,21 @@ class InferenceEngine:
         self._thread = threading.Thread(
             target=self._loop, daemon=True, name="llm-engine")
         self._thread.start()
+
+    def _new_pools(self):
+        from ..models.paged import init_paged_pools
+
+        return init_paged_pools(self.model_config, self.config.pool_pages,
+                                self.config.page_size, self.ring_scratch)
+
+    def _ring_pages_held(self) -> int:
+        """Ring pages that hold a live sequence's rows: a sequence fills
+        its slot's ring up to its own length and no further."""
+        return sum(min(self.ring, len(r.pages))
+                   for r in self.slots if r is not None)
+
+    def _pages_used(self) -> int:
+        return self.allocator.used_count + self._ring_pages_held()
 
     # ------------------------------------------------------------- client API
 
@@ -585,8 +639,11 @@ class InferenceEngine:
             "steps": self.step_count,
             "active_seqs": active,
             "queued": queued,
-            "free_pages": self.allocator.free_count,
-            "total_pages": self.allocator.total,
+            # Of both kinds of cache together (a model without window
+            # layers has the one); the ring's own under "window_pages".
+            "free_pages": (self.allocator.free_count
+                           + self.ring_scratch - self._ring_pages_held()),
+            "total_pages": self.allocator.total + self.ring_scratch,
             "shared_pages": self.allocator.shared_count,
             "completed": self.completed,
             "shed": self.shed,
@@ -597,6 +654,14 @@ class InferenceEngine:
             "tenants": tenants,
             "prefix_cache": (self._cache.stats()
                              if self._cache is not None else None),
+            # Why it is off where the configuration asked for it.
+            "prefix_cache_off": ("window layers" if self.ring
+                                 and self.config.prefix_cache else None),
+            "window_pages": ({"ring_entries": self.ring,
+                              "free": (self.ring_scratch
+                                       - self._ring_pages_held()),
+                              "total": self.ring_scratch}
+                             if self.ring else None),
             "adapters": self.adapter_pool.stats(),
         }
 
@@ -742,31 +807,47 @@ class InferenceEngine:
                 self.clear_prefix_cache()
             warm("prefill", bucket,
                  lambda n=n: run(np.ones((n,), np.int32), 1))
-        if self._cache is not None \
-                and self.config.max_prompt_len >= self.config.page_size:
+        cached = self._cache is not None \
+            and self.config.max_prompt_len >= self.config.page_size
+        if cached:
             # Re-run the largest prompt: it hits the pages the line above
             # cached, compiling the COW copy + suffix-prefill path too.
             n = self.config.max_prompt_len
             warm("prefix_hit", n,
                  lambda: run(np.ones((n,), np.int32), 1))
             self.clear_prefix_cache()
+        # One control op per program: each waits for ONE cold compile,
+        # under the bound a cold prefill gets through its stream (at
+        # 1B-parameter size a bucket compiles for tens of seconds).
+        import functools
+
+        compile_s = self.config.stream_timeout_s
+        if cached or self.config.max_prompt_len > buckets[-1]:
             # The re-run traces the prefix path only for the ONE suffix
             # bucket (and COW divergence) its geometry happens to hit —
             # compile every suffix bucket and the COW copy explicitly
             # (dummy tokens into the scratch page; page 0 onto itself)
-            # so no real prefix hit after warmup pays a trace.
+            # so no real prefix hit after warmup pays a trace.  A prompt
+            # past the largest bucket runs the same program, a chunk a
+            # call, and its last chunk can be of any bucket.
             def _warm_suffix_bucket(b):
                 import jax.numpy as jnp
 
                 from ..models.paged import paged_prefill_prefix
                 pt = jnp.full((self.maxp,), self.scratch, jnp.int32)
+                ring = jnp.full((self.ring,), self.ring_scratch,
+                                jnp.int32) if self.ring else None
                 zero = jnp.asarray(0, jnp.int32)
                 _, self._d_key, self.pools = paged_prefill_prefix(
                     self.model_config, self.params, self.pools,
                     self.adapter_pool.arrays, jnp.zeros((1, b), jnp.int32),
                     zero, jnp.asarray(1, jnp.int32), pt, zero,
-                    jnp.asarray(0.0, jnp.float32), self._d_key)
+                    jnp.asarray(0.0, jnp.float32), self._d_key, ring)
 
+            for b in buckets:
+                warm("prefill_prefix", b, lambda b=b: self._run_on_loop(
+                    functools.partial(_warm_suffix_bucket, b), compile_s))
+        if cached:
             def _warm_cow_copy():
                 import jax.numpy as jnp
 
@@ -774,15 +855,6 @@ class InferenceEngine:
                 zero = jnp.asarray(0, jnp.int32)
                 self.pools = copy_page(self.pools, zero, zero)
 
-            # One control op per program: each waits for ONE cold compile,
-            # under the bound a cold prefill gets through its stream (at
-            # 1B-parameter size a bucket compiles for tens of seconds).
-            import functools
-
-            compile_s = self.config.stream_timeout_s
-            for b in buckets:
-                warm("prefill_prefix", b, lambda b=b: self._run_on_loop(
-                    functools.partial(_warm_suffix_bucket, b), compile_s))
             warm("copy_page", None,
                  lambda: self._run_on_loop(_warm_cow_copy, compile_s))
         # Compile the adapter-load path too (zero payload into the zero
@@ -946,16 +1018,15 @@ class InferenceEngine:
 
     def _prefill(self, req: _Request) -> None:
         """Run one admitted sequence's prompt through the bucketed
-        prefill program and emit its first token (TTFT point).  A
+        prefill programs and emit its first token (TTFT point).  A
         prefix-cache hit copies the COW page (mid-page divergence) and
-        prefills only the uncached suffix."""
-        from ..util.profiling import annotation
-
+        prefills only the uncached suffix; a prompt longer than the
+        largest bucket goes through in chunks."""
         # This request's own account: the prefill's phases go on its
         # first_tokens entry, not among the step's.
         acct: Dict[str, float] = {}
-        with annotation(PH_PREFILL, acct) as phase:
-            bucket, routing = self._prefill_body(req, acct)
+        t0 = time.perf_counter()
+        bucket, chunks, routing = self._prefill_body(req, acct)
         n, prefix_len = int(req.prompt.size), int(req.cache_hit_len)
         entry = {
             "queue_s": round(req.admit_t - req.submit_t, 6),
@@ -963,6 +1034,7 @@ class InferenceEngine:
             "prefill_wait_s": round(acct[PH_PREFILL_WAIT], 6),
             "ttft_s": round(req.first_token_t - req.submit_t, 6),
             "prompt": n, "bucket": bucket, "cached": prefix_len,
+            "chunks": chunks,
             **routing,  # a routed model's counters of this prefill
         }
         rec = self._rec()
@@ -976,20 +1048,70 @@ class InferenceEngine:
             self._emit_req_span(req, "engine:queue", req.submit_wall,
                                 req.submit_wall + entry["queue_s"],
                                 prompt_len=n)
-            start = req.wall(phase.t0)
+            start = req.wall(t0)
             self._emit_req_span(req, "engine:prefill", start,
                                 start + entry["prefill_s"], bucket=bucket,
                                 prompt_len=n, cached_prefix=prefix_len)
 
     def _prefill_body(self, req: _Request, acct: Dict[str, float]
-                      ) -> Tuple[int, Dict[str, int]]:
-        """The work of :meth:`_prefill`; returns the padded length run and
-        (of a model whose FFN is routed) the prefill's routing counters."""
+                      ) -> Tuple[int, int, Dict[str, int]]:
+        """The work of :meth:`_prefill`; returns the padded rows run, the
+        program calls they took (the chunks) and, of a model whose FFN is
+        routed, the prefill's routing counters.  ``PH_PREFILL`` is
+        annotated once a call: the first holds the adapter's weights and a
+        prefix hit's page copy too, the last the wait for the first token
+        and the bookkeeping after it."""
         import jax.numpy as jnp
 
-        from ..models.paged import (ROUTING_KEYS, copy_page, paged_prefill,
-                                    paged_prefill_prefix)
+        from ..models.paged import paged_prefill, paged_prefill_prefix
         from ..util.profiling import annotation
+
+        n = int(req.prompt.size)
+        prefix_len = int(req.cache_hit_len)
+        # What is not cached, a chunk (the largest bucket) at a time: each
+        # call writes its rows' K/V and attends over what is cached before
+        # them; the last one's token is the request's first.
+        chunk = self.config.prefill_buckets()[-1]
+        starts = list(range(prefix_len, n, chunk))
+        rows, firsts, routing = 0, [], {}
+        for start in starts:
+            end = min(start + chunk, n)
+            with annotation(PH_PREFILL, acct):
+                if start == prefix_len:  # the first call's share
+                    self._prefill_prepare(req)
+                    aid = jnp.asarray(req.adapter_slot, jnp.int32)
+                    temp = jnp.asarray(req.temperature, jnp.float32)
+                    table = jnp.asarray(req.page_table)
+                    ring = jnp.asarray(self._ring_tables[req.slot]) \
+                        if self.ring else None
+                s_pad = self._bucket_len(end - start)
+                rows += s_pad
+                toks = np.zeros((1, s_pad), np.int32)
+                toks[0, :end - start] = req.prompt[start:end]
+                if start:
+                    first, self._d_key, self.pools = paged_prefill_prefix(
+                        self.model_config, self.params, self.pools,
+                        self.adapter_pool.arrays, jnp.asarray(toks),
+                        jnp.asarray(start, jnp.int32),
+                        jnp.asarray(end, jnp.int32), table, aid, temp,
+                        self._d_key, ring)
+                else:
+                    first, self._d_key, self.pools = paged_prefill(
+                        self.model_config, self.params, self.pools,
+                        self.adapter_pool.arrays, jnp.asarray(toks),
+                        jnp.asarray(end, jnp.int32), table, aid, temp,
+                        self._d_key, ring)
+                firsts.append(first)
+                if end == n:
+                    routing = self._finish_prefill(req, firsts, acct)
+        return rows, len(starts), routing
+
+    def _prefill_prepare(self, req: _Request) -> None:
+        """Before a request's first prefill call: the adapter's weights,
+        and the private copy of a prefix hit's divergent page."""
+        import jax.numpy as jnp
+
+        from ..models.paged import copy_page
 
         # Admission reserved (pinned) the slot; materialize the weights
         # if this is the adapter's first use since eviction.
@@ -998,10 +1120,7 @@ class InferenceEngine:
         if ev > self._adapter_evictions_seen:
             self._m_adapter_evict.inc(ev - self._adapter_evictions_seen)
             self._adapter_evictions_seen = ev
-        n = req.prompt.size
-        prefix_len = req.cache_hit_len
-        aid = jnp.asarray(req.adapter_slot, jnp.int32)
-        adapters = self.adapter_pool.arrays
+        n, prefix_len = int(req.prompt.size), int(req.cache_hit_len)
         if prefix_len > 0:
             match = req.match
             if match.cow_src is not None:
@@ -1013,31 +1132,27 @@ class InferenceEngine:
                     jnp.asarray(dest, jnp.int32))
                 self.allocator.free([req.cow_ref])
                 req.cow_ref = None
-            suffix = req.prompt[prefix_len:]
-            s_pad = self._bucket_len(suffix.size)
-            toks = np.zeros((1, s_pad), np.int32)
-            toks[0, :suffix.size] = suffix
-            first, self._d_key, self.pools = paged_prefill_prefix(
-                self.model_config, self.params, self.pools, adapters,
-                jnp.asarray(toks), jnp.asarray(prefix_len, jnp.int32),
-                jnp.asarray(n, jnp.int32), jnp.asarray(req.page_table),
-                aid, jnp.asarray(req.temperature, jnp.float32),
-                self._d_key)
             self._m_pc_hits.inc(1)
-            self._m_prefill.inc(suffix.size)  # only the work actually done
-        else:
-            s_pad = self._bucket_len(n)
-            toks = np.zeros((1, s_pad), np.int32)
-            toks[0, :n] = req.prompt
-            first, self._d_key, self.pools = paged_prefill(
-                self.model_config, self.params, self.pools, adapters,
-                jnp.asarray(toks), jnp.asarray(n, jnp.int32),
-                jnp.asarray(req.page_table), aid,
-                jnp.asarray(req.temperature, jnp.float32), self._d_key)
-            self._m_prefill.inc(n)
+        self._m_prefill.inc(n - prefix_len)  # only the work actually done
+
+    def _finish_prefill(self, req: _Request, firsts: List[Any],
+                        acct: Dict[str, float]) -> Dict[str, int]:
+        """Wait for the last prefill call of ``req`` (``firsts``: what each
+        of its calls returned), stream its token and hand the slot to the
+        decode step; returns the calls' routing counters, summed (the
+        heaviest load: the largest)."""
+        from ..models.paged import ROUTING_KEYS
+        from ..util.profiling import annotation
+
         with annotation(PH_PREFILL_WAIT, acct):
-            out = np.asarray(first).reshape(-1)  # rt-sync-ok: THE prefill readback — the first token must reach the host to stream it
-        first = int(out[0])
+            outs = [np.asarray(f).reshape(-1) for f in firsts]  # rt-sync-ok: THE prefill readback — the first token must reach the host to stream it
+        first = int(outs[-1][0])
+        counters = np.stack([o[1:] for o in outs])
+        routing = dict(zip(ROUTING_KEYS, counters.sum(0).tolist()))
+        if routing:
+            heaviest = ROUTING_KEYS.index("expert_load_max")
+            routing["expert_load_max"] = int(counters[:, heaviest].max())
+        n = int(req.prompt.size)
         # Cache every fully-frozen prompt page (decode appends past the
         # prompt, so pages wholly inside it never change again).
         if self._cache is not None:
@@ -1065,7 +1180,7 @@ class InferenceEngine:
         self._adapter_slots[slot] = req.adapter_slot
         self._dirty = True
         self._emit_token(req, first, self.step_count)
-        return int(s_pad), dict(zip(ROUTING_KEYS, out[1:].tolist()))
+        return routing
 
     def _emit_token(self, req: _Request, token: int, step: int) -> None:
         req.generated += 1
@@ -1082,8 +1197,6 @@ class InferenceEngine:
         return to the free list, and the pools are rebuilt (a failed
         donated call may have invalidated them).  Queued requests stay
         queued — they retry against the fresh pool."""
-        from ..models.paged import init_paged_pools
-
         self._quiesce()
         self._acct = None  # its seconds fall to the next record's between_s
         now_wall = time.time()
@@ -1120,9 +1233,7 @@ class InferenceEngine:
         self._temps[:] = 0.0
         self._adapter_slots[:] = self.adapter_pool.zero_slot
         self._dirty = True
-        self.pools = init_paged_pools(
-            self.model_config, self.config.pool_pages,
-            self.config.page_size)
+        self.pools = self._new_pools()
 
     def _loop(self) -> None:
         from ..util.profiling import annotation
@@ -1165,7 +1276,7 @@ class InferenceEngine:
                 if not admitted and active == 0 and not control \
                         and self._inflight is None:
                     self._m_active.set(0, tags=self._pid_tags)
-                    self._m_pages.set(self.allocator.used_count,
+                    self._m_pages.set(self._pages_used(),
                                       tags=self._pid_tags)
                     with annotation(PH_IDLE, self._gap_acct):
                         self._wake.wait(timeout=0.05)
@@ -1293,14 +1404,13 @@ class InferenceEngine:
                 self.adapter_pool.arrays,
                 self._d_tokens, self._d_page_tables, self._d_seq_lens,
                 self._d_active, self._d_temps, self._d_adapter_slots,
-                self._d_key)
+                self._d_key, self._d_ring_tables)
         return _Step(self.step_count, self._d_tokens, list(self.slots),
                      ahead)
 
     def _finish_step(self, step: _Step) -> None:
         """Read a dispatched step's tokens, hand each to the request its
         slot decoded for, and close the record."""
-        from ..models.paged import ROUTING_KEYS
         from ..util.profiling import annotation
 
         rec = self._rec()
@@ -1310,7 +1420,8 @@ class InferenceEngine:
         with annotation(PH_EMIT, phases) as phase:
             now = phase.t0
             routing = dict(zip(
-                ROUTING_KEYS, toks[self.config.batch_slots:].tolist()))
+                self._counter_keys,
+                toks[self.config.batch_slots:].tolist()))
             for slot, req in enumerate(step.owners):
                 if req is None or self.slots[slot] is not req:
                     continue  # stopped under this step: the token is dropped
@@ -1326,7 +1437,7 @@ class InferenceEngine:
         self._m_active.set(
             sum(1 for s in self.slots if s is not None),
             tags=self._pid_tags)
-        self._m_pages.set(self.allocator.used_count,
+        self._m_pages.set(self._pages_used(),
                           tags=self._pid_tags)
         if rec is not None:
             with annotation(PH_RECORD):
@@ -1407,10 +1518,20 @@ class InferenceEngine:
             "prefix_hits": sum(1 for e in first_tokens if e["cached"]),
             "adapter_pins": self.adapter_pool.pinned_count,
             "tenants": tenants,
-            # Only a model whose FFN is routed has these (of the decode
-            # step; a prefill's are on its first_tokens entry).
+            # Only a model whose FFN is routed, or one with window
+            # layers, has these (of the decode step; a prefill's routing
+            # counters are on its first_tokens entry).
             **routing,
         }
+        if self.ring:
+            # Pages held for live sequences, a layer's each: by the two
+            # kinds of cache, and what one pool in which every layer kept
+            # every page would hold for the same sequences.
+            whole, window = self._kv_layers
+            rec.update(
+                pages_global=whole * self.allocator.used_count,
+                pages_window=window * self._ring_pages_held(),
+                pages_uniform=(whole + window) * self.allocator.used_count)
         # Which step recompiled, for every jitted program of the process
         # (trace_counts above knows the three paged ones).
         compiles = devmem.compile_count() - acct.compiles0

@@ -565,7 +565,7 @@ PHASE_FIELDS = {"t0": float, "between_s": float, "idle_s": float,
                 "emit_s": float, "first_tokens": list, "ahead": int}
 ENTRY_FIELDS = {"queue_s": float, "prefill_s": float,
                 "prefill_wait_s": float, "ttft_s": float, "prompt": int,
-                "bucket": int, "cached": int}
+                "bucket": int, "cached": int, "chunks": int}
 RT_PHASES = {"admit", "prefill", "prefill_wait", "upload", "dispatch",
              "readback", "emit", "record"}
 REPEATED = [9, 8, 7, 6, 5, 4, 3, 2, 1, 2, 3]  # one full page of 8, then 3
