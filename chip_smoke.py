@@ -109,18 +109,6 @@ def check_device(device: dict, platform: str, count=None) -> None:
             f"worker ran on {device}, expected {count} x {platform!r}")
 
 
-COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
-               "collective-permute")
-
-
-def count_collectives(compiled_text: str) -> dict:
-    """Collective ops in a compiled program's text, by kind."""
-    import re
-
-    return {op: len(re.findall(rf"\b{op}(?:-start)?\(", compiled_text))
-            for op in COLLECTIVES}
-
-
 def train_model_config(model: str, seq: int):
     """What the train phase trains: b1 as bench.py builds it for its
     4 x 2048 tier, or (rehearsals) tiny."""
@@ -246,6 +234,7 @@ def _train_loop(config: dict) -> None:
     from ray_tpu.models.train_state import (default_optimizer,
                                             make_train_step,
                                             shard_train_state)
+    from ray_tpu.train.session import get_session
 
     seq, batch_size = config["seq"], config["batch"]
     cfg = train_model_config(config["model"], seq)
@@ -283,7 +272,8 @@ def _train_loop(config: dict) -> None:
             "n_params": sum(x.size for x in jax.tree.leaves(state.params)),
             "compile_s": compile_s,
             "tpu_custom_calls": text.count("tpu_custom_call"),
-            "collectives": count_collectives(text),
+            "collectives": get_session().telemetry.record_compiled(
+                compiled),
             # Train state resident per device, before the first step.
             "bytes_in_use": [s.get("bytes_in_use") for s in stats],
             "param_shards": sorted({

@@ -12,6 +12,12 @@ for the deltas on the q and v projections.
 ``llama`` and ``moe`` are reached through their modules, at call time: the
 helpers a test swaps there (``llama._qk_norm``, ``moe._moe_ffn``) are the
 ones every program runs.
+
+The layer also says where its activations live under a mesh
+(``sharding.constrain``: the residual stream whole in its hidden
+dimension, heads and the FFN's hidden units over tp).  Only a training
+program is traced under an ambient mesh: in a serving program and inside
+the pipeline stage's ``shard_map`` the calls return their argument.
 """
 
 from __future__ import annotations
@@ -21,6 +27,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 
 from ..ops.norms import rms_norm
+from ..parallel.sharding import (HEADS, RESIDUAL, SPLIT, VOCAB_ROWS,
+                                 constrain)
 from . import llama, moe
 
 Params = Dict[str, Any]
@@ -42,15 +50,16 @@ def project_qkv(config, a: Params, h: jax.Array,
         v = v + lora("wv", h)
     q, k = llama._qk_norm(config, a, q, k)
     lead, hd = h.shape[:-1], config.head_dim
-    return (q.reshape(*lead, config.n_heads, hd),
-            k.reshape(*lead, config.n_kv_heads, hd),
-            v.reshape(*lead, config.n_kv_heads, hd))
+    return (constrain(q.reshape(*lead, config.n_heads, hd), HEADS),
+            constrain(k.reshape(*lead, config.n_kv_heads, hd), HEADS),
+            constrain(v.reshape(*lead, config.n_kv_heads, hd), HEADS))
 
 
 def attention(config, a: Params, h: jax.Array, attend: Attend,
               lora: Optional[Lora] = None) -> jax.Array:
     """Attention of normalised ``h`` through the output projection."""
-    return attend(*project_qkv(config, a, h, lora)) @ a["wo"]
+    out = constrain(attend(*project_qkv(config, a, h, lora)), SPLIT)
+    return out @ a["wo"]
 
 
 def layer_window(config, i: int) -> int:
@@ -108,9 +117,9 @@ def ffn(config, layer: Params, x: jax.Array,
         early = {} if logits is None else {"logits": logits}
         out, aux, counts = moe._moe_ffn(config, layer["moe"], h, valid,
                                         **early)
-        return x + out, aux, counts
+        return constrain(x + out, RESIDUAL), aux, counts
     h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-    return x + llama._mlp(layer, h), None, None
+    return constrain(x + llama._mlp(layer, h), RESIDUAL), None, None
 
 
 def decoder_layer(config, layer: Params, x: jax.Array, attend: Attend, *,
@@ -122,7 +131,8 @@ def decoder_layer(config, layer: Params, x: jax.Array, attend: Attend, *,
     logits = None
     if getattr(config, "router_before_attn", False):
         logits = moe.router_logits(layer["moe"], h)
-    x = x + attention(config, layer["attn"], h, attend, lora)
+    x = constrain(x + attention(config, layer["attn"], h, attend, lora),
+                  RESIDUAL)
     return ffn(config, layer, x, valid, logits)
 
 
@@ -134,7 +144,8 @@ def decoder_stack(config, params: Params, tokens: jax.Array,
     (hidden [..., d], the layers' aux losses, their expert counts); the
     head stays with the caller, which takes its own rows of the hidden
     state."""
-    x = params["embed"][tokens].astype(config.dtype)
+    table = constrain(params["embed"], VOCAB_ROWS)
+    x = constrain(table[tokens].astype(config.dtype), RESIDUAL)
     auxes, counts = [], []
     for i, layer in enumerate(params["layers"]):
         x, aux, c = layer_fn(i, layer, x)

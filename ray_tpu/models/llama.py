@@ -4,7 +4,9 @@ Pure functional: `llama_init` builds a param pytree, `llama_apply` runs the
 forward pass.  Attention goes through the Pallas flash kernel (TPU) or the
 jnp reference (CPU), and through ring attention when the sequence is sharded
 on the `sp` mesh axis.  Sharding is declared in `llama_sharding_rules`
-(megatron TP + FSDP), applied by pjit — no wrapper classes.
+(megatron TP + FSDP) for the parameters and, under an ambient mesh, by the
+layer for its activations (`sharding.constrain`), and applied by pjit — no
+wrapper classes.
 
 LoRA: `lora_init` creates low-rank adapters for the attention projections;
 the base params stay frozen (the Llama-2-7B LoRA fine-tune target in
@@ -15,7 +17,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import math
 from typing import Any, Dict, Optional
 
 import jax
@@ -27,7 +28,8 @@ from ..ops.norms import rms_norm
 from ..ops.ring_attention import ring_attention
 from ..ops.rotary import apply_rotary, rope_frequencies
 from ..parallel.mesh import AXIS_DP, AXIS_FSDP, AXIS_SP, AXIS_TP
-from ..parallel.sharding import ShardingRules
+from ..parallel.sharding import (SPLIT, ShardingRules, auto_mesh, constrain,
+                                 fit_spec)
 
 Params = Dict[str, Any]
 
@@ -178,10 +180,13 @@ def _qk_norm(config, a: Params, q: jax.Array, k: jax.Array):
 
 
 def llama_sharding_rules() -> ShardingRules:
-    """Megatron TP x FSDP rules (2D); norms replicated.
+    """Megatron TP x FSDP rules (2D); norms replicated.  A matrix is split
+    over tp the way its product is (``sharding.SPLIT``) and over fsdp in
+    its other dimension, to be gathered where it is used; the embedding's
+    rows over both (``sharding.VOCAB_ROWS`` where it is looked up).
     Reference behavior replaced: train_loop_utils.py prepare_model wrappers."""
     return ShardingRules([
-        (r"embed", P(AXIS_TP, AXIS_FSDP)),
+        (r"embed", P((AXIS_TP, AXIS_FSDP), None)),
         (r"lm_head", P(AXIS_FSDP, AXIS_TP)),
         (r"attn/(wq|wk|wv)", P(AXIS_FSDP, AXIS_TP)),
         (r"attn/wo", P(AXIS_TP, AXIS_FSDP)),
@@ -209,18 +214,11 @@ def _flash_per_shard(config, q, k, v):
         flash_attention, causal=True,
         block_q=_option(config, "flash_block_q"),
         block_k=_option(config, "flash_block_k"))
-    mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.manual_axes:  # no mesh / already per-shard
+    mesh = auto_mesh()
+    if mesh is None:  # no mesh / already per-shard
         return flash(q, k, v)
-    sizes = mesh.shape
-
-    def dividing(dim: int, *axes: str):
-        axes = tuple(a for a in axes if a in sizes)
-        fits = dim % math.prod(sizes[a] for a in axes) == 0
-        return axes if axes and fits else None
-
-    spec = P(dividing(q.shape[0], AXIS_DP, AXIS_FSDP),
-             dividing(k.shape[1], AXIS_TP), None, None)
+    spec = fit_spec(P((AXIS_DP, AXIS_FSDP), AXIS_TP, None, None), k.shape,
+                    mesh.shape)
     return jax.shard_map(flash, in_specs=(spec,) * 3, out_specs=spec,
                          check_vma=False)(q, k, v)
 
@@ -306,7 +304,8 @@ def _attention(config, x, layer, cos, sin, lora_layer=None):
 
 def _mlp(layer, x):
     m = layer["mlp"]
-    return (jax.nn.silu(x @ m["w1"]) * (x @ m["w3"])) @ m["w2"]
+    gate = jax.nn.silu(constrain(x @ m["w1"], SPLIT))
+    return (gate * constrain(x @ m["w3"], SPLIT)) @ m["w2"]
 
 
 def _layer(config, x, layer, cos, sin, lora_layer=None, kind=(0, True)):
@@ -406,7 +405,9 @@ def llama_loss(
     from ..ops.losses import masked_nll
 
     def chunk_nll(h_c, tgt_c):
-        logits = (h_c @ w).astype(jnp.float32)
+        # The vocabulary over tp (the head's rule): the loss reduces over
+        # tp on [B, chunk], never on the logits.
+        logits = constrain((h_c @ w).astype(jnp.float32), SPLIT)
         return masked_nll(logits, tgt_c, ignore_index)
 
     chunk = config.loss_chunk

@@ -1,9 +1,10 @@
 """Train state + sharded train-step factory.
 
 The factory returns a jitted SPMD step: inputs sharded over dp/fsdp (and sp),
-params/optimizer state sharded per the rule table, gradient reduction done by
-XLA from the sharding annotations (no explicit allreduce — the TPU-native
-replacement for torch DDP/FSDP wrappers, reference:
+params/optimizer state sharded per the rule table, activations where the
+model's layer says (``parallel.sharding.constrain``, under an ambient mesh),
+gradient reduction done by XLA from those layouts (no explicit allreduce —
+the TPU-native replacement for torch DDP/FSDP wrappers, reference:
 train/torch/train_loop_utils.py:162 prepare_model).
 """
 

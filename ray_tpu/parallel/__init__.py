@@ -24,7 +24,7 @@ _EXPORTS = {
     "unstack_layers": "pipeline",
     "ShardingRules": "sharding", "infer_param_specs": "sharding",
     "named_sharding": "sharding", "shard_pytree": "sharding",
-    "with_sharding_constraint": "sharding",
+    "constrain": "sharding", "count_collectives": "sharding",
     "initialize_process_group": "distributed",
     "process_group_barrier": "distributed",
 }

@@ -2,8 +2,9 @@
 
 The mesh is the TPU-native replacement for the reference's process groups:
 instead of wiring NCCL communicators per worker pair, a single logical mesh is
-declared once and XLA inserts the right ICI/DCN collectives from sharding
-annotations (the "How to Scale Your Model" recipe).
+declared once and XLA inserts the right ICI/DCN collectives from the layouts
+the program states: its parameters' (a rule table) and its activations'
+(``sharding.constrain``) (the "How to Scale Your Model" recipe).
 
 Axis convention (outer → inner, matching ICI locality preferences):
 - dp:    pure data parallel (gradient psum, rides DCN across slices)

@@ -106,6 +106,10 @@ class TrainTelemetry:
             "ray_tpu_train_compile_seconds",
             "Cumulative compile/tracing seconds observed by this worker",
             tag_keys=("rank",))
+        self._g_collectives = get_gauge(
+            "ray_tpu_train_step_collectives",
+            "Collective ops in the compiled train step, by kind",
+            tag_keys=("rank", "kind"))
         self._compile_total = 0.0
 
     # -- configuration ---------------------------------------------------------
@@ -134,6 +138,20 @@ class TrainTelemetry:
         self._compile_total += max(0.0, seconds)
         self._g_compile.set(self._compile_total, tags=self._tags)
         self.last["compile_time_s"] = seconds
+
+    def record_compiled(self, compiled) -> Dict[str, int]:
+        """Count the collectives of the step this worker runs, from what it
+        compiled (``jitted.lower(...).compile()``), by kind: all-gather,
+        all-reduce, reduce-scatter, all-to-all, collective-permute.  A loop
+        hands its compiled step over once; an all-to-all in a dense
+        fsdp x tp step is a layout lost (``parallel/sharding.py``)."""
+        from ..parallel.sharding import count_collectives
+
+        counts = count_collectives(compiled.as_text())
+        for kind, n in counts.items():
+            self._g_collectives.set(n, tags={**(self._tags or {}),
+                                             "kind": kind})
+        return counts
 
     def record_step(self, step_time_s: float,
                     tokens: Optional[float] = None,

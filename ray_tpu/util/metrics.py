@@ -42,6 +42,7 @@ BUILTIN_METRICS: Dict[str, str] = {
     "ray_tpu_train_tokens_per_sec": "gauge",
     "ray_tpu_train_mfu": "gauge",
     "ray_tpu_train_compile_seconds": "gauge",
+    "ray_tpu_train_step_collectives": "gauge",
     # serve (serve/replica.py, serve/batching.py, serve/handle.py)
     "ray_tpu_serve_request_latency_seconds": "histogram",
     "ray_tpu_serve_replica_queue_depth": "gauge",

@@ -33,6 +33,7 @@ from ray_tpu.ops import attention as att
 from ray_tpu.ops.norms import rms_norm_pallas
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel.mesh import MESH_AXES, batch_spec
+from ray_tpu.parallel.sharding import count_collectives
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -175,7 +176,94 @@ def test_ring_flash_compiles_on_four_devices(v5e, grad):
     text = jax.jit(fn).lower(arg, arg, arg).compile().as_text()
     # One kernel per ring step forward; forward + 2 per step with the grad.
     assert text.count("tpu_custom_call") == (12 if grad else 4)
-    assert chip_smoke.count_collectives(text)["collective-permute"] > 0
+    assert count_collectives(text)["collective-permute"] > 0
+
+
+# ------------------------------------------- the sharded train step's layout
+
+
+def _lower_train_step(v5e, cfg, tx, shape, **axes):
+    """``make_train_step`` of ``cfg``'s loss on a batch of ``shape``, lowered
+    for the described host: over a mesh of ``axes`` with the rule table,
+    under it as the ambient mesh; or, with no axes, on one device."""
+    from ray_tpu.models import (TrainState, llama_init, llama_loss,
+                                llama_sharding_rules)
+    from ray_tpu.models.train_state import make_train_step
+    from ray_tpu.parallel.sharding import named_sharding
+
+    state = jax.eval_shape(lambda: TrainState.create(
+        llama_init(cfg, jax.random.PRNGKey(0)), tx))
+    loss = lambda p, b: llama_loss(cfg, p, b["tokens"], b["targets"])
+    if not axes:
+        one = SingleDeviceSharding(v5e.devices[0])
+        state = jax.tree.map(lambda x: _on(one, x.shape, x.dtype), state)
+        batch = {k: _on(one, shape, jnp.int32) for k in ("tokens", "targets")}
+        return make_train_step(loss, tx).lower(state, batch)
+    mesh, rules = _mesh(v5e, **axes), llama_sharding_rules()
+    state = jax.tree.map(
+        lambda x, s: _on(s, x.shape, x.dtype), state,
+        named_sharding(mesh, rules.tree_specs(state)))
+    data = NamedSharding(mesh, batch_spec())
+    batch = {k: _on(data, shape, jnp.int32) for k in ("tokens", "targets")}
+    with jax.set_mesh(mesh):
+        return make_train_step(loss, tx, mesh, rules).lower(state, batch)
+
+
+def _cell_train_step(v5e, layers, **axes):
+    """The four-chip training cell's step (internlm2-1.8b's widths, 8 x 2048
+    tokens, remat ``save_attn``, one loss chunk) at ``layers`` layers."""
+    from ray_tpu.models import LlamaConfig
+    from ray_tpu.models.train_state import default_optimizer
+
+    cfg = LlamaConfig(vocab_size=92544, d_model=2048, n_layers=layers,
+                      n_heads=16, n_kv_heads=8, d_ff=8192, max_seq=2048,
+                      rope_theta=1e6, remat=True, remat_policy="save_attn",
+                      loss_chunk=2048)
+    return _lower_train_step(
+        v5e, cfg, default_optimizer(lr=3e-4, grad_clip=1.0), (8, 2048),
+        **axes)
+
+
+def _collective_shapes(text, kind):
+    """Result shapes of the ``kind`` collectives in a compiled text."""
+    import re
+
+    return re.findall(r"= \(?(\w+\[[\d,]*\])[^=]*? " + kind
+                      + r"(?:-start)?\(", text)
+
+
+@pytest.mark.parametrize("layers,axes,permutes", [
+    (2, dict(fsdp=2, tp=2), 4), (1, dict(fsdp=4), 3), (1, dict(tp=4), 0)],
+    ids=["fsdp2-tp2", "fsdp4", "tp4"])
+def test_sharded_train_step_keeps_its_activations_in_place(
+        v5e, kernels_as_on_chip, layers, axes, permutes):
+    """The step says where its activations live (``sharding.constrain``),
+    so the partitioner gathers weights over fsdp and reduces over tp and
+    moves no activation between the two: no all-to-all, and nothing of the
+    batch's shape gathered.  Before, two layers held 30 all-to-alls, 19
+    collective-permutes and the head's logits for the whole batch gathered
+    19 times.  ~20 s a case."""
+    text = _cell_train_step(v5e, layers, **axes).compile().as_text()
+    counts = count_collectives(text)
+    assert counts["all-to-all"] == 0, counts
+    assert text.count("tpu_custom_call") == 4 * layers
+    gathered = _collective_shapes(text, "all-gather")
+    assert (len(gathered) > 0) == ("fsdp" in axes)  # the pattern still reads
+    # Token ids gathered for the embedding's scatter-add are no activation.
+    assert not [s for s in gathered
+                if s.startswith(("bf16[8,2048,", "f32[8,2048,"))], gathered
+    # What is left is the compiler's own: boundary rows of the combined
+    # gradient buffers, exchanged inside an fsdp group (0.3 MB each).
+    assert counts["collective-permute"] <= permutes, counts
+
+
+def test_train_step_without_a_mesh_lowers_as_it_did(v5e, kernels_as_on_chip):
+    """With no ambient mesh ``constrain`` returns its argument: the
+    one-chip step lowers to the text it had before the layer said anything
+    (2211 lines at two layers, counted on the parent commit)."""
+    text = _cell_train_step(v5e, 2).as_text()
+    assert "sharding_constraint" not in text
+    assert len(text.splitlines()) == 2211
 
 
 # ------------------------------------------------------- chip_smoke, on CPU
@@ -281,30 +369,20 @@ def kernels_as_on_chip(monkeypatch):
     monkeypatch.setattr(att, "_on_tpu", lambda: True)
 
 
-def _smoke_train_step(mesh=None):
-    from ray_tpu.models import (TrainState, llama_init, llama_loss,
-                                llama_sharding_rules)
-    from ray_tpu.models.train_state import default_optimizer, make_train_step
+def _smoke_train_step(v5e, **axes):
+    """chip_smoke.py's train step, compiled for the described host."""
+    from ray_tpu.models.train_state import default_optimizer
 
     t = chip_smoke.TRAIN
     cfg = chip_smoke.train_model_config(t["model"], t["seq"])
-    tx = default_optimizer(lr=t["lr"], grad_clip=1.0)
-    state = jax.eval_shape(lambda: TrainState.create(
-        llama_init(cfg, jax.random.PRNGKey(0)), tx))
-    rules = llama_sharding_rules() if mesh is not None else None
-    step = make_train_step(
-        lambda p, b: llama_loss(cfg, p, b["tokens"], b["targets"]),
-        tx, mesh, rules)
-    return step, state, rules, (t["batch"], t["seq"])
+    return _lower_train_step(
+        v5e, cfg, default_optimizer(lr=t["lr"], grad_clip=1.0),
+        (t["batch"], t["seq"]), **axes).compile()
 
 
 @pytest.mark.slow  # ~30 s
 def test_b1_train_step_fits_one_chip(v5e, kernels_as_on_chip):
-    step, state, _, shape = _smoke_train_step()
-    one = SingleDeviceSharding(v5e.devices[0])
-    state = jax.tree.map(lambda x: _on(one, x.shape, x.dtype), state)
-    batch = {k: _on(one, shape, jnp.int32) for k in ("tokens", "targets")}
-    compiled = step.lower(state, batch).compile()
+    compiled = _smoke_train_step(v5e)
     assert compiled.as_text().count("tpu_custom_call") == 60  # 20 x 3
     ma = compiled.memory_analysis()
     # Weights and both Adam moments (donated, so counted once) plus the
@@ -316,20 +394,12 @@ def test_b1_train_step_fits_one_chip(v5e, kernels_as_on_chip):
 def test_b1_train_step_partitions_over_four_chips(v5e, kernels_as_on_chip):
     """fsdp=2 x tp=2: XLA cannot partition a Mosaic kernel by itself, so
     the model runs the flash kernel per shard (llama._flash_per_shard)."""
-    from ray_tpu.parallel.sharding import named_sharding
-
-    mesh = _mesh(v5e, fsdp=2, tp=2)
-    step, state, rules, shape = _smoke_train_step(mesh)
-    state = jax.tree.map(
-        lambda x, s: _on(s, x.shape, x.dtype), state,
-        named_sharding(mesh, rules.tree_specs(state)))
-    data = NamedSharding(mesh, batch_spec())
-    batch = {k: _on(data, shape, jnp.int32) for k in ("tokens", "targets")}
-    with jax.set_mesh(mesh):
-        compiled = step.lower(state, batch).compile()
+    compiled = _smoke_train_step(v5e, fsdp=2, tp=2)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 60
-    assert chip_smoke.count_collectives(text)["all-gather"] > 0
+    counts = count_collectives(text)
+    # PR 21 counted 246 all-to-alls and 103 collective-permutes here.
+    assert counts["all-gather"] > 0 and counts["all-to-all"] == 0, counts
     ma = compiled.memory_analysis()  # bytes on EACH device
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < HBM_BYTES / 2
 

@@ -268,6 +268,81 @@ class TestMoE:
         assert w1.sharding.spec == P("ep", "fsdp", "tp")
 
 
+class TestActivationLayout:
+    """The training programs say where their activations live
+    (``parallel/sharding.constrain``): only under an ambient mesh, and
+    without changing what the step computes."""
+
+    @pytest.mark.parametrize("routed", [False, True],
+                             ids=["dense", "routed"])
+    def test_constrained_step_matches_one_device(self, routed):
+        """Three steps under fsdp=2 x tp=2 with the mesh ambient, so that
+        the constraints engage, against the single-device step."""
+        from ray_tpu.models import (MoEConfig, moe_init, moe_loss,
+                                    moe_sharding_rules)
+
+        if routed:
+            cfg = MoEConfig.tiny(dtype=jnp.float32, remat=False)
+            init, loss, rules = moe_init, moe_loss, moe_sharding_rules()
+        else:
+            cfg = LlamaConfig.tiny(dtype=jnp.float32, remat=True,
+                                   loss_chunk=16)
+            init, loss, rules = llama_init, llama_loss, llama_sharding_rules()
+        params = init(cfg, jax.random.PRNGKey(0))
+        mesh = make_mesh(MeshConfig(fsdp=2, tp=2), devices=jax.devices()[:4])
+        toks = _tokens(cfg, B=4, S=32)
+        batch = {"tokens": toks, "targets": jnp.roll(toks, -1, axis=1)}
+        tx = default_optimizer(lr=1e-3)
+        loss_fn = lambda p, b: loss(cfg, p, b["tokens"], b["targets"])
+
+        state_r = TrainState.create(jax.tree.map(jnp.copy, params), tx)
+        step_r = make_train_step(loss_fn, tx)
+        state_s = shard_train_state(
+            TrainState.create(jax.tree.map(jnp.copy, params), tx), mesh, rules)
+        step_s = make_train_step(loss_fn, tx, mesh, rules)
+
+        def constraints(lowered):
+            return lowered.as_text().count("sharding_constraint")
+
+        # With no ambient mesh the step holds only the parameters' and the
+        # batch's constraints; under one, the activations' too.
+        bare = constraints(step_s.lower(state_s, batch))
+        with set_mesh(mesh):
+            assert constraints(step_s.lower(state_s, batch)) \
+                >= bare + 6 * cfg.n_layers
+            sharded = []
+            for _ in range(3):
+                state_s, m = step_s(state_s, batch)
+                sharded.append((float(m["loss"]), float(m["grad_norm"])))
+        for loss_s, gnorm_s in sharded:
+            state_r, m = step_r(state_r, batch)
+            assert abs(loss_s - float(m["loss"])) < 1e-3
+            assert gnorm_s == pytest.approx(float(m["grad_norm"]), rel=1e-3)
+
+    def test_no_mesh_no_constraint(self):
+        """With no ambient mesh, and inside ``shard_map``, ``constrain``
+        returns its argument: such a program traces to what it would
+        without the call."""
+        from ray_tpu.parallel import constrain
+        from ray_tpu.parallel.sharding import RESIDUAL
+
+        x = jnp.zeros((4, 8, 16))
+        assert constrain(x, RESIDUAL) is x
+        mesh = make_mesh(MeshConfig(fsdp=2, tp=2), devices=jax.devices()[:4])
+        seen = []
+
+        def per_shard(y):
+            seen.append(constrain(y, RESIDUAL) is y)
+            return y
+
+        with set_mesh(mesh):
+            assert "sharding_constraint" in jax.jit(
+                lambda y: constrain(y, RESIDUAL)).lower(x).as_text()
+            jax.eval_shape(jax.shard_map(
+                per_shard, in_specs=P("fsdp"), out_specs=P("fsdp")), x)
+        assert seen == [True]
+
+
 class TestPipelineParallel:
     """GPipe-style in-jit pipeline over the pp mesh axis (the in-model
     counterpart of the actor pipelines in ray_tpu.dag; the reference's only

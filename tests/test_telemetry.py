@@ -194,6 +194,30 @@ def test_train_telemetry_step_context():
     assert tel.last["tokens_per_sec"] > 0
 
 
+def test_train_telemetry_counts_the_compiled_steps_collectives():
+    """A loop hands its compiled step over once: the collectives it holds,
+    by kind, returned and on the gauge, a series a rank and kind."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.train.telemetry import TrainTelemetry
+
+    mesh = Mesh(jax.devices()[:2], ("tp",))
+    rows = NamedSharding(mesh, P(None, "tp"))
+    compiled = jax.jit(lambda a, b: a @ b, in_shardings=(
+        rows, NamedSharding(mesh, P("tp", None))),
+        out_shardings=NamedSharding(mesh, P())).lower(
+            jnp.ones((8, 16)), jnp.ones((16, 8))).compile()
+    tel = TrainTelemetry(rank=3)
+    counts = tel.record_compiled(compiled)
+    assert counts["all-reduce"] == 1 and counts["all-to-all"] == 0
+    series = {tuple(sorted(r["tags"].items())): r["value"]
+              for r in tel._g_collectives._snapshot()}
+    assert series[(("kind", "all-reduce"), ("rank", "3"))] == 1
+    assert series[(("kind", "all-to-all"), ("rank", "3"))] == 0
+
+
 def test_session_report_augments_goodput():
     """report() derives step_time_s / tokens_per_sec / mfu for each round
     after the first, without clobbering user keys."""
