@@ -7,7 +7,11 @@ between them is the attention: each caller hands ``decoder_layer`` an
 ``attend(q, k, v)`` closure that owns rotary, any cache write and the
 attention arithmetic (flash or ring in training, the paged pool's gather
 in decode), and, where it serves adapters, a ``lora(name, h)`` closure
-for the deltas on the q and v projections.
+for the deltas on the q and v projections.  Where attention goes through a
+compressed latent (``is_latent``) the closure is handed the latent and
+chooses the form: ``attend(q, c, k_r, wkv_b)`` expands K and V out of it
+(the full forward, a cold prefill) or absorbs ``wkv_b`` into q and the
+output (the paged cache's programs).
 
 ``llama`` and ``moe`` are reached through their modules, at call time: the
 helpers a test swaps there (``llama._qk_norm``, ``moe._moe_ffn``) are the
@@ -32,10 +36,47 @@ from ..parallel.sharding import (HEADS, RESIDUAL, SPLIT, VOCAB_ROWS,
 from . import llama, moe
 
 Params = Dict[str, Any]
-#: ``attend(q [..., H, D], k, v [..., H_kv, D]) -> [..., H*D]``
-Attend = Callable[[jax.Array, jax.Array, jax.Array], jax.Array]
+#: ``attend(q [..., H, D], k, v [..., H_kv, D]) -> [..., H*D]``; of a
+#: latent model ``attend(q, c [..., rank], k_r [..., rope], wkv_b)``
+Attend = Callable[..., jax.Array]
 #: ``lora("wq" | "wv", h [..., d]) -> the projection's low-rank delta``
 Lora = Callable[[str, jax.Array], jax.Array]
+
+
+def is_latent(config) -> bool:
+    """Whether ``config``'s attention goes through a compressed latent
+    (``MoEConfig.kv_lora_rank``): the cache then keeps one latent row a
+    token and layer, not K and V."""
+    return getattr(config, "kv_lora_rank", 0) > 0
+
+
+def rotary_dim(config) -> int:
+    """How many of a head's dimensions rotate with position: all of
+    ``head_dim``, or a latent model's ``qk_rope_head_dim``."""
+    return getattr(config, "qk_rope_head_dim", 0) or config.head_dim
+
+
+def project_latent(config, a: Params, h: jax.Array):
+    """Latent attention's projections of normalised ``h`` [..., d]:
+    q [..., H, nope + rope] through its latent and that latent's norm (not
+    rotated yet), the normalised K/V latent c [..., kv_lora_rank], and the
+    one rotary key k_r [..., rope] every head shares (not rotated yet).
+    ``[c ; RoPE(k_r)]`` is what a cache keeps of the token."""
+    q = rms_norm(h @ a["wq_a"], a["q_norm"], config.norm_eps) @ a["wq_b"]
+    kv = h @ a["wkv_a"]
+    rank = config.kv_lora_rank
+    c = rms_norm(kv[..., :rank], a["kv_norm"], config.norm_eps)
+    q = q.reshape(*h.shape[:-1], config.n_heads, config.head_dim)
+    return constrain(q, HEADS), c, kv[..., rank:]
+
+
+def latent_up(config, wkv_b: jax.Array):
+    """``wkv_b`` [kv_lora_rank, H * (nope + v)] as its two halves:
+    (W_uk [rank, H, nope], W_uv [rank, H, v]), the maps from the latent to
+    a head's unrotated key and to its value."""
+    w = wkv_b.reshape(config.kv_lora_rank, config.n_heads, -1)
+    return (w[..., :config.qk_nope_head_dim],
+            w[..., config.qk_nope_head_dim:])
 
 
 def project_qkv(config, a: Params, h: jax.Array,
@@ -57,9 +98,14 @@ def project_qkv(config, a: Params, h: jax.Array,
 
 def attention(config, a: Params, h: jax.Array, attend: Attend,
               lora: Optional[Lora] = None) -> jax.Array:
-    """Attention of normalised ``h`` through the output projection."""
-    out = constrain(attend(*project_qkv(config, a, h, lora)), SPLIT)
-    return out @ a["wo"]
+    """Attention of normalised ``h`` through the output projection.  A
+    latent layer takes no adapter: the deltas on ``wq`` and ``wv`` have no
+    counterpart among its projections (the engine refuses to load one)."""
+    if is_latent(config):
+        out = attend(*project_latent(config, a, h), a["wkv_b"])
+    else:
+        out = attend(*project_qkv(config, a, h, lora))
+    return constrain(out, SPLIT) @ a["wo"]
 
 
 def layer_window(config, i: int) -> int:
@@ -84,11 +130,17 @@ def layer_kind(config, i: int) -> Tuple[int, bool]:
     return layer_window(config, i), layer_rotary(config, i)
 
 
-def is_routed(config) -> bool:
-    """Whether the FFN of ``config``'s layers is routed (``moe.py``) or
-    dense (``llama.py``): the one place under ``ray_tpu/`` that asks what
-    a configuration object is."""
-    return isinstance(config, moe.MoEConfig)
+def is_routed(config, i: Optional[int] = None) -> bool:
+    """Whether the FFN of layer ``i`` of ``config`` is routed (``moe.py``)
+    or dense (``llama.py``); with no layer named, whether any layer's is
+    (then the programs carry routing counters).  The one place under
+    ``ray_tpu/`` that asks what a configuration object is."""
+    if not isinstance(config, moe.MoEConfig):
+        return False
+    layout = config.ffn_layout
+    if not layout:
+        return True
+    return any(layout) if i is None else bool(layout[i])
 
 
 def init_and_apply(config):
@@ -102,38 +154,42 @@ def init_and_apply(config):
 
 def ffn(config, layer: Params, x: jax.Array,
         valid: Optional[jax.Array] = None,
-        logits: Optional[jax.Array] = None):
+        logits: Optional[jax.Array] = None, *, routed: bool):
     """The second half of the block, x + FFN(norm(x)), dense or routed (a
     trace-time branch, so a dense model compiles to the program it always
-    did).  ``valid`` marks the rows that hold a real token; only a routed
-    FFN looks at it.  ``logits`` are the router's where ``decoder_layer``
-    took them before attention.  Returns (x, the routed layer's
-    load-balancing loss, its per-expert token counts [E]); the last two are
-    None where the FFN is dense."""
-    if is_routed(config):
+    did).  ``routed`` is ``is_routed`` of this layer, asked by the program
+    that walks the layers.  ``valid`` marks the rows that hold a real
+    token; only a routed FFN looks at it.  ``logits`` are the router's where
+    ``decoder_layer`` took them before attention.  Returns (x, the routed
+    layer's load-balancing loss, its per-expert token counts [E]); the last
+    two are None where the FFN is dense."""
+    if routed:
         h = rms_norm(x, layer["moe_norm"], config.norm_eps)
         # Only a layer that took them early passes ``logits``: the tests'
         # stand-ins for ``_moe_ffn`` keep its older signature.
         early = {} if logits is None else {"logits": logits}
         out, aux, counts = moe._moe_ffn(config, layer["moe"], h, valid,
                                         **early)
+        if config.n_shared_experts:
+            out = out + moe._shared_expert(config, layer["moe"]["shared"],
+                                           h, valid)
         return constrain(x + out, RESIDUAL), aux, counts
     h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
     return constrain(x + llama._mlp(layer, h), RESIDUAL), None, None
 
 
 def decoder_layer(config, layer: Params, x: jax.Array, attend: Attend, *,
-                  lora: Optional[Lora] = None,
+                  routed: bool, lora: Optional[Lora] = None,
                   valid: Optional[jax.Array] = None):
-    """One pre-norm decoder layer on x [..., d]; returns what ``ffn``
-    returns."""
+    """One pre-norm decoder layer on x [..., d] (``routed``: see ``ffn``);
+    returns what ``ffn`` returns."""
     h = rms_norm(x, layer["attn_norm"], config.norm_eps)
     logits = None
     if getattr(config, "router_before_attn", False):
         logits = moe.router_logits(layer["moe"], h)
     x = constrain(x + attention(config, layer["attn"], h, attend, lora),
                   RESIDUAL)
-    return ffn(config, layer, x, valid, logits)
+    return ffn(config, layer, x, valid, logits, routed=routed)
 
 
 def decoder_stack(config, params: Params, tokens: jax.Array,

@@ -281,6 +281,35 @@ def _attend(config, cos, sin, q, k, v, kind=(0, True)):
     return out.transpose(0, 2, 1, 3).reshape(B, S, -1)
 
 
+def _attend_latent(config, cos, sin, q, c, k_r, wkv_b):
+    """The full forward's ``attend`` of a latent model (``block.is_latent``),
+    the EXPANDED form: every position's keys and values are made out of its
+    latent c [B, S, rank] by ``wkv_b``, the one rotary key k_r [B, S, rope]
+    is rotated and shared by the heads, q [B, S, H, nope + rope] rotates its
+    last ``rope`` dimensions, and the scores are scaled by
+    ``(nope + rope) ** -0.5``.  Plain masked attention (there is no flash
+    kernel for keys and values of two widths: ROADMAP M3).  Returns
+    [B, S, H * v]."""
+    from . import block
+
+    B, S, H, _ = q.shape
+    nope = config.qk_nope_head_dim
+    w_uk, w_uv = block.latent_up(config, wkv_b)
+    k_n = jnp.einsum("bsc,chd->bshd", c, w_uk)
+    v = jnp.einsum("bsc,chd->bshd", c, w_uv)
+    q_r = apply_rotary(q[..., nope:].transpose(0, 2, 1, 3), cos, sin)
+    k_r = apply_rotary(k_r[:, None], cos, sin)             # [B, 1, S, rope]
+    scores = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :nope], k_n,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bhqd,bkd->bhqk", q_r, k_r[:, 0],
+                           preferred_element_type=jnp.float32)) \
+        * (config.head_dim ** -0.5)
+    i, j = jnp.arange(S)[:, None], jnp.arange(S)[None, :]
+    scores = jnp.where(j <= i, scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(B, S, -1)
+
+
 def _lora(lora_layer: Optional[Params]):
     """``block``'s ``lora`` closure over one layer of a ``lora_init``
     tree: delta = h @ A @ B * (alpha/r)."""
@@ -308,22 +337,29 @@ def _mlp(layer, x):
     return (gate * constrain(x @ m["w3"], SPLIT)) @ m["w2"]
 
 
-def _layer(config, x, layer, cos, sin, lora_layer=None, kind=(0, True)):
+def _layer(config, x, layer, cos, sin, lora_layer, kind, routed):
     """One decoder layer of a training program, dense or routed, with its
     arrays as arguments (what ``jax.checkpoint`` wraps; ``kind``, the
-    layer's ``block.layer_kind``, is static, so layers of one kind and
-    shape share a trace): (x, the routed FFN's aux loss or None)."""
+    layer's ``block.layer_kind``, and ``routed``, its ``block.is_routed``,
+    are static, so layers of one kind and shape share a trace): (x, the
+    routed FFN's aux loss or None)."""
     from . import block
 
-    x, aux, _ = block.decoder_layer(
-        config, layer, x,
-        functools.partial(_attend, config, cos, sin, kind=kind),
-        lora=_lora(lora_layer))
+    attend = functools.partial(_attend_latent, config, cos, sin) \
+        if block.is_latent(config) \
+        else functools.partial(_attend, config, cos, sin, kind=kind)
+    x, aux, _ = block.decoder_layer(config, layer, x, attend,
+                                    lora=_lora(lora_layer), routed=routed)
     return x, aux
 
 
 def _block(config: LlamaConfig, x, layer, cos, sin, lora_layer=None):
-    return _layer(config, x, layer, cos, sin, lora_layer)[0]
+    """A layer of a stack whose layers are all alike (the pipeline stage
+    scans over them, so no layer has a number to ask about)."""
+    from . import block
+
+    return _layer(config, x, layer, cos, sin, lora_layer, (0, True),
+                  block.is_routed(config))[0]
 
 
 def llama_apply(
@@ -345,7 +381,7 @@ def hidden_and_aux(config, params: Params, tokens: jax.Array,
     from . import block
 
     cos, sin = rope_frequencies(
-        config.head_dim, config.max_seq, config.rope_theta
+        block.rotary_dim(config), config.max_seq, config.rope_theta
     )
     layer_fn = _layer
     if config.remat:
@@ -373,14 +409,15 @@ def hidden_and_aux(config, params: Params, tokens: jax.Array,
                 (cps.checkpoint_dots_with_no_batch_dims, True),
         }[_option(config, "remat_policy")]
         layer_fn = jax.checkpoint(
-            _layer, static_argnums=(0, 6), policy=policy,
+            _layer, static_argnums=(0, 6, 7), policy=policy,
             prevent_cse=prevent_cse,
         )
 
     def run(i, layer, x):
         ll = lora_params["layers"][i] if lora_params is not None else None
         return (*layer_fn(config, x, layer, cos, sin, ll,
-                          block.layer_kind(config, i)), None)
+                          block.layer_kind(config, i),
+                          block.is_routed(config, i)), None)
 
     hidden, auxes, _ = block.decoder_stack(config, params, tokens, run)
     return hidden, auxes

@@ -1,5 +1,5 @@
-"""Mixture-of-Experts decoder (Mixtral, OLMoE, SmallThinker), TPU-first
-with expert parallelism.
+"""Mixture-of-Experts decoder (Mixtral, OLMoE, SmallThinker, GLM-4.7-Flash),
+TPU-first with expert parallelism.
 
 The reference framework has no MoE/EP feature (SURVEY §2.4: expert parallel
 "absent as a framework feature") — this is a net-new, first-class TPU
@@ -8,7 +8,9 @@ dimension.
 
 Design (token-choice top-k, no capacity and no drops):
 - router: logits [.., E] in float32; softmax over all experts, the top-k
-  probabilities kept as they are (OLMoE) or renormalised (Mixtral)
+  probabilities kept as they are (OLMoE) or renormalised (Mixtral); or
+  sigmoid scores, the top-k chosen by score plus a bias that enters the
+  choice only, renormalised and scaled (GLM-4.7-Flash)
 - the tokens x top_k (token, expert) pairs are sorted by expert, so each
   expert's rows are one contiguous group of a [tokens*k, d] array
 - experts: three grouped products over those groups (``jax.lax.ragged_dot``,
@@ -36,7 +38,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.mesh import AXIS_EP, AXIS_FSDP, AXIS_TP
 from ..parallel.sharding import ShardingRules
-from .llama import (LlamaConfig, hidden_and_aux, llama_sharding_rules,
+from .llama import (LlamaConfig, _mlp, hidden_and_aux, llama_sharding_rules,
                     qk_norm_init)
 
 Params = Dict[str, Any]
@@ -58,7 +60,23 @@ class MoEConfig:
     window anywhere, rotary everywhere); ``expert_act`` (the gate's
     non-linearity: ``silu``, or ``relu`` for a gated ReLU); and
     ``router_before_attn`` (the router reads the layer's normalised INPUT,
-    the activations attention reads, not the FFN's)."""
+    the activations attention reads, not the FFN's).
+
+    GLM-4.7-Flash's fields.  Latent attention (``kv_lora_rank`` > 0; 0:
+    ordinary attention, and the other four are 0 too): q through a latent
+    of ``q_lora_rank`` and its norm, K and V through one of
+    ``kv_lora_rank`` and its norm, beside ONE rotary key of
+    ``qk_rope_head_dim`` that every head shares; a head's q and k are
+    ``qk_nope_head_dim`` (no position) + ``qk_rope_head_dim`` wide
+    (``head_dim`` is their sum), its v ``v_head_dim``.  ``ffn_layout[i]``
+    false: layer ``i`` has a dense SwiGLU of ``dense_d_ff`` in place of the
+    routed FFN (read through ``block.is_routed``; empty: every layer is
+    routed).  ``n_shared_experts`` experts, ``d_ff`` wide each, take every
+    token beside the routed ones.  ``router_score`` ``sigmoid``: the
+    experts are scored each on its own, the ``top_k`` largest of score plus
+    the layer's ``router_bias`` are taken, and their weights are the bare
+    scores (renormalised where ``norm_topk_prob``); every routed weight is
+    multiplied by ``routed_scaling_factor``."""
 
     vocab_size: int = 32000
     d_model: int = 4096
@@ -82,12 +100,34 @@ class MoEConfig:
     rope_layout: Tuple[int, ...] = ()
     expert_act: str = "silu"
     router_before_attn: bool = False
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    ffn_layout: Tuple[int, ...] = ()
+    dense_d_ff: int = 0
+    n_shared_experts: int = 0
+    router_score: str = "softmax"
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self):
+        latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                  self.qk_rope_head_dim, self.v_head_dim)
+        if any(latent):
+            if not all(x > 0 for x in latent) or self.qk_rope_head_dim % 2:
+                raise ValueError(f"latent attention needs all five of its "
+                                 f"widths, got {latent}")
+            if self.n_kv_heads != self.n_heads or self.qk_norm:
+                raise ValueError("latent attention has a key for every head "
+                                 "and no QK-norm")
+            object.__setattr__(
+                self, "head_dim",
+                self.qk_nope_head_dim + self.qk_rope_head_dim)
         if not self.head_dim:
             object.__setattr__(self, "head_dim",
                                self.d_model // self.n_heads)
-        for name in ("window_layout", "rope_layout"):
+        for name in ("window_layout", "rope_layout", "ffn_layout"):
             layout = tuple(int(x) for x in getattr(self, name))
             if layout and len(layout) != self.n_layers:
                 raise ValueError(f"{name} has {len(layout)} entries for "
@@ -98,19 +138,35 @@ class MoEConfig:
                              "is not positive")
         if self.expert_act not in ("silu", "relu"):
             raise ValueError(f"expert_act {self.expert_act!r}")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score {self.router_score!r}")
+        if self.ffn_layout and not all(self.ffn_layout) \
+                and self.dense_d_ff <= 0:
+            raise ValueError("ffn_layout names dense layers and dense_d_ff "
+                             "is not positive")
 
     def param_count(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
-        per_layer = (
-            2 * d * q + 2 * d * kv               # attention
-            + d * self.n_experts                 # router
-            + self.n_experts * 3 * d * f         # experts
-            + 2 * d
-        )
+        attn = 2 * d * q + 2 * d * kv
         if self.qk_norm:
-            per_layer += q + kv
-        return v * d + self.n_layers * per_layer + d + d * v
+            attn += q + kv
+        if self.kv_lora_rank:
+            rq, rkv, rope = (self.q_lora_rank, self.kv_lora_rank,
+                             self.qk_rope_head_dim)
+            attn = (d * rq + rq + rq * q + d * (rkv + rope) + rkv
+                    + rkv * self.n_heads * (self.qk_nope_head_dim
+                                            + self.v_head_dim)
+                    + self.n_heads * self.v_head_dim * d)
+        routed = (d * self.n_experts                 # router
+                  + self.n_experts * 3 * d * f       # experts
+                  + self.n_shared_experts * 3 * d * f)
+        if self.router_score == "sigmoid":
+            routed += self.n_experts                 # the selection bias
+        n_routed = sum(self.ffn_layout) if self.ffn_layout else self.n_layers
+        return (v * d + self.n_layers * (attn + 2 * d) + n_routed * routed
+                + (self.n_layers - n_routed) * 3 * d * self.dense_d_ff
+                + d + d * v)
 
     def as_llama(self) -> LlamaConfig:
         """The attention fields as a ``LlamaConfig``.  No program needs it
@@ -149,6 +205,11 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
             config.dtype
         )
 
+    def swiglu(ks, width):
+        return {"w1": dense(ks[0], (d, width), std),
+                "w3": dense(ks[1], (d, width), std),
+                "w2": dense(ks[2], (width, d), width ** -0.5)}
+
     params: Params = {
         "embed": dense(keys[0], (config.vocab_size, d), 1.0),
         "final_norm": jnp.ones((d,), config.dtype),
@@ -157,26 +218,59 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
     }
     for i in range(config.n_layers):
         ks = jax.random.split(keys[2 + i], 8)
-        params["layers"].append({
-            "attn_norm": jnp.ones((d,), config.dtype),
-            "attn": {
+        # What only GLM-4.7-Flash's line has draws from keys of its own,
+        # so that the older architectures' weights are what they were.
+        more = jax.random.split(jax.random.fold_in(keys[2 + i], 1), 8)
+        layer = {"attn_norm": jnp.ones((d,), config.dtype)}
+        if config.kv_lora_rank:
+            rq, rkv = config.q_lora_rank, config.kv_lora_rank
+            o_in = config.n_heads * config.v_head_dim
+            layer["attn"] = {
+                "wq_a": dense(ks[0], (d, rq), std),
+                "q_norm": jnp.ones((rq,), config.dtype),
+                "wq_b": dense(ks[1], (rq, q_out), rq ** -0.5),
+                "wkv_a": dense(ks[2], (d, rkv + config.qk_rope_head_dim),
+                               std),
+                "kv_norm": jnp.ones((rkv,), config.dtype),
+                "wkv_b": dense(more[0], (rkv, config.n_heads * (
+                    config.qk_nope_head_dim + config.v_head_dim)),
+                    rkv ** -0.5),
+                "wo": dense(ks[3], (o_in, d), o_in ** -0.5),
+            }
+        else:
+            layer["attn"] = {
                 "wq": dense(ks[0], (d, q_out), std),
                 "wk": dense(ks[1], (d, kv_out), std),
                 "wv": dense(ks[2], (d, kv_out), std),
                 "wo": dense(ks[3], (q_out, d), q_out ** -0.5),
-            },
-            "moe_norm": jnp.ones((d,), config.dtype),
-            "moe": {
-                # Router in fp32: tiny, and top-k boundaries are precision
-                # sensitive.
-                "router": jax.random.normal(ks[4], (d, E), jnp.float32) * std,
-                "w1": dense(ks[5], (E, d, f), std),
-                "w3": dense(ks[6], (E, d, f), std),
-                "w2": dense(ks[7], (E, f, d), f ** -0.5),
-            },
-        })
+            }
         if config.qk_norm:
-            params["layers"][-1]["attn"].update(qk_norm_init(config))
+            layer["attn"].update(qk_norm_init(config))
+        params["layers"].append(layer)
+        if config.ffn_layout and not config.ffn_layout[i]:  # a dense layer
+            layer["mlp_norm"] = jnp.ones((d,), config.dtype)
+            layer["mlp"] = swiglu(ks[5:], config.dense_d_ff)
+            continue
+        layer["moe_norm"] = jnp.ones((d,), config.dtype)
+        layer["moe"] = {
+            # Router in fp32: tiny, and top-k boundaries are precision
+            # sensitive.
+            "router": jax.random.normal(ks[4], (d, E), jnp.float32) * std,
+            "w1": dense(ks[5], (E, d, f), std),
+            "w3": dense(ks[6], (E, d, f), std),
+            "w2": dense(ks[7], (E, f, d), f ** -0.5),
+        }
+        if config.router_score == "sigmoid":
+            # Not zero (that would leave "the choice only" untested) and
+            # small against the scores' spread: the k-th and next score
+            # of E lie about 1 / E apart, so a bias of that size flips
+            # near ties and leaves the experts' load as the router has it
+            # (the published bias exists to even that load, not skew it).
+            layer["moe"]["router_bias"] = jax.random.normal(
+                more[1], (E,), jnp.float32) / (2 * E)
+        if config.n_shared_experts:
+            layer["moe"]["shared"] = swiglu(
+                more[2:], config.n_shared_experts * f)
     return params
 
 
@@ -206,16 +300,43 @@ def _route(config: MoEConfig, moe: Params, xf: jax.Array,
            logits: Optional[jax.Array] = None
            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The router on tokens xf [G, d] (or on ``logits`` [G, E] taken
-    earlier in the layer, ``router_before_attn``): the softmax over all
-    experts [G, E], and each token's top-k probabilities and experts [G, k]
-    (renormalised to sum to 1 only where the architecture does)."""
+    earlier in the layer, ``router_before_attn``): each expert's share of
+    the router's mass [G, E] (the softmax over all experts; of sigmoid
+    scores, each over their sum), and each token's top-k weights and
+    experts [G, k] (renormalised to sum to 1 only where the architecture
+    does, then scaled by ``routed_scaling_factor``).  Sigmoid scores are
+    ranked with the layer's ``router_bias`` added; the weights are the
+    scores without it."""
     if logits is None:
         logits = router_logits(moe, xf)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, config.top_k)
-    if config.norm_topk_prob:
-        top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if config.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, top_e = jax.lax.top_k(scores + moe["router_bias"], config.top_k)
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+        probs = scores / scores.sum(-1, keepdims=True)
+        if config.norm_topk_prob:
+            top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, config.top_k)
+        if config.norm_topk_prob:
+            top_p = top_p / jnp.maximum(top_p.sum(-1, keepdims=True), 1e-9)
+    if config.routed_scaling_factor != 1.0:
+        top_p = top_p * config.routed_scaling_factor
     return probs, top_p, top_e
+
+
+def _shared_expert(config: MoEConfig, shared: Params, x: jax.Array,
+                   valid: Optional[jax.Array] = None) -> jax.Array:
+    """The experts every token visits, on x [..., d]: ``n_shared_experts``
+    SwiGLUs of ``d_ff``, which is one of their widths together.  No router
+    sees them and no counter counts them; a row that holds no token
+    (``valid`` false) gets zero, as from the routed experts."""
+    with jax.named_scope("moe_shared"):
+        out = _mlp({"mlp": shared}, x)
+        if valid is not None:
+            out = jnp.where(valid[..., None], out, 0)
+    return out.astype(config.dtype)
 
 
 def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
@@ -275,8 +396,9 @@ def moe_apply(config: MoEConfig, params: Params, tokens: jax.Array
     """Returns (logits [B, S, vocab] fp32, aux_loss scalar)."""
     x, auxes = hidden_and_aux(config, params, tokens)
     logits = (x @ params["lm_head"]).astype(jnp.float32)
+    auxes = [a for a in auxes if a is not None]  # a dense layer has none
     return logits, sum(auxes, jnp.zeros((), jnp.float32)) \
-        / max(config.n_layers, 1)
+        / max(len(auxes), 1)
 
 
 def moe_loss(config: MoEConfig, params: Params, tokens: jax.Array,

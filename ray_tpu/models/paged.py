@@ -39,6 +39,19 @@ slot holds is arithmetic on the last position written
 (``_ring_positions``).  A configuration without a pattern has the one
 kind, the one pool pair and the programs it always had.
 
+A third kind where attention goes through a compressed latent
+(``block.is_latent``): ONE pool ``kv`` of latent rows
+``[L, P+1, page, latent_row_width]``, a token's ``[norm(c_kv) ; RoPE(k_r)]``
+on a layer (``kv_lora_rank + qk_rope_head_dim`` numbers, then zeros up to
+whole 128-lane tiles: ``latent_row_width``), behind the page tables the
+whole-length kind uses (every layer keeps every page, so a radix node is
+still one page valid for every layer).  The decode step and the prefill
+over cached rows attend ABSORBED, in the latent (``_latent_attend``): the
+row is the key of ONE KV head that all query heads share, and its first
+``kv_lora_rank`` columns are that head's value; ``wkv_b`` is multiplied
+into the queries and into the output, never into the cached rows.  The
+cold prefill expands its own chunk's keys and values.
+
 Compile counts are observable via ``trace_count()`` — the jitted bodies
 bump a counter when TRACED (python executes only at trace time), which is
 how tests assert the engine never recompiles after warmup.
@@ -114,12 +127,28 @@ def ring_entries(config, page_size: int, chunk: int) -> int:
     return -(-config.window // page_size) + -(-chunk // page_size)
 
 
+def latent_row_width(config) -> int:
+    """The width of a latent pool's rows: ``kv_lora_rank +
+    qk_rope_head_dim`` rounded up to whole tiles of 128 lanes (576 -> 640).
+    A pool whose rows are not whole tiles gets a page-minor layout from the
+    TPU compiler, which then copies the WHOLE pool to a row-minor one and
+    back around every layer's write (4.8 GB of temporaries at the
+    benchmark's geometry, compiled for a v5e); row-minor, the tiles hold
+    the padding anyway."""
+    return -(-(config.kv_lora_rank + config.qk_rope_head_dim) // 128) * 128
+
+
 def init_paged_pools(config: LlamaConfig, num_pages: int,
                      page_size: int, window_pages: int = 0) -> PagedPools:
-    """One pool pair a kind of layer for the whole replica; the last index
+    """One pool pair a kind of layer for the whole replica (of a latent
+    model, the one pool ``kv`` of latent rows); the last index
     (``num_pages``, ``window_pages``) of each is its scratch page (writes
     routed there are never read)."""
     whole, window = kv_layers(config)
+    if block.is_latent(config):
+        return {"kv": jnp.zeros(
+            (len(whole), num_pages + 1, page_size,
+             latent_row_width(config)), config.dtype)}
 
     def pair(suffix, n_layers, pages):
         shape = (n_layers, pages + 1, page_size,
@@ -133,8 +162,14 @@ def init_paged_pools(config: LlamaConfig, num_pages: int,
     return pools
 
 
+def _whole(pools: PagedPools) -> jax.Array:
+    """A pool behind the whole-length page tables: ``k``, or a latent
+    model's ``kv``."""
+    return pools["kv"] if "kv" in pools else pools["k"]
+
+
 def _page_size(pools: PagedPools) -> int:
-    return pools["k"].shape[2]
+    return _whole(pools).shape[2]
 
 
 def _write_rows(pool: jax.Array, layer: int, page_idx: jax.Array,
@@ -165,11 +200,11 @@ def _ring_positions(last: jax.Array, entries: int, ps: int) -> jax.Array:
 
 
 def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
-                  v_pool: jax.Array, layer: int, tables: jax.Array,
-                  visible: jax.Array) -> jax.Array:
+                  v_pool: Optional[jax.Array], layer: int,
+                  tables: jax.Array, visible: jax.Array) -> jax.Array:
     """Attention of q [B, Q, H, D] over the pages of tables [B, MAXP]:
     visible [B, Q, MAXP*page] bool says which gathered positions a query
-    may see (scratch and unwritten ones never).  Returns [B, Q, H*D].
+    may see (scratch and unwritten ones never).  Returns [B, Q, H*D_v].
 
     A table's pages reshape to a linear ``[B, MAXP*page, H_kv, D]`` view
     as gathered; queries are grouped ``[.., H_kv, n_rep, D]`` and
@@ -178,13 +213,19 @@ def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
     float32, so this is what upcasting the gathered K first computed,
     without the float32 copy.  Where the whole score matrix would pass
     ``SCORE_BLOCK_BYTES`` (a prefill chunk over a long table) the queries
-    are walked in equal blocks, one at a time (``lax.map``)."""
+    are walked in equal blocks, one at a time (``lax.map``).
+
+    ``v_pool`` None: the latent pool (rows ``[.., D]``, no head axis).  A
+    row is the key of one KV head that every query head shares, and its
+    first ``kv_lora_rank`` columns are its value (one gather serves both);
+    the scores are scaled for the width of an expanded head's q and k."""
     B, Q = q.shape[:2]
-    n_rep = config.n_heads // config.n_kv_heads
-    k_seq = k_pool[layer, tables].reshape(
-        B, -1, config.n_kv_heads, config.head_dim)
-    v_seq = v_pool[layer, tables].reshape(k_seq.shape)
-    qg = q.reshape(B, Q, config.n_kv_heads, n_rep, config.head_dim)
+    n_kv = config.n_kv_heads if v_pool is not None else 1
+    n_rep = config.n_heads // n_kv
+    k_seq = k_pool[layer, tables].reshape(B, -1, n_kv, q.shape[-1])
+    v_seq = v_pool[layer, tables].reshape(k_seq.shape) \
+        if v_pool is not None else k_seq[..., :config.kv_lora_rank]
+    qg = q.reshape(B, Q, n_kv, n_rep, q.shape[-1])
 
     def attend(qg, visible):
         scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_seq,
@@ -211,9 +252,13 @@ def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
 
 #: What ``_with_routing`` appends, in its order: the step record's keys.
 ROUTING_KEYS = ("experts_hit", "expert_pairs", "expert_load_max")
-#: What the decode step of a configuration with window layers appends
-#: behind them (``_kv_rows``).
+#: What the decode step of a configuration with window layers, or with a
+#: latent pool, appends behind them (``_with_kv_rows``).
 KV_KEYS = ("kv_rows_read", "kv_rows_live")
+
+
+def _counts_kv_rows(config) -> bool:
+    return bool(kv_layers(config)[1]) or block.is_latent(config)
 
 
 def counter_keys(config) -> tuple:
@@ -221,7 +266,7 @@ def counter_keys(config) -> tuple:
     appends to the tokens it returns, in their order (a prefill appends
     the ``ROUTING_KEYS`` among them)."""
     return (ROUTING_KEYS if block.is_routed(config) else ()) \
-        + (KV_KEYS if kv_layers(config)[1] else ())
+        + (KV_KEYS if _counts_kv_rows(config) else ())
 
 
 def routing_width(config) -> int:
@@ -236,10 +281,12 @@ def _with_routing(toks: jax.Array, counts: List[Optional[jax.Array]]
     program's ``ROUTING_KEYS``: over all layers, the experts that got a
     token, the (token, expert) pairs routed, and the most tokens on one
     expert of one layer.  They ride in the array the engine reads back
-    anyway, so they cost it no transfer of their own."""
-    if counts[0] is None:
+    anyway, so they cost it no transfer of their own.  A dense layer among
+    routed ones (its entry is None) has no experts to count."""
+    counts = [c for c in counts if c is not None]
+    if not counts:
         return toks
-    c = jnp.stack(counts)  # [L, E]
+    c = jnp.stack(counts)  # [routed layers, E]
     return jnp.concatenate([toks, jnp.stack(
         [jnp.sum(c > 0, dtype=jnp.int32), jnp.sum(c), jnp.max(c)])])
 
@@ -247,18 +294,18 @@ def _with_routing(toks: jax.Array, counts: List[Optional[jax.Array]]
 def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
                   ring_tables: Optional[jax.Array], seq_lens: jax.Array,
                   active: jax.Array, ps: int) -> jax.Array:
-    """``toks`` followed (where ``config`` has window layers) by the decode
-    step's ``KV_KEYS``, summed over layers and slots: the rows of K its
-    gathers brought in (every slot's whole table, live or not: that is
-    what the program reads), and the rows a query could see
-    (``len + 1`` on a whole-length layer, at most the window on a window
-    layer, nothing in an empty slot)."""
+    """``toks`` followed (where ``config`` has window layers or a latent
+    pool) by the decode step's ``KV_KEYS``, summed over layers and slots:
+    the rows of K (of the latent pool) its gathers brought in (every
+    slot's whole table, live or not: that is what the program reads), and
+    the rows a query could see (``len + 1`` on a whole-length layer, at
+    most the window on a window layer, nothing in an empty slot)."""
     whole, window = kv_layers(config)
-    if not window:
+    if not _counts_kv_rows(config):
         return toks
     B = seq_lens.shape[0]
     read = B * ps * (len(whole) * page_tables.shape[1]
-                     + len(window) * ring_tables.shape[1])
+                     + (len(window) * ring_tables.shape[1] if window else 0))
     rows = jnp.where(active, seq_lens + 1, 0)
     live = len(whole) * jnp.sum(rows) \
         + len(window) * jnp.sum(jnp.minimum(rows, config.window))
@@ -269,8 +316,9 @@ def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
 
 def _first_token(tok: jax.Array, counts) -> jax.Array:
     """A prefill's result: the scalar token, or [token, *ROUTING_KEYS]
-    where the layers were routed."""
-    return tok[0] if counts[0] is None else _with_routing(tok, counts)
+    where a layer was routed."""
+    return tok[0] if all(c is None for c in counts) \
+        else _with_routing(tok, counts)
 
 
 # ------------------------------------------------------- adapter pool
@@ -456,18 +504,18 @@ def _stack(config, params: Params, tokens: jax.Array, attend, lora,
         config, params, tokens,
         lambda i, layer, x: block.decoder_layer(
             config, layer, x, functools.partial(attend, i),
-            lora=functools.partial(lora, i), valid=valid))
+            lora=functools.partial(lora, i), valid=valid,
+            routed=block.is_routed(config, i)))
     return hidden, counts
 
 
-def _write_kv(pools: PagedPools, kind: str, layer: int,
-              page_idx: jax.Array, off: jax.Array, k: jax.Array,
-              v: jax.Array) -> None:
-    """``_write_rows`` of a layer's K and V into its kind's pools (``kind``
-    and ``layer`` as ``_kv_slot`` gives them), the dict's pools
-    replaced."""
-    for name, rows in (("k" + kind, k), ("v" + kind, v)):
-        pools[name] = _write_rows(pools[name], layer, page_idx, off, rows)
+def _write_kv(pools: PagedPools, layer: int, page_idx: jax.Array,
+              off: jax.Array, **rows: jax.Array) -> None:
+    """``_write_rows`` of a layer's new rows into the pools they are named
+    for (``k`` and ``v`` of its kind, ``layer`` as ``_kv_slot`` gives it,
+    or a latent model's ``kv``), the dict's pools replaced."""
+    for name, new in rows.items():
+        pools[name] = _write_rows(pools[name], layer, page_idx, off, new)
 
 
 def _paged_attend(config, pools: PagedPools, i: int, q, k, v, *, whole,
@@ -482,10 +530,57 @@ def _paged_attend(config, pools: PagedPools, i: int, q, k, v, *, whole,
     configuration that has none)."""
     kind, slot = _kv_slot(config, i)
     page_idx, off, tables, visible = ring if kind else whole
-    _write_kv(pools, kind, slot, page_idx, off, k, v)
+    _write_kv(pools, slot, page_idx, off, **{"k" + kind: k, "v" + kind: v})
     with jax.named_scope("attn_window" if kind else "attn_global"):
         return _attend_pages(config, q, pools["k" + kind],
                              pools["v" + kind], slot, tables, visible)
+
+
+def _tile_padded(config, *parts: jax.Array) -> jax.Array:
+    """``parts`` [..., w_i] side by side, then zeros up to
+    ``latent_row_width``."""
+    lead, width = parts[0].shape[:-1], sum(p.shape[-1] for p in parts)
+    pad = jnp.zeros((*lead, latent_row_width(config) - width),
+                    parts[0].dtype)
+    return jnp.concatenate([*parts, pad], axis=-1)
+
+
+def _latent_row(config, k_r: jax.Array, c: jax.Array, cos, sin,
+                positions: jax.Array) -> jax.Array:
+    """What the latent pool keeps of the tokens at ``positions`` [N]:
+    ``[c ; RoPE(k_r) ; 0]`` [N, latent_row_width], c [N, rank] normalised
+    by the block, k_r [N, rope] the one rotary key the heads share."""
+    k_r = _rotary_single(k_r[:, None], cos, sin, positions)[:, 0]
+    return _tile_padded(config, c, k_r.astype(c.dtype))
+
+
+def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
+                   cos, sin, positions, page_idx, off, tables, visible,
+                   scope: str):
+    """What the decode step and the suffix prefill do in layer ``i`` of a
+    latent model with the new rows' q [N, H, nope + rope], latent c
+    [N, rank] and rotary key k_r [N, rope] at ``positions`` [N] (N = B * Q
+    rows of ``tables`` [B, MAXP] and ``visible`` [B, Q, MAXP*page]): the
+    rows written into the latent pool, and attention over the table
+    ABSORBED, never expanding a cached row.  With ``wkv_b``'s halves W_uk
+    and W_uv: ``q_lat = q_n W_uk^T`` [N, H, rank], the scores are
+    ``[q_lat ; RoPE(q_r) ; 0] . [c ; RoPE(k_r) ; 0]`` over the gathered rows, the
+    values those rows' first ``rank`` columns, and the heads' outputs in
+    the latent go through W_uv.  Returns [N, H * v]."""
+    B, Q = visible.shape[:2]
+    nope = config.qk_nope_head_dim
+    _write_kv(pools, i, page_idx, off,
+              kv=_latent_row(config, k_r, c, cos, sin, positions))
+    w_uk, w_uv = block.latent_up(config, wkv_b)
+    with jax.named_scope(scope):
+        q_lat = jnp.einsum("nhd,chd->nhc", q[..., :nope], w_uk)
+        q_r = _rotary_single(q[..., nope:], cos, sin, positions)
+        q_abs = _tile_padded(config, q_lat, q_r)
+        o_lat = _attend_pages(
+            config, q_abs.reshape(B, Q, *q_abs.shape[1:]), pools["kv"],
+            None, i, tables, visible)
+        o_lat = o_lat.reshape(B * Q, config.n_heads, config.kv_lora_rank)
+        return jnp.einsum("nhc,chd->nhd", o_lat, w_uv).reshape(B * Q, -1)
 
 
 def decode_logits(config, params: Params, pools: PagedPools,
@@ -498,7 +593,7 @@ def decode_logits(config, params: Params, pools: PagedPools,
     B, maxp = page_tables.shape
     ps = _page_size(pools)
     pools = dict(pools)
-    cos, sin = rope_frequencies(config.head_dim, maxp * ps,
+    cos, sin = rope_frequencies(block.rotary_dim(config), maxp * ps,
                                 config.rope_theta)
     page_idx = page_tables[jnp.arange(B), seq_lens // ps]  # [B]
     off = seq_lens % ps
@@ -521,6 +616,12 @@ def decode_logits(config, params: Params, pools: PagedPools,
         return _paged_attend(
             config, pools, i, q[:, None], k, v,
             whole=(page_idx, off, page_tables, visible), ring=ring)[:, 0]
+
+    if block.is_latent(config):  # one row a slot: q [B, H, D], c, k_r
+        attend = functools.partial(
+            _latent_attend, config, pools, cos=cos, sin=sin,
+            positions=seq_lens, page_idx=page_idx, off=off,
+            tables=page_tables, visible=visible, scope="attn_latent")
 
     x, counts = _stack(config, params, tokens[:B], attend,
                        _adapter_lora(adapters, adapter_ids), active)
@@ -590,7 +691,8 @@ def prefill_logits(config, params: Params, pools: PagedPools,
     ps = _page_size(pools)
     pools = dict(pools)
     n_rep = config.n_heads // config.n_kv_heads
-    cos, sin = rope_frequencies(config.head_dim, s_pad, config.rope_theta)
+    cos, sin = rope_frequencies(block.rotary_dim(config), s_pad,
+                                config.rope_theta)
     positions = jnp.arange(s_pad)
     page_idx = page_table[positions // ps]  # [S_pad]
     off = positions % ps
@@ -606,8 +708,8 @@ def prefill_logits(config, params: Params, pools: PagedPools,
             q = apply_rotary(q[None], cos, sin)[0]
             k = apply_rotary(k[None], cos, sin)[0]
         kind, slot = _kv_slot(config, i)
-        _write_kv(pools, kind, slot, ring_idx if kind else page_idx, off,
-                  k.transpose(1, 0, 2), v)
+        _write_kv(pools, slot, ring_idx if kind else page_idx, off,
+                  **{"k" + kind: k.transpose(1, 0, 2), "v" + kind: v})
         kr, vr = k, v.transpose(1, 0, 2)  # [H_kv, S_pad, D]
         if n_rep > 1:
             kr = jnp.repeat(kr, n_rep, axis=0)
@@ -622,6 +724,29 @@ def prefill_logits(config, params: Params, pools: PagedPools,
             out = jnp.einsum("hqk,hkd->hqd", probs, vr)
         return out.transpose(1, 0, 2).reshape(s_pad, -1)
 
+    def attend_latent(i, q, c, k_r, wkv_b):
+        # The cold chunk EXPANDS its own rows' keys and values (nothing is
+        # cached before them) and writes the latent rows for what follows.
+        row = _latent_row(config, k_r, c, cos, sin, positions)
+        _write_kv(pools, i, page_idx, off, kv=row)
+        nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
+        w_uk, w_uv = block.latent_up(config, wkv_b)
+        with jax.named_scope("attn_latent_prefill"):
+            k_n = jnp.einsum("sc,chd->shd", c, w_uk)
+            v = jnp.einsum("sc,chd->shd", c, w_uv)
+            q_r = _rotary_single(q[..., nope:], cos, sin, positions)
+            scores = (jnp.einsum("qhd,khd->hqk", q[..., :nope], k_n,
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("qhd,kd->hqk", q_r,
+                                   row[:, rank:rank + q_r.shape[-1]],
+                                   preferred_element_type=jnp.float32)) \
+                * (config.head_dim ** -0.5)
+            scores = jnp.where(causal[None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+            return jnp.einsum("hqk,khd->qhd", probs, v).reshape(s_pad, -1)
+
+    if block.is_latent(config):
+        attend = attend_latent
     x, counts = _stack(config, params, tokens[0], attend,
                        _adapter_lora(adapters, adapter_id),
                        positions < length)
@@ -669,9 +794,9 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
     _, s_pad = tokens.shape
     maxp = page_table.shape[0]
     ps = _page_size(pools)
-    scratch = pools["k"].shape[1] - 1
+    scratch = _whole(pools).shape[1] - 1
     pools = dict(pools)
-    cos, sin = rope_frequencies(config.head_dim, maxp * ps,
+    cos, sin = rope_frequencies(block.rotary_dim(config), maxp * ps,
                                 config.rope_theta)
     positions = prefix_len + jnp.arange(s_pad)  # global positions
     valid = positions < length
@@ -707,6 +832,13 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
         return _paged_attend(
             config, pools, i, q[None], k, v,
             whole=(page_idx, off, page_table[None], visible), ring=ring)[0]
+
+    if block.is_latent(config):  # q [S_pad, H, D], c, k_r: a batch of one
+        attend = functools.partial(
+            _latent_attend, config, pools, cos=cos, sin=sin,
+            positions=positions, page_idx=page_idx, off=off,
+            tables=page_table[None], visible=visible,
+            scope="attn_latent_prefill")
 
     x, counts = _stack(config, params, tokens[0], attend,
                        _adapter_lora(adapters, adapter_id), valid)
@@ -754,10 +886,9 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
 @functools.partial(jax.jit, donate_argnums=(0,))
 def copy_page(pools: PagedPools, src: jax.Array,
               dst: jax.Array) -> PagedPools:
-    """Copy one page's K/V across every layer (copy-on-write when a
-    request diverges mid-page from a cached prefix).  src/dst are data —
-    one compile covers every divergence."""
+    """Copy one page's K/V (a latent model's rows) across every layer
+    (copy-on-write when a request diverges mid-page from a cached prefix).
+    src/dst are data — one compile covers every divergence."""
     _bump("page_copy", src=src, dst=dst)
-    k, v = pools["k"], pools["v"]
-    return {"k": k.at[:, dst].set(k[:, src]),
-            "v": v.at[:, dst].set(v[:, src])}
+    return {name: pools[name].at[:, dst].set(pools[name][:, src])
+            for name in ("k", "v", "kv") if name in pools}

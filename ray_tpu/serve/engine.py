@@ -214,13 +214,15 @@ class _Request:
 class _Step(NamedTuple):
     """A decode step that was dispatched and whose tokens the host has not
     read: its number, the device array of its tokens, the request each
-    slot decoded for when it was dispatched, and whether it went out
-    before the step ahead of it was read."""
+    slot decoded for when it was dispatched, whether it went out before
+    the step ahead of it was read, and how many of its live slots' pages
+    were another live slot's too (``InferenceEngine._shared_dups``)."""
 
     number: int
     tokens: Any
     owners: List[Optional[_Request]]
     ahead: bool
+    shared_dups: int = 0
 
 
 class _Account:
@@ -322,6 +324,10 @@ class InferenceEngine:
         self.pools = self._new_pools()
         #: The names of the counters behind the decode step's tokens.
         self._counter_keys = counter_keys(model_config)
+        # For ``kv_rows_distinct``: how many decoding slots hold each page,
+        # and over the pages held by several, the holders past the first.
+        self._page_holders: Dict[int, int] = {}
+        self._shared_dups = 0
         # Multi-tenant plane: device-resident LoRA slots + the radix
         # prefix tree over the page pool.  Both are owned by the loop
         # thread like the allocator.
@@ -489,6 +495,22 @@ class InferenceEngine:
 
         return init_paged_pools(self.model_config, self.config.pool_pages,
                                 self.config.page_size, self.ring_scratch)
+
+    def _hold_pages(self, pages: List[int], by: int) -> None:
+        """A slot starts (``by`` 1) or stops (-1) decoding over ``pages``.
+        Only where the decode step counts its rows and the prefix cache
+        can share a page is there anything to keep."""
+        if self._cache is None or "kv_rows_live" not in self._counter_keys:
+            return
+        holders = self._page_holders
+        for p in pages:
+            was = holders.get(p, 0)
+            # Past the first holder, each one reads rows another reads too.
+            self._shared_dups += max(0, was + by - 1) - max(0, was - 1)
+            if was + by:
+                holders[p] = was + by
+            else:
+                del holders[p]
 
     def _ring_pages_held(self) -> int:
         """Ring pages that hold a live sequence's rows: a sequence fills
@@ -741,6 +763,14 @@ class InferenceEngine:
         resident copy AND the adapter's prefix-cache tree — its cached V
         deltas are stale."""
 
+        from ..models import block
+
+        if block.is_latent(self.model_config):
+            raise ValueError(
+                "a model with latent attention takes no LoRA adapter: the "
+                "deltas on wq and wv have no counterpart among its "
+                "projections")
+
         def do():
             self.adapter_pool.register(name, source)
             if self._cache is not None:
@@ -982,6 +1012,8 @@ class InferenceEngine:
             if req.first_token_t is not None else None,
             mean_itl_s=round(sum(req.itls) / len(req.itls), 6)
             if req.itls else None)
+        if self._active[slot]:
+            self._hold_pages(req.pages, -1)
         self.allocator.free(req.pages)  # refcounted: shared prefix
         req.pages = []                  # pages may stay cached
         if req.cow_ref is not None:     # evicted before the COW copy ran
@@ -1176,6 +1208,7 @@ class InferenceEngine:
         self._seq_lens[slot] = n
         self._tokens[slot] = first
         self._active[slot] = True
+        self._hold_pages(req.pages, 1)
         self._temps[slot] = req.temperature
         self._adapter_slots[slot] = req.adapter_slot
         self._dirty = True
@@ -1230,6 +1263,8 @@ class InferenceEngine:
         self._seq_lens[:] = 0
         self._tokens[:] = 0
         self._active[:] = False
+        self._page_holders.clear()  # no slot decodes: no page is held
+        self._shared_dups = 0
         self._temps[:] = 0.0
         self._adapter_slots[:] = self.adapter_pool.zero_slot
         self._dirty = True
@@ -1406,7 +1441,7 @@ class InferenceEngine:
                 self._d_active, self._d_temps, self._d_adapter_slots,
                 self._d_key, self._d_ring_tables)
         return _Step(self.step_count, self._d_tokens, list(self.slots),
-                     ahead)
+                     ahead, self._shared_dups)
 
     def _finish_step(self, step: _Step) -> None:
         """Read a dispatched step's tokens, hand each to the request its
@@ -1523,6 +1558,14 @@ class InferenceEngine:
             # counters are on its first_tokens entry).
             **routing,
         }
+        if "kv_rows_live" in routing:
+            # The live rows counted once a physical page: slots that share
+            # prefix pages (always whole and wholly live) read the same
+            # rows, and any program computing these tokens reads them at
+            # least once.
+            rec["kv_rows_distinct"] = routing["kv_rows_live"] - (
+                self._kv_layers[0] * self.config.page_size
+                * step.shared_dups)
         if self.ring:
             # Pages held for live sequences, a layer's each: by the two
             # kinds of cache, and what one pool in which every layer kept

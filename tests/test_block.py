@@ -21,7 +21,18 @@ WIDTHS = dict(vocab_size=320, d_model=64, n_layers=N_LAYERS, n_heads=4,
 SLOTS, PAGE, MAXP, BUCKET = 2, 8, 4, 16
 
 
-def _config(routed: bool, **kw):
+#: Latent attention, a dense layer before the routed ones, a shared expert,
+#: sigmoid routing: what ``MoEConfig`` gained for GLM-4.7-Flash's line.
+LATENT = dict(q_lora_rank=24, kv_lora_rank=16, qk_nope_head_dim=12,
+              qk_rope_head_dim=8, v_head_dim=20, ffn_layout=(0, 1, 1),
+              dense_d_ff=80, n_shared_experts=1, router_score="sigmoid",
+              routed_scaling_factor=1.8)
+
+
+def _config(routed, **kw):
+    if routed == "latent":
+        return MoEConfig(n_experts=4, top_k=2, **{**WIDTHS, "n_kv_heads": 4},
+                         **LATENT, **kw)
     if routed:
         return MoEConfig(n_experts=4, top_k=2, qk_norm=True, **WIDTHS, **kw)
     return LlamaConfig(**WIDTHS, **kw)
@@ -42,9 +53,11 @@ def _train(routed, remat=False):
 
 
 def _train_remat(routed):
-    """``jax.checkpoint`` traces the layer once for layers of one shape."""
+    """``jax.checkpoint`` traces the layer once for layers of one shape
+    and kind (the dense layer before the routed ones is a kind of its
+    own)."""
     _train(routed, remat=True)
-    return 1
+    return 2 if routed == "latent" else 1
 
 
 def _pipeline_stage(routed):
@@ -108,20 +121,29 @@ def _prefill_prefix(routed):
     (_train_remat, True), (_pipeline_stage, False),
     (_decode, False), (_decode, True), (_prefill, False), (_prefill, True),
     (_prefill_prefix, False), (_prefill_prefix, True),
+    (_train, "latent"), (_train_remat, "latent"), (_decode, "latent"),
+    (_prefill, "latent"), (_prefill_prefix, "latent"),
 ], ids=lambda v: v.__name__.strip("_") if callable(v) else (
-    "routed" if v else "dense"))
+    v if isinstance(v, str) else "routed" if v else "dense"))
 def test_every_program_runs_the_one_decoder_layer(monkeypatch, program,
                                                   routed):
-    calls, real = [], block.decoder_layer
+    calls, kinds, real = [], [], block.decoder_layer
 
     def counted(config, *args, **kwargs):
         calls.append(config)
+        kinds.append(kwargs.get("routed"))
         return real(config, *args, **kwargs)
 
     monkeypatch.setattr(block, "decoder_layer", counted)
     expected = program(routed)
     assert len(calls) == expected
-    assert all(block.is_routed(c) == routed for c in calls)
+    assert all(block.is_routed(c) == bool(routed) for c in calls)
+    # Which FFN a layer has is asked of the layer (``block.is_routed(config,
+    # i)``), by every program: a pattern reads dense, routed, routed.
+    if routed == "latent" and expected == N_LAYERS:
+        assert kinds == [False, True, True]
+    elif routed != "latent":
+        assert set(kinds) == {bool(routed)}
 
 
 def test_the_routed_ffn_of_one_expert_is_the_dense_layer():
@@ -136,8 +158,10 @@ def test_the_routed_ffn_of_one_expert_is_the_dense_layer():
         **{k: w[None] for k, w in layer["mlp"].items()}})
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 8, dense.d_model))
     attend = lambda q, k, v: q.reshape(*q.shape[:-2], -1)  # noqa: E731
-    want, no_aux, no_counts = block.decoder_layer(dense, layer, x, attend)
-    got, aux, counts = block.decoder_layer(routed, as_routed, x, attend)
+    want, no_aux, no_counts = block.decoder_layer(dense, layer, x, attend,
+                                                  routed=False)
+    got, aux, counts = block.decoder_layer(routed, as_routed, x, attend,
+                                           routed=True)
     assert no_aux is None and no_counts is None
     assert counts.tolist() == [16] and float(aux) == pytest.approx(1.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
