@@ -50,7 +50,11 @@ over cached rows attend ABSORBED, in the latent (``_latent_attend``): the
 row is the key of ONE KV head that all query heads share, and its first
 ``kv_lora_rank`` columns are that head's value; ``wkv_b`` is multiplied
 into the queries and into the output, never into the cached rows.  The
-cold prefill expands its own chunk's keys and values.
+cold prefill expands its own chunk's keys and values.  On a TPU the decode
+step does not gather: a Pallas kernel walks each slot's LIVE pages where
+they lie and reads each once (``ops/latent_decode.py``,
+``_walks_live_pages``); the gather form is its reference, and what the
+suffix prefill's many query rows and every other backend take.
 
 Compile counts are observable via ``trace_count()`` — the jitted bodies
 bump a counter when TRACED (python executes only at trace time), which is
@@ -65,6 +69,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
+from ..ops import latent_decode
 from ..ops.rotary import apply_rotary, rope_frequencies
 from . import block
 from .llama import LlamaConfig
@@ -253,7 +258,9 @@ def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
 #: What ``_with_routing`` appends, in its order: the step record's keys.
 ROUTING_KEYS = ("experts_hit", "expert_pairs", "expert_load_max")
 #: What the decode step of a configuration with window layers, or with a
-#: latent pool, appends behind them (``_with_kv_rows``).
+#: latent pool, appends behind them (``_with_kv_rows``): the rows the program
+#: brought in (a gather's whole tables, or the pages the latent kernel
+#: walks) and the rows a query could see.
 KV_KEYS = ("kv_rows_read", "kv_rows_live")
 
 
@@ -291,21 +298,35 @@ def _with_routing(toks: jax.Array, counts: List[Optional[jax.Array]]
         [jnp.sum(c > 0, dtype=jnp.int32), jnp.sum(c), jnp.max(c)])])
 
 
+def _walks_live_pages(config) -> bool:
+    """Whether the decode step of ``config`` attends through
+    ``ops.latent_decode_attention`` in place of ``_attend_pages``' gather:
+    a latent pool (one query row a slot is what a decode step has), on a
+    TPU.  The one place that chooses; ``_with_kv_rows`` counts by it."""
+    return block.is_latent(config) and latent_decode.on_tpu()
+
+
 def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
                   ring_tables: Optional[jax.Array], seq_lens: jax.Array,
                   active: jax.Array, ps: int) -> jax.Array:
     """``toks`` followed (where ``config`` has window layers or a latent
     pool) by the decode step's ``KV_KEYS``, summed over layers and slots:
-    the rows of K (of the latent pool) its gathers brought in (every
-    slot's whole table, live or not: that is what the program reads), and
-    the rows a query could see (``len + 1`` on a whole-length layer, at
-    most the window on a window layer, nothing in an empty slot)."""
+    the rows of K (of the latent pool) the program brought in, and the rows
+    a query could see (``len + 1`` on a whole-length layer, at most the
+    window on a window layer, nothing in an empty slot).  What it brings in
+    is every slot's whole table, live or not, where it gathers; where the
+    latent kernel walks (``_walks_live_pages``), each slot's live pages,
+    ``seq_lens // page + 1`` of them (one of an empty slot)."""
     whole, window = kv_layers(config)
     if not _counts_kv_rows(config):
         return toks
     B = seq_lens.shape[0]
-    read = B * ps * (len(whole) * page_tables.shape[1]
-                     + (len(window) * ring_tables.shape[1] if window else 0))
+    if _walks_live_pages(config):
+        read = len(whole) * ps * jnp.sum(seq_lens // ps + 1)
+    else:
+        read = B * ps * (
+            len(whole) * page_tables.shape[1]
+            + (len(window) * ring_tables.shape[1] if window else 0))
     rows = jnp.where(active, seq_lens + 1, 0)
     live = len(whole) * jnp.sum(rows) \
         + len(window) * jnp.sum(jnp.minimum(rows, config.window))
@@ -556,7 +577,7 @@ def _latent_row(config, k_r: jax.Array, c: jax.Array, cos, sin,
 
 def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
                    cos, sin, positions, page_idx, off, tables, visible,
-                   scope: str):
+                   scope: str, walk_lens: Optional[jax.Array] = None):
     """What the decode step and the suffix prefill do in layer ``i`` of a
     latent model with the new rows' q [N, H, nope + rope], latent c
     [N, rank] and rotary key k_r [N, rope] at ``positions`` [N] (N = B * Q
@@ -566,7 +587,9 @@ def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
     and W_uv: ``q_lat = q_n W_uk^T`` [N, H, rank], the scores are
     ``[q_lat ; RoPE(q_r) ; 0] . [c ; RoPE(k_r) ; 0]`` over the gathered rows, the
     values those rows' first ``rank`` columns, and the heads' outputs in
-    the latent go through W_uv.  Returns [N, H * v]."""
+    the latent go through W_uv.  ``walk_lens`` [B] (the decode step's
+    ``seq_lens``, where ``_walks_live_pages``): the kernel walks the live
+    pages in place of the gather.  Returns [N, H * v]."""
     B, Q = visible.shape[:2]
     nope = config.qk_nope_head_dim
     _write_kv(pools, i, page_idx, off,
@@ -576,9 +599,14 @@ def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
         q_lat = jnp.einsum("nhd,chd->nhc", q[..., :nope], w_uk)
         q_r = _rotary_single(q[..., nope:], cos, sin, positions)
         q_abs = _tile_padded(config, q_lat, q_r)
-        o_lat = _attend_pages(
-            config, q_abs.reshape(B, Q, *q_abs.shape[1:]), pools["kv"],
-            None, i, tables, visible)
+        if walk_lens is not None:  # Q is 1
+            o_lat = latent_decode.latent_decode_attention(
+                q_abs, pools["kv"], i, tables, walk_lens,
+                rank=config.kv_lora_rank, sm_scale=config.head_dim ** -0.5)
+        else:
+            o_lat = _attend_pages(
+                config, q_abs.reshape(B, Q, *q_abs.shape[1:]), pools["kv"],
+                None, i, tables, visible)
         o_lat = o_lat.reshape(B * Q, config.n_heads, config.kv_lora_rank)
         return jnp.einsum("nhc,chd->nhd", o_lat, w_uv).reshape(B * Q, -1)
 
@@ -621,7 +649,8 @@ def decode_logits(config, params: Params, pools: PagedPools,
         attend = functools.partial(
             _latent_attend, config, pools, cos=cos, sin=sin,
             positions=seq_lens, page_idx=page_idx, off=off,
-            tables=page_tables, visible=visible, scope="attn_latent")
+            tables=page_tables, visible=visible, scope="attn_latent",
+            walk_lens=seq_lens if _walks_live_pages(config) else None)
 
     x, counts = _stack(config, params, tokens[:B], attend,
                        _adapter_lora(adapters, adapter_ids), active)
@@ -653,7 +682,8 @@ def paged_decode_step(config: LlamaConfig, params: Params,
     Pools are donated and keep their layout through the program
     (``tests/test_chip_compile.py`` holds the compiled step to it), so
     steady-state decode never copies the cache: a layer writes B rows in
-    place and reads one gather of the page tables.  ring_tables
+    place and reads one gather of the page tables (a latent model's on a
+    TPU, the live pages where they lie: ``_walks_live_pages``).  ring_tables
     [B, entries] int32 are the window layers' rings (None for a
     configuration without them): those layers write and gather through
     them, that wide and no wider; the branch is taken layer by layer at

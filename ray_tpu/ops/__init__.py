@@ -2,15 +2,17 @@
 
 Net-new relative to the reference, which delegates all device compute to
 torch/CUDA (SURVEY.md §5.7): flash attention, ring attention (sequence
-parallelism), fused RMSNorm, rotary embeddings.
+parallelism), decode attention over a latent paged pool, fused RMSNorm,
+rotary embeddings.
 """
 
 from .attention import flash_attention, mha_reference
+from .latent_decode import latent_decode_attention
 from .norms import rms_norm
 from .rotary import apply_rotary, rope_frequencies
 from .ring_attention import ring_attention
 
 __all__ = [
-    "flash_attention", "mha_reference", "rms_norm",
+    "flash_attention", "mha_reference", "latent_decode_attention", "rms_norm",
     "apply_rotary", "rope_frequencies", "ring_attention",
 ]
