@@ -152,7 +152,8 @@ def cmd_status(args) -> int:
             for row in _engine_rows(engines, devmem):
                 print(f"  engine {row['engine']}: slots {row['slots']}  "
                       f"queued {row['queued']}  pages {row['pages']}  "
-                      f"stall {row['stall%']}%  host {row['host%']}%  "
+                      f"stall {row['stall%']}%  "
+                      f"starved {row['starved%']}%  host {row['host%']}%  "
                       f"ahead {row['ahead%']}%  "
                       f"queue wait {row['qwait_ms']} ms  "
                       f"loop {row['loop%']}%  compiles {row['compiles']}  "
@@ -180,15 +181,18 @@ def _engine_rows(engines, devmem_items) -> list:
 
         wall, stall = total("wall_s"), total("stall_s")
         # The loop's own account (records of an engine that keeps one):
-        # the host's share of a step, the share of decode steps that were
-        # dispatched before the step ahead of them was read (so that much
-        # of the host's share was hidden behind the chip), how long a
-        # request waited for admission to look at it, the share of the
-        # loop thread's time the retained records tile (under 100: records
-        # were lost), and the programs JAX built inside the window's steps.
+        # the share of the loop's time in which the chip had no work of
+        # the engine's (what the host costs the chip), the host's share of
+        # a step, the share of decode steps that were dispatched before
+        # the step ahead of them was read (so that much of the host's
+        # share was hidden behind the chip), how long a request waited for
+        # admission to look at it, the share of the loop thread's time the
+        # retained records tile (under 100: records were lost), and the
+        # programs JAX built inside the window's steps.
         loop = total("wall_s", "between_s")
         host = total("between_s", "upload_s", "dispatch_s", "emit_s")
         ahead = [r["ahead"] for r in recs if "ahead" in r and r["occupancy"]]
+        starved = [r["starved_s"] for r in recs if "starved_s" in r]
         waits = sorted(e["queue_s"] for r in recs
                        for e in r.get("first_tokens") or ())
         accounted = "t0" in latest
@@ -208,6 +212,8 @@ def _engine_rows(engines, devmem_items) -> list:
                      f"{latest.get('slots', 0)}",
             "queued": latest.get("queued", 0),
             "stall%": f"{100.0 * stall / wall:.1f}" if wall > 0 else "0.0",
+            "starved%": f"{100.0 * sum(starved) / loop:.1f}"
+                        if starved and loop > 0 else "-",
             "host%": f"{100.0 * host / loop:.1f}"
                      if accounted and loop > 0 else "-",
             "ahead%": f"{100.0 * sum(ahead) / len(ahead):.1f}"
@@ -364,9 +370,9 @@ def _render_top(cl) -> str:
         "",
         _format_table(
             _engine_rows(engines, devmem),
-            ["engine", "slots", "queued", "stall%", "host%", "ahead%",
-             "qwait_ms", "loop%", "compiles", "pages", "adapters", "hbm",
-             "tenants"],
+            ["engine", "slots", "queued", "stall%", "starved%", "host%",
+             "ahead%", "qwait_ms", "loop%", "compiles", "pages", "adapters",
+             "hbm", "tenants"],
             empty="(no engines reporting — flight recorder off or no "
                   "serve traffic yet)",
         ),

@@ -50,6 +50,8 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from ..util.profiling import annotation
+
 #: Engine identity within one process: step records carry
 #: ``"<pid>.<seq>"`` so the head's per-engine rings stay distinct when a
 #: process hosts several engines (bench harnesses, tests).
@@ -157,7 +159,7 @@ class _Request:
         "req_id", "prompt", "max_new", "temperature", "stop_token",
         "out_q", "cancelled", "finished", "pages", "page_table",
         "length", "generated", "submit_t", "first_token_t",
-        "last_token_t", "itls", "slot",
+        "last_token_t", "slot",
         "trace_ctx", "submit_wall", "admit_t",
         "tenant", "weight", "adapter", "adapter_slot", "match",
         "cow_ref", "cache_hit_len",
@@ -186,10 +188,9 @@ class _Request:
         self.submit_wall = time.time()
         self.admit_t = 0.0
         self.first_token_t: Optional[float] = None
+        # Both at emission in the loop, not where a consumer took the
+        # token: their distance over the tokens between is the mean gap.
         self.last_token_t: Optional[float] = None
-        # Engine-side inter-token latencies: measured at emission, so
-        # they reflect decode cadence, not consumer scheduling.
-        self.itls: List[float] = []
         self.slot = -1
         # Tracing: the submitter's span context (None when the request
         # arrived untraced/unsampled — then the engine emits nothing).
@@ -246,6 +247,75 @@ class _Account:
         self.traces0 = trace_counts()
 
 
+class _Starved:
+    """The loop's account of the seconds it left the chip without work:
+    from ``since`` (``time.perf_counter()`` where a blocking read returned
+    and nothing else the engine dispatched was unread; None while the chip
+    has work, or the loop nothing to give it) until the next program call
+    returns.  They are charged by the loop phase in which they passed, from
+    the phases' own stamps, and wait here for the record that closes next:
+    ``_record_step`` takes them as ``idle_s`` is taken from ``_gap_acct``."""
+
+    __slots__ = ("since", "by_phase", "stretch")
+
+    def __init__(self):
+        self.since: Optional[float] = None
+        self.by_phase: Dict[str, float] = {}
+        #: Charged since the chip last got work, of what waits here.
+        self.stretch = 0.0
+
+    def free(self, t: float) -> None:
+        """Nothing the engine dispatched is unread since ``t``."""
+        if self.since is None:
+            self.since = t
+
+    def spend(self, name: str, t0: float, t1: float) -> None:
+        """The loop was in phase ``name`` from ``t0`` to ``t1``: what of it
+        the chip stood still is that phase's, and what lay in no phase
+        before it is ``between``'s."""
+        since = self.since
+        if since is None or t1 <= since:
+            return
+        by = self.by_phase
+        if t0 > since:
+            by["between"] = by.get("between", 0.0) + t0 - since
+            since = t0
+        by[name] = by.get(name, 0.0) + t1 - since
+        self.stretch += t1 - self.since
+        self.since = t1
+
+    def stop(self) -> float:
+        """A program call has returned (or the loop has nothing to run):
+        the seconds charged since the last such moment, of those that wait
+        for the open record."""
+        self.since = None
+        charged, self.stretch = self.stretch, 0.0
+        return charged
+
+    def take(self) -> Dict[str, float]:
+        """What waits, for the record being closed."""
+        out, self.by_phase = self.by_phase, {}
+        self.stretch = 0.0
+        return out
+
+
+class _Phase(annotation):
+    """A phase of the loop that the chip may stand still in: a
+    ``util.profiling.annotation`` that hands its two stamps to the
+    engine's :class:`_Starved`."""
+
+    __slots__ = ("starved",)
+
+    def __init__(self, name: str, account: Optional[dict],
+                 starved: _Starved):
+        super().__init__(name, account)
+        self.starved = starved
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__(*exc)
+        self.starved.spend(self.name, self.t0, self.t1)
+
+
 class TokenStream:
     """Per-request token iterator; the consumer side of the engine's
     emission queue.  ``cancel()`` (or closing the iterating generator)
@@ -255,7 +325,6 @@ class TokenStream:
         self._engine = engine
         self._req = req
         self.steps: List[int] = []   # decode-step index of each token
-        self.ttft_s: Optional[float] = None
 
     def __iter__(self):
         return self
@@ -269,8 +338,6 @@ class TokenStream:
             raise RuntimeError(
                 "engine stream stalled past stream_timeout_s") from None
         if kind == "tok":
-            if self.ttft_s is None and self._req.first_token_t is not None:
-                self.ttft_s = self._req.first_token_t - self._req.submit_t
             self.steps.append(step)
             return int(payload)
         if kind == "err":
@@ -472,6 +539,7 @@ class InferenceEngine:
             {} if cfg.step_record else None)
         self._acct: Optional[_Account] = None
         self._prev_end = round(time.perf_counter(), 6)
+        self._starved = _Starved()
         # Device-memory attribution: the engine owns the big allocations,
         # so it names them for util/devmem snapshots.  Weights bytes are
         # static; pool/adapter lambdas chase the live arrays (donation
@@ -1010,8 +1078,9 @@ class InferenceEngine:
             time.time(), tokens=req.generated, reason=reason,
             ttft_s=round(req.first_token_t - req.submit_t, 6)
             if req.first_token_t is not None else None,
-            mean_itl_s=round(sum(req.itls) / len(req.itls), 6)
-            if req.itls else None)
+            mean_itl_s=round((req.last_token_t - req.first_token_t)
+                             / (req.generated - 1), 6)
+            if req.generated > 1 else None)
         if self._active[slot]:
             self._hold_pages(req.pages, -1)
         self.allocator.free(req.pages)  # refcounted: shared prefix
@@ -1067,6 +1136,10 @@ class InferenceEngine:
             "ttft_s": round(req.first_token_t - req.submit_t, 6),
             "prompt": n, "bucket": bucket, "cached": prefix_len,
             "chunks": chunks,
+            # What this admission left the chip standing still, of the
+            # open record's starved seconds: up to its first call's return
+            # here, after its first token in _dispatch_step.
+            "starved_s": round(acct["starved"], 6),
             **routing,  # a routed model's counters of this prefill
         }
         rec = self._rec()
@@ -1096,7 +1169,6 @@ class InferenceEngine:
         import jax.numpy as jnp
 
         from ..models.paged import paged_prefill, paged_prefill_prefix
-        from ..util.profiling import annotation
 
         n = int(req.prompt.size)
         prefix_len = int(req.cache_hit_len)
@@ -1108,7 +1180,7 @@ class InferenceEngine:
         rows, firsts, routing = 0, [], {}
         for start in starts:
             end = min(start + chunk, n)
-            with annotation(PH_PREFILL, acct):
+            with self._phase(PH_PREFILL, acct) as phase:
                 if start == prefix_len:  # the first call's share
                     self._prefill_prepare(req)
                     aid = jnp.asarray(req.adapter_slot, jnp.int32)
@@ -1133,6 +1205,10 @@ class InferenceEngine:
                         self.adapter_pool.arrays, jnp.asarray(toks),
                         jnp.asarray(end, jnp.int32), table, aid, temp,
                         self._d_key, ring)
+                if start == prefix_len:  # the chip has work again
+                    self._starved.spend(PH_PREFILL, phase.t0,
+                                        time.perf_counter())
+                    acct["starved"] = self._starved.stop()
                 firsts.append(first)
                 if end == n:
                     routing = self._finish_prefill(req, firsts, acct)
@@ -1174,10 +1250,10 @@ class InferenceEngine:
         decode step; returns the calls' routing counters, summed (the
         heaviest load: the largest)."""
         from ..models.paged import ROUTING_KEYS
-        from ..util.profiling import annotation
 
-        with annotation(PH_PREFILL_WAIT, acct):
+        with annotation(PH_PREFILL_WAIT, acct) as wait:
             outs = [np.asarray(f).reshape(-1) for f in firsts]  # rt-sync-ok: THE prefill readback — the first token must reach the host to stream it
+        self._starved.free(wait.t1)  # the step in flight was read before
         first = int(outs[-1][0])
         counters = np.stack([o[1:] for o in outs])
         routing = dict(zip(ROUTING_KEYS, counters.sum(0).tolist()))
@@ -1270,11 +1346,14 @@ class InferenceEngine:
         self._dirty = True
         self.pools = self._new_pools()
 
-    def _loop(self) -> None:
-        from ..util.profiling import annotation
+    def _phase(self, name: str, account: Optional[dict] = None) -> _Phase:
+        return _Phase(name, account, self._starved)
 
+    def _loop(self) -> None:
         while True:
-            with annotation(PH_ADMIT), self._lock:
+            with self._phase(PH_ADMIT) as phase, self._lock:
+                if self._inflight is None:  # the loop's start, after idling
+                    self._starved.free(phase.t0)
                 if self._stop:
                     break
                 control, self._control = self._control, []
@@ -1313,6 +1392,9 @@ class InferenceEngine:
                     self._m_active.set(0, tags=self._pid_tags)
                     self._m_pages.set(self._pages_used(),
                                       tags=self._pid_tags)
+                    # Nothing to run is not starvation: this turn's seconds
+                    # are idle_s, and nobody's.
+                    self._starved.stop()
                     with annotation(PH_IDLE, self._gap_acct):
                         self._wake.wait(timeout=0.05)
                     continue
@@ -1363,6 +1445,7 @@ class InferenceEngine:
                 np.asarray(step.tokens)  # rt-sync-ok: shutdown or failure, the device must be quiet before slots and pools go
             except Exception:  # noqa: BLE001 — its failure is being handled
                 pass
+        self._starved.free(time.perf_counter())
 
     def _may_run_ahead(self) -> bool:
         """Whether the step after the one in flight can be dispatched
@@ -1384,8 +1467,6 @@ class InferenceEngine:
         nothing in flight (the serial case) the turn prefills what was
         admitted, uploads the mirrors and dispatches, and the next turn
         reads."""
-        from ..util.profiling import annotation
-
         self._rec()  # a record opens where its first turn begins
         step, self._inflight = self._inflight, None
         if step is not None:
@@ -1403,8 +1484,8 @@ class InferenceEngine:
         if any(s is not None for s in self.slots):
             self._inflight = self._dispatch_step(ahead=False)
         elif rec is not None and admitted:  # prefills that ended at once
-            with annotation(PH_RECORD):
-                self._record_step(rec, None, {})
+            with self._phase(PH_RECORD) as phase:
+                self._record_step(rec, None, {}, phase)
         else:
             self._acct = None  # an empty turn (a control op's) is no record
 
@@ -1415,7 +1496,6 @@ class InferenceEngine:
         import jax.numpy as jnp
 
         from ..models.paged import paged_decode_step
-        from ..util.profiling import annotation
 
         rec = self._rec()
         phases = rec.phases if rec is not None else None
@@ -1424,7 +1504,7 @@ class InferenceEngine:
             # Membership changed since the last step: re-upload the
             # host mirrors.  Steady-state decode skips this — tokens,
             # lengths, and the PRNG key advance on device.
-            with annotation(PH_UPLOAD, phases):
+            with self._phase(PH_UPLOAD, phases):
                 self._d_tokens = jnp.asarray(self._tokens)
                 self._d_page_tables = jnp.asarray(self._page_tables)
                 self._d_seq_lens = jnp.asarray(self._seq_lens)
@@ -1432,7 +1512,7 @@ class InferenceEngine:
                 self._d_temps = jnp.asarray(self._temps)
                 self._d_adapter_slots = jnp.asarray(self._adapter_slots)
                 self._dirty = False
-        with annotation(PH_DISPATCH, phases):
+        with self._phase(PH_DISPATCH, phases):
             (self._d_tokens, self._d_seq_lens, self._d_key,
              self.pools) = paged_decode_step(
                 self.model_config, self.params, self.pools,
@@ -1440,19 +1520,25 @@ class InferenceEngine:
                 self._d_tokens, self._d_page_tables, self._d_seq_lens,
                 self._d_active, self._d_temps, self._d_adapter_slots,
                 self._d_key, self._d_ring_tables)
+        # The chip has work again.  What it stood still since the last
+        # admission's first token is that admission's.
+        since_token = self._starved.stop()
+        if since_token and rec is not None and rec.first_tokens:
+            entry = rec.first_tokens[-1]
+            entry["starved_s"] = round(entry["starved_s"] + since_token, 6)
         return _Step(self.step_count, self._d_tokens, list(self.slots),
                      ahead, self._shared_dups)
 
     def _finish_step(self, step: _Step) -> None:
         """Read a dispatched step's tokens, hand each to the request its
         slot decoded for, and close the record."""
-        from ..util.profiling import annotation
-
         rec = self._rec()
         phases = rec.phases if rec is not None else None
-        with annotation(PH_READBACK, phases):
+        with annotation(PH_READBACK, phases) as readback:
             toks = np.asarray(step.tokens)  # rt-sync-ok: THE decode-step readback — one batched token fetch per step
-        with annotation(PH_EMIT, phases) as phase:
+        if self._inflight is None:  # no step was dispatched ahead of it
+            self._starved.free(readback.t1)
+        with self._phase(PH_EMIT, phases) as phase:
             now = phase.t0
             routing = dict(zip(
                 self._counter_keys,
@@ -1464,9 +1550,7 @@ class InferenceEngine:
                 req.length += 1
                 self._tokens[slot] = toks[slot]
                 if req.last_token_t is not None:
-                    itl = now - req.last_token_t
-                    req.itls.append(itl)
-                    self._m_itl.observe(itl)
+                    self._m_itl.observe(now - req.last_token_t)
                 req.last_token_t = now
                 self._emit_token(req, int(toks[slot]), step.number)
         self._m_active.set(
@@ -1475,11 +1559,11 @@ class InferenceEngine:
         self._m_pages.set(self._pages_used(),
                           tags=self._pid_tags)
         if rec is not None:
-            with annotation(PH_RECORD):
-                self._record_step(rec, step, routing)
+            with self._phase(PH_RECORD) as phase:
+                self._record_step(rec, step, routing, phase)
 
     def _record_step(self, acct: _Account, step: Optional[_Step],
-                     routing: Dict[str, int]) -> None:
+                     routing: Dict[str, int], phase: _Phase) -> None:
         """Close ``acct`` into one flight-recorder record: of ``step``,
         which was just read, or (None) of prefills that left no sequence to
         decode.  Called on the loop thread; everything here is host
@@ -1498,13 +1582,27 @@ class InferenceEngine:
         next step's dispatch, ``readback_s`` the time still blocked on the
         chip, and ``wall_s + between_s`` the step's period.  ``ahead`` is
         1 when the step was dispatched before the one ahead of it was
-        read."""
+        read.
+
+        ``starved_s`` is what of ``wall_s + between_s`` the chip had no work
+        of the engine's (:class:`_Starved`), ``starved`` the same by the
+        phase it passed in (``between``: in none), and ``traced`` is 1 on
+        a record closed while a profiler session was open, so that a reader
+        of a device trace takes the records of the traced seconds."""
+        import jax
+
         from ..models.paged import trace_counts
         from ..util import devmem, steprec
 
         self._acct = None
         t0, stall_s = acct.t0, acct.stall_s
-        wall_s = time.perf_counter() - t0
+        end = time.perf_counter()
+        wall_s = end - t0
+        # The record ends here, inside its own last phase: what the chip
+        # stands still in the rest of it is the next record's.
+        self._starved.spend(PH_RECORD, phase.t0, end)
+        starved = {name.rpartition("/")[2]: round(s, 6)
+                   for name, s in self._starved.take().items()}
         now = time.time()
         if step is not None:
             self._step_walls.append(wall_s)
@@ -1540,6 +1638,8 @@ class InferenceEngine:
             "dispatch_s": round(phases.get(PH_DISPATCH, 0.0), 6),
             "readback_s": round(phases.get(PH_READBACK, 0.0), 6),
             "emit_s": round(phases.get(PH_EMIT, 0.0), 6),
+            "starved_s": round(sum(starved.values(), 0.0), 6),
+            "starved": {name: s for name, s in starved.items() if s},
             "first_tokens": first_tokens,
             "occupancy": sum(1 for s in self.slots if s is not None),
             "slots": self.config.batch_slots,
@@ -1580,6 +1680,8 @@ class InferenceEngine:
         compiles = devmem.compile_count() - acct.compiles0
         if compiles:
             rec["compiles"] = compiles
+        if jax.profiler.TraceAnnotation.is_enabled():
+            rec["traced"] = 1
         steprec.record_step(rec)
 
 

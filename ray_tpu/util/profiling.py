@@ -103,11 +103,11 @@ class annotation:
     also adds its elapsed ``time.perf_counter()`` seconds to
     ``account[name]`` when an account (a dict) is given.  One object does
     both, so a phase begins and ends at the same instants in a step record
-    and in a device trace; ``t0`` is the ``perf_counter`` at entry.  With
-    no profiler session open the annotation is a flag test; the whole
-    context costs about a microsecond."""
+    and in a device trace; ``t0`` is the ``perf_counter`` at entry and
+    ``t1`` the one at exit.  With no profiler session open the annotation
+    is a flag test; the whole context costs about a microsecond."""
 
-    __slots__ = ("name", "account", "_trace", "t0")
+    __slots__ = ("name", "account", "_trace", "t0", "t1")
 
     def __init__(self, name: str, account: Optional[dict] = None):
         global _TraceAnnotation
@@ -125,8 +125,9 @@ class annotation:
         return self
 
     def __exit__(self, *exc) -> None:
-        elapsed = time.perf_counter() - self.t0
+        self.t1 = time.perf_counter()
         self._trace.__exit__(*exc)
         account = self.account
         if account is not None:
-            account[self.name] = account.get(self.name, 0.0) + elapsed
+            account[self.name] = (account.get(self.name, 0.0)
+                                  + self.t1 - self.t0)
