@@ -13,11 +13,15 @@ Design (token-choice top-k, no capacity and no drops):
   choice only, renormalised and scaled (GLM-4.7-Flash)
 - the tokens x top_k (token, expert) pairs are sorted by expert, so each
   expert's rows are one contiguous group of a [tokens*k, d] array
-- experts: three grouped products over those groups (``jax.lax.ragged_dot``,
-  which XLA lowers on the TPU to a grouped-matmul kernel of its own): memory
-  and operations are proportional to tokens x top_k whatever the load; an
-  expert with no token costs nothing and one with every token is just a
-  long group
+- experts: three grouped products over those groups: memory and operations
+  are proportional to tokens x top_k whatever the load; an expert with no
+  token costs nothing and one with every token is just a long group.  Two
+  forms of one arithmetic (``_streams_experts`` chooses by what it can see):
+  ``jax.lax.ragged_dot`` three times, which XLA lowers on the TPU to a
+  grouped-matmul kernel of its own (every prefill, training, every backend
+  that is not a TPU); and where an expert gets a handful of rows on a TPU (a
+  decode step), ``ops.grouped_ffn``: one Pallas kernel that streams each hit
+  expert's weights once, in large blocks, through all three
 - combine: the pairs are put back in token order and summed with their
   router weights in float32
 - aux loss: Switch load-balancing loss (mean expert fraction x mean router
@@ -36,6 +40,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from ..ops import grouped_ffn
 from ..parallel.mesh import AXIS_EP, AXIS_FSDP, AXIS_TP
 from ..parallel.sharding import ShardingRules
 from .llama import (LlamaConfig, _mlp, hidden_and_aux, llama_sharding_rules,
@@ -339,6 +344,33 @@ def _shared_expert(config: MoEConfig, shared: Params, x: jax.Array,
     return out.astype(config.dtype)
 
 
+#: The most rows an expert gets, on average, where its weights are streamed.
+STREAM_ROWS_AN_EXPERT = 4
+
+
+def _streams_experts(config: MoEConfig, pairs: int) -> bool:
+    """Whether the grouped products over ``pairs`` (token, expert) rows go
+    through ``ops.grouped_ffn_stream`` in place of three ``ragged_dot``
+    calls: on a TPU, a few rows an expert (the work is then reading the
+    hit experts' weights: every decode program, 96-128 pairs over 64
+    experts; a prefill bucket or a training batch has eight rows an expert
+    and up), widths the kernel's DMAs can cut.  ``pairs`` is the program's
+    static count, so one program holds one form.  The one place that
+    chooses; the kernel defines no gradient, which training does not ask of
+    it at such a size."""
+    return (grouped_ffn.on_tpu()
+            and pairs <= STREAM_ROWS_AN_EXPERT * config.n_experts
+            and config.d_model % grouped_ffn.LANES == 0
+            and config.d_ff % grouped_ffn.LANES == 0)
+
+
+def grouped_form(config: MoEConfig, tokens: int) -> str:
+    """The form the grouped products of a program over ``tokens`` rows
+    take, by name (``LLMServer.stats()["grouped_ffn"]``)."""
+    return ("stream" if _streams_experts(config, tokens * config.top_k)
+            else "ragged_dot")
+
+
 def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
              valid: Optional[jax.Array] = None,
              logits: Optional[jax.Array] = None
@@ -375,10 +407,16 @@ def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
             return jax.lax.ragged_dot(rows, w, counts,
                                       preferred_element_type=jnp.float32)
 
-        act = jax.nn.silu if config.expert_act == "silu" else jax.nn.relu
-        h = (act(grouped(xs, moe["w1"])) * grouped(xs, moe["w3"])
-             ).astype(config.dtype)
-        ys = grouped(h, moe["w2"])[rank].reshape(G, k, d)      # float32
+        if _streams_experts(config, G * k):
+            ys = grouped_ffn.grouped_ffn_stream(
+                xs, moe["w1"], moe["w3"], moe["w2"], counts,
+                act=config.expert_act)
+        else:
+            act = grouped_ffn.ACTS[config.expert_act]
+            h = (act(grouped(xs, moe["w1"])) * grouped(xs, moe["w3"])
+                 ).astype(config.dtype)
+            ys = grouped(h, moe["w2"])
+        ys = ys[rank].reshape(G, k, d)                         # float32
         if valid is not None:  # rows behind the last group are not written
             ys = jnp.where((top_e < E)[..., None], ys, 0.0)
         out = jnp.einsum("gk,gkd->gd", top_p, ys).astype(config.dtype)

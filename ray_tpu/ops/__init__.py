@@ -2,17 +2,19 @@
 
 Net-new relative to the reference, which delegates all device compute to
 torch/CUDA (SURVEY.md §5.7): flash attention, ring attention (sequence
-parallelism), decode attention over a latent paged pool, fused RMSNorm,
-rotary embeddings.
+parallelism), decode attention over a latent paged pool, the routed FFN
+streamed expert by expert, fused RMSNorm, rotary embeddings.
 """
 
 from .attention import flash_attention, mha_reference
+from .grouped_ffn import grouped_ffn_stream
 from .latent_decode import latent_decode_attention
 from .norms import rms_norm
 from .rotary import apply_rotary, rope_frequencies
 from .ring_attention import ring_attention
 
 __all__ = [
-    "flash_attention", "mha_reference", "latent_decode_attention", "rms_norm",
+    "flash_attention", "mha_reference", "latent_decode_attention",
+    "grouped_ffn_stream", "rms_norm",
     "apply_rotary", "rope_frequencies", "ring_attention",
 ]

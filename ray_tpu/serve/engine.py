@@ -723,6 +723,7 @@ class InferenceEngine:
                 for t, rec in self._tenants.items()
             }
         active = sum(1 for s in self.slots if s is not None)
+        from ..models.moe import grouped_form
         from ..models.paged import trace_count
 
         return {
@@ -752,6 +753,11 @@ class InferenceEngine:
                                        - self._ring_pages_held()),
                               "total": self.ring_scratch}
                              if self.ring else None),
+            # The form a routed model's decode program holds its experts'
+            # products in: "stream" (ops/grouped_ffn.py) or "ragged_dot".
+            "grouped_ffn": (
+                grouped_form(self.model_config, self.config.batch_slots)
+                if "experts_hit" in self._counter_keys else None),
             "adapters": self.adapter_pool.stats(),
         }
 
@@ -1808,6 +1814,10 @@ class LLMServer:
         if programs:  # once, into the replica's log
             print(f"set-up of {model}: {_setup_table(self._setup)}",
                   file=sys.stderr, flush=True)
+            form = self.engine.stats()["grouped_ffn"]
+            if form:
+                print(f"the decode step's grouped products: {form}",
+                      file=sys.stderr, flush=True)
 
     def load_adapter(self, name: str, source: Any = None) -> str:
         """Register a LoRA adapter on this replica's engine.  ``source``

@@ -30,6 +30,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from ray_tpu.ops import attention as att
+from ray_tpu.ops import grouped_ffn
 from ray_tpu.ops.norms import rms_norm_pallas
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.parallel.mesh import MESH_AXES, batch_spec
@@ -139,8 +140,69 @@ def test_rms_norm_kernel_compiles(v5e):
     assert text.count("tpu_custom_call") == 1
 
 
+#: The decode step's grouped products of the three routed cells: (rows =
+#: slots x top_k, d, f, the gate's non-linearity); 64 experts each.
+STREAM_SHAPES = {"olmoe": (128, 2048, 1024, "silu"),
+                 "smallthinker": (96, 2560, 768, "relu"),
+                 "glm": (128, 2048, 1536, "silu")}
+
+
+def _stream_args(v5e, rows, d, f, experts=64):
+    one = SingleDeviceSharding(v5e.devices[0])
+    return (_on(one, (rows, d)), _on(one, (experts, d, f)),
+            _on(one, (experts, d, f)), _on(one, (experts, f, d)),
+            _on(one, (experts,), jnp.int32))
+
+
+@pytest.mark.parametrize("cell", STREAM_SHAPES)
+def test_grouped_ffn_stream_compiles_at_the_cells_decode_geometry(v5e, cell):
+    """One Mosaic call within the VMEM it asks for (both halves of its
+    slabs, 19-25 MB: over the compiler's default, so the kernel states its
+    own limit), and no temporary beside its arguments: the experts'
+    weights are read where they lie, none a second time in HBM."""
+    rows, d, f, act = STREAM_SHAPES[cell]
+    width = grouped_ffn.slab_width(d, f, jnp.bfloat16)
+    assert 2 * 3 * d * width * 2 > 16 << 20
+    compiled = jax.jit(functools.partial(
+        grouped_ffn.grouped_ffn_stream, act=act)).lower(
+        *_stream_args(v5e, rows, d, f)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "%ragged-dot-stream" in calls[0], calls
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20
+    assert mem.output_size_in_bytes == rows * d * 4
+
+
+@pytest.mark.parametrize("tokens,stream", [(16, True), (128, False)],
+                         ids=["decode-step", "smallest-prefill-bucket"])
+def test_the_routed_ffn_streams_where_an_expert_gets_a_handful_of_rows(
+        v5e, monkeypatch, tokens, stream):
+    """``moe._moe_ffn`` at OLMoE's widths as a TPU takes it: a decode
+    step's sixteen rows go through the one streaming kernel and no
+    ``ragged_dot``; a prefill bucket keeps XLA's three."""
+    from ray_tpu.models import MoEConfig, moe
+
+    monkeypatch.setattr(grouped_ffn, "on_tpu", lambda: True)
+    cfg = MoEConfig(vocab_size=64, d_model=2048, n_layers=1, n_heads=16,
+                    n_kv_heads=16, d_ff=1024, n_experts=64, top_k=8,
+                    norm_topk_prob=False, max_seq=128, remat=False)
+    one = SingleDeviceSharding(v5e.devices[0])
+    layer = jax.tree.map(
+        lambda x: _on(one, x.shape, x.dtype),
+        jax.eval_shape(lambda: moe.moe_init(
+            cfg, jax.random.PRNGKey(0))["layers"][0]["moe"]))
+    text = jax.jit(lambda m, x: moe._moe_ffn(cfg, m, x)).lower(
+        layer, _on(one, (tokens, 2048))).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert sum("%ragged-dot-stream" in ln for ln in calls) == int(stream)
+    assert sum("%ragged-dot-none" in ln for ln in calls) \
+        == (0 if stream else 3)
+
+
 @pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
-                                    "flash_bwd_dkv", "rms_norm"])
+                                    "flash_bwd_dkv", "rms_norm",
+                                    "ragged-dot-stream"])
 def test_kernels_carry_their_names_into_the_compiled_program(v5e, kernel):
     """``pallas_call(name=...)``: what a device trace (and a reduction of
     it) can tell the Mosaic calls apart by."""
@@ -148,6 +210,9 @@ def test_kernels_carry_their_names_into_the_compiled_program(v5e, kernel):
     if kernel == "rms_norm":
         fn, args = rms_norm_pallas, (_on(one, (8192, 2048)),
                                      _on(one, (2048,)))
+    elif kernel == "ragged-dot-stream":
+        fn, args = grouped_ffn.grouped_ffn_stream, _stream_args(
+            v5e, 32, 256, 256, experts=8)
     else:
         def loss(q, k, v):
             out = att.flash_attention(q, k, v, force_pallas=True)
