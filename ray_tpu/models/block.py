@@ -29,6 +29,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from ..ops.norms import rms_norm
 from ..parallel.sharding import (HEADS, RESIDUAL, SPLIT, VOCAB_ROWS,
@@ -105,6 +106,10 @@ def attention(config, a: Params, h: jax.Array, attend: Attend,
         out = attend(*project_latent(config, a, h), a["wkv_b"])
     else:
         out = attend(*project_qkv(config, a, h, lora))
+    if getattr(config, "attn_gate", False):
+        with jax.named_scope("attn_gate"):
+            gate = jax.nn.sigmoid((h @ a["wg"]).astype(jnp.float32))
+            out = (out * gate).astype(out.dtype)
     return constrain(out, SPLIT) @ a["wo"]
 
 
@@ -152,6 +157,16 @@ def init_and_apply(config):
     return llama.llama_init, llama.llama_apply
 
 
+def post_norm(config, layer: Params, name: str, out: jax.Array) -> jax.Array:
+    """A half-block's output through its own norm before it joins the
+    residual stream, where the block is sandwich-normed
+    (``MoEConfig.post_norm``: ``layer[name]``); as it is elsewhere."""
+    if not getattr(config, "post_norm", False):
+        return out
+    with jax.named_scope("post_norm"):
+        return rms_norm(out, layer[name], config.norm_eps)
+
+
 def ffn(config, layer: Params, x: jax.Array,
         valid: Optional[jax.Array] = None,
         logits: Optional[jax.Array] = None, *, routed: bool):
@@ -173,35 +188,43 @@ def ffn(config, layer: Params, x: jax.Array,
         if config.n_shared_experts:
             out = out + moe._shared_expert(config, layer["moe"]["shared"],
                                            h, valid)
+        out = post_norm(config, layer, "ffn_post_norm", out)
         return constrain(x + out, RESIDUAL), aux, counts
     h = rms_norm(x, layer["mlp_norm"], config.norm_eps)
-    return constrain(x + llama._mlp(layer, h), RESIDUAL), None, None
+    out = post_norm(config, layer, "ffn_post_norm", llama._mlp(layer, h))
+    return constrain(x + out, RESIDUAL), None, None
 
 
 def decoder_layer(config, layer: Params, x: jax.Array, attend: Attend, *,
                   routed: bool, lora: Optional[Lora] = None,
                   valid: Optional[jax.Array] = None):
-    """One pre-norm decoder layer on x [..., d] (``routed``: see ``ffn``);
-    returns what ``ffn`` returns."""
+    """One pre-norm decoder layer on x [..., d] (``routed``: see ``ffn``;
+    sandwich-normed where ``post_norm`` says); returns what ``ffn``
+    returns."""
     h = rms_norm(x, layer["attn_norm"], config.norm_eps)
     logits = None
     if getattr(config, "router_before_attn", False):
         logits = moe.router_logits(layer["moe"], h)
-    x = constrain(x + attention(config, layer["attn"], h, attend, lora),
-                  RESIDUAL)
+    out = post_norm(config, layer, "attn_post_norm",
+                    attention(config, layer["attn"], h, attend, lora))
+    x = constrain(x + out, RESIDUAL)
     return ffn(config, layer, x, valid, logits, routed=routed)
 
 
 def decoder_stack(config, params: Params, tokens: jax.Array,
                   layer_fn: Callable[[int, Params, jax.Array], Tuple]
                   ) -> Tuple[jax.Array, List, List]:
-    """Embedding, ``layer_fn(i, layer, x) -> (x, aux, counts)`` over the
+    """Embedding (scaled where ``embed_scale`` says),
+    ``layer_fn(i, layer, x) -> (x, aux, counts)`` over the
     layers (a Python list: the program is unrolled), final norm.  Returns
     (hidden [..., d], the layers' aux losses, their expert counts); the
     head stays with the caller, which takes its own rows of the hidden
     state."""
     table = constrain(params["embed"], VOCAB_ROWS)
-    x = constrain(table[tokens].astype(config.dtype), RESIDUAL)
+    x = table[tokens].astype(config.dtype)
+    if getattr(config, "embed_scale", 1.0) != 1.0:
+        x = x * config.embed_scale
+    x = constrain(x, RESIDUAL)
     auxes, counts = [], []
     for i, layer in enumerate(params["layers"]):
         x, aux, c = layer_fn(i, layer, x)

@@ -161,20 +161,29 @@ def llama_init(config: LlamaConfig, key: jax.Array) -> Params:
 
 
 def qk_norm_init(config) -> Params:
-    """The two QK-norm weights of one layer's ``attn`` (see ``_qk_norm``)."""
-    return {"q_norm": jnp.ones((config.n_heads * config.head_dim,),
-                               config.dtype),
-            "k_norm": jnp.ones((config.n_kv_heads * config.head_dim,),
-                               config.dtype)}
+    """The two QK-norm weights of one layer's ``attn`` (see ``_qk_norm``):
+    over the projections' widths, or one head's where ``qk_norm`` is
+    ``"head"``."""
+    q_heads, k_heads = (1, 1) if config.qk_norm == "head" \
+        else (config.n_heads, config.n_kv_heads)
+    return {"q_norm": jnp.ones((q_heads * config.head_dim,), config.dtype),
+            "k_norm": jnp.ones((k_heads * config.head_dim,), config.dtype)}
 
 
 def _qk_norm(config, a: Params, q: jax.Array, k: jax.Array):
     """QK-norm where the configuration has it: q [..., H*D] and
-    k [..., H_kv*D] are the flat projections, normalised over their whole
-    width before they are split into heads and rotated.  A trace-time
-    branch: a model without it compiles to the program it always did."""
+    k [..., H_kv*D] are the flat projections, normalised before they are
+    split into heads and rotated: over their whole width, or
+    (``qk_norm`` ``"head"``) each head over its own ``head_dim`` with one
+    weight that the heads share.  A trace-time branch: a model without it
+    compiles to the program it always did."""
     if not config.qk_norm:
         return q, k
+    if config.qk_norm == "head":
+        def a_head(x, w):
+            heads = x.reshape(*x.shape[:-1], -1, config.head_dim)
+            return rms_norm(heads, w, config.norm_eps).reshape(x.shape)
+        return a_head(q, a["q_norm"]), a_head(k, a["k_norm"])
     return (rms_norm(q, a["q_norm"], config.norm_eps),
             rms_norm(k, a["k_norm"], config.norm_eps))
 
@@ -188,7 +197,7 @@ def llama_sharding_rules() -> ShardingRules:
     return ShardingRules([
         (r"embed", P((AXIS_TP, AXIS_FSDP), None)),
         (r"lm_head", P(AXIS_FSDP, AXIS_TP)),
-        (r"attn/(wq|wk|wv)", P(AXIS_FSDP, AXIS_TP)),
+        (r"attn/(wq|wk|wv|wg)", P(AXIS_FSDP, AXIS_TP)),
         (r"attn/wo", P(AXIS_TP, AXIS_FSDP)),
         (r"mlp/(w1|w3)", P(AXIS_FSDP, AXIS_TP)),
         (r"mlp/w2", P(AXIS_TP, AXIS_FSDP)),

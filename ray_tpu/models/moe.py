@@ -1,5 +1,5 @@
-"""Mixture-of-Experts decoder (Mixtral, OLMoE, SmallThinker, GLM-4.7-Flash),
-TPU-first with expert parallelism.
+"""Mixture-of-Experts decoder (Mixtral, OLMoE, SmallThinker, GLM-4.7-Flash,
+Trinity-Mini), TPU-first with expert parallelism.
 
 The reference framework has no MoE/EP feature (SURVEY §2.4: expert parallel
 "absent as a framework feature") — this is a net-new, first-class TPU
@@ -10,7 +10,7 @@ Design (token-choice top-k, no capacity and no drops):
 - router: logits [.., E] in float32; softmax over all experts, the top-k
   probabilities kept as they are (OLMoE) or renormalised (Mixtral); or
   sigmoid scores, the top-k chosen by score plus a bias that enters the
-  choice only, renormalised and scaled (GLM-4.7-Flash)
+  choice only, renormalised and scaled (GLM-4.7-Flash, Trinity-Mini)
 - the tokens x top_k (token, expert) pairs are sorted by expert, so each
   expert's rows are one contiguous group of a [tokens*k, d] array
 - experts: three grouped products over those groups: memory and operations
@@ -81,7 +81,19 @@ class MoEConfig:
     experts are scored each on its own, the ``top_k`` largest of score plus
     the layer's ``router_bias`` are taken, and their weights are the bare
     scores (renormalised where ``norm_topk_prob``); every routed weight is
-    multiplied by ``routed_scaling_factor``."""
+    multiplied by ``routed_scaling_factor``.
+
+    Trinity-Mini's fields (``afmoe``; the first configuration whose
+    ``ffn_layout`` and ``window_layout`` are both set).  ``qk_norm``
+    ``"head"``: q and k are RMS-normalised a head, one weight of
+    ``head_dim`` each (``True`` is OLMoE's: one weight over the
+    projection's whole width).  ``attn_gate``: attention's heads are
+    multiplied by ``sigmoid(h Wg)``, ``Wg`` [d, H * D] on the layer's
+    normalised input, before the output projection.  ``post_norm``: a
+    sandwich-normed block, ``x + N2(Attn(N1 x))`` then
+    ``x + N4(FFN(N3 x))`` (``attn_post_norm``, ``ffn_post_norm``).
+    ``embed_scale``: the embedding's rows are multiplied by it
+    (``sqrt(d_model)`` under muP; 1: not at all)."""
 
     vocab_size: int = 32000
     d_model: int = 4096
@@ -92,7 +104,7 @@ class MoEConfig:
     n_experts: int = 8
     top_k: int = 2
     norm_topk_prob: bool = True
-    qk_norm: bool = False
+    qk_norm: Any = False          # False | True (the width) | "head"
     max_seq: int = 4096
     rope_theta: float = 10000.0
     norm_eps: float = 1e-5
@@ -115,6 +127,9 @@ class MoEConfig:
     n_shared_experts: int = 0
     router_score: str = "softmax"
     routed_scaling_factor: float = 1.0
+    attn_gate: bool = False
+    post_norm: bool = False
+    embed_scale: float = 1.0
 
     def __post_init__(self):
         latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
@@ -123,9 +138,10 @@ class MoEConfig:
             if not all(x > 0 for x in latent) or self.qk_rope_head_dim % 2:
                 raise ValueError(f"latent attention needs all five of its "
                                  f"widths, got {latent}")
-            if self.n_kv_heads != self.n_heads or self.qk_norm:
-                raise ValueError("latent attention has a key for every head "
-                                 "and no QK-norm")
+            if self.n_kv_heads != self.n_heads or self.qk_norm \
+                    or self.attn_gate:
+                raise ValueError("latent attention has a key for every head, "
+                                 "no QK-norm and no gate")
             object.__setattr__(
                 self, "head_dim",
                 self.qk_nope_head_dim + self.qk_rope_head_dim)
@@ -141,6 +157,8 @@ class MoEConfig:
         if any(self.window_layout) and self.window <= 0:
             raise ValueError("window_layout names window layers and window "
                              "is not positive")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm {self.qk_norm!r}")
         if self.expert_act not in ("silu", "relu"):
             raise ValueError(f"expert_act {self.expert_act!r}")
         if self.router_score not in ("softmax", "sigmoid"):
@@ -155,7 +173,9 @@ class MoEConfig:
         q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
         attn = 2 * d * q + 2 * d * kv
         if self.qk_norm:
-            attn += q + kv
+            attn += 2 * self.head_dim if self.qk_norm == "head" else q + kv
+        if self.attn_gate:
+            attn += d * q
         if self.kv_lora_rank:
             rq, rkv, rope = (self.q_lora_rank, self.kv_lora_rank,
                              self.qk_rope_head_dim)
@@ -169,7 +189,8 @@ class MoEConfig:
         if self.router_score == "sigmoid":
             routed += self.n_experts                 # the selection bias
         n_routed = sum(self.ffn_layout) if self.ffn_layout else self.n_layers
-        return (v * d + self.n_layers * (attn + 2 * d) + n_routed * routed
+        norms = 4 * d if self.post_norm else 2 * d
+        return (v * d + self.n_layers * (attn + norms) + n_routed * routed
                 + (self.n_layers - n_routed) * 3 * d * self.dense_d_ff
                 + d + d * v)
 
@@ -223,8 +244,9 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
     }
     for i in range(config.n_layers):
         ks = jax.random.split(keys[2 + i], 8)
-        # What only GLM-4.7-Flash's line has draws from keys of its own,
-        # so that the older architectures' weights are what they were.
+        # What only GLM-4.7-Flash's and Trinity-Mini's lines have draws
+        # from keys of its own, so that the older architectures' weights
+        # are what they were.
         more = jax.random.split(jax.random.fold_in(keys[2 + i], 1), 8)
         layer = {"attn_norm": jnp.ones((d,), config.dtype)}
         if config.kv_lora_rank:
@@ -251,6 +273,16 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
             }
         if config.qk_norm:
             layer["attn"].update(qk_norm_init(config))
+        if config.attn_gate:
+            layer["attn"]["wg"] = dense(more[5], (d, q_out), std)
+        if config.post_norm:
+            # At the embedding's scale: a half-block joins a stream whose
+            # embedding was multiplied by ``embed_scale``, and with ones
+            # here the layers of a seeded model would be a fiftieth of it
+            # (no comparison with a reference would see a fault in one).
+            for name in ("attn_post_norm", "ffn_post_norm"):
+                layer[name] = jnp.full((d,), config.embed_scale,
+                                       config.dtype)
         params["layers"].append(layer)
         if config.ffn_layout and not config.ffn_layout[i]:  # a dense layer
             layer["mlp_norm"] = jnp.ones((d,), config.dtype)
