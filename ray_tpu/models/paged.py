@@ -50,11 +50,15 @@ over cached rows attend ABSORBED, in the latent (``_latent_attend``): the
 row is the key of ONE KV head that all query heads share, and its first
 ``kv_lora_rank`` columns are that head's value; ``wkv_b`` is multiplied
 into the queries and into the output, never into the cached rows.  The
-cold prefill expands its own chunk's keys and values.  On a TPU the decode
-step does not gather: a Pallas kernel walks each slot's LIVE pages where
-they lie and reads each once (``ops/latent_decode.py``,
-``_walks_live_pages``); the gather form is its reference, and what the
-suffix prefill's many query rows and every other backend take.
+cold prefill expands its own chunk's keys and values.
+
+On a TPU the decode step of a latent model, and of a model with window
+layers, does not gather: a Pallas kernel walks each slot's LIVE pages where
+they lie and reads each once (``ops/latent_decode.py`` over the latent
+pool; ``ops/paged_decode.py`` over K/V pairs, on the whole-length layers
+and on the rings alike; ``_walks_live_pages`` chooses).  The gather form is
+their reference, and what the suffix prefill's many query rows, every other
+backend and a configuration of the one whole-length kind take.
 
 Compile counts are observable via ``trace_count()`` — the jitted bodies
 bump a counter when TRACED (python executes only at trace time), which is
@@ -69,7 +73,7 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from ..ops import latent_decode
+from ..ops import latent_decode, paged_decode
 from ..ops.rotary import apply_rotary, rope_frequencies
 from . import block
 from .llama import LlamaConfig
@@ -259,8 +263,8 @@ def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
 ROUTING_KEYS = ("experts_hit", "expert_pairs", "expert_load_max")
 #: What the decode step of a configuration with window layers, or with a
 #: latent pool, appends behind them (``_with_kv_rows``): the rows the program
-#: brought in (a gather's whole tables, or the pages the latent kernel
-#: walks) and the rows a query could see.
+#: brought in (a gather's whole tables, or the pages a kernel walks) and
+#: the rows a query could see.
 KV_KEYS = ("kv_rows_read", "kv_rows_live")
 
 
@@ -299,11 +303,26 @@ def _with_routing(toks: jax.Array, counts: List[Optional[jax.Array]]
 
 
 def _walks_live_pages(config) -> bool:
-    """Whether the decode step of ``config`` attends through
-    ``ops.latent_decode_attention`` in place of ``_attend_pages``' gather:
-    a latent pool (one query row a slot is what a decode step has), on a
-    TPU.  The one place that chooses; ``_with_kv_rows`` counts by it."""
-    return block.is_latent(config) and latent_decode.on_tpu()
+    """Whether the decode step of ``config`` (one query row a slot) attends
+    through a kernel that walks the live pages in place of
+    ``_attend_pages``' gather: on a TPU, a latent pool
+    (``ops.latent_decode_attention``) or a model with window layers
+    (``ops.paged_decode_attention``, on its whole-length layers and its
+    rings), which are the configurations whose decode program counts its
+    rows.  The one place that chooses; ``_with_kv_rows`` counts by it."""
+    kernel = latent_decode if block.is_latent(config) else paged_decode
+    return _counts_kv_rows(config) and kernel.on_tpu()
+
+
+def decode_attention_form(config) -> str:
+    """The form the decode program of ``config`` attends its cache in, by
+    name (``LLMServer.stats()["decode_attention"]``)."""
+    return "walk" if _walks_live_pages(config) else "gather"
+
+
+def _window_lo(config, seq_lens: jax.Array) -> jax.Array:
+    """The first position a window layer's query at ``seq_lens`` sees."""
+    return jnp.maximum(0, seq_lens - config.window + 1)
 
 
 def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
@@ -314,15 +333,18 @@ def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
     the rows of K (of the latent pool) the program brought in, and the rows
     a query could see (``len + 1`` on a whole-length layer, at most the
     window on a window layer, nothing in an empty slot).  What it brings in
-    is every slot's whole table, live or not, where it gathers; where the
-    latent kernel walks (``_walks_live_pages``), each slot's live pages,
-    ``seq_lens // page + 1`` of them (one of an empty slot)."""
+    is every slot's whole table, live or not, where it gathers; where a
+    kernel walks (``_walks_live_pages``), the pages it visits: of a
+    whole-length table ``seq_lens // page + 1`` a slot, of a ring those from
+    the window's first position to ``seq_lens``' (one of an empty slot)."""
     whole, window = kv_layers(config)
     if not _counts_kv_rows(config):
         return toks
     B = seq_lens.shape[0]
     if _walks_live_pages(config):
-        read = len(whole) * ps * jnp.sum(seq_lens // ps + 1)
+        last = seq_lens // ps
+        read = ps * (len(whole) * jnp.sum(last + 1) + len(window) * jnp.sum(
+            last - _window_lo(config, seq_lens) // ps + 1))
     else:
         read = B * ps * (
             len(whole) * page_tables.shape[1]
@@ -540,7 +562,7 @@ def _write_kv(pools: PagedPools, layer: int, page_idx: jax.Array,
 
 
 def _paged_attend(config, pools: PagedPools, i: int, q, k, v, *, whole,
-                  ring):
+                  ring, walk_lens: Optional[jax.Array] = None):
     """What the decode step and the suffix prefill do in layer ``i`` with
     q [B, Q, H, D] and the new rows' k, v [N, H_kv, D] (rotated by the
     caller where the layer has rotary): the rows written into the layer's
@@ -548,13 +570,22 @@ def _paged_attend(config, pools: PagedPools, i: int, q, k, v, *, whole,
     ``ring`` are each ``(page_idx [N], off [N], tables [B, T], visible
     [B, Q, T*page])``: the write indices and the read side of the
     whole-length table and of the window layers' ring (None for a
-    configuration that has none)."""
+    configuration that has none).  ``walk_lens`` [B] (the decode step's
+    ``seq_lens``, where ``_walks_live_pages``; Q is 1): the kernel walks the
+    pages that hold what ``visible`` says, in place of the gather."""
     kind, slot = _kv_slot(config, i)
     page_idx, off, tables, visible = ring if kind else whole
     _write_kv(pools, slot, page_idx, off, **{"k" + kind: k, "v" + kind: v})
     with jax.named_scope("attn_window" if kind else "attn_global"):
-        return _attend_pages(config, q, pools["k" + kind],
-                             pools["v" + kind], slot, tables, visible)
+        if walk_lens is None:
+            return _attend_pages(config, q, pools["k" + kind],
+                                 pools["v" + kind], slot, tables, visible)
+        lo = _window_lo(config, walk_lens) if kind \
+            else jnp.zeros_like(walk_lens)
+        return paged_decode.paged_decode_attention(
+            q[:, 0], pools["k" + kind], pools["v" + kind], slot, tables, lo,
+            walk_lens, sm_scale=config.head_dim ** -0.5
+        ).reshape(q.shape[0], 1, -1)
 
 
 def _tile_padded(config, *parts: jax.Array) -> jax.Array:
@@ -637,20 +668,23 @@ def decode_logits(config, params: Params, pools: PagedPools,
                 ring_tables,
                 ((held >= 0) & (age >= 0) & (age < config.window))[:, None])
 
+    walk_lens = seq_lens if _walks_live_pages(config) else None
+
     def attend(i, q, k, v):  # one row a slot: [B, H, D]
         if block.layer_rotary(config, i):
             q = _rotary_single(q, cos, sin, seq_lens)
             k = _rotary_single(k, cos, sin, seq_lens)
         return _paged_attend(
             config, pools, i, q[:, None], k, v,
-            whole=(page_idx, off, page_tables, visible), ring=ring)[:, 0]
+            whole=(page_idx, off, page_tables, visible), ring=ring,
+            walk_lens=walk_lens)[:, 0]
 
     if block.is_latent(config):  # one row a slot: q [B, H, D], c, k_r
         attend = functools.partial(
             _latent_attend, config, pools, cos=cos, sin=sin,
             positions=seq_lens, page_idx=page_idx, off=off,
             tables=page_tables, visible=visible, scope="attn_latent",
-            walk_lens=seq_lens if _walks_live_pages(config) else None)
+            walk_lens=walk_lens)
 
     x, counts = _stack(config, params, tokens[:B], attend,
                        _adapter_lora(adapters, adapter_ids), active)
@@ -682,8 +716,9 @@ def paged_decode_step(config: LlamaConfig, params: Params,
     Pools are donated and keep their layout through the program
     (``tests/test_chip_compile.py`` holds the compiled step to it), so
     steady-state decode never copies the cache: a layer writes B rows in
-    place and reads one gather of the page tables (a latent model's on a
-    TPU, the live pages where they lie: ``_walks_live_pages``).  ring_tables
+    place and reads one gather of the page tables (on a TPU a latent
+    model's, and a model's with window layers, the live pages where they
+    lie: ``_walks_live_pages``).  ring_tables
     [B, entries] int32 are the window layers' rings (None for a
     configuration without them): those layers write and gather through
     them, that wide and no wider; the branch is taken layer by layer at

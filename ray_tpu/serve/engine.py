@@ -724,7 +724,7 @@ class InferenceEngine:
             }
         active = sum(1 for s in self.slots if s is not None)
         from ..models.moe import grouped_form
-        from ..models.paged import trace_count
+        from ..models.paged import decode_attention_form, trace_count
 
         return {
             "steps": self.step_count,
@@ -758,6 +758,10 @@ class InferenceEngine:
             "grouped_ffn": (
                 grouped_form(self.model_config, self.config.batch_slots)
                 if "experts_hit" in self._counter_keys else None),
+            # The form the decode program attends its cache in: "walk" (a
+            # kernel over the live pages: ops/latent_decode.py,
+            # ops/paged_decode.py) or "gather".
+            "decode_attention": decode_attention_form(self.model_config),
             "adapters": self.adapter_pool.stats(),
         }
 
@@ -1814,10 +1818,12 @@ class LLMServer:
         if programs:  # once, into the replica's log
             print(f"set-up of {model}: {_setup_table(self._setup)}",
                   file=sys.stderr, flush=True)
-            form = self.engine.stats()["grouped_ffn"]
-            if form:
-                print(f"the decode step's grouped products: {form}",
-                      file=sys.stderr, flush=True)
+            stats = self.engine.stats()
+            if stats["grouped_ffn"]:
+                print("the decode step's grouped products: "
+                      f"{stats['grouped_ffn']}", file=sys.stderr, flush=True)
+            print(f"the decode step's attention: {stats['decode_attention']}",
+                  file=sys.stderr, flush=True)
 
     def load_adapter(self, name: str, source: Any = None) -> str:
         """Register a LoRA adapter on this replica's engine.  ``source``
