@@ -11,7 +11,12 @@ for the deltas on the q and v projections.  Where attention goes through a
 compressed latent (``is_latent``) the closure is handed the latent and
 chooses the form: ``attend(q, c, k_r, wkv_b)`` expands K and V out of it
 (the full forward, a cold prefill) or absorbs ``wkv_b`` into q and the
-output (the paged cache's programs).
+output (the paged cache's programs).  A gated delta-rule layer (``is_kda``,
+``models/kda.py``) keeps a recurrent state and no cache rows:
+``attend(pre, g, beta, a)`` is handed the q/k/v projections before their
+convolution, the log-decay and the write strength, owns the state, and
+chooses the recurrent or the chunk form.  A configuration with an
+``attn_layout`` has layers of both kinds: a program asks each layer.
 
 ``llama`` and ``moe`` are reached through their modules, at call time: the
 helpers a test swaps there (``llama._qk_norm``, ``moe._moe_ffn``) are the
@@ -34,7 +39,7 @@ import jax.numpy as jnp
 from ..ops.norms import rms_norm
 from ..parallel.sharding import (HEADS, RESIDUAL, SPLIT, VOCAB_ROWS,
                                  constrain)
-from . import llama, moe
+from . import kda, llama, moe
 
 Params = Dict[str, Any]
 #: ``attend(q [..., H, D], k, v [..., H_kv, D]) -> [..., H*D]``; of a
@@ -44,11 +49,25 @@ Attend = Callable[..., jax.Array]
 Lora = Callable[[str, jax.Array], jax.Array]
 
 
-def is_latent(config) -> bool:
-    """Whether ``config``'s attention goes through a compressed latent
-    (``MoEConfig.kv_lora_rank``): the cache then keeps one latent row a
-    token and layer, not K and V."""
-    return getattr(config, "kv_lora_rank", 0) > 0
+def is_latent(config, i: Optional[int] = None) -> bool:
+    """Whether the attention of layer ``i`` of ``config`` goes through a
+    compressed latent (``MoEConfig.kv_lora_rank``): the cache then keeps one
+    latent row a token and layer, not K and V.  With no layer named, whether
+    any layer's does (then the pool behind the page tables is the latent
+    one)."""
+    if getattr(config, "kv_lora_rank", 0) <= 0:
+        return False
+    return i is None or not is_kda(config, i)
+
+
+def is_kda(config, i: Optional[int] = None) -> bool:
+    """Whether layer ``i`` of ``config`` is a gated delta-rule layer
+    (``MoEConfig.attn_layout``, ``models/kda.py``), which keeps a recurrent
+    state a sequence and no cache rows; with no layer named, whether any
+    layer is."""
+    if i is None:
+        return "kda" in getattr(config, "attn_layout", ())
+    return layer_attn(config, i) == "kda"
 
 
 def rotary_dim(config) -> int:
@@ -63,7 +82,11 @@ def project_latent(config, a: Params, h: jax.Array):
     rotated yet), the normalised K/V latent c [..., kv_lora_rank], and the
     one rotary key k_r [..., rope] every head shares (not rotated yet).
     ``[c ; RoPE(k_r)]`` is what a cache keeps of the token."""
-    q = rms_norm(h @ a["wq_a"], a["q_norm"], config.norm_eps) @ a["wq_b"]
+    if config.q_lora_rank:
+        q = rms_norm(h @ a["wq_a"], a["q_norm"], config.norm_eps) \
+            @ a["wq_b"]
+    else:  # no q latent: one projection, no norm
+        q = h @ a["wq"]
     kv = h @ a["wkv_a"]
     rank = config.kv_lora_rank
     c = rms_norm(kv[..., :rank], a["kv_norm"], config.norm_eps)
@@ -98,10 +121,15 @@ def project_qkv(config, a: Params, h: jax.Array,
 
 
 def attention(config, a: Params, h: jax.Array, attend: Attend,
-              lora: Optional[Lora] = None) -> jax.Array:
+              lora: Optional[Lora] = None, attn: str = "") -> jax.Array:
     """Attention of normalised ``h`` through the output projection.  A
     latent layer takes no adapter: the deltas on ``wq`` and ``wv`` have no
-    counterpart among its projections (the engine refuses to load one)."""
+    counterpart among its projections (the engine refuses to load one);
+    nor does a KDA layer (``attn`` ``"kda"``: the layer's entry of
+    ``attn_layout``, empty for a configuration of one kind)."""
+    if attn == "kda":
+        return kda.output(config, a, h,
+                          attend(*kda.project(config, a, h), a))
     if is_latent(config):
         out = attend(*project_latent(config, a, h), a["wkv_b"])
     else:
@@ -128,11 +156,21 @@ def layer_rotary(config, i: int) -> bool:
     return bool(layout[i]) if layout else True
 
 
-def layer_kind(config, i: int) -> Tuple[int, bool]:
-    """(``layer_window``, ``layer_rotary``) of layer ``i``: what a program
-    that traces a layer once a kind (``jax.checkpoint`` in the train step)
-    keys the trace on."""
-    return layer_window(config, i), layer_rotary(config, i)
+def layer_attn(config, i: int) -> str:
+    """Layer ``i``'s entry of ``attn_layout`` (``"kda"`` | ``"latent"``);
+    empty for a configuration whose layers are of one kind."""
+    layout = getattr(config, "attn_layout", ())
+    return layout[i] if layout else ""
+
+
+def layer_kind(config, i: int) -> Tuple:
+    """(``layer_window``, ``layer_rotary``) of layer ``i``, and behind them
+    its ``layer_attn`` where the configuration has an ``attn_layout``: what
+    a program that traces a layer once a kind (``jax.checkpoint`` in the
+    train step) keys the trace on."""
+    kind, attn = (layer_window(config, i), layer_rotary(config, i)), \
+        layer_attn(config, i)
+    return kind + (attn,) if attn else kind
 
 
 def is_routed(config, i: Optional[int] = None) -> bool:
@@ -197,16 +235,16 @@ def ffn(config, layer: Params, x: jax.Array,
 
 def decoder_layer(config, layer: Params, x: jax.Array, attend: Attend, *,
                   routed: bool, lora: Optional[Lora] = None,
-                  valid: Optional[jax.Array] = None):
+                  valid: Optional[jax.Array] = None, attn: str = ""):
     """One pre-norm decoder layer on x [..., d] (``routed``: see ``ffn``;
-    sandwich-normed where ``post_norm`` says); returns what ``ffn``
-    returns."""
+    sandwich-normed where ``post_norm`` says; ``attn``: see ``attention``);
+    returns what ``ffn`` returns."""
     h = rms_norm(x, layer["attn_norm"], config.norm_eps)
     logits = None
     if getattr(config, "router_before_attn", False):
         logits = moe.router_logits(layer["moe"], h)
     out = post_norm(config, layer, "attn_post_norm",
-                    attention(config, layer["attn"], h, attend, lora))
+                    attention(config, layer["attn"], h, attend, lora, attn))
     x = constrain(x + out, RESIDUAL)
     return ffn(config, layer, x, valid, logits, routed=routed)
 

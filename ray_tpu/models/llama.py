@@ -257,7 +257,7 @@ def _attend(config, cos, sin, q, k, v, kind=(0, True)):
     pattern."""
     B, S = q.shape[:2]
     q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-    window, rotary = kind
+    window, rotary = kind[:2]
     if window or not rotary:
         if rotary:
             q, k = apply_rotary(q, cos, sin), apply_rotary(k, cos, sin)
@@ -290,15 +290,16 @@ def _attend(config, cos, sin, q, k, v, kind=(0, True)):
     return out.transpose(0, 2, 1, 3).reshape(B, S, -1)
 
 
-def _attend_latent(config, cos, sin, q, c, k_r, wkv_b):
+def _attend_latent(config, cos, sin, q, c, k_r, wkv_b, rotary=True):
     """The full forward's ``attend`` of a latent model (``block.is_latent``),
     the EXPANDED form: every position's keys and values are made out of its
     latent c [B, S, rank] by ``wkv_b``, the one rotary key k_r [B, S, rope]
     is rotated and shared by the heads, q [B, S, H, nope + rope] rotates its
     last ``rope`` dimensions, and the scores are scaled by
-    ``(nope + rope) ** -0.5``.  Plain masked attention (there is no flash
-    kernel for keys and values of two widths: ROADMAP M3).  Returns
-    [B, S, H * v]."""
+    ``(nope + rope) ** -0.5``; ``rotary`` false (the layer's
+    ``block.layer_rotary``): neither rotates.  Plain masked attention
+    (there is no flash kernel for keys and values of two widths: ROADMAP
+    M3).  Returns [B, S, H * v]."""
     from . import block
 
     B, S, H, _ = q.shape
@@ -306,8 +307,10 @@ def _attend_latent(config, cos, sin, q, c, k_r, wkv_b):
     w_uk, w_uv = block.latent_up(config, wkv_b)
     k_n = jnp.einsum("bsc,chd->bshd", c, w_uk)
     v = jnp.einsum("bsc,chd->bshd", c, w_uv)
-    q_r = apply_rotary(q[..., nope:].transpose(0, 2, 1, 3), cos, sin)
-    k_r = apply_rotary(k_r[:, None], cos, sin)             # [B, 1, S, rope]
+    q_r, k_r = q[..., nope:].transpose(0, 2, 1, 3), k_r[:, None]
+    if rotary:
+        q_r = apply_rotary(q_r, cos, sin)
+        k_r = apply_rotary(k_r, cos, sin)                  # [B, 1, S, rope]
     scores = (jnp.einsum("bqhd,bkhd->bhqk", q[..., :nope], k_n,
                          preferred_element_type=jnp.float32)
               + jnp.einsum("bhqd,bkd->bhqk", q_r, k_r[:, 0],
@@ -354,11 +357,18 @@ def _layer(config, x, layer, cos, sin, lora_layer, kind, routed):
     routed FFN's aux loss or None)."""
     from . import block
 
-    attend = functools.partial(_attend_latent, config, cos, sin) \
-        if block.is_latent(config) \
-        else functools.partial(_attend, config, cos, sin, kind=kind)
+    attn = kind[2] if len(kind) > 2 else ""
+    if attn == "kda":
+        from . import kda
+        attend = kda.full_attend(config)
+    elif block.is_latent(config):
+        attend = functools.partial(_attend_latent, config, cos, sin,
+                                   rotary=kind[1])
+    else:
+        attend = functools.partial(_attend, config, cos, sin, kind=kind)
     x, aux, _ = block.decoder_layer(config, layer, x, attend,
-                                    lora=_lora(lora_layer), routed=routed)
+                                    lora=_lora(lora_layer), routed=routed,
+                                    attn=attn)
     return x, aux
 
 

@@ -1,5 +1,5 @@
 """Mixture-of-Experts decoder (Mixtral, OLMoE, SmallThinker, GLM-4.7-Flash,
-Trinity-Mini), TPU-first with expert parallelism.
+Trinity-Mini, Kimi-Linear), TPU-first with expert parallelism.
 
 The reference framework has no MoE/EP feature (SURVEY §2.4: expert parallel
 "absent as a framework feature") — this is a net-new, first-class TPU
@@ -93,7 +93,26 @@ class MoEConfig:
     sandwich-normed block, ``x + N2(Attn(N1 x))`` then
     ``x + N4(FFN(N3 x))`` (``attn_post_norm``, ``ffn_post_norm``).
     ``embed_scale``: the embedding's rows are multiplied by it
-    (``sqrt(d_model)`` under muP; 1: not at all)."""
+    (``sqrt(d_model)`` under muP; 1: not at all).
+
+    Kimi-Linear's fields (``kimi_linear``; the first configuration whose
+    layers differ in the KIND of their attention, and whose chip holds a
+    share of the experts).  ``attn_layout[i]`` ``"kda"``: layer ``i`` is a
+    gated delta-rule layer (``models/kda.py``: ``kda_heads`` heads whose
+    keys and values are ``kda_head_dim`` wide behind a causal depthwise
+    convolution over the last ``kda_conv`` positions; it keeps a recurrent
+    state a sequence, no cache rows); ``"latent"``: latent attention as
+    above (read through ``block.is_kda`` / ``block.is_latent``; empty: every
+    layer is of the configuration's one kind).  ``q_lora_rank`` 0 beside
+    ``kv_lora_rank`` > 0: q is one projection, no latent and no norm.  A
+    latent layer obeys ``rope_layout`` (false: its 64 "rotary" dimensions
+    enter the score unrotated).  ``n_experts`` is the experts this program
+    HOLDS (the width of ``w1``/``w3``/``w2``): experts ``first_expert ..
+    first_expert + n_experts`` of the ``router_experts`` the router scores
+    (0: it scores the held ones, all of them).  A token's ``top_k`` are
+    chosen among all the router's experts; the pairs of experts held
+    elsewhere are dropped before the grouped products, and the layer
+    returns the partial sum of its own."""
 
     vocab_size: int = 32000
     d_model: int = 4096
@@ -130,14 +149,23 @@ class MoEConfig:
     attn_gate: bool = False
     post_norm: bool = False
     embed_scale: float = 1.0
+    attn_layout: Tuple[str, ...] = ()
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 0
+    router_experts: int = 0       # 0 -> n_experts
+    first_expert: int = 0
 
     def __post_init__(self):
-        latent = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+        latent = (self.kv_lora_rank, self.qk_nope_head_dim,
                   self.qk_rope_head_dim, self.v_head_dim)
-        if any(latent):
-            if not all(x > 0 for x in latent) or self.qk_rope_head_dim % 2:
-                raise ValueError(f"latent attention needs all five of its "
-                                 f"widths, got {latent}")
+        if any(latent) or self.q_lora_rank:
+            if not all(x > 0 for x in latent) or self.qk_rope_head_dim % 2 \
+                    or self.q_lora_rank < 0:
+                raise ValueError(
+                    f"latent attention needs all five of its widths (the "
+                    f"q latent alone may be 0: one projection), got "
+                    f"{(self.q_lora_rank, *latent)}")
             if self.n_kv_heads != self.n_heads or self.qk_norm \
                     or self.attn_gate:
                 raise ValueError("latent attention has a key for every head, "
@@ -154,6 +182,33 @@ class MoEConfig:
                 raise ValueError(f"{name} has {len(layout)} entries for "
                                  f"{self.n_layers} layers")
             object.__setattr__(self, name, layout)
+        layout = tuple(str(x) for x in self.attn_layout)
+        object.__setattr__(self, "attn_layout", layout)
+        if layout:
+            if len(layout) != self.n_layers \
+                    or any(a not in ("kda", "latent") for a in layout):
+                raise ValueError(
+                    f"attn_layout is not \"kda\" or \"latent\" for each of "
+                    f"the {self.n_layers} layers: {layout}")
+            if "latent" in layout and not self.kv_lora_rank:
+                raise ValueError("attn_layout names latent layers and "
+                                 "kv_lora_rank is 0")
+            if "kda" in layout and not (self.kda_heads > 0
+                                        and self.kda_head_dim > 0
+                                        and self.kda_conv > 1):
+                raise ValueError(
+                    "attn_layout names kda layers: kda_heads, kda_head_dim "
+                    "and kda_conv (at least 2) are needed")
+            if any(self.window_layout) or self.attn_gate or self.post_norm:
+                raise ValueError("attn_layout goes with no window layer, "
+                                 "gate or sandwich norm")
+        if not 0 <= self.first_expert \
+                <= self.router_width - self.n_experts:
+            raise ValueError(
+                f"experts {self.first_expert}..+{self.n_experts} are not "
+                f"among the router's {self.router_width}")
+        if self.top_k > self.router_width:
+            raise ValueError("top_k is more than the router's experts")
         if any(self.window_layout) and self.window <= 0:
             raise ValueError("window_layout names window layers and window "
                              "is not positive")
@@ -168,6 +223,12 @@ class MoEConfig:
             raise ValueError("ffn_layout names dense layers and dense_d_ff "
                              "is not positive")
 
+    @property
+    def router_width(self) -> int:
+        """The experts the router scores: ``router_experts``, or the held
+        ones where that is 0."""
+        return self.router_experts or self.n_experts
+
     def param_count(self) -> int:
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
@@ -179,18 +240,24 @@ class MoEConfig:
         if self.kv_lora_rank:
             rq, rkv, rope = (self.q_lora_rank, self.kv_lora_rank,
                              self.qk_rope_head_dim)
-            attn = (d * rq + rq + rq * q + d * (rkv + rope) + rkv
+            attn = ((d * rq + rq + rq * q if rq else d * q)
+                    + d * (rkv + rope) + rkv
                     + rkv * self.n_heads * (self.qk_nope_head_dim
                                             + self.v_head_dim)
                     + self.n_heads * self.v_head_dim * d)
-        routed = (d * self.n_experts                 # router
-                  + self.n_experts * 3 * d * f       # experts
+        n_kda = self.attn_layout.count("kda")
+        attn *= self.n_layers - n_kda
+        if n_kda:
+            from . import kda
+            attn += n_kda * kda.param_count(self)
+        routed = (d * self.router_width              # router
+                  + self.n_experts * 3 * d * f       # experts (held)
                   + self.n_shared_experts * 3 * d * f)
         if self.router_score == "sigmoid":
-            routed += self.n_experts                 # the selection bias
+            routed += self.router_width              # the selection bias
         n_routed = sum(self.ffn_layout) if self.ffn_layout else self.n_layers
         norms = 4 * d if self.post_norm else 2 * d
-        return (v * d + self.n_layers * (attn + norms) + n_routed * routed
+        return (v * d + attn + self.n_layers * norms + n_routed * routed
                 + (self.n_layers - n_routed) * 3 * d * self.dense_d_ff
                 + d + d * v)
 
@@ -221,6 +288,7 @@ class MoEConfig:
 
 def moe_init(config: MoEConfig, key: jax.Array) -> Params:
     d, f, E = config.d_model, config.d_ff, config.n_experts
+    routed_E = config.router_width
     hd = config.head_dim
     q_out, kv_out = config.n_heads * hd, config.n_kv_heads * hd
     std = d ** -0.5
@@ -249,13 +317,17 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
         # are what they were.
         more = jax.random.split(jax.random.fold_in(keys[2 + i], 1), 8)
         layer = {"attn_norm": jnp.ones((d,), config.dtype)}
-        if config.kv_lora_rank:
+        if config.attn_layout and config.attn_layout[i] == "kda":
+            from . import kda
+            layer["attn"] = kda.init(config, keys[2 + i])
+        elif config.kv_lora_rank:
             rq, rkv = config.q_lora_rank, config.kv_lora_rank
             o_in = config.n_heads * config.v_head_dim
             layer["attn"] = {
-                "wq_a": dense(ks[0], (d, rq), std),
-                "q_norm": jnp.ones((rq,), config.dtype),
-                "wq_b": dense(ks[1], (rq, q_out), rq ** -0.5),
+                **({"wq_a": dense(ks[0], (d, rq), std),
+                    "q_norm": jnp.ones((rq,), config.dtype),
+                    "wq_b": dense(ks[1], (rq, q_out), rq ** -0.5)} if rq
+                   else {"wq": dense(ks[0], (d, q_out), std)}),
                 "wkv_a": dense(ks[2], (d, rkv + config.qk_rope_head_dim),
                                std),
                 "kv_norm": jnp.ones((rkv,), config.dtype),
@@ -292,7 +364,8 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
         layer["moe"] = {
             # Router in fp32: tiny, and top-k boundaries are precision
             # sensitive.
-            "router": jax.random.normal(ks[4], (d, E), jnp.float32) * std,
+            "router": jax.random.normal(ks[4], (d, routed_E),
+                                        jnp.float32) * std,
             "w1": dense(ks[5], (E, d, f), std),
             "w3": dense(ks[6], (E, d, f), std),
             "w2": dense(ks[7], (E, f, d), f ** -0.5),
@@ -304,7 +377,7 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
             # near ties and leaves the experts' load as the router has it
             # (the published bias exists to even that load, not skew it).
             layer["moe"]["router_bias"] = jax.random.normal(
-                more[1], (E,), jnp.float32) / (2 * E)
+                more[1], (routed_E,), jnp.float32) / (2 * routed_E)
         if config.n_shared_experts:
             layer["moe"]["shared"] = swiglu(
                 more[2:], config.n_shared_experts * f)
@@ -389,9 +462,11 @@ def _streams_experts(config: MoEConfig, pairs: int) -> bool:
     and up), widths the kernel's DMAs can cut.  ``pairs`` is the program's
     static count, so one program holds one form.  The one place that
     chooses; the kernel defines no gradient, which training does not ask of
-    it at such a size."""
+    it at such a size.  Where the program holds a share of the router's
+    experts, ``pairs`` are the pairs routed, of which its share lands here:
+    the rows an expert gets are ``pairs`` over the ROUTER's width."""
     return (grouped_ffn.on_tpu()
-            and pairs <= STREAM_ROWS_AN_EXPERT * config.n_experts
+            and pairs <= STREAM_ROWS_AN_EXPERT * config.router_width
             and config.d_model % grouped_ffn.LANES == 0
             and config.d_ff % grouped_ffn.LANES == 0)
 
@@ -409,7 +484,11 @@ def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Top-k expert FFN over x [..., d]: every token reaches all ``top_k``
     of its experts at any load.  Returns (out, aux_loss, counts), counts
-    [E] int32 = the tokens each expert got.
+    [E] int32 = the tokens each expert got.  Where the program holds a
+    share of the router's experts (``router_width`` > ``n_experts``) the
+    pairs of the others are dropped as a padded row's are, ``out`` is the
+    partial sum over the held ones, and ``counts`` has one more entry
+    behind the held experts': the pairs routed, here or elsewhere.
 
     ``valid`` [...] bool marks the real tokens of a statically shaped batch
     (the serving programs' padded prompt rows and empty slots): the others
@@ -418,12 +497,22 @@ def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
     router's where the layer took them before attention."""
     lead, d = x.shape[:-1], x.shape[-1]
     E, k = config.n_experts, config.top_k
+    routed_E = config.router_width
+    share = routed_E > E
     xf = x.reshape(-1, d)
     G = xf.shape[0]
     with jax.named_scope("moe_ffn"):
         probs, top_p, top_e = _route(
             config, moe, xf,
-            None if logits is None else logits.reshape(G, E))
+            None if logits is None else logits.reshape(G, routed_E))
+        if share:  # the held experts' own numbers; the others' as padding
+            first = top_e[:, 0]
+            routed = jnp.asarray(G * k, jnp.int32)
+            if valid is not None:
+                first = jnp.where(valid.reshape(G), first, routed_E)
+                routed = k * jnp.sum(valid, dtype=jnp.int32)
+            top_e = top_e - config.first_expert
+            top_e = jnp.where((top_e >= 0) & (top_e < E), top_e, E)
         if valid is not None:
             top_e = jnp.where(valid.reshape(G, 1), top_e, E)
         # Sort the G*k pairs by expert (stable: token order inside a
@@ -449,15 +538,18 @@ def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
                  ).astype(config.dtype)
             ys = grouped(h, moe["w2"])
         ys = ys[rank].reshape(G, k, d)                         # float32
-        if valid is not None:  # rows behind the last group are not written
-            ys = jnp.where((top_e < E)[..., None], ys, 0.0)
+        if valid is not None or share:  # rows behind the last group are
+            ys = jnp.where((top_e < E)[..., None], ys, 0.0)  # not written
         out = jnp.einsum("gk,gkd->gd", top_p, ys).astype(config.dtype)
 
         # Switch load-balancing loss: E * sum_e f_e * P_e, where f_e is the
         # fraction of tokens whose TOP-1 choice is e and P_e the mean router
         # probability for e.
-        top1 = jax.nn.one_hot(top_e[:, 0], E, dtype=jnp.float32)
-        aux = E * jnp.sum(top1.mean(0) * probs.mean(0))
+        top1 = jax.nn.one_hot(first if share else top_e[:, 0], routed_E,
+                              dtype=jnp.float32)
+        aux = routed_E * jnp.sum(top1.mean(0) * probs.mean(0))
+        if share:
+            counts = jnp.concatenate([counts, routed[None]])
     return out.reshape(*lead, d), aux, counts
 
 

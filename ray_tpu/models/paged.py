@@ -60,6 +60,17 @@ and on the rings alike; ``_walks_live_pages`` chooses).  The gather form is
 their reference, and what the suffix prefill's many query rows, every other
 backend and a configuration of the one whole-length kind take.
 
+A fourth kind of per-sequence state where the configuration has gated
+delta-rule layers (``block.is_kda``, ``models/kda.py``): no rows at all, but
+a float32 matrix state a head and the last pre-activation rows of the
+layer's convolutions, a SLOT's each (pools ``S`` and ``conv``, indexed
+[KDA layer, slot]; ``kda.state_shapes``).  Nothing allocates it: a slot owns
+its state as it owns its ring.  The decode step reads and writes every
+active slot's; a cold prefill starts from zeros and leaves the state behind
+its last real row in the slot it is told (``state_slot``), and each further
+chunk of a chunked prompt takes the slot's state in and hands it on.  The
+latent pool beside it holds rows for the latent layers only.
+
 Compile counts are observable via ``trace_count()`` — the jitted bodies
 bump a counter when TRACED (python executes only at trace time), which is
 how tests assert the engine never recompiles after warmup.
@@ -68,6 +79,7 @@ how tests assert the engine never recompiles after warmup.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -75,7 +87,7 @@ import jax.numpy as jnp
 
 from ..ops import latent_decode, paged_decode
 from ..ops.rotary import apply_rotary, rope_frequencies
-from . import block
+from . import block, kda
 from .llama import LlamaConfig
 
 Params = Any
@@ -114,10 +126,29 @@ def _bump(name: str, **arrays: Any) -> None:
 def kv_layers(config):
     """(the layers that keep a sequence's whole length, the window layers):
     each a list of layer indices, in order.  A layer's place in its list is
-    its index in that kind's pools."""
+    its index in that kind's pools.  A gated delta-rule layer keeps no rows
+    and is in neither (``state_layers``)."""
     layers = range(config.n_layers)
     window = [i for i in layers if block.layer_window(config, i)]
-    return [i for i in layers if i not in window], window
+    return [i for i in layers
+            if i not in window and not block.is_kda(config, i)], window
+
+
+def state_layers(config) -> List[int]:
+    """The gated delta-rule layers, in order: a layer's place in the list is
+    its index in the state pools ``S`` and ``conv``."""
+    return [i for i in range(config.n_layers) if block.is_kda(config, i)]
+
+
+def state_bytes(config, slots: int = 1) -> int:
+    """The bytes of recurrent state ``slots`` sequences hold (0 without
+    gated delta-rule layers)."""
+    layers = state_layers(config)
+    if not layers:
+        return 0
+    return sum(math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
+               for s in kda.state_shapes(config, len(layers),
+                                         slots).values())
 
 
 def _kv_slot(config, i: int):
@@ -148,16 +179,27 @@ def latent_row_width(config) -> int:
 
 
 def init_paged_pools(config: LlamaConfig, num_pages: int,
-                     page_size: int, window_pages: int = 0) -> PagedPools:
+                     page_size: int, window_pages: int = 0,
+                     state_slots: int = 0) -> PagedPools:
     """One pool pair a kind of layer for the whole replica (of a latent
-    model, the one pool ``kv`` of latent rows); the last index
-    (``num_pages``, ``window_pages``) of each is its scratch page (writes
-    routed there are never read)."""
+    model, the one pool ``kv`` of latent rows, over its latent layers); the
+    last index (``num_pages``, ``window_pages``) of each is its scratch page
+    (writes routed there are never read).  Beside them, where the
+    configuration has gated delta-rule layers, the recurrent state of
+    ``state_slots`` sequences (``kda.state_shapes``), zeros."""
     whole, window = kv_layers(config)
     if block.is_latent(config):
-        return {"kv": jnp.zeros(
+        pools = {"kv": jnp.zeros(
             (len(whole), num_pages + 1, page_size,
              latent_row_width(config)), config.dtype)}
+        if block.is_kda(config):
+            if state_slots <= 0:
+                raise ValueError("a configuration with gated delta-rule "
+                                 "layers keeps a state a slot: state_slots")
+            for name, shape in kda.state_shapes(
+                    config, len(state_layers(config)), state_slots).items():
+                pools[name] = jnp.zeros(shape.shape, shape.dtype)
+        return pools
 
     def pair(suffix, n_layers, pages):
         shape = (n_layers, pages + 1, page_size,
@@ -261,6 +303,10 @@ def _attend_pages(config: LlamaConfig, q: jax.Array, k_pool: jax.Array,
 
 #: What ``_with_routing`` appends, in its order: the step record's keys.
 ROUTING_KEYS = ("experts_hit", "expert_pairs", "expert_load_max")
+#: Behind them where the program holds a share of the router's experts
+#: (the three above count its OWN experts and the pairs that land on them):
+#: the pairs the router made, here or elsewhere (real rows x ``top_k``).
+SHARE_KEYS = ("expert_pairs_routed",)
 #: What the decode step of a configuration with window layers, or with a
 #: latent pool, appends behind them (``_with_kv_rows``): the rows the program
 #: brought in (a gather's whole tables, or the pages a kernel walks) and
@@ -272,11 +318,20 @@ def _counts_kv_rows(config) -> bool:
     return bool(kv_layers(config)[1]) or block.is_latent(config)
 
 
+def routing_keys(config) -> tuple:
+    """The names of the routing counters a program of ``config`` appends
+    (``_with_routing``), in their order; none where no layer is routed."""
+    if not block.is_routed(config):
+        return ()
+    share = config.router_width > config.n_experts
+    return ROUTING_KEYS + (SHARE_KEYS if share else ())
+
+
 def counter_keys(config) -> tuple:
     """The names of the int32 counters the decode program of ``config``
     appends to the tokens it returns, in their order (a prefill appends
-    the ``ROUTING_KEYS`` among them)."""
-    return (ROUTING_KEYS if block.is_routed(config) else ()) \
+    the ``routing_keys`` among them)."""
+    return routing_keys(config) \
         + (KV_KEYS if _counts_kv_rows(config) else ())
 
 
@@ -286,20 +341,25 @@ def routing_width(config) -> int:
     return len(counter_keys(config))
 
 
-def _with_routing(toks: jax.Array, counts: List[Optional[jax.Array]]
-                  ) -> jax.Array:
+def _with_routing(config, toks: jax.Array,
+                  counts: List[Optional[jax.Array]]) -> jax.Array:
     """``toks`` [N] int32, followed (where the layers were routed) by the
-    program's ``ROUTING_KEYS``: over all layers, the experts that got a
-    token, the (token, expert) pairs routed, and the most tokens on one
-    expert of one layer.  They ride in the array the engine reads back
+    program's ``routing_keys``: over all layers, the experts that got a
+    token, the (token, expert) pairs routed to them, and the most tokens on
+    one expert of one layer; where the program holds a share of the experts
+    (``_moe_ffn`` then appends the pairs routed anywhere to a layer's
+    counts), those summed too.  They ride in the array the engine reads back
     anyway, so they cost it no transfer of their own.  A dense layer among
     routed ones (its entry is None) has no experts to count."""
     counts = [c for c in counts if c is not None]
     if not counts:
         return toks
-    c = jnp.stack(counts)  # [routed layers, E]
+    c = jnp.stack(counts)  # [routed layers, E] (E + 1 of a share)
+    more = []
+    if c.shape[1] > config.n_experts:
+        more, c = [jnp.sum(c[:, -1])], c[:, :-1]
     return jnp.concatenate([toks, jnp.stack(
-        [jnp.sum(c > 0, dtype=jnp.int32), jnp.sum(c), jnp.max(c)])])
+        [jnp.sum(c > 0, dtype=jnp.int32), jnp.sum(c), jnp.max(c)] + more)])
 
 
 def _walks_live_pages(config) -> bool:
@@ -357,11 +417,11 @@ def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
                           live.astype(jnp.int32)])])
 
 
-def _first_token(tok: jax.Array, counts) -> jax.Array:
-    """A prefill's result: the scalar token, or [token, *ROUTING_KEYS]
+def _first_token(config, tok: jax.Array, counts) -> jax.Array:
+    """A prefill's result: the scalar token, or [token, *routing_keys]
     where a layer was routed."""
     return tok[0] if all(c is None for c in counts) \
-        else _with_routing(tok, counts)
+        else _with_routing(config, tok, counts)
 
 
 # ------------------------------------------------------- adapter pool
@@ -537,19 +597,62 @@ def _sample_tokens(logits: jax.Array, temps: jax.Array,
 
 
 def _stack(config, params: Params, tokens: jax.Array, attend, lora,
-           valid: jax.Array):
+           valid: jax.Array, attend_kda=None):
     """The decoder stack of a serving program over tokens [N]:
     ``attend(i, q, k, v)`` and ``lora(i, name, h)`` are ``block``'s
     closures with the layer's index in front (the pools and the adapter
-    pool are indexed by it).  Returns (hidden [N, d], the layers' expert
-    counts)."""
+    pool are indexed by it); ``attend_kda(i, pre, g, beta, a)`` is the
+    gated delta-rule layers' (``_kda_decode``, ``_kda_prefill``).  Returns
+    (hidden [N, d], the layers' expert counts)."""
     hidden, _, counts = block.decoder_stack(
         config, params, tokens,
         lambda i, layer, x: block.decoder_layer(
-            config, layer, x, functools.partial(attend, i),
+            config, layer, x, functools.partial(
+                attend_kda if block.is_kda(config, i) else attend, i),
             lora=functools.partial(lora, i), valid=valid,
-            routed=block.is_routed(config, i)))
+            routed=block.is_routed(config, i),
+            attn=block.layer_attn(config, i)))
     return hidden, counts
+
+
+def _kda_decode(config, pools: PagedPools, active: jax.Array, i: int, pre,
+                g, beta, a):
+    """What the decode step does in gated delta-rule layer ``i`` with the
+    new rows' projections (``kda.project``: pre [B, .], one row a slot):
+    every slot's state through ``kda.recurrent``, an inactive slot's
+    (``active`` [B] false) left as it was, the state pools replaced.
+    Returns the heads' outputs [B, H, D] float32."""
+    layer = state_layers(config).index(i)
+    S, rows = pools["S"][layer], pools["conv"][layer]
+    q, k, v, nxt = kda.conv(config, a, pre[:, None], rows)
+    o, new = kda.recurrent(S, q[:, 0], k[:, 0], v[:, 0], g, beta)
+    with jax.named_scope("attn_kda"):  # the write is the recurrence's
+        pools["S"] = pools["S"].at[layer].set(
+            jnp.where(active[:, None, None, None], new, S))
+    pools["conv"] = pools["conv"].at[layer].set(
+        jnp.where(active[:, None, None], nxt.astype(rows.dtype), rows))
+    return o
+
+
+def _kda_prefill(config, pools: PagedPools, slot: jax.Array, fresh,
+                 valid: jax.Array, i: int, pre, g, beta, a):
+    """What a prefill does in gated delta-rule layer ``i`` with the
+    projections of ONE sequence's rows (pre [S_pad, .], ``valid`` [S_pad]
+    the real ones): the chunk form from zeros where ``fresh`` (True of the
+    cold prefill; a scalar bool of a chunk's program), else from what
+    ``slot`` holds (a chunk behind another), and the state behind the last
+    real row left in the slot.  Returns the heads' outputs [S_pad, H, D]
+    float32."""
+    layer = state_layers(config).index(i)
+    S = jnp.where(fresh, 0, pools["S"][layer, slot])[None]
+    rows = jnp.where(fresh, 0, pools["conv"][layer, slot])[None]
+    n = jnp.sum(valid, dtype=jnp.int32)
+    q, k, v, nxt = kda.conv(config, a, pre[None], rows, n[None])
+    o, new = kda.chunked(S, q, k, v, g[None], beta[None], valid[None])
+    pools["S"] = pools["S"].at[layer, slot].set(new[0])
+    pools["conv"] = pools["conv"].at[layer, slot].set(
+        nxt[0].astype(rows.dtype))
+    return o[0]
 
 
 def _write_kv(pools: PagedPools, layer: int, page_idx: jax.Array,
@@ -598,11 +701,13 @@ def _tile_padded(config, *parts: jax.Array) -> jax.Array:
 
 
 def _latent_row(config, k_r: jax.Array, c: jax.Array, cos, sin,
-                positions: jax.Array) -> jax.Array:
+                positions: jax.Array, rotary: bool = True) -> jax.Array:
     """What the latent pool keeps of the tokens at ``positions`` [N]:
     ``[c ; RoPE(k_r) ; 0]`` [N, latent_row_width], c [N, rank] normalised
-    by the block, k_r [N, rope] the one rotary key the heads share."""
-    k_r = _rotary_single(k_r[:, None], cos, sin, positions)[:, 0]
+    by the block, k_r [N, rope] the one rotary key the heads share
+    (``rotary`` false, the layer's ``block.layer_rotary``: as it is)."""
+    if rotary:
+        k_r = _rotary_single(k_r[:, None], cos, sin, positions)[:, 0]
     return _tile_padded(config, c, k_r.astype(c.dtype))
 
 
@@ -623,12 +728,16 @@ def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
     pages in place of the gather.  Returns [N, H * v]."""
     B, Q = visible.shape[:2]
     nope = config.qk_nope_head_dim
+    rotary = block.layer_rotary(config, i)
+    i = _kv_slot(config, i)[1]  # the layer's place among the latent ones
     _write_kv(pools, i, page_idx, off,
-              kv=_latent_row(config, k_r, c, cos, sin, positions))
+              kv=_latent_row(config, k_r, c, cos, sin, positions, rotary))
     w_uk, w_uv = block.latent_up(config, wkv_b)
     with jax.named_scope(scope):
         q_lat = jnp.einsum("nhd,chd->nhc", q[..., :nope], w_uk)
-        q_r = _rotary_single(q[..., nope:], cos, sin, positions)
+        q_r = q[..., nope:]
+        if rotary:
+            q_r = _rotary_single(q_r, cos, sin, positions)
         q_abs = _tile_padded(config, q_lat, q_r)
         if walk_lens is not None:  # Q is 1
             o_lat = latent_decode.latent_decode_attention(
@@ -687,7 +796,8 @@ def decode_logits(config, params: Params, pools: PagedPools,
             walk_lens=walk_lens)
 
     x, counts = _stack(config, params, tokens[:B], attend,
-                       _adapter_lora(adapters, adapter_ids), active)
+                       _adapter_lora(adapters, adapter_ids), active,
+                       functools.partial(_kda_decode, config, pools, active))
     logits = (x @ params["lm_head"]).astype(jnp.float32)
     return logits, pools, counts
 
@@ -740,8 +850,9 @@ def paged_decode_step(config: LlamaConfig, params: Params,
     key, sub = jax.random.split(key)
     toks = _sample_tokens(logits, temps, sub)
     new_lens = jnp.where(active, seq_lens + 1, 0)
-    out = _with_kv_rows(config, _with_routing(toks, counts), page_tables,
-                        ring_tables, seq_lens, active, _page_size(pools))
+    out = _with_kv_rows(config, _with_routing(config, toks, counts),
+                        page_tables, ring_tables, seq_lens, active,
+                        _page_size(pools))
     return out, new_lens, key, pools
 
 
@@ -749,7 +860,8 @@ def prefill_logits(config, params: Params, pools: PagedPools,
                    adapters: AdapterArrays, tokens: jax.Array,
                    length: jax.Array, page_table: jax.Array,
                    adapter_id: jax.Array,
-                   ring_table: Optional[jax.Array] = None):
+                   ring_table: Optional[jax.Array] = None,
+                   state_slot: Optional[jax.Array] = None):
     """``paged_prefill`` up to its sampling: (logits [1, V] float32 after
     the last real position, pools, per-layer expert counts)."""
     _, s_pad = tokens.shape
@@ -792,14 +904,17 @@ def prefill_logits(config, params: Params, pools: PagedPools,
     def attend_latent(i, q, c, k_r, wkv_b):
         # The cold chunk EXPANDS its own rows' keys and values (nothing is
         # cached before them) and writes the latent rows for what follows.
-        row = _latent_row(config, k_r, c, cos, sin, positions)
-        _write_kv(pools, i, page_idx, off, kv=row)
+        rotary = block.layer_rotary(config, i)
+        row = _latent_row(config, k_r, c, cos, sin, positions, rotary)
+        _write_kv(pools, _kv_slot(config, i)[1], page_idx, off, kv=row)
         nope, rank = config.qk_nope_head_dim, config.kv_lora_rank
         w_uk, w_uv = block.latent_up(config, wkv_b)
         with jax.named_scope("attn_latent_prefill"):
             k_n = jnp.einsum("sc,chd->shd", c, w_uk)
             v = jnp.einsum("sc,chd->shd", c, w_uv)
-            q_r = _rotary_single(q[..., nope:], cos, sin, positions)
+            q_r = q[..., nope:]
+            if rotary:
+                q_r = _rotary_single(q_r, cos, sin, positions)
             scores = (jnp.einsum("qhd,khd->hqk", q[..., :nope], k_n,
                                  preferred_element_type=jnp.float32)
                       + jnp.einsum("qhd,kd->hqk", q_r,
@@ -814,7 +929,10 @@ def prefill_logits(config, params: Params, pools: PagedPools,
         attend = attend_latent
     x, counts = _stack(config, params, tokens[0], attend,
                        _adapter_lora(adapters, adapter_id),
-                       positions < length)
+                       positions < length,
+                       functools.partial(_kda_prefill, config, pools,
+                                         state_slot, True,
+                                         positions < length))
     x_last = jnp.take(x, length - 1, axis=0)  # last REAL position
     logits = (x_last @ params["lm_head"]).astype(jnp.float32)[None]
     return logits, pools, counts
@@ -825,7 +943,8 @@ def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
                   adapters: AdapterArrays, tokens: jax.Array,
                   length: jax.Array, page_table: jax.Array,
                   adapter_id: jax.Array, temp: jax.Array, key: jax.Array,
-                  ring_table: Optional[jax.Array] = None):
+                  ring_table: Optional[jax.Array] = None,
+                  state_slot: Optional[jax.Array] = None):
     """Prefill ONE sequence's prompt into its pages and sample the first
     token.
 
@@ -833,7 +952,10 @@ def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
     compile per bucket, see the engine's bucket table), length scalar =
     real prompt length, page_table [MAXP], adapter_id scalar pool-slot
     index (data, like the decode step's), ring_table [entries] the window
-    layers' ring (None without them).  Padded tail positions write
+    layers' ring (None without them), state_slot a scalar: the slot whose
+    recurrent state this sequence owns (None without gated delta-rule
+    layers; the state starts from zeros here, whatever the slot held, and
+    is left behind the last real row).  Padded tail positions write
     through the page table like real ones (their garbage K/V is masked by
     length until decode overwrites it) or to the scratch page past the
     allocated prefix.  The key advances on device like the decode step's.
@@ -843,17 +965,18 @@ def paged_prefill(config: LlamaConfig, params: Params, pools: PagedPools,
           key=key)
     logits, pools, counts = prefill_logits(
         config, params, pools, adapters, tokens, length, page_table,
-        adapter_id, ring_table)
+        adapter_id, ring_table, state_slot)
     key, sub = jax.random.split(key)
     tok = _sample_tokens(logits, temp[None], sub)
-    return _first_token(tok, counts), key, pools
+    return _first_token(config, tok, counts), key, pools
 
 
 def prefill_prefix_logits(config, params: Params, pools: PagedPools,
                           adapters: AdapterArrays, tokens: jax.Array,
                           prefix_len: jax.Array, length: jax.Array,
                           page_table: jax.Array, adapter_id: jax.Array,
-                          ring_table: Optional[jax.Array] = None):
+                          ring_table: Optional[jax.Array] = None,
+                          state_slot: Optional[jax.Array] = None):
     """``paged_prefill_prefix`` up to its sampling; returns what
     ``prefill_logits`` returns."""
     _, s_pad = tokens.shape
@@ -906,7 +1029,9 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
             scope="attn_latent_prefill")
 
     x, counts = _stack(config, params, tokens[0], attend,
-                       _adapter_lora(adapters, adapter_id), valid)
+                       _adapter_lora(adapters, adapter_id), valid,
+                       functools.partial(_kda_prefill, config, pools,
+                                         state_slot, prefix_len == 0, valid))
     x_last = jnp.take(x, length - prefix_len - 1, axis=0)  # last real row
     logits = (x_last @ params["lm_head"]).astype(jnp.float32)[None]
     return logits, pools, counts
@@ -919,7 +1044,8 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
                          length: jax.Array, page_table: jax.Array,
                          adapter_id: jax.Array, temp: jax.Array,
                          key: jax.Array,
-                         ring_table: Optional[jax.Array] = None):
+                         ring_table: Optional[jax.Array] = None,
+                         state_slot: Optional[jax.Array] = None):
     """Prefill only the SUFFIX of a prompt whose first ``prefix_len``
     positions are already cached in this sequence's page table (radix
     prefix-cache hit; shared pages were written by an earlier identical
@@ -936,16 +1062,19 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
     Queries then attend the full gathered table like the decode step —
     cached prefix plus fresh suffix — masked by global causal position;
     on a window layer, the gathered ring (``ring_table``), masked by what
-    each slot holds.
+    each slot holds.  A gated delta-rule layer takes the state of
+    ``state_slot`` in (zeros where ``prefix_len`` is 0) and leaves the state
+    behind this call's last real row there: a chunked prompt carries its
+    state from chunk to chunk in the slot.
     Returns what ``paged_prefill`` returns."""
     _bump("prefill_prefix", tokens=tokens, page_table=page_table,
           temp=temp, key=key)
     logits, pools, counts = prefill_prefix_logits(
         config, params, pools, adapters, tokens, prefix_len, length,
-        page_table, adapter_id, ring_table)
+        page_table, adapter_id, ring_table, state_slot)
     key, sub = jax.random.split(key)
     tok = _sample_tokens(logits, temp[None], sub)
-    return _first_token(tok, counts), key, pools
+    return _first_token(config, tok, counts), key, pools
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

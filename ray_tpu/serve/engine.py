@@ -361,7 +361,8 @@ class InferenceEngine:
 
         from ..devtools import jitguard
         from ..models.paged import (PAGED_PROGRAMS, PageAllocator,
-                                    counter_keys, kv_layers, ring_entries)
+                                    counter_keys, kv_layers, ring_entries,
+                                    routing_keys, state_bytes, state_layers)
         from ..util.metrics import get_counter, get_gauge, get_histogram
 
         # A fresh engine means fresh geometry: re-registering stands the
@@ -387,10 +388,18 @@ class InferenceEngine:
         self.ring = min(self.maxp, ring_entries(
             model_config, cfg.page_size, cfg.prefill_buckets()[-1]))
         self.ring_scratch = cfg.batch_slots * self.ring
+        # A third kind where the model has gated delta-rule layers: a
+        # recurrent state a layer, which slot s owns as it owns its ring
+        # (the pools' ``S`` and ``conv``, indexed by the slot).  An
+        # admission's first prefill call starts it from zeros on the
+        # device; nothing here allocates or clears it.
+        self._state_layers = len(state_layers(model_config))
+        self._state_bytes = state_bytes(model_config)  # a slot's
         self.allocator = PageAllocator(cfg.pool_pages)
         self.pools = self._new_pools()
         #: The names of the counters behind the decode step's tokens.
         self._counter_keys = counter_keys(model_config)
+        self._routing_keys = routing_keys(model_config)
         # For ``kv_rows_distinct``: how many decoding slots hold each page,
         # and over the pages held by several, the holders past the first.
         self._page_holders: Dict[int, int] = {}
@@ -405,13 +414,19 @@ class InferenceEngine:
             model_config, max_adapters=cfg.max_adapters,
             rank=cfg.lora_rank)
         # A radix node is one page, valid for every layer: false of a
-        # window layer's ring page, so such a model runs without it.
+        # window layer's ring page, so such a model runs without it; and a
+        # node holds no recurrent state at its depth, so a model with
+        # gated delta-rule layers does too.
+        self._cache_off = ("window layers" if self.ring else
+                           "recurrent layers" if self._state_layers
+                           else None)
         self._cache: Optional[RadixPrefixCache] = (
             RadixPrefixCache(cfg.page_size)
-            if cfg.prefix_cache and not self.ring else None)
-        if cfg.prefix_cache and self.ring:
-            print("engine: the model has window layers, whose pages the "
-                  "prefix cache cannot share: prefix_cache is off",
+            if cfg.prefix_cache and not self._cache_off else None)
+        if cfg.prefix_cache and self._cache_off:
+            print(f"engine: the model has {self._cache_off}, whose "
+                  f"{'pages' if self.ring else 'state'} the prefix cache "
+                  f"cannot share: prefix_cache is off",
                   file=sys.stderr, flush=True)
         self._adapter_evictions_seen = 0
         # ONE device-resident PRNG key threads through every prefill and
@@ -562,7 +577,8 @@ class InferenceEngine:
         from ..models.paged import init_paged_pools
 
         return init_paged_pools(self.model_config, self.config.pool_pages,
-                                self.config.page_size, self.ring_scratch)
+                                self.config.page_size, self.ring_scratch,
+                                self.config.batch_slots)
 
     def _hold_pages(self, pages: List[int], by: int) -> None:
         """A slot starts (``by`` 1) or stops (-1) decoding over ``pages``.
@@ -746,8 +762,15 @@ class InferenceEngine:
             "prefix_cache": (self._cache.stats()
                              if self._cache is not None else None),
             # Why it is off where the configuration asked for it.
-            "prefix_cache_off": ("window layers" if self.ring
-                                 and self.config.prefix_cache else None),
+            "prefix_cache_off": (self._cache_off
+                                 if self.config.prefix_cache else None),
+            # The recurrent state of a model with gated delta-rule layers:
+            # a slot's own, in the pools beside the pages.
+            "state": ({"layers": self._state_layers,
+                       "slot_bytes": self._state_bytes,
+                       "total_bytes": (self._state_bytes
+                                       * self.config.batch_slots)}
+                      if self._state_layers else None),
             "window_pages": ({"ring_entries": self.ring,
                               "free": (self.ring_scratch
                                        - self._ring_pages_held()),
@@ -946,11 +969,14 @@ class InferenceEngine:
                 ring = jnp.full((self.ring,), self.ring_scratch,
                                 jnp.int32) if self.ring else None
                 zero = jnp.asarray(0, jnp.int32)
+                # Slot 0's state is written: its next admission's first
+                # call starts from zeros whatever is there.
                 _, self._d_key, self.pools = paged_prefill_prefix(
                     self.model_config, self.params, self.pools,
                     self.adapter_pool.arrays, jnp.zeros((1, b), jnp.int32),
                     zero, jnp.asarray(1, jnp.int32), pt, zero,
-                    jnp.asarray(0.0, jnp.float32), self._d_key, ring)
+                    jnp.asarray(0.0, jnp.float32), self._d_key, ring,
+                    zero if self._state_layers else None)
 
             for b in buckets:
                 warm("prefill_prefix", b, lambda b=b: self._run_on_loop(
@@ -1198,6 +1224,8 @@ class InferenceEngine:
                     table = jnp.asarray(req.page_table)
                     ring = jnp.asarray(self._ring_tables[req.slot]) \
                         if self.ring else None
+                    state = jnp.asarray(req.slot, jnp.int32) \
+                        if self._state_layers else None
                 s_pad = self._bucket_len(end - start)
                 rows += s_pad
                 toks = np.zeros((1, s_pad), np.int32)
@@ -1208,13 +1236,13 @@ class InferenceEngine:
                         self.adapter_pool.arrays, jnp.asarray(toks),
                         jnp.asarray(start, jnp.int32),
                         jnp.asarray(end, jnp.int32), table, aid, temp,
-                        self._d_key, ring)
+                        self._d_key, ring, state)
                 else:
                     first, self._d_key, self.pools = paged_prefill(
                         self.model_config, self.params, self.pools,
                         self.adapter_pool.arrays, jnp.asarray(toks),
                         jnp.asarray(end, jnp.int32), table, aid, temp,
-                        self._d_key, ring)
+                        self._d_key, ring, state)
                 if start == prefix_len:  # the chip has work again
                     self._starved.spend(PH_PREFILL, phase.t0,
                                         time.perf_counter())
@@ -1259,16 +1287,14 @@ class InferenceEngine:
         of its calls returned), stream its token and hand the slot to the
         decode step; returns the calls' routing counters, summed (the
         heaviest load: the largest)."""
-        from ..models.paged import ROUTING_KEYS
-
         with annotation(PH_PREFILL_WAIT, acct) as wait:
             outs = [np.asarray(f).reshape(-1) for f in firsts]  # rt-sync-ok: THE prefill readback — the first token must reach the host to stream it
         self._starved.free(wait.t1)  # the step in flight was read before
         first = int(outs[-1][0])
         counters = np.stack([o[1:] for o in outs])
-        routing = dict(zip(ROUTING_KEYS, counters.sum(0).tolist()))
+        routing = dict(zip(self._routing_keys, counters.sum(0).tolist()))
         if routing:
-            heaviest = ROUTING_KEYS.index("expert_load_max")
+            heaviest = self._routing_keys.index("expert_load_max")
             routing["expert_load_max"] = int(counters[:, heaviest].max())
         n = int(req.prompt.size)
         # Cache every fully-frozen prompt page (decode appends past the
@@ -1668,6 +1694,11 @@ class InferenceEngine:
             # counters are on its first_tokens entry).
             **routing,
         }
+        if self._state_layers and step is not None:
+            # What the step's program read and wrote of recurrent state:
+            # every slot's, live or not (a masked write still moves it).
+            rec["state_bytes"] = (2 * self._state_bytes
+                                  * self.config.batch_slots)
         if "kv_rows_live" in routing:
             # The live rows counted once a physical page: slots that share
             # prefix pages (always whole and wholly live) read the same
