@@ -56,8 +56,13 @@ On a TPU the decode step of a latent model, and of a model with window
 layers, does not gather: a Pallas kernel walks each slot's LIVE pages where
 they lie and reads each once (``ops/latent_decode.py`` over the latent
 pool; ``ops/paged_decode.py`` over K/V pairs, on the whole-length layers
-and on the rings alike; ``_walks_live_pages`` chooses).  The gather form is
-their reference, and what the suffix prefill's many query rows, every other
+and on the rings alike; ``_walks_live_pages`` chooses).  Where the cache is
+K/V pairs the suffix prefill's many query rows walk too
+(``ops/paged_prefill.py``: a block of rows against the live pages of the
+call's table or ring, online softmax, no score matrix in HBM; the engine
+then sends a prompt's first rows through it as well, a suffix behind
+nothing).  The gather form is their reference, and what a latent model's
+prefills, every other
 backend and a configuration of the one whole-length kind take.
 
 A fourth kind of per-sequence state where the configuration has gated
@@ -86,6 +91,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import latent_decode, paged_decode
+from ..ops import paged_prefill as paged_prefill_op
 from ..ops.rotary import apply_rotary, rope_frequencies
 from . import block, kda
 from .llama import LlamaConfig
@@ -363,13 +369,15 @@ def _with_routing(config, toks: jax.Array,
 
 
 def _walks_live_pages(config) -> bool:
-    """Whether the decode step of ``config`` (one query row a slot) attends
-    through a kernel that walks the live pages in place of
-    ``_attend_pages``' gather: on a TPU, a latent pool
-    (``ops.latent_decode_attention``) or a model with window layers
-    (``ops.paged_decode_attention``, on its whole-length layers and its
-    rings), which are the configurations whose decode program counts its
-    rows.  The one place that chooses; ``_with_kv_rows`` counts by it."""
+    """Whether the programs of ``config`` attend through a kernel that walks
+    the live pages in place of ``_attend_pages``' gather: on a TPU, a latent
+    pool (the decode step's one query row a slot,
+    ``ops.latent_decode_attention``) or a model with window layers (the
+    decode step, ``ops.paged_decode_attention``, AND the suffix prefill's
+    many query rows, ``ops.paged_prefill_attention``: on its whole-length
+    layers and its rings), which are the configurations whose decode program
+    counts its rows.  The one place that chooses; ``_with_kv_rows`` counts
+    by it."""
     kernel = latent_decode if block.is_latent(config) else paged_decode
     return _counts_kv_rows(config) and kernel.on_tpu()
 
@@ -378,6 +386,39 @@ def decode_attention_form(config) -> str:
     """The form the decode program of ``config`` attends its cache in, by
     name (``LLMServer.stats()["decode_attention"]``)."""
     return "walk" if _walks_live_pages(config) else "gather"
+
+
+def _prefill_walks(config) -> bool:
+    """Whether the suffix prefill of ``config`` walks: where the decode
+    step does and the cache is K/V pairs (a latent model's prefills keep the
+    gather and the expanded cold chunk).  The engine then runs every call
+    of a prompt through it, the first at ``prefix_len`` 0: ``prefill_logits``
+    (dense ``[H, S, S]`` scores) is for the configurations that gather."""
+    return _walks_live_pages(config) and not block.is_latent(config)
+
+
+def prefill_attention_form(config) -> str:
+    """The form the prefill calls of ``config`` attend in, by name
+    (``LLMServer.stats()["prefill_attention"]``)."""
+    return "walk" if _prefill_walks(config) else "gather"
+
+
+def attn_pairs(config, start: int, end: int) -> int:
+    """The (query, key) pairs the real rows of one prefill call could see,
+    summed over the layers that keep K/V rows: the rows at positions
+    ``start .. end - 1`` see every position up to their own on a
+    whole-length layer, at most the window's on a window layer.  Host
+    arithmetic on Python ints (a first_tokens entry's ``attn_pairs``)."""
+    whole, window = kv_layers(config)
+    causal = (start + 1 + end) * (end - start) // 2  # sum of p + 1
+    pairs = len(whole) * causal
+    if window:
+        w = config.window
+        over = max(0, end - max(start, w))  # the rows that see w positions
+        under = end - start - over          # the rows before the window fills
+        pairs += len(window) * (
+            over * w + (2 * start + 1 + under) * under // 2)
+    return pairs
 
 
 def _window_lo(config, seq_lens: jax.Array) -> jax.Array:
@@ -665,7 +706,8 @@ def _write_kv(pools: PagedPools, layer: int, page_idx: jax.Array,
 
 
 def _paged_attend(config, pools: PagedPools, i: int, q, k, v, *, whole,
-                  ring, walk_lens: Optional[jax.Array] = None):
+                  ring, walk_lens: Optional[jax.Array] = None,
+                  walk_rows: Optional[tuple] = None):
     """What the decode step and the suffix prefill do in layer ``i`` with
     q [B, Q, H, D] and the new rows' k, v [N, H_kv, D] (rotated by the
     caller where the layer has rotary): the rows written into the layer's
@@ -673,13 +715,21 @@ def _paged_attend(config, pools: PagedPools, i: int, q, k, v, *, whole,
     ``ring`` are each ``(page_idx [N], off [N], tables [B, T], visible
     [B, Q, T*page])``: the write indices and the read side of the
     whole-length table and of the window layers' ring (None for a
-    configuration that has none).  ``walk_lens`` [B] (the decode step's
-    ``seq_lens``, where ``_walks_live_pages``; Q is 1): the kernel walks the
-    pages that hold what ``visible`` says, in place of the gather."""
+    configuration that has none).  Where ``_walks_live_pages``, a kernel
+    walks the pages that hold what ``visible`` says, in place of the gather
+    (``visible`` is then not read, and may be None): ``walk_lens`` [B] is
+    the decode step's ``seq_lens`` (Q is 1); ``walk_rows`` a prefill's
+    ``(first position, length)`` (B is 1: row ``r`` of q sits at ``first +
+    r``, rows at or past ``length`` are padding)."""
     kind, slot = _kv_slot(config, i)
     page_idx, off, tables, visible = ring if kind else whole
     _write_kv(pools, slot, page_idx, off, **{"k" + kind: k, "v" + kind: v})
     with jax.named_scope("attn_window" if kind else "attn_global"):
+        if walk_rows is not None:
+            return paged_prefill_op.paged_prefill_attention(
+                q[0], pools["k" + kind], pools["v" + kind], slot, tables[0],
+                *walk_rows, window=config.window if kind else 0,
+                sm_scale=config.head_dim ** -0.5).reshape(1, q.shape[1], -1)
         if walk_lens is None:
             return _attend_pages(config, q, pools["k" + kind],
                                  pools["v" + kind], slot, tables, visible)
@@ -1010,16 +1060,19 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
                 ((held >= 0) & (held <= last) & (age >= 0)
                  & (age < config.window))[None])
 
+    walk_rows = (prefix_len, length) if _prefill_walks(config) else None
+
     def attend(i, q, k, v):  # [S_pad, H, D]
         # Per-row RoPE at global positions (suffix rows are not at 0).
         if block.layer_rotary(config, i):
             q = _rotary_single(q, cos, sin, positions)
             k = _rotary_single(k, cos, sin, positions)
-        # Attend the WHOLE table (cached prefix + fresh suffix) like the
-        # decode step, as a batch of one.
+        # Attend the table (cached prefix + fresh suffix) like the decode
+        # step, as a batch of one: gathered whole, or its live pages walked.
         return _paged_attend(
             config, pools, i, q[None], k, v,
-            whole=(page_idx, off, page_table[None], visible), ring=ring)[0]
+            whole=(page_idx, off, page_table[None], visible), ring=ring,
+            walk_rows=walk_rows)[0]
 
     if block.is_latent(config):  # q [S_pad, H, D], c, k_r: a batch of one
         attend = functools.partial(
@@ -1059,10 +1112,14 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
     mid-page COW divergence).  Suffix K/V is written through the page
     table at global positions ``prefix_len + row``; rows past the real
     suffix route to the scratch page (they may not even own a page).
-    Queries then attend the full gathered table like the decode step —
-    cached prefix plus fresh suffix — masked by global causal position;
-    on a window layer, the gathered ring (``ring_table``), masked by what
-    each slot holds.  A gated delta-rule layer takes the state of
+    Queries then attend the table like the decode step — cached prefix
+    plus fresh suffix — masked by global causal position; on a window
+    layer, the ring (``ring_table``), masked by what each slot holds.  The
+    table or ring is gathered whole (``_attend_pages``) or, on a TPU where
+    the model has window layers (``_walks_live_pages``), its live pages are
+    walked a block of query rows at a time (``ops/paged_prefill.py``): the
+    pages from the window of the block's first row to its last real row,
+    and no score matrix in HBM.  A gated delta-rule layer takes the state of
     ``state_slot`` in (zeros where ``prefix_len`` is 0) and leaves the state
     behind this call's last real row there: a chunked prompt carries its
     state from chunk to chunk in the slot.

@@ -3,7 +3,7 @@
 Net-new relative to the reference, which delegates all device compute to
 torch/CUDA (SURVEY.md §5.7): flash attention, ring attention (sequence
 parallelism), decode attention over a latent paged pool and over paged K/V
-pairs, the routed FFN
+pairs, prefill attention over paged K/V pairs, the routed FFN
 streamed expert by expert, fused RMSNorm, rotary embeddings.
 """
 
@@ -12,11 +12,13 @@ from .grouped_ffn import grouped_ffn_stream
 from .latent_decode import latent_decode_attention
 from .norms import rms_norm
 from .paged_decode import paged_decode_attention
+from .paged_prefill import paged_prefill_attention
 from .rotary import apply_rotary, rope_frequencies
 from .ring_attention import ring_attention
 
 __all__ = [
     "flash_attention", "mha_reference", "latent_decode_attention",
-    "paged_decode_attention", "grouped_ffn_stream", "rms_norm",
+    "paged_decode_attention", "paged_prefill_attention", "grouped_ffn_stream",
+    "rms_norm",
     "apply_rotary", "rope_frequencies", "ring_attention",
 ]

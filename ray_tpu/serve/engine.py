@@ -740,7 +740,8 @@ class InferenceEngine:
             }
         active = sum(1 for s in self.slots if s is not None)
         from ..models.moe import grouped_form
-        from ..models.paged import decode_attention_form, trace_count
+        from ..models.paged import (decode_attention_form,
+                                    prefill_attention_form, trace_count)
 
         return {
             "steps": self.step_count,
@@ -785,6 +786,10 @@ class InferenceEngine:
             # kernel over the live pages: ops/latent_decode.py,
             # ops/paged_decode.py) or "gather".
             "decode_attention": decode_attention_form(self.model_config),
+            # And the prefills: "walk" (ops/paged_prefill.py: a block of
+            # query rows over the live pages, no scores in HBM; every call
+            # then goes through the suffix program, a first one at 0).
+            "prefill_attention": prefill_attention_form(self.model_config),
             "adapters": self.adapter_pool.stats(),
         }
 
@@ -1204,7 +1209,9 @@ class InferenceEngine:
         and the bookkeeping after it."""
         import jax.numpy as jnp
 
-        from ..models.paged import paged_prefill, paged_prefill_prefix
+        from ..models.paged import (attn_pairs, paged_prefill,
+                                    paged_prefill_prefix,
+                                    prefill_attention_form)
 
         n = int(req.prompt.size)
         prefix_len = int(req.cache_hit_len)
@@ -1214,6 +1221,11 @@ class InferenceEngine:
         chunk = self.config.prefill_buckets()[-1]
         starts = list(range(prefix_len, n, chunk))
         rows, firsts, routing = 0, [], {}
+        # Where the prefills walk the live pages, a prompt's first rows are
+        # a suffix behind nothing: the same kernel at a first position of
+        # 0, so one program a bucket serves both, and the cold program
+        # (its dense scores) is never compiled there.
+        walks = prefill_attention_form(self.model_config) == "walk"
         for start in starts:
             end = min(start + chunk, n)
             with self._phase(PH_PREFILL, acct) as phase:
@@ -1230,7 +1242,7 @@ class InferenceEngine:
                 rows += s_pad
                 toks = np.zeros((1, s_pad), np.int32)
                 toks[0, :end - start] = req.prompt[start:end]
-                if start:
+                if start or walks:
                     first, self._d_key, self.pools = paged_prefill_prefix(
                         self.model_config, self.params, self.pools,
                         self.adapter_pool.arrays, jnp.asarray(toks),
@@ -1250,6 +1262,12 @@ class InferenceEngine:
                 firsts.append(first)
                 if end == n:
                     routing = self._finish_prefill(req, firsts, acct)
+        if walks:
+            # The pairs the kernel's real rows could see, over the calls
+            # (what paged_prefill_roofline.swa divides its seconds by).
+            routing["attn_pairs"] = sum(
+                attn_pairs(self.model_config, start, min(start + chunk, n))
+                for start in starts)
         return rows, len(starts), routing
 
     def _prefill_prepare(self, req: _Request) -> None:
@@ -1853,7 +1871,8 @@ class LLMServer:
             if stats["grouped_ffn"]:
                 print("the decode step's grouped products: "
                       f"{stats['grouped_ffn']}", file=sys.stderr, flush=True)
-            print(f"the decode step's attention: {stats['decode_attention']}",
+            print(f"the decode step's attention: {stats['decode_attention']}"
+                  f", the prefills': {stats['prefill_attention']}",
                   file=sys.stderr, flush=True)
 
     def load_adapter(self, name: str, source: Any = None) -> str:
