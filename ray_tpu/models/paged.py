@@ -56,14 +56,14 @@ On a TPU the decode step of a latent model, and of a model with window
 layers, does not gather: a Pallas kernel walks each slot's LIVE pages where
 they lie and reads each once (``ops/latent_decode.py`` over the latent
 pool; ``ops/paged_decode.py`` over K/V pairs, on the whole-length layers
-and on the rings alike; ``_walks_live_pages`` chooses).  Where the cache is
-K/V pairs the suffix prefill's many query rows walk too
-(``ops/paged_prefill.py``: a block of rows against the live pages of the
-call's table or ring, online softmax, no score matrix in HBM; the engine
-then sends a prompt's first rows through it as well, a suffix behind
-nothing).  The gather form is their reference, and what a latent model's
-prefills, every other
-backend and a configuration of the one whole-length kind take.
+and on the rings alike; ``_walks_live_pages`` chooses).  There the suffix
+prefill's many query rows walk too (``ops/paged_prefill.py`` over K/V
+pairs, ``ops/latent_prefill.py`` over the latent pool: a block of rows
+against the live pages of the call's table or ring, online softmax, no score
+matrix in HBM; the engine then sends a prompt's first rows through it as
+well, a suffix behind nothing).  The gather form is their reference, and
+what every other backend and a configuration of the one whole-length kind
+take.
 
 A fourth kind of per-sequence state where the configuration has gated
 delta-rule layers (``block.is_kda``, ``models/kda.py``): no rows at all, but
@@ -91,6 +91,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import latent_decode, paged_decode
+from ..ops import latent_prefill as latent_prefill_op
 from ..ops import paged_prefill as paged_prefill_op
 from ..ops.rotary import apply_rotary, rope_frequencies
 from . import block, kda
@@ -372,12 +373,12 @@ def _walks_live_pages(config) -> bool:
     """Whether the programs of ``config`` attend through a kernel that walks
     the live pages in place of ``_attend_pages``' gather: on a TPU, a latent
     pool (the decode step's one query row a slot,
-    ``ops.latent_decode_attention``) or a model with window layers (the
-    decode step, ``ops.paged_decode_attention``, AND the suffix prefill's
-    many query rows, ``ops.paged_prefill_attention``: on its whole-length
-    layers and its rings), which are the configurations whose decode program
-    counts its rows.  The one place that chooses; ``_with_kv_rows`` counts
-    by it."""
+    ``ops.latent_decode_attention``, and the suffix prefill's many,
+    ``ops.latent_prefill_attention``) or a model with window layers (the
+    decode step, ``ops.paged_decode_attention``, and the suffix prefill,
+    ``ops.paged_prefill_attention``: on its whole-length layers and its
+    rings), which are the configurations whose decode program counts its
+    rows.  The one place that chooses; ``_with_kv_rows`` counts by it."""
     kernel = latent_decode if block.is_latent(config) else paged_decode
     return _counts_kv_rows(config) and kernel.on_tpu()
 
@@ -390,11 +391,11 @@ def decode_attention_form(config) -> str:
 
 def _prefill_walks(config) -> bool:
     """Whether the suffix prefill of ``config`` walks: where the decode
-    step does and the cache is K/V pairs (a latent model's prefills keep the
-    gather and the expanded cold chunk).  The engine then runs every call
-    of a prompt through it, the first at ``prefix_len`` 0: ``prefill_logits``
-    (dense ``[H, S, S]`` scores) is for the configurations that gather."""
-    return _walks_live_pages(config) and not block.is_latent(config)
+    step does.  The engine then runs every call of a prompt through it, the
+    first at ``prefix_len`` 0: ``prefill_logits`` (dense ``[H, S, S]``
+    scores, a latent model's chunk expanded) is for the configurations that
+    gather."""
+    return _walks_live_pages(config)
 
 
 def prefill_attention_form(config) -> str:
@@ -763,7 +764,8 @@ def _latent_row(config, k_r: jax.Array, c: jax.Array, cos, sin,
 
 def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
                    cos, sin, positions, page_idx, off, tables, visible,
-                   scope: str, walk_lens: Optional[jax.Array] = None):
+                   scope: str, walk_lens: Optional[jax.Array] = None,
+                   walk_rows: Optional[tuple] = None):
     """What the decode step and the suffix prefill do in layer ``i`` of a
     latent model with the new rows' q [N, H, nope + rope], latent c
     [N, rank] and rotary key k_r [N, rope] at ``positions`` [N] (N = B * Q
@@ -773,10 +775,13 @@ def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
     and W_uv: ``q_lat = q_n W_uk^T`` [N, H, rank], the scores are
     ``[q_lat ; RoPE(q_r) ; 0] . [c ; RoPE(k_r) ; 0]`` over the gathered rows, the
     values those rows' first ``rank`` columns, and the heads' outputs in
-    the latent go through W_uv.  ``walk_lens`` [B] (the decode step's
-    ``seq_lens``, where ``_walks_live_pages``): the kernel walks the live
-    pages in place of the gather.  Returns [N, H * v]."""
-    B, Q = visible.shape[:2]
+    the latent go through W_uv.  Where ``_walks_live_pages``, a kernel walks
+    the live pages in place of the gather (``visible`` is then not read, and
+    may be None): ``walk_lens`` [B] is the decode step's ``seq_lens`` (Q is
+    1); ``walk_rows`` a prefill's ``(first position, length)`` (B is 1, as
+    ``_paged_attend`` takes it).  Returns [N, H * v]."""
+    B = tables.shape[0]
+    Q = q.shape[0] // B
     nope = config.qk_nope_head_dim
     rotary = block.layer_rotary(config, i)
     i = _kv_slot(config, i)[1]  # the layer's place among the latent ones
@@ -792,6 +797,10 @@ def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
         if walk_lens is not None:  # Q is 1
             o_lat = latent_decode.latent_decode_attention(
                 q_abs, pools["kv"], i, tables, walk_lens,
+                rank=config.kv_lora_rank, sm_scale=config.head_dim ** -0.5)
+        elif walk_rows is not None:  # B is 1
+            o_lat = latent_prefill_op.latent_prefill_attention(
+                q_abs, pools["kv"], i, tables[0], *walk_rows,
                 rank=config.kv_lora_rank, sm_scale=config.head_dim ** -0.5)
         else:
             o_lat = _attend_pages(
@@ -1079,7 +1088,7 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
             _latent_attend, config, pools, cos=cos, sin=sin,
             positions=positions, page_idx=page_idx, off=off,
             tables=page_table[None], visible=visible,
-            scope="attn_latent_prefill")
+            scope="attn_latent_prefill", walk_rows=walk_rows)
 
     x, counts = _stack(config, params, tokens[0], attend,
                        _adapter_lora(adapters, adapter_id), valid,
@@ -1116,10 +1125,11 @@ def paged_prefill_prefix(config: LlamaConfig, params: Params,
     plus fresh suffix — masked by global causal position; on a window
     layer, the ring (``ring_table``), masked by what each slot holds.  The
     table or ring is gathered whole (``_attend_pages``) or, on a TPU where
-    the model has window layers (``_walks_live_pages``), its live pages are
-    walked a block of query rows at a time (``ops/paged_prefill.py``): the
-    pages from the window of the block's first row to its last real row,
-    and no score matrix in HBM.  A gated delta-rule layer takes the state of
+    the model has window layers or a latent pool (``_walks_live_pages``), its
+    live pages are walked a block of query rows at a time
+    (``ops/paged_prefill.py``, ``ops/latent_prefill.py``): the pages from the
+    window of the block's first row to its last real row, and no score
+    matrix in HBM.  A gated delta-rule layer takes the state of
     ``state_slot`` in (zeros where ``prefix_len`` is 0) and leaves the state
     behind this call's last real row there: a chunked prompt carries its
     state from chunk to chunk in the slot.

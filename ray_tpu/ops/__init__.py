@@ -3,13 +3,14 @@
 Net-new relative to the reference, which delegates all device compute to
 torch/CUDA (SURVEY.md §5.7): flash attention, ring attention (sequence
 parallelism), decode attention over a latent paged pool and over paged K/V
-pairs, prefill attention over paged K/V pairs, the routed FFN
-streamed expert by expert, fused RMSNorm, rotary embeddings.
+pairs, prefill attention over a latent paged pool and over paged K/V
+pairs, the routed FFN streamed expert by expert, fused RMSNorm, rotary embeddings.
 """
 
 from .attention import flash_attention, mha_reference
 from .grouped_ffn import grouped_ffn_stream
 from .latent_decode import latent_decode_attention
+from .latent_prefill import latent_prefill_attention
 from .norms import rms_norm
 from .paged_decode import paged_decode_attention
 from .paged_prefill import paged_prefill_attention
@@ -18,7 +19,7 @@ from .ring_attention import ring_attention
 
 __all__ = [
     "flash_attention", "mha_reference", "latent_decode_attention",
-    "paged_decode_attention", "paged_prefill_attention", "grouped_ffn_stream",
+    "latent_prefill_attention", "paged_decode_attention", "paged_prefill_attention", "grouped_ffn_stream",
     "rms_norm",
     "apply_rotary", "rope_frequencies", "ring_attention",
 ]

@@ -1264,7 +1264,8 @@ class InferenceEngine:
                     routing = self._finish_prefill(req, firsts, acct)
         if walks:
             # The pairs the kernel's real rows could see, over the calls
-            # (what paged_prefill_roofline.swa divides its seconds by).
+            # (what paged_prefill_roofline.swa and
+            # latent_prefill_roofline.mla divide their seconds by).
             routing["attn_pairs"] = sum(
                 attn_pairs(self.model_config, start, min(start + chunk, n))
                 for start in starts)

@@ -124,8 +124,10 @@ def test_the_prefill_programs_through_the_kernel(monkeypatch, name, heads,
 
 def test_off_the_tpu_the_prefills_are_the_gather_form():
     """Nothing steers it here: on this backend a model with window layers
-    lowers without the kernel; one without them, and a latent model,
-    everywhere, whatever the backend answers."""
+    lowers without the kernel, and one without them everywhere, whatever the
+    backend answers; a latent model gathers here and walks where
+    ``latent_decode.on_tpu`` answers true (``ops/latent_prefill.py``), as
+    its decode step does."""
     import dataclasses
 
     from ray_tpu.ops import latent_decode
@@ -146,9 +148,10 @@ def test_off_the_tpu_the_prefills_are_the_gather_form():
         latent = spec.family(model).program_config(model, remat=False,
                                                    max_seq=TINY_SEQ)
         assert paged._walks_live_pages(latent)
-        assert paged.prefill_attention_form(latent) == "gather"
+        assert paged.prefill_attention_form(latent) == "walk"
     finally:
         paged_decode.on_tpu, latent_decode.on_tpu = was
+    assert paged.prefill_attention_form(latent) == "gather"
 
 
 @pytest.mark.parametrize("start, end", [(0, 5), (0, 8), (0, 30), (8, 24),
