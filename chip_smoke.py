@@ -58,7 +58,7 @@ SERVE_NEW_TOKENS = 32
 #: rounding, and the two paths sum in different orders.
 LOGIT_TOL = 0.25
 
-#: The train step bench.py builds for its 4 x 2048 tier.
+#: The b1 train step at 4 x 2048.
 TRAIN = dict(model="b1", batch=4, seq=2048, steps=5, lr=3e-4)
 #: Relative bands for the sharded loss against the one-device loss.  The
 #: first step is one forward pass over identical weights: bf16 sums
@@ -110,8 +110,8 @@ def check_device(device: dict, platform: str, count=None) -> None:
 
 
 def train_model_config(model: str, seq: int):
-    """What the train phase trains: b1 as bench.py builds it for its
-    4 x 2048 tier, or (rehearsals) tiny."""
+    """What the train phase trains: b1 at 4 x 2048, or (rehearsals)
+    tiny."""
     import jax.numpy as jnp
 
     from ray_tpu.models import LlamaConfig

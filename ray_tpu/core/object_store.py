@@ -38,7 +38,7 @@ _PREFIX = "rtpu"
 # wait on the daemon's accounting lock.  Two sinks per observation: the
 # cluster histograms (``ray_tpu_put_copy_seconds`` by stage,
 # ``ray_tpu_store_lock_wait_seconds``) for `doctor --object-plane`, and a
-# process-local accumulator bench_core/tests read without a cluster.
+# process-local accumulator tests read without a cluster.
 
 #: Cold segments below this size skip the pre-touch pass (the fault cost
 #: of a few pages is noise; the Python per-page loop is not).

@@ -55,7 +55,7 @@ class LlamaConfig:
     # "save_attn" asks the policy to keep flash-attention residuals
     # (q/k/v/out/lse, tagged "flash_res"); "xla_cse" disables the CSE
     # barrier so XLA itself chooses which activations to keep — the highest
-    # MFU when it fits in HBM (bench.py tries it first, falling back to
+    # MFU when it fits in HBM (a caller tries it first and falls back to
     # "full").  Note: custom_vjp residual saving is best-effort — measure.
     remat_policy: str = "full"
     # sp_axis set -> use ring attention over that mesh axis inside shard_map

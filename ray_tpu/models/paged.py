@@ -389,19 +389,14 @@ def decode_attention_form(config) -> str:
     return "walk" if _walks_live_pages(config) else "gather"
 
 
-def _prefill_walks(config) -> bool:
-    """Whether the suffix prefill of ``config`` walks: where the decode
-    step does.  The engine then runs every call of a prompt through it, the
-    first at ``prefix_len`` 0: ``prefill_logits`` (dense ``[H, S, S]``
-    scores, a latent model's chunk expanded) is for the configurations that
-    gather."""
-    return _walks_live_pages(config)
-
-
 def prefill_attention_form(config) -> str:
     """The form the prefill calls of ``config`` attend in, by name
-    (``LLMServer.stats()["prefill_attention"]``)."""
-    return "walk" if _prefill_walks(config) else "gather"
+    (``LLMServer.stats()["prefill_attention"]``): the suffix prefill walks
+    where the decode step does.  The engine then runs every call of a prompt
+    through it, the first at ``prefix_len`` 0: ``prefill_logits`` (dense
+    ``[H, S, S]`` scores, a latent model's chunk expanded) is for the
+    configurations that gather."""
+    return "walk" if _walks_live_pages(config) else "gather"
 
 
 def attn_pairs(config, start: int, end: int) -> int:
@@ -1069,7 +1064,7 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
                 ((held >= 0) & (held <= last) & (age >= 0)
                  & (age < config.window))[None])
 
-    walk_rows = (prefix_len, length) if _prefill_walks(config) else None
+    walk_rows = (prefix_len, length) if _walks_live_pages(config) else None
 
     def attend(i, q, k, v):  # [S_pad, H, D]
         # Per-row RoPE at global positions (suffix rows are not at 0).

@@ -4,7 +4,8 @@ Net-new relative to the reference, which delegates all device compute to
 torch/CUDA (SURVEY.md §5.7): flash attention, ring attention (sequence
 parallelism), decode attention over a latent paged pool and over paged K/V
 pairs, prefill attention over a latent paged pool and over paged K/V
-pairs, the routed FFN streamed expert by expert, fused RMSNorm, rotary embeddings.
+pairs (four fronts of the one walk in ``page_walk.py``), the routed FFN
+streamed expert by expert, fused RMSNorm, rotary embeddings.
 """
 
 from .attention import flash_attention, mha_reference

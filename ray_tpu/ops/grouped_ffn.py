@@ -49,7 +49,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _on_tpu as on_tpu  # noqa: F401 (moe asks it)
-from .latent_decode import _sublanes
+from .page_walk import sublanes as _sublanes
 
 LANES = 128
 #: The most bytes one slab (its three blocks) may hold: two of them are the

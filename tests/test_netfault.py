@@ -169,17 +169,22 @@ def test_server_stall_models_gray_failure(loopback):
     from ray_tpu.core import rpc
 
     server, _ = loopback
+    armed = time.monotonic()
     sched = netfault.arm("stall:link=unit-server,dur=1", SEED)
     stalled = rpc.RpcClient("127.0.0.1", server.port, name="unit-client-2")
     try:
-        t0 = time.monotonic()
         with pytest.raises(CfTimeoutError):
             stalled.call("ping", {}, timeout=0.3)  # alive but mute
-        # After the stall window the same connection serves normally.
+        # After the stall window the same connection serves normally: the
+        # answer cannot come before the window, armed for 1 s, has closed.
         assert stalled.call("ping", {"w": 5}, timeout=5) == {"echo": {"w": 5}}
-        assert time.monotonic() - t0 >= 0.8
+        assert time.monotonic() - armed >= 0.95
+        # The stall this test armed and saw end was injected, not a slow
+        # box.  Not an exact count: the server's loop may reach the accept
+        # of the fixture's own client only after the arming on a loaded
+        # box, and that connection then stalls and counts beside this one.
         with sched._lock:
-            assert sched.counts.get("stall", 0) == 1
+            assert sched.counts.get("stall", 0) >= 1
     finally:
         stalled.close()
 

@@ -5,6 +5,7 @@ test_batching.py, test_proxy.py.
 """
 
 import json
+import os
 import time
 import urllib.request
 
@@ -49,21 +50,29 @@ def test_deploy_and_route_across_replicas(rt):
 def test_replicas_construct_in_parallel_and_run_waits_for_them(rt):
     """serve.run waits on the constructors (a model replica compiles for
     minutes), not on a constant inside the controller, and replicas of one
-    deployment construct side by side."""
+    deployment construct side by side: each is inside its constructor while
+    the other is, whatever the box's load makes of the seconds."""
     @serve.deployment(num_replicas=2)
     class Slow:
         def __init__(self):
+            self.began = time.time()
             time.sleep(1.5)
-            self.t = time.time()
+            self.ended = time.time()
 
         def __call__(self):
-            return self.t
+            return os.getpid(), self.began, self.ended
 
     t0 = time.time()
     handle = serve.run(Slow.bind())
-    assert time.time() - t0 < 2.9  # two 1.5 s constructors, side by side
+    returned = time.time()
     assert serve.status()["Slow"]["running_replicas"] == 2
-    assert handle.remote().result() > t0
+    spans, deadline = {}, time.time() + 30
+    while len(spans) < 2 and time.time() < deadline:
+        pid, began, ended = handle.remote().result()
+        spans[pid] = (began, ended)
+    (began_a, ended_a), (began_b, ended_b) = spans.values()
+    assert t0 < began_a < ended_b and t0 < began_b < ended_a  # side by side
+    assert max(ended_a, ended_b) <= returned  # run waited for both
 
 
 def test_failing_constructor_fails_run_with_its_error(rt):
