@@ -1032,11 +1032,12 @@ class Client:
         except Exception:
             pass
 
-    def next_stream_item(self, task_id: bytes, index: int) -> dict:
+    def next_stream_item(self, task_id: bytes, index: int,
+                         values: bool = False) -> dict:
         if self._dataplane is not None:
             # Direct streaming tasks serve their items straight from the
             # executing worker (peer_next_stream_item).
-            reply = self._dataplane.next_stream_item(task_id, index)
+            reply = self._dataplane.next_stream_item(task_id, index, values)
             if reply is not None:
                 return reply
         with self._maybe_blocked():
@@ -1046,6 +1047,11 @@ class Client:
                 "next_stream_item", {"task_id": task_id, "index": index},
                 timeout=None,
             )
+
+    def adopt_stream_item(self, item: dict) -> bytes:
+        """An inline item a direct stream pull brought ahead of its turn
+        (``next_stream_item``'s ``ahead``), sealed locally: its object id."""
+        return self._dataplane.adopt_stream_item(item)
 
     # -- KV --------------------------------------------------------------------
 

@@ -1253,9 +1253,13 @@ class Dataplane:
 
     # -- streaming -------------------------------------------------------------
 
-    def next_stream_item(self, task_id: bytes, index: int) -> Optional[dict]:
+    def next_stream_item(self, task_id: bytes, index: int,
+                         values: bool = False) -> Optional[dict]:
         """Route an ObjectRefGenerator pull for a direct streaming task.
-        None = not a direct stream (caller uses the head path)."""
+        None = not a direct stream (caller uses the head path).
+        ``values``: an inline item comes back as it is (``{"inline": ..}``)
+        for a consumer that wants the value and no reference to it: nothing
+        is sealed here, so nothing has to be freed."""
         while True:
             # The spec may still be staged client-side: flush, then wait
             # for it to be either sent (peer route exists) or re-routed to
@@ -1318,6 +1322,9 @@ class Dataplane:
                 self._stream_routes.pop(task_id, None)
             return {"error": reply["error"]}
         item = reply["item"]
+        if values and item.get("inline") is not None:
+            return {"inline": item["inline"],
+                    "ahead": reply.get("ahead") or ()}
         raw = item["object_id"]
         with self._lock:
             if item.get("inline") is not None:
@@ -1332,7 +1339,19 @@ class Dataplane:
                 }
                 self._results[raw] = desc
                 self._register_result(raw, desc)
-        return {"object_id": raw}
+        # Inline items the producer already had behind this one: the
+        # generator adopts them one by one (``adopt_stream_item``) without
+        # a round trip each.
+        return {"object_id": raw, "ahead": reply.get("ahead") or ()}
+
+    def adopt_stream_item(self, item: dict) -> bytes:
+        """Seal one inline item that rode ahead on a stream pull, as
+        ``next_stream_item`` seals the one it asked for; returns its
+        object id."""
+        raw = item["object_id"]
+        with self._lock:
+            self._results[raw] = {"inline": item["inline"]}
+        return raw
 
     # -- cancellation ----------------------------------------------------------
 

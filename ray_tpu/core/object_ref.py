@@ -128,18 +128,52 @@ class ObjectRefGenerator:
     def __init__(self, task_id_bytes: bytes):
         self._task_id = task_id_bytes
         self._index = 0
+        # Inline items a pull brought beyond the one asked for (a direct
+        # stream whose consumer fell behind): taken from here, in order,
+        # before the producer is asked again.
+        self._ahead: list = []
 
     def __iter__(self):
         return self
 
     def __next__(self) -> ObjectRef:
-        item = ctx.client.next_stream_item(self._task_id, self._index)
-        if item.get("done"):
-            raise StopIteration
-        if item.get("error") is not None:
-            raise serialization.unpack(item["error"])
+        if self._ahead:
+            raw = ctx.client.adopt_stream_item(self._ahead.pop(0))
+        else:
+            item = ctx.client.next_stream_item(self._task_id, self._index)
+            if item.get("done"):
+                raise StopIteration
+            if item.get("error") is not None:
+                raise serialization.unpack(item["error"])
+            raw = item["object_id"]
+            self._ahead = list(item.get("ahead") or ())
         self._index += 1
-        return ObjectRef(ObjectID(item["object_id"]))
+        return ObjectRef(ObjectID(raw))
+
+    def values(self):
+        """The stream's items as VALUES, in order: what ``get`` of each
+        reference would give, for a consumer that keeps no reference (a
+        token stream's client).  An inline item of a direct stream is
+        unpacked where it arrives: no reference is made, sealed, counted or
+        freed for it, which is most of what a small item costs its
+        consumer."""
+        while True:
+            if self._ahead:
+                info = self._ahead.pop(0)
+            else:
+                info = ctx.client.next_stream_item(self._task_id,
+                                                   self._index, values=True)
+                if info.get("done"):
+                    return
+                if info.get("error") is not None:
+                    raise serialization.unpack(info["error"])
+                self._ahead = list(info.get("ahead") or ())
+            self._index += 1
+            if info.get("inline") is not None:
+                yield serialization.unpack(info["inline"])
+            else:  # the head's path, or an item too large to ride inline
+                from . import api
+                yield api.get(ObjectRef(ObjectID(info["object_id"])))
 
     def cancel(self, force: bool = False) -> None:
         """Cancel the producing task (reference: ray.cancel on a streaming

@@ -42,6 +42,15 @@ from .ids import ActorID, ObjectID, TaskID
 from .object_ref import ObjectRef, _TopLevelRef
 
 _DEBUG_PUSH = bool(os.environ.get("RT_DEBUG_PUSH"))
+#: The most inline items of a direct stream one pull hands the consumer
+#: behind the one it asked for (``h_peer_next_stream_item``).
+_STREAM_AHEAD = 64
+
+
+def _resolve(fut) -> None:
+    """Wake whoever awaits ``fut`` (on its loop), once."""
+    if not fut.done():
+        fut.set_result(None)
 
 
 @guarded
@@ -705,6 +714,7 @@ class Worker:
                 st["done"] = stream_count
                 if error is not None:
                     st["error"] = error
+        self._wake_stream(st)
 
         with self._direct_replies_lock:
             self._direct_replies.append((fut, body))
@@ -816,9 +826,26 @@ class Worker:
                             break
                 self.direct_streams[spec["task_id"]] = {
                     "items": [], "done": None, "error": None,
+                    # (loop, future) of a pull waiting for the next item.
+                    "waiter": None,
                 }
         self.task_queue.put(spec)
         return await fut
+
+    def _wake_stream(self, st: Optional[dict]) -> None:
+        """Wake the pull waiting on direct stream ``st`` (if one is): called
+        by the producing thread once it has appended an item or marked the
+        stream ended, outside ``_streams_lock``."""
+        if st is None:
+            return
+        with self._streams_lock:
+            waiter, st["waiter"] = st["waiter"], None
+        if waiter is not None:
+            loop, fut = waiter
+            try:
+                loop.call_soon_threadsafe(_resolve, fut)
+            except RuntimeError:  # the loop is closed: nobody waits
+                pass
 
     async def h_peer_next_stream_item(self, conn, body):
         """Direct-result streaming: the submitter pulls a streaming task's
@@ -836,14 +863,41 @@ class Worker:
                 if st is None:
                     return {"done": True}
                 if index < len(st["items"]):
-                    return {"item": st["items"][index]}
+                    # The item asked for and, where the consumer has fallen
+                    # behind the producer, the inline ones already behind
+                    # it: a consumer that is late catches up in one round
+                    # trip, not one a token (it keeps pace item by item).
+                    reply = {"item": st["items"][index]}
+                    ahead = []
+                    for info in st["items"][index + 1:
+                                            index + 1 + _STREAM_AHEAD]:
+                        if info.get("inline") is None:
+                            break
+                        ahead.append(info)
+                    if ahead:
+                        reply["ahead"] = ahead
+                    return reply
                 if st["error"] is not None:
                     return {"error": st["error"]}
                 if st["done"] is not None:
                     # Fully consumed: drop the retained stream state.
                     self.direct_streams.pop(task_id, None)
                     return {"done": True}
-            await asyncio.sleep(0.005)
+                # Nothing yet: the producer wakes this pull when it
+                # appends, fails or ends (``_wake_stream``).  Registered
+                # under the lock it appends under, so no item slips
+                # between the look above and the wait below.
+                loop = asyncio.get_running_loop()
+                fut = loop.create_future()
+                st["waiter"] = (loop, fut)
+            # The timer only bounds a wake that never comes (the stream's
+            # state shed while a pull waits on it); a timer handle, not
+            # ``wait_for``: that would make a task a token.
+            timer = loop.call_later(0.25, _resolve, fut)
+            try:
+                await fut
+            finally:
+                timer.cancel()
 
     async def h_peer_cancel(self, conn, body):
         self._peer_validate("peer_cancel", body)
@@ -1037,6 +1091,7 @@ class Worker:
                             st = self.direct_streams.get(task_id)
                             if st is not None:
                                 st["items"].append(info)
+                        self._wake_stream(st)
                     else:
                         self.client.call_bg(
                             "stream_item",

@@ -15,8 +15,12 @@ output (the paged cache's programs).  A gated delta-rule layer (``is_kda``,
 ``models/kda.py``) keeps a recurrent state and no cache rows:
 ``attend(pre, g, beta, a)`` is handed the q/k/v projections before their
 convolution, the log-decay and the write strength, owns the state, and
-chooses the recurrent or the chunk form.  A configuration with an
-``attn_layout`` has layers of both kinds: a program asks each layer.
+chooses the recurrent or the chunk form.  A selective state-space layer
+(``is_ssm``, ``models/mamba.py``) likewise: ``attend(pre, a)`` is handed the
+in-projection's xs half before its convolution and returns the recurrence's
+output beside the convolved xs.  A configuration with an ``attn_layout`` has
+layers of two kinds, one recurrent and one that keeps cache rows: a program
+asks each layer.
 
 ``llama`` and ``moe`` are reached through their modules, at call time: the
 helpers a test swaps there (``llama._qk_norm``, ``moe._moe_ffn``) are the
@@ -39,7 +43,7 @@ import jax.numpy as jnp
 from ..ops.norms import rms_norm
 from ..parallel.sharding import (HEADS, RESIDUAL, SPLIT, VOCAB_ROWS,
                                  constrain)
-from . import kda, llama, moe
+from . import kda, llama, mamba, moe
 
 Params = Dict[str, Any]
 #: ``attend(q [..., H, D], k, v [..., H_kv, D]) -> [..., H*D]``; of a
@@ -68,6 +72,22 @@ def is_kda(config, i: Optional[int] = None) -> bool:
     if i is None:
         return "kda" in getattr(config, "attn_layout", ())
     return layer_attn(config, i) == "kda"
+
+
+def is_ssm(config, i: Optional[int] = None) -> bool:
+    """As ``is_kda``, of a selective state-space layer
+    (``models/mamba.py``)."""
+    if i is None:
+        return "ssm" in getattr(config, "attn_layout", ())
+    return layer_attn(config, i) == "ssm"
+
+
+def recurrent(config, i: Optional[int] = None):
+    """The module of layer ``i``'s recurrence (``kda`` or ``mamba``: what
+    keeps a state a sequence and no cache rows), None of a layer that keeps
+    rows; with no layer named, of the configuration's recurrent layers,
+    which are of one kind."""
+    return kda if is_kda(config, i) else mamba if is_ssm(config, i) else None
 
 
 def rotary_dim(config) -> int:
@@ -125,11 +145,15 @@ def attention(config, a: Params, h: jax.Array, attend: Attend,
     """Attention of normalised ``h`` through the output projection.  A
     latent layer takes no adapter: the deltas on ``wq`` and ``wv`` have no
     counterpart among its projections (the engine refuses to load one);
-    nor does a KDA layer (``attn`` ``"kda"``: the layer's entry of
-    ``attn_layout``, empty for a configuration of one kind)."""
+    nor does a KDA or a state-space layer (``attn`` ``"kda"`` / ``"ssm"``:
+    the layer's entry of ``attn_layout``, empty for a configuration of one
+    kind)."""
     if attn == "kda":
         return kda.output(config, a, h,
                           attend(*kda.project(config, a, h), a))
+    if attn == "ssm":
+        pre, z = mamba.project(config, a, h)
+        return mamba.output(config, a, *attend(pre, a), z)
     if is_latent(config):
         out = attend(*project_latent(config, a, h), a["wkv_b"])
     else:
@@ -157,7 +181,8 @@ def layer_rotary(config, i: int) -> bool:
 
 
 def layer_attn(config, i: int) -> str:
-    """Layer ``i``'s entry of ``attn_layout`` (``"kda"`` | ``"latent"``);
+    """Layer ``i``'s entry of ``attn_layout`` (``"kda"`` | ``"ssm"``, the
+    recurrent kinds; ``"latent"`` | ``"kv"``, the kinds that keep rows);
     empty for a configuration whose layers are of one kind."""
     layout = getattr(config, "attn_layout", ())
     return layout[i] if layout else ""
@@ -189,10 +214,23 @@ def is_routed(config, i: Optional[int] = None) -> bool:
 def init_and_apply(config):
     """``(init(config, key) -> params, apply(config, params, tokens) ->
     logits [B, S, V] float32)`` of ``config``'s architecture: how its
-    weights are made, and its full forward pass."""
-    if is_routed(config):
+    weights are made, and its full forward pass.  A layout of attention
+    kinds is ``moe.py``'s to read, routed layers beside it or none."""
+    if is_routed(config) or getattr(config, "attn_layout", ()):
         return moe.moe_init, lambda c, p, t: moe.moe_apply(c, p, t)[0]
     return llama.llama_init, llama.llama_apply
+
+
+def head(config, params: Params, x: jax.Array) -> jax.Array:
+    """The logits [..., V] float32 of final-normed hidden states x [..., d]:
+    through ``lm_head``, or, where the head is tied
+    (``MoEConfig.tie_embeddings``: the tree has no ``lm_head``), by the
+    embedding where it lies, contracted over its rows' width, so that the
+    tree and the device hold the one matrix."""
+    if getattr(config, "tie_embeddings", False):
+        return jnp.einsum("...d,vd->...v", x, params["embed"]).astype(
+            jnp.float32)
+    return (x @ params["lm_head"]).astype(jnp.float32)
 
 
 def post_norm(config, layer: Params, name: str, out: jax.Array) -> jax.Array:

@@ -57,6 +57,9 @@ Params = Dict[str, Any]
 
 #: Tokens in one block of the chunk form.
 BLOCK = 64
+#: The named scope of the recurrent form (``paged.py`` writes the state pool
+#: under it too).
+SCOPE = "attn_kda"
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -183,7 +186,7 @@ def recurrent(S: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
               g: jax.Array, beta: jax.Array):
     """One token a sequence: S [B, H, D, D] float32, q, k, v, g [B, H, D],
     beta [B, H].  Returns (o [B, H, D], the new state)."""
-    with jax.named_scope("attn_kda"):
+    with jax.named_scope(SCOPE):
         S = S * jnp.exp(g)[..., None]
         ks = jnp.sum(S * k[..., None], axis=-2)            # S'^T k
         qs = jnp.sum(S * q[..., None], axis=-2)            # S'^T q
@@ -249,6 +252,33 @@ def chunked(S: jax.Array, q: jax.Array, k: jax.Array, v: jax.Array,
         S, o = jax.lax.scan(_block, S, tuple(map(blocks, (q, k, v, g, beta))))
         o = jnp.moveaxis(o, 0, 1).reshape(B, T + pad, *o.shape[3:])
     return o[:, :T], S
+
+
+def decode_rows(config, a: Params, S: jax.Array, rows: jax.Array,
+                pre: jax.Array, g: jax.Array, beta: jax.Array):
+    """A decode step's work in one KDA layer: the new rows' projections
+    (``project``: pre [B, .], one row a slot) behind each slot's convolution
+    rows, through the recurrent form on each slot's state S.  Returns (the
+    heads' outputs [B, H, D] float32, the new states, the next convolution
+    rows).  ``mamba.decode_rows`` is its sibling: the two calls ``paged.py``
+    makes of a recurrent layer."""
+    q, k, v, nxt = conv(config, a, pre[:, None], rows)
+    o, new = recurrent(S, q[:, 0], k[:, 0], v[:, 0], g, beta)
+    return o, new, nxt
+
+
+def prefill_rows(config, a: Params, S: jax.Array, rows: jax.Array,
+                 valid: jax.Array, pre: jax.Array, g: jax.Array,
+                 beta: jax.Array):
+    """A prefill call's work in one KDA layer on ONE sequence's rows (pre
+    [S_pad, .], ``valid`` [S_pad] the real ones) behind the state S [1, .]
+    and the convolution rows [1, .] they follow: the chunk form.  Returns
+    (the heads' outputs [1, S_pad, H, D] float32, the state and the
+    convolution rows behind the last real row, each [1, .])."""
+    n = jnp.sum(valid, dtype=jnp.int32)
+    q, k, v, nxt = conv(config, a, pre[None], rows, n[None])
+    o, new = chunked(S, q, k, v, g[None], beta[None], valid[None])
+    return o, new, nxt
 
 
 def full_attend(config):

@@ -358,9 +358,8 @@ def _layer(config, x, layer, cos, sin, lora_layer, kind, routed):
     from . import block
 
     attn = kind[2] if len(kind) > 2 else ""
-    if attn == "kda":
-        from . import kda
-        attend = kda.full_attend(config)
+    if attn in ("kda", "ssm"):
+        attend = block.recurrent(config).full_attend(config)
     elif block.is_latent(config):
         attend = functools.partial(_attend_latent, config, cos, sin,
                                    rotary=kind[1])

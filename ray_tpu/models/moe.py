@@ -112,7 +112,22 @@ class MoEConfig:
     (0: it scores the held ones, all of them).  A token's ``top_k`` are
     chosen among all the router's experts; the pairs of experts held
     elsewhere are dropped before the grouped products, and the layer
-    returns the partial sum of its own."""
+    returns the partial sum of its own.
+
+    Jamba's fields (``jamba``; the first configuration with an
+    ``attn_layout`` and NO routed layer: ``ffn_layout`` all false, every FFN
+    the dense SwiGLU of ``dense_d_ff``, and ``n_experts`` / ``top_k`` say
+    nothing).  ``attn_layout[i]`` ``"ssm"``: layer ``i`` is a selective
+    state-space layer (``models/mamba.py``: ``ssm_inner`` channels with a
+    state of ``ssm_state`` each behind a causal depthwise convolution over
+    the last ``ssm_conv`` positions, the step projected through
+    ``ssm_dt_rank``; a recurrent state a sequence, no cache rows);
+    ``"kv"``: ordinary attention (K and V rows, whole length) BESIDE
+    recurrent layers.  A configuration has one kind of recurrent layer
+    (``"kda"`` or ``"ssm"``: the state pools are of one shape) and one kind
+    of cache (``"latent"`` or ``"kv"``).  ``tie_embeddings``: there is no
+    ``lm_head``; the logits multiply by the embedding where it lies
+    (``block.head``)."""
 
     vocab_size: int = 32000
     d_model: int = 4096
@@ -155,6 +170,11 @@ class MoEConfig:
     kda_conv: int = 0
     router_experts: int = 0       # 0 -> n_experts
     first_expert: int = 0
+    ssm_inner: int = 0
+    ssm_state: int = 0
+    ssm_dt_rank: int = 0
+    ssm_conv: int = 0
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         latent = (self.kv_lora_rank, self.qk_nope_head_dim,
@@ -185,14 +205,27 @@ class MoEConfig:
         layout = tuple(str(x) for x in self.attn_layout)
         object.__setattr__(self, "attn_layout", layout)
         if layout:
-            if len(layout) != self.n_layers \
-                    or any(a not in ("kda", "latent") for a in layout):
+            if len(layout) != self.n_layers or any(
+                    a not in ("kda", "latent", "ssm", "kv") for a in layout):
                 raise ValueError(
-                    f"attn_layout is not \"kda\" or \"latent\" for each of "
-                    f"the {self.n_layers} layers: {layout}")
+                    f"attn_layout is not \"kda\", \"latent\", \"ssm\" or "
+                    f"\"kv\" for each of the {self.n_layers} layers: "
+                    f"{layout}")
             if "latent" in layout and not self.kv_lora_rank:
                 raise ValueError("attn_layout names latent layers and "
                                  "kv_lora_rank is 0")
+            if "kv" in layout and self.kv_lora_rank:
+                raise ValueError("attn_layout names K/V layers beside a "
+                                 "latent pool: one kind of cache")
+            if "kda" in layout and "ssm" in layout:
+                raise ValueError("attn_layout names two kinds of recurrent "
+                                 "layer: the state pools are of one shape")
+            if "ssm" in layout and not (
+                    self.ssm_inner > 0 and self.ssm_state > 0
+                    and self.ssm_dt_rank > 0 and self.ssm_conv > 1):
+                raise ValueError(
+                    "attn_layout names ssm layers: ssm_inner, ssm_state, "
+                    "ssm_dt_rank and ssm_conv (at least 2) are needed")
             if "kda" in layout and not (self.kda_heads > 0
                                         and self.kda_head_dim > 0
                                         and self.kda_conv > 1):
@@ -245,11 +278,11 @@ class MoEConfig:
                     + rkv * self.n_heads * (self.qk_nope_head_dim
                                             + self.v_head_dim)
                     + self.n_heads * self.v_head_dim * d)
-        n_kda = self.attn_layout.count("kda")
-        attn *= self.n_layers - n_kda
-        if n_kda:
-            from . import kda
-            attn += n_kda * kda.param_count(self)
+        n_rec = self.attn_layout.count("kda") + self.attn_layout.count("ssm")
+        attn *= self.n_layers - n_rec
+        if n_rec:  # of one kind (``__post_init__``): its module counts
+            from . import block
+            attn += n_rec * block.recurrent(self).param_count(self)
         routed = (d * self.router_width              # router
                   + self.n_experts * 3 * d * f       # experts (held)
                   + self.n_shared_experts * 3 * d * f)
@@ -259,7 +292,7 @@ class MoEConfig:
         norms = 4 * d if self.post_norm else 2 * d
         return (v * d + attn + self.n_layers * norms + n_routed * routed
                 + (self.n_layers - n_routed) * 3 * d * self.dense_d_ff
-                + d + d * v)
+                + d + (0 if self.tie_embeddings else d * v))
 
     def as_llama(self) -> LlamaConfig:
         """The attention fields as a ``LlamaConfig``.  No program needs it
@@ -305,11 +338,18 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
                 "w2": dense(ks[2], (width, d), width ** -0.5)}
 
     params: Params = {
-        "embed": dense(keys[0], (config.vocab_size, d), 1.0),
+        # Tied, the embedding is the head too and is drawn as a head is:
+        # at 1.0 a token's own row (norm sqrt(d)) would stand in the
+        # residual stream it is scored against, every logit but its own
+        # would be noise beside that one, and no comparison of logits
+        # would see a fault (each row's best token the one it was fed).
+        "embed": dense(keys[0], (config.vocab_size, d),
+                       std if config.tie_embeddings else 1.0),
         "final_norm": jnp.ones((d,), config.dtype),
-        "lm_head": dense(keys[1], (d, config.vocab_size), std),
         "layers": [],
     }
+    if not config.tie_embeddings:  # tied: the head is the embedding
+        params["lm_head"] = dense(keys[1], (d, config.vocab_size), std)
     for i in range(config.n_layers):
         ks = jax.random.split(keys[2 + i], 8)
         # What only GLM-4.7-Flash's and Trinity-Mini's lines have draws
@@ -317,9 +357,10 @@ def moe_init(config: MoEConfig, key: jax.Array) -> Params:
         # are what they were.
         more = jax.random.split(jax.random.fold_in(keys[2 + i], 1), 8)
         layer = {"attn_norm": jnp.ones((d,), config.dtype)}
-        if config.attn_layout and config.attn_layout[i] == "kda":
-            from . import kda
-            layer["attn"] = kda.init(config, keys[2 + i])
+        if config.attn_layout and config.attn_layout[i] in ("kda", "ssm"):
+            from . import block
+            layer["attn"] = block.recurrent(config, i).init(
+                config, keys[2 + i])
         elif config.kv_lora_rank:
             rq, rkv = config.q_lora_rank, config.kv_lora_rank
             o_in = config.n_heads * config.v_head_dim
@@ -556,8 +597,10 @@ def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
 def moe_apply(config: MoEConfig, params: Params, tokens: jax.Array
               ) -> Tuple[jax.Array, jax.Array]:
     """Returns (logits [B, S, vocab] fp32, aux_loss scalar)."""
+    from . import block
+
     x, auxes = hidden_and_aux(config, params, tokens)
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+    logits = block.head(config, params, x)
     auxes = [a for a in auxes if a is not None]  # a dense layer has none
     return logits, sum(auxes, jnp.zeros((), jnp.float32)) \
         / max(len(auxes), 1)

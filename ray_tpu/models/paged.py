@@ -76,6 +76,14 @@ its last real row in the slot it is told (``state_slot``), and each further
 chunk of a chunked prompt takes the slot's state in and hands it on.  The
 latent pool beside it holds rows for the latent layers only.
 
+A fifth arrangement where the recurrent layers are selective state-space
+layers (``block.is_ssm``, ``models/mamba.py``) beside ordinary attention
+layers (``attn_layout`` ``"kv"``): the same two slot pools, of that module's
+shapes (``S`` ``[layer, slot, N, I]`` float32, ``conv`` a row a slot), beside
+the ONE K/V pool pair of the whole-length kind, over the attention layers
+only.  ``block.recurrent`` names the module; its ``state_shapes``,
+``decode_rows`` and ``prefill_rows`` are all this file asks of it.
+
 Compile counts are observable via ``trace_count()`` — the jitted bodies
 bump a counter when TRACED (python executes only at trace time), which is
 how tests assert the engine never recompiles after warmup.
@@ -94,7 +102,7 @@ from ..ops import latent_decode, paged_decode
 from ..ops import latent_prefill as latent_prefill_op
 from ..ops import paged_prefill as paged_prefill_op
 from ..ops.rotary import apply_rotary, rope_frequencies
-from . import block, kda
+from . import block
 from .llama import LlamaConfig
 
 Params = Any
@@ -133,29 +141,35 @@ def _bump(name: str, **arrays: Any) -> None:
 def kv_layers(config):
     """(the layers that keep a sequence's whole length, the window layers):
     each a list of layer indices, in order.  A layer's place in its list is
-    its index in that kind's pools.  A gated delta-rule layer keeps no rows
-    and is in neither (``state_layers``)."""
+    its index in that kind's pools.  A recurrent layer (gated delta-rule,
+    state-space) keeps no rows and is in neither (``state_layers``)."""
     layers = range(config.n_layers)
     window = [i for i in layers if block.layer_window(config, i)]
-    return [i for i in layers
-            if i not in window and not block.is_kda(config, i)], window
+    return [i for i in layers if i not in window
+            and block.recurrent(config, i) is None], window
 
 
 def state_layers(config) -> List[int]:
-    """The gated delta-rule layers, in order: a layer's place in the list is
-    its index in the state pools ``S`` and ``conv``."""
-    return [i for i in range(config.n_layers) if block.is_kda(config, i)]
+    """The recurrent layers, in order: a layer's place in the list is its
+    index in the state pools ``S`` and ``conv``."""
+    return [i for i in range(config.n_layers)
+            if block.recurrent(config, i) is not None]
+
+
+def _state_shapes(config, slots: int) -> Dict[str, Any]:
+    """The state pools of ``config``'s recurrent layers for ``slots``
+    sequences, by name (none without such layers)."""
+    layers = state_layers(config)
+    if not layers:
+        return {}
+    return block.recurrent(config).state_shapes(config, len(layers), slots)
 
 
 def state_bytes(config, slots: int = 1) -> int:
     """The bytes of recurrent state ``slots`` sequences hold (0 without
-    gated delta-rule layers)."""
-    layers = state_layers(config)
-    if not layers:
-        return 0
+    recurrent layers)."""
     return sum(math.prod(s.shape) * jnp.dtype(s.dtype).itemsize
-               for s in kda.state_shapes(config, len(layers),
-                                         slots).values())
+               for s in _state_shapes(config, slots).values())
 
 
 def _kv_slot(config, i: int):
@@ -192,21 +206,20 @@ def init_paged_pools(config: LlamaConfig, num_pages: int,
     model, the one pool ``kv`` of latent rows, over its latent layers); the
     last index (``num_pages``, ``window_pages``) of each is its scratch page
     (writes routed there are never read).  Beside them, where the
-    configuration has gated delta-rule layers, the recurrent state of
-    ``state_slots`` sequences (``kda.state_shapes``), zeros."""
+    configuration has recurrent layers, the state of ``state_slots``
+    sequences (the module's ``state_shapes``), zeros."""
     whole, window = kv_layers(config)
+    state = {}
+    if state_layers(config):
+        if state_slots <= 0:
+            raise ValueError("a configuration with recurrent layers "
+                             "keeps a state a slot: state_slots")
+        state = {name: jnp.zeros(shape.shape, shape.dtype) for name, shape
+                 in _state_shapes(config, state_slots).items()}
     if block.is_latent(config):
-        pools = {"kv": jnp.zeros(
+        return {"kv": jnp.zeros(
             (len(whole), num_pages + 1, page_size,
-             latent_row_width(config)), config.dtype)}
-        if block.is_kda(config):
-            if state_slots <= 0:
-                raise ValueError("a configuration with gated delta-rule "
-                                 "layers keeps a state a slot: state_slots")
-            for name, shape in kda.state_shapes(
-                    config, len(state_layers(config)), state_slots).items():
-                pools[name] = jnp.zeros(shape.shape, shape.dtype)
-        return pools
+             latent_row_width(config)), config.dtype), **state}
 
     def pair(suffix, n_layers, pages):
         shape = (n_layers, pages + 1, page_size,
@@ -217,7 +230,7 @@ def init_paged_pools(config: LlamaConfig, num_pages: int,
     pools = pair("", len(whole), num_pages)
     if window:
         pools.update(pair("w", len(window), window_pages))
-    return pools
+    return {**pools, **state}
 
 
 def _whole(pools: PagedPools) -> jax.Array:
@@ -314,15 +327,17 @@ ROUTING_KEYS = ("experts_hit", "expert_pairs", "expert_load_max")
 #: (the three above count its OWN experts and the pairs that land on them):
 #: the pairs the router made, here or elsewhere (real rows x ``top_k``).
 SHARE_KEYS = ("expert_pairs_routed",)
-#: What the decode step of a configuration with window layers, or with a
-#: latent pool, appends behind them (``_with_kv_rows``): the rows the program
-#: brought in (a gather's whole tables, or the pages a kernel walks) and
-#: the rows a query could see.
+#: What the decode step of a configuration with window layers, a latent
+#: pool or recurrent layers appends behind them (``_with_kv_rows``): the
+#: rows the program brought in (a gather's whole tables, or the pages a
+#: kernel walks) and the rows a query could see.
 KV_KEYS = ("kv_rows_read", "kv_rows_live")
 
 
 def _counts_kv_rows(config) -> bool:
-    return bool(kv_layers(config)[1]) or block.is_latent(config)
+    """Rings, a latent pool, or rows of any kind beside recurrent layers."""
+    return bool(kv_layers(config)[1]) or block.is_latent(config) \
+        or bool(state_layers(config))
 
 
 def routing_keys(config) -> tuple:
@@ -377,8 +392,10 @@ def _walks_live_pages(config) -> bool:
     ``ops.latent_prefill_attention``) or a model with window layers (the
     decode step, ``ops.paged_decode_attention``, and the suffix prefill,
     ``ops.paged_prefill_attention``: on its whole-length layers and its
-    rings), which are the configurations whose decode program counts its
-    rows.  The one place that chooses; ``_with_kv_rows`` counts by it."""
+    rings), or one whose K/V layers stand beside recurrent layers (the same
+    two kernels on its few whole-length layers), which are the
+    configurations whose decode program counts its rows.  The one place
+    that chooses; ``_with_kv_rows`` counts by it."""
     kernel = latent_decode if block.is_latent(config) else paged_decode
     return _counts_kv_rows(config) and kernel.on_tpu()
 
@@ -425,8 +442,9 @@ def _window_lo(config, seq_lens: jax.Array) -> jax.Array:
 def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
                   ring_tables: Optional[jax.Array], seq_lens: jax.Array,
                   active: jax.Array, ps: int) -> jax.Array:
-    """``toks`` followed (where ``config`` has window layers or a latent
-    pool) by the decode step's ``KV_KEYS``, summed over layers and slots:
+    """``toks`` followed (where ``config`` has window layers, a latent
+    pool or recurrent layers) by the decode step's ``KV_KEYS``, summed over
+    the layers that keep rows and over slots:
     the rows of K (of the latent pool) the program brought in, and the rows
     a query could see (``len + 1`` on a whole-length layer, at most the
     window on a window layer, nothing in an empty slot).  What it brings in
@@ -634,62 +652,69 @@ def _sample_tokens(logits: jax.Array, temps: jax.Array,
 
 
 def _stack(config, params: Params, tokens: jax.Array, attend, lora,
-           valid: jax.Array, attend_kda=None):
+           valid: jax.Array, attend_state=None):
     """The decoder stack of a serving program over tokens [N]:
     ``attend(i, q, k, v)`` and ``lora(i, name, h)`` are ``block``'s
     closures with the layer's index in front (the pools and the adapter
-    pool are indexed by it); ``attend_kda(i, pre, g, beta, a)`` is the
-    gated delta-rule layers' (``_kda_decode``, ``_kda_prefill``).  Returns
+    pool are indexed by it); ``attend_state(i, *projected, a)`` is the
+    recurrent layers' (``_state_decode``, ``_state_prefill``).  Returns
     (hidden [N, d], the layers' expert counts)."""
     hidden, _, counts = block.decoder_stack(
         config, params, tokens,
         lambda i, layer, x: block.decoder_layer(
             config, layer, x, functools.partial(
-                attend_kda if block.is_kda(config, i) else attend, i),
+                attend if block.recurrent(config, i) is None
+                else attend_state, i),
             lora=functools.partial(lora, i), valid=valid,
             routed=block.is_routed(config, i),
             attn=block.layer_attn(config, i)))
     return hidden, counts
 
 
-def _kda_decode(config, pools: PagedPools, active: jax.Array, i: int, pre,
-                g, beta, a):
-    """What the decode step does in gated delta-rule layer ``i`` with the
-    new rows' projections (``kda.project``: pre [B, .], one row a slot):
-    every slot's state through ``kda.recurrent``, an inactive slot's
-    (``active`` [B] false) left as it was, the state pools replaced.
-    Returns the heads' outputs [B, H, D] float32."""
+def _state_decode(config, pools: PagedPools, active: jax.Array, i: int,
+                  *projected):
+    """What the decode step does in recurrent layer ``i`` with the new rows'
+    projections (``block.attention`` hands the closure what the layer's
+    module projects, one row a slot, and the layer's weights last): every
+    slot's state through the module's recurrent form (``decode_rows``), an
+    inactive slot's (``active`` [B] false) left as it was, the state pools
+    replaced.  Returns what the module's ``output`` takes."""
+    *projected, a = projected
+    module = block.recurrent(config, i)
     layer = state_layers(config).index(i)
     S, rows = pools["S"][layer], pools["conv"][layer]
-    q, k, v, nxt = kda.conv(config, a, pre[:, None], rows)
-    o, new = kda.recurrent(S, q[:, 0], k[:, 0], v[:, 0], g, beta)
-    with jax.named_scope("attn_kda"):  # the write is the recurrence's
-        pools["S"] = pools["S"].at[layer].set(
-            jnp.where(active[:, None, None, None], new, S))
+    out, new, nxt = module.decode_rows(config, a, S, rows, *projected)
+
+    def live(new, old):  # active [B] against [B, ...]
+        return jnp.where(active[(slice(None),) + (None,) * (old.ndim - 1)],
+                         new, old)
+
+    with jax.named_scope(module.SCOPE):  # the write is the recurrence's
+        pools["S"] = pools["S"].at[layer].set(live(new, S))
     pools["conv"] = pools["conv"].at[layer].set(
-        jnp.where(active[:, None, None], nxt.astype(rows.dtype), rows))
-    return o
+        live(nxt.astype(rows.dtype), rows))
+    return out
 
 
-def _kda_prefill(config, pools: PagedPools, slot: jax.Array, fresh,
-                 valid: jax.Array, i: int, pre, g, beta, a):
-    """What a prefill does in gated delta-rule layer ``i`` with the
-    projections of ONE sequence's rows (pre [S_pad, .], ``valid`` [S_pad]
-    the real ones): the chunk form from zeros where ``fresh`` (True of the
-    cold prefill; a scalar bool of a chunk's program), else from what
-    ``slot`` holds (a chunk behind another), and the state behind the last
-    real row left in the slot.  Returns the heads' outputs [S_pad, H, D]
-    float32."""
+def _state_prefill(config, pools: PagedPools, slot: jax.Array, fresh,
+                   valid: jax.Array, i: int, *projected):
+    """What a prefill does in recurrent layer ``i`` with the projections of
+    ONE sequence's rows ([S_pad, .], ``valid`` [S_pad] the real ones; the
+    layer's weights last): the module's chunk form (``prefill_rows``) from
+    zeros where ``fresh`` (True of the cold prefill; a scalar bool of a
+    chunk's program), else from what ``slot`` holds (a chunk behind
+    another), and the state behind the last real row left in the slot.
+    Returns what the module's ``output`` takes."""
+    *projected, a = projected
     layer = state_layers(config).index(i)
     S = jnp.where(fresh, 0, pools["S"][layer, slot])[None]
     rows = jnp.where(fresh, 0, pools["conv"][layer, slot])[None]
-    n = jnp.sum(valid, dtype=jnp.int32)
-    q, k, v, nxt = kda.conv(config, a, pre[None], rows, n[None])
-    o, new = kda.chunked(S, q, k, v, g[None], beta[None], valid[None])
+    out, new, nxt = block.recurrent(config, i).prefill_rows(
+        config, a, S, rows, valid, *projected)
     pools["S"] = pools["S"].at[layer, slot].set(new[0])
     pools["conv"] = pools["conv"].at[layer, slot].set(
         nxt[0].astype(rows.dtype))
-    return o[0]
+    return jax.tree.map(lambda t: t[0], out)  # the batch of one
 
 
 def _write_kv(pools: PagedPools, layer: int, page_idx: jax.Array,
@@ -851,8 +876,8 @@ def decode_logits(config, params: Params, pools: PagedPools,
 
     x, counts = _stack(config, params, tokens[:B], attend,
                        _adapter_lora(adapters, adapter_ids), active,
-                       functools.partial(_kda_decode, config, pools, active))
-    logits = (x @ params["lm_head"]).astype(jnp.float32)
+                       functools.partial(_state_decode, config, pools, active))
+    logits = block.head(config, params, x)
     return logits, pools, counts
 
 
@@ -984,11 +1009,11 @@ def prefill_logits(config, params: Params, pools: PagedPools,
     x, counts = _stack(config, params, tokens[0], attend,
                        _adapter_lora(adapters, adapter_id),
                        positions < length,
-                       functools.partial(_kda_prefill, config, pools,
+                       functools.partial(_state_prefill, config, pools,
                                          state_slot, True,
                                          positions < length))
     x_last = jnp.take(x, length - 1, axis=0)  # last REAL position
-    logits = (x_last @ params["lm_head"]).astype(jnp.float32)[None]
+    logits = block.head(config, params, x_last)[None]
     return logits, pools, counts
 
 
@@ -1087,10 +1112,10 @@ def prefill_prefix_logits(config, params: Params, pools: PagedPools,
 
     x, counts = _stack(config, params, tokens[0], attend,
                        _adapter_lora(adapters, adapter_id), valid,
-                       functools.partial(_kda_prefill, config, pools,
+                       functools.partial(_state_prefill, config, pools,
                                          state_slot, prefix_len == 0, valid))
     x_last = jnp.take(x, length - prefix_len - 1, axis=0)  # last real row
-    logits = (x_last @ params["lm_head"]).astype(jnp.float32)[None]
+    logits = block.head(config, params, x_last)[None]
     return logits, pools, counts
 
 

@@ -388,11 +388,11 @@ class InferenceEngine:
         self.ring = min(self.maxp, ring_entries(
             model_config, cfg.page_size, cfg.prefill_buckets()[-1]))
         self.ring_scratch = cfg.batch_slots * self.ring
-        # A third kind where the model has gated delta-rule layers: a
-        # recurrent state a layer, which slot s owns as it owns its ring
-        # (the pools' ``S`` and ``conv``, indexed by the slot).  An
-        # admission's first prefill call starts it from zeros on the
-        # device; nothing here allocates or clears it.
+        # A third kind where the model has recurrent layers (gated
+        # delta-rule, state-space): a state a layer, which slot s owns as
+        # it owns its ring (the pools' ``S`` and ``conv``, indexed by the
+        # slot).  An admission's first prefill call starts it from zeros
+        # on the device; nothing here allocates or clears it.
         self._state_layers = len(state_layers(model_config))
         self._state_bytes = state_bytes(model_config)  # a slot's
         self.allocator = PageAllocator(cfg.pool_pages)
@@ -416,7 +416,7 @@ class InferenceEngine:
         # A radix node is one page, valid for every layer: false of a
         # window layer's ring page, so such a model runs without it; and a
         # node holds no recurrent state at its depth, so a model with
-        # gated delta-rule layers does too.
+        # recurrent layers does too.
         self._cache_off = ("window layers" if self.ring else
                            "recurrent layers" if self._state_layers
                            else None)
@@ -765,7 +765,7 @@ class InferenceEngine:
             # Why it is off where the configuration asked for it.
             "prefix_cache_off": (self._cache_off
                                  if self.config.prefix_cache else None),
-            # The recurrent state of a model with gated delta-rule layers:
+            # The recurrent state of a model with recurrent layers:
             # a slot's own, in the pools beside the pages.
             "state": ({"layers": self._state_layers,
                        "slot_bytes": self._state_bytes,
@@ -1269,6 +1269,12 @@ class InferenceEngine:
             routing["attn_pairs"] = sum(
                 attn_pairs(self.model_config, start, min(start + chunk, n))
                 for start in starts)
+        if self._state_layers:
+            # What the recurrent layers' chunk form went over: the real
+            # positions, and the rows of the calls' buckets behind them
+            # (each costs a position's step and leaves the state alone).
+            routing["scan_rows"] = n - prefix_len
+            routing["scan_rows_padded"] = rows - (n - prefix_len)
         return rows, len(starts), routing
 
     def _prefill_prepare(self, req: _Request) -> None:

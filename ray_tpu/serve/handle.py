@@ -155,8 +155,12 @@ class DeploymentResponseGenerator:
             backoff = _replica_retry_policy()
             while True:
                 try:
-                    for ref in self._gen:
-                        yield ray_tpu.get(ref)
+                    # A stream's own generator hands values (no reference
+                    # a token); any other iterator of references is read.
+                    gen = self._gen
+                    for value in (gen.values() if hasattr(gen, "values")
+                                  else map(ray_tpu.get, gen)):
+                        yield value
                         yielded = True
                     return
                 except (ActorDiedError, WorkerCrashedError):

@@ -208,6 +208,40 @@ def test_direct_streaming(rt):
     assert _head_dispatched() - d0 == 0.0
 
 
+def test_a_late_consumer_catches_up_in_one_pull(rt):
+    """A consumer behind its producer is handed, with the item it asked for,
+    the inline items already behind it (``_STREAM_AHEAD`` at most), and
+    adopts them without a round trip each: every item once, in order,
+    whatever mix of pulls and adoptions brought it; one that keeps pace
+    still pulls item by item."""
+    a = Echo.remote()
+    _establish_direct(rt, a)
+    gen = a.stream.options(num_returns="streaming").remote(150)
+    time.sleep(1.0)  # the producer runs ahead of the first pull
+    first = rt.get(next(gen))
+    assert first == 0 and len(gen._ahead) == 64
+    # Adopted refs resolve like pulled ones, and the end is still the
+    # producer's to say (the list ends on its StopIteration).
+    assert [rt.get(r) for r in gen] == [10 * i for i in range(1, 150)]
+    assert gen._index == 150 and not gen._ahead
+
+
+def test_a_streams_values_come_without_references(rt):
+    """``ObjectRefGenerator.values()``: each inline item unpacked where it
+    arrives, late or on time, in order; nothing is sealed for it."""
+    a = Echo.remote()
+    _establish_direct(rt, a)
+    dp = _dp()
+    gen = a.stream.options(num_returns="streaming").remote(100)
+    time.sleep(0.5)
+    with dp._lock:
+        sealed = len(dp._results)
+    assert list(gen.values()) == [10 * i for i in range(100)]
+    with dp._lock:
+        assert len(dp._results) <= sealed
+    assert gen._index == 100
+
+
 def test_direct_error_and_cancel(rt):
     @rt.remote
     class Bad:
