@@ -15,13 +15,17 @@ Design (token-choice top-k, no capacity and no drops):
   expert's rows are one contiguous group of a [tokens*k, d] array
 - experts: three grouped products over those groups: memory and operations
   are proportional to tokens x top_k whatever the load; an expert with no
-  token costs nothing and one with every token is just a long group.  Two
-  forms of one arithmetic (``_streams_experts`` chooses by what it can see):
-  ``jax.lax.ragged_dot`` three times, which XLA lowers on the TPU to a
-  grouped-matmul kernel of its own (every prefill, training, every backend
-  that is not a TPU); and where an expert gets a handful of rows on a TPU (a
-  decode step), ``ops.grouped_ffn``: one Pallas kernel that streams each hit
-  expert's weights once, in large blocks, through all three
+  token costs nothing and one with every token is just a long group.  Three
+  forms of one arithmetic, all in ``ops.grouped_ffn`` (``_streams_experts``
+  chooses by what it can see): ``jax.lax.ragged_dot`` three times, which
+  sweeps the weights once a call and sends ``h`` through HBM between (every
+  backend that is not a TPU, widths the kernels cannot cut, a program under
+  a mesh or holding a share of the router's experts, and the gradient); and
+  on a TPU one Pallas kernel that reads each hit expert's weights once
+  through all three: ``"stream"`` where an expert gets a handful of rows (a
+  decode step: the step's rows whole in VMEM), ``"rows"`` where it gets a
+  prompt's (every prefill, the full forward, a training batch: the rows
+  pass in MXU-sized blocks)
 - combine: the pairs are put back in token order and summed with their
   router weights in float32
 - aux loss: Switch load-balancing loss (mean expert fraction x mean router
@@ -42,7 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops import grouped_ffn
 from ..parallel.mesh import AXIS_EP, AXIS_FSDP, AXIS_TP
-from ..parallel.sharding import ShardingRules
+from ..parallel.sharding import ShardingRules, auto_mesh
 from .llama import (LlamaConfig, _mlp, hidden_and_aux, llama_sharding_rules,
                     qk_norm_init)
 
@@ -490,33 +494,57 @@ def _shared_expert(config: MoEConfig, shared: Params, x: jax.Array,
     return out.astype(config.dtype)
 
 
-#: The most rows an expert gets, on average, where its weights are streamed.
+#: The most rows an expert gets, on average, where its weights are streamed
+#: past a step's rows held whole in VMEM.
 STREAM_ROWS_AN_EXPERT = 4
 
 
-def _streams_experts(config: MoEConfig, pairs: int) -> bool:
-    """Whether the grouped products over ``pairs`` (token, expert) rows go
-    through ``ops.grouped_ffn_stream`` in place of three ``ragged_dot``
-    calls: on a TPU, a few rows an expert (the work is then reading the
-    hit experts' weights: every decode program, 96-128 pairs over 64
-    experts; a prefill bucket or a training batch has eight rows an expert
-    and up), widths the kernel's DMAs can cut.  ``pairs`` is the program's
-    static count, so one program holds one form.  The one place that
-    chooses; the kernel defines no gradient, which training does not ask of
-    it at such a size.  Where the program holds a share of the router's
-    experts, ``pairs`` are the pairs routed, of which its share lands here:
-    the rows an expert gets are ``pairs`` over the ROUTER's width."""
-    return (grouped_ffn.on_tpu()
-            and pairs <= STREAM_ROWS_AN_EXPERT * config.router_width
+def _streams_experts(config: MoEConfig, pairs: int) -> Optional[str]:
+    """Which of ``ops.grouped_ffn``'s kernels does the grouped products over
+    ``pairs`` (token, expert) rows, each reading every hit expert's weights
+    once; None: three ``ragged_dot`` calls.  The one place that chooses, by
+    what it can see.  ``pairs`` is the program's static count, so one
+    program holds one form.
+
+    A kernel needs a TPU, widths its DMAs can cut (whole lane tiles) and a
+    program of one device (XLA does not partition a Mosaic call: under a
+    mesh the products stay ``ragged_dot``).  Then ``"stream"`` where an
+    expert gets a few rows (the work is reading the hit experts' weights:
+    every decode program, 96-128 pairs over 64 experts); where the program
+    holds a share of the router's experts, ``pairs`` are the pairs routed,
+    of which its share lands here, so the rows an expert gets are ``pairs``
+    over the ROUTER's width.  ``"rows"`` for more (a prefill bucket, a
+    chunk, the full forward, a training batch: the rows pass each expert in
+    MXU-sized blocks), unless an expert does not fit VMEM twice
+    (``grouped_ffn.holds_an_expert``) or the program holds a SHARE of its
+    router's experts: seven eighths of Kimi-Linear's sorted rows are other
+    chips' pairs, and compacting them belongs with the exchange between the
+    chips (ROADMAP M2); its prefills keep ``ragged_dot``."""
+    if not (grouped_ffn.on_tpu() and auto_mesh() is None
             and config.d_model % grouped_ffn.LANES == 0
-            and config.d_ff % grouped_ffn.LANES == 0)
+            and config.d_ff % grouped_ffn.LANES == 0):
+        return None
+    if pairs <= STREAM_ROWS_AN_EXPERT * config.router_width:
+        return "stream"
+    if config.router_width > config.n_experts or not \
+            grouped_ffn.holds_an_expert(config.d_model, config.d_ff,
+                                        config.dtype):
+        return None
+    return "rows"
+
+
+#: The forms of the grouped products, by ``grouped_form``'s names.
+GROUPED = {"stream": grouped_ffn.grouped_ffn_stream,
+           "rows": grouped_ffn.grouped_ffn_rows,
+           "ragged_dot": grouped_ffn.grouped_ffn_ragged}
 
 
 def grouped_form(config: MoEConfig, tokens: int) -> str:
     """The form the grouped products of a program over ``tokens`` rows
-    take, by name (``LLMServer.stats()["grouped_ffn"]``)."""
-    return ("stream" if _streams_experts(config, tokens * config.top_k)
-            else "ragged_dot")
+    take, by name: ``"stream"``, ``"rows"`` or ``"ragged_dot"``
+    (``LLMServer.stats()["grouped_ffn"]`` of the decode program,
+    ``["grouped_ffn_prefill"]`` of the prefills)."""
+    return _streams_experts(config, tokens * config.top_k) or "ragged_dot"
 
 
 def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
@@ -565,19 +593,9 @@ def _moe_ffn(config: MoEConfig, moe: Params, x: jax.Array,
                          dtype=jnp.int32)
         xs = xf[order // k]                                    # [G*k, d]
 
-        def grouped(rows, w):
-            return jax.lax.ragged_dot(rows, w, counts,
-                                      preferred_element_type=jnp.float32)
-
-        if _streams_experts(config, G * k):
-            ys = grouped_ffn.grouped_ffn_stream(
-                xs, moe["w1"], moe["w3"], moe["w2"], counts,
-                act=config.expert_act)
-        else:
-            act = grouped_ffn.ACTS[config.expert_act]
-            h = (act(grouped(xs, moe["w1"])) * grouped(xs, moe["w3"])
-                 ).astype(config.dtype)
-            ys = grouped(h, moe["w2"])
+        ys = GROUPED[grouped_form(config, G)](
+            xs, moe["w1"], moe["w3"], moe["w2"], counts,
+            act=config.expert_act)
         ys = ys[rank].reshape(G, k, d)                         # float32
         if valid is not None or share:  # rows behind the last group are
             ys = jnp.where((top_e < E)[..., None], ys, 0.0)  # not written

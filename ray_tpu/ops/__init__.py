@@ -5,11 +5,12 @@ torch/CUDA (SURVEY.md §5.7): flash attention, ring attention (sequence
 parallelism), decode attention over a latent paged pool and over paged K/V
 pairs, prefill attention over a latent paged pool and over paged K/V
 pairs (four fronts of the one walk in ``page_walk.py``), the routed FFN
-streamed expert by expert, fused RMSNorm, rotary embeddings.
+streamed expert by expert (a step's rows, a prompt's rows), fused RMSNorm,
+rotary embeddings.
 """
 
 from .attention import flash_attention, mha_reference
-from .grouped_ffn import grouped_ffn_stream
+from .grouped_ffn import grouped_ffn_rows, grouped_ffn_stream
 from .latent_decode import latent_decode_attention
 from .latent_prefill import latent_prefill_attention
 from .norms import rms_norm
@@ -20,7 +21,7 @@ from .ring_attention import ring_attention
 
 __all__ = [
     "flash_attention", "mha_reference", "latent_decode_attention",
-    "latent_prefill_attention", "paged_decode_attention", "paged_prefill_attention", "grouped_ffn_stream",
+    "latent_prefill_attention", "paged_decode_attention", "paged_prefill_attention", "grouped_ffn_rows", "grouped_ffn_stream",
     "rms_norm",
     "apply_rotary", "rope_frequencies", "ring_attention",
 ]

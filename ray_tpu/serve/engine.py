@@ -778,9 +778,15 @@ class InferenceEngine:
                               "total": self.ring_scratch}
                              if self.ring else None),
             # The form a routed model's decode program holds its experts'
-            # products in: "stream" (ops/grouped_ffn.py) or "ragged_dot".
+            # products in: "stream" (ops/grouped_ffn.py) or "ragged_dot";
+            # and its prefill programs, by their largest bucket: "rows"
+            # (the same file's row-block kernel) or "ragged_dot".
             "grouped_ffn": (
                 grouped_form(self.model_config, self.config.batch_slots)
+                if "experts_hit" in self._counter_keys else None),
+            "grouped_ffn_prefill": (
+                grouped_form(self.model_config,
+                             self.config.prefill_buckets()[-1])
                 if "experts_hit" in self._counter_keys else None),
             # The form the decode program attends its cache in: "walk" (a
             # kernel over the live pages: ops/latent_decode.py,
@@ -1877,7 +1883,9 @@ class LLMServer:
             stats = self.engine.stats()
             if stats["grouped_ffn"]:
                 print("the decode step's grouped products: "
-                      f"{stats['grouped_ffn']}", file=sys.stderr, flush=True)
+                      f"{stats['grouped_ffn']}, the prefills': "
+                      f"{stats['grouped_ffn_prefill']}",
+                      file=sys.stderr, flush=True)
             print(f"the decode step's attention: {stats['decode_attention']}"
                   f", the prefills': {stats['prefill_attention']}",
                   file=sys.stderr, flush=True)

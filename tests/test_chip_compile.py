@@ -174,13 +174,44 @@ def test_grouped_ffn_stream_compiles_at_the_cells_decode_geometry(v5e, cell):
     assert mem.output_size_in_bytes == rows * d * 4
 
 
-@pytest.mark.parametrize("tokens,stream", [(16, True), (128, False)],
+#: A prefill call's grouped products: (rows = tokens x top_k, d, f, experts,
+#: the gate's non-linearity) of SmallThinker's 2048-row chunk, GLM's bucket
+#: 1024 and Trinity-Mini's chunk.
+ROWS_SHAPES = {"smallthinker-chunk": (12288, 2560, 768, 64, "relu"),
+               "glm-bucket-1024": (4096, 2048, 1536, 64, "silu"),
+               "trinity-mini-chunk": (16384, 2048, 1024, 128, "silu")}
+
+
+@pytest.mark.parametrize("cell", ROWS_SHAPES)
+def test_grouped_ffn_rows_compiles_at_the_cells_prefill_geometry(v5e, cell):
+    """One Mosaic call within the VMEM it asks for (two experts whole,
+    GLM's 19 MB each, beside two blocks of rows and of output: 42-52 MB of
+    a v5e's 128), its rows and its float32 output left in HBM, and no
+    temporary beside them: nothing the size of ``h`` (rows x f) or of the
+    output is made a second time."""
+    rows, d, f, experts, act = ROWS_SHAPES[cell]
+    tile, block = grouped_ffn.rows_blocks(rows, experts, jnp.bfloat16)
+    assert (tile, block) == (64, 256)
+    assert grouped_ffn.holds_an_expert(d, f, jnp.bfloat16)
+    compiled = jax.jit(functools.partial(
+        grouped_ffn.grouped_ffn_rows, act=act)).lower(
+        *_stream_args(v5e, rows, d, f, experts)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "%ragged-dot-rows" in calls[0], calls
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 << 20 < rows * f * 2
+    assert mem.output_size_in_bytes == rows * d * 4
+
+
+@pytest.mark.parametrize("tokens,form", [(16, "stream"), (128, "rows")],
                          ids=["decode-step", "smallest-prefill-bucket"])
 def test_the_routed_ffn_streams_where_an_expert_gets_a_handful_of_rows(
-        v5e, monkeypatch, tokens, stream):
+        v5e, monkeypatch, tokens, form):
     """``moe._moe_ffn`` at OLMoE's widths as a TPU takes it: a decode
-    step's sixteen rows go through the one streaming kernel and no
-    ``ragged_dot``; a prefill bucket keeps XLA's three."""
+    step's sixteen rows go through the one streaming kernel, a prefill
+    bucket's through the one row-block kernel, and neither holds a
+    ``ragged_dot``."""
     from ray_tpu.models import MoEConfig, moe
 
     monkeypatch.setattr(grouped_ffn, "on_tpu", lambda: True)
@@ -195,14 +226,17 @@ def test_the_routed_ffn_streams_where_an_expert_gets_a_handful_of_rows(
     text = jax.jit(lambda m, x: moe._moe_ffn(cfg, m, x)).lower(
         layer, _on(one, (tokens, 2048))).compile().as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
-    assert sum("%ragged-dot-stream" in ln for ln in calls) == int(stream)
-    assert sum("%ragged-dot-none" in ln for ln in calls) \
-        == (0 if stream else 3)
+    assert sum("%ragged-dot-stream" in ln for ln in calls) \
+        == int(form == "stream")
+    assert sum("%ragged-dot-rows" in ln for ln in calls) \
+        == int(form == "rows")
+    assert not any("%ragged-dot-none" in ln for ln in calls)
+    assert len(calls) == 1
 
 
 @pytest.mark.parametrize("kernel", ["flash_fwd", "flash_bwd_dq",
                                     "flash_bwd_dkv", "rms_norm",
-                                    "ragged-dot-stream"])
+                                    "ragged-dot-stream", "ragged-dot-rows"])
 def test_kernels_carry_their_names_into_the_compiled_program(v5e, kernel):
     """``pallas_call(name=...)``: what a device trace (and a reduction of
     it) can tell the Mosaic calls apart by."""
@@ -210,9 +244,10 @@ def test_kernels_carry_their_names_into_the_compiled_program(v5e, kernel):
     if kernel == "rms_norm":
         fn, args = rms_norm_pallas, (_on(one, (8192, 2048)),
                                      _on(one, (2048,)))
-    elif kernel == "ragged-dot-stream":
-        fn, args = grouped_ffn.grouped_ffn_stream, _stream_args(
-            v5e, 32, 256, 256, experts=8)
+    elif kernel.startswith("ragged-dot"):
+        fn = {"ragged-dot-stream": grouped_ffn.grouped_ffn_stream,
+              "ragged-dot-rows": grouped_ffn.grouped_ffn_rows}[kernel]
+        args = _stream_args(v5e, 32, 256, 256, experts=8)
     else:
         def loss(q, k, v):
             out = att.flash_attention(q, k, v, force_pallas=True)
