@@ -25,8 +25,12 @@ the closure owns the state, as an attention layer's owns its cache
 (``decode_rows`` / ``prefill_rows``, the two calls ``paged.py`` makes of a
 recurrent layer of either kind):
 
-- ``recurrent``: one token a sequence (the decode step): every live slot's
-  state read once and written once, one fused elementwise pass;
+- ``recurrent``: one token a sequence (the decode step), in plain
+  ``jax.numpy``: XLA reads every slot's state, writes it, and reads it again
+  for ``y``.  On a TPU the decode step hands the recurrence to the Pallas
+  kernel ``ops/ssm_decode.py`` instead, which passes over the state pool
+  once, where it lies (``_steps_in_place`` chooses; ``recurrent`` is that
+  kernel's reference and every other backend's form);
 - ``chunked``: a prompt, or one chunk of it, with the state carried from
   position to position by a ``lax.scan`` (a whole chunk's ``[2048, I, N]``
   float32 states are 671 MB a layer at the published widths and are never
@@ -51,6 +55,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import ssm_decode
 from ..ops.norms import rms_norm
 
 Params = Dict[str, Any]
@@ -229,14 +234,37 @@ def chunked(a: Params, H: jax.Array, xs: jax.Array, delta: jax.Array,
         return jnp.moveaxis(y, 0, 1), H
 
 
+def _steps_in_place(config) -> bool:
+    """Whether the decode step of ``config``'s Mamba layers updates the
+    state POOL where it lies, through ``ops.ssm_decode_step`` (one pass over
+    each slot's state, ``y`` from the same pass), in place of ``recurrent``
+    on a layer's slice of it.  The one place that chooses, by what it can
+    see: the kernel needs a TPU and a state it can cut (channels of whole
+    lane tiles, N of whole sublane tiles, float32: ``state_shapes``')."""
+    i, n, _ = widths(config)
+    return ssm_decode.on_tpu() and ssm_decode.takes(n, i, jnp.float32)
+
+
 def decode_rows(config, a: Params, H: jax.Array, rows: jax.Array,
-                pre: jax.Array):
+                pre: jax.Array, layer=None,
+                active: Optional[jax.Array] = None):
     """A decode step's work in one Mamba layer: the new rows' xs halves pre
     [B, I] (one a slot) behind each slot's convolution rows, through the
-    recurrent form on each slot's state.  Returns ((y, xs) for ``output``,
-    the new states, the next convolution rows)."""
+    recurrent form on each slot's state H [B, N, I].  Returns ((y, xs) for
+    ``output``, the new states, the next convolution rows).
+
+    Where ``_steps_in_place(config)``, H is the WHOLE state pool
+    [layers, B, N, I] and ``layer`` this layer's place in it: the kernel
+    updates that layer of the ``active`` [B] slots where it lies, and the
+    pool comes back (an inactive slot's state as it was, its y zero)."""
     xs, nxt = _conv_row(config, a, pre, rows)
-    y, H = recurrent(a, H, xs, *drive(config, a, xs))
+    delta, bm, cm = drive(config, a, xs)
+    if _steps_in_place(config):
+        with jax.named_scope(SCOPE):
+            y, H = ssm_decode.ssm_decode_step(
+                H, layer, a["A_log"], delta, xs, bm, cm, active)
+    else:
+        y, H = recurrent(a, H, xs, delta, bm, cm)
     return (y, xs), H, nxt
 
 

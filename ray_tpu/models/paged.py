@@ -82,7 +82,10 @@ layers (``attn_layout`` ``"kv"``): the same two slot pools, of that module's
 shapes (``S`` ``[layer, slot, N, I]`` float32, ``conv`` a row a slot), beside
 the ONE K/V pool pair of the whole-length kind, over the attention layers
 only.  ``block.recurrent`` names the module; its ``state_shapes``,
-``decode_rows`` and ``prefill_rows`` are all this file asks of it.
+``decode_rows`` and ``prefill_rows`` are all this file asks of it; on a TPU
+the decode step hands the state-space module the ``S`` pool whole with the
+layer's place in it, and the module's kernel updates it where it lies
+(``_steps_in_place``).
 
 Compile counts are observable via ``trace_count()`` — the jitted bodies
 bump a counter when TRACED (python executes only at trace time), which is
@@ -102,7 +105,7 @@ from ..ops import latent_decode, paged_decode
 from ..ops import latent_prefill as latent_prefill_op
 from ..ops import paged_prefill as paged_prefill_op
 from ..ops.rotary import apply_rotary, rope_frequencies
-from . import block
+from . import block, mamba
 from .llama import LlamaConfig
 
 Params = Any
@@ -671,6 +674,26 @@ def _stack(config, params: Params, tokens: jax.Array, attend, lora,
     return hidden, counts
 
 
+def _steps_in_place(config, i: Optional[int] = None) -> bool:
+    """Whether the decode step hands recurrent layer ``i``'s module (with no
+    layer named, the configuration's recurrent layers') the state POOL and
+    the layer's place in it, to update where it lies: a state-space layer
+    where ``mamba._steps_in_place`` says so (``ops.ssm_decode_step``).  A
+    gated delta-rule layer's module takes its layer's slice."""
+    return block.recurrent(config, i) is mamba \
+        and mamba._steps_in_place(config)
+
+
+def recurrent_decode_form(config) -> Optional[str]:
+    """The form the decode program of ``config`` steps its recurrent layers'
+    state in, by name (``LLMServer.stats()["recurrent_decode"]``):
+    ``"kernel"`` (one pass over the pool where it lies) or ``"jnp"``; None
+    without recurrent layers."""
+    if not state_layers(config):
+        return None
+    return "kernel" if _steps_in_place(config) else "jnp"
+
+
 def _state_decode(config, pools: PagedPools, active: jax.Array, i: int,
                   *projected):
     """What the decode step does in recurrent layer ``i`` with the new rows'
@@ -678,19 +701,28 @@ def _state_decode(config, pools: PagedPools, active: jax.Array, i: int,
     module projects, one row a slot, and the layer's weights last): every
     slot's state through the module's recurrent form (``decode_rows``), an
     inactive slot's (``active`` [B] false) left as it was, the state pools
-    replaced.  Returns what the module's ``output`` takes."""
+    replaced.  A module that steps in place (``_steps_in_place``) is handed
+    the state pool whole and returns it.  Returns what the module's
+    ``output`` takes."""
     *projected, a = projected
     module = block.recurrent(config, i)
     layer = state_layers(config).index(i)
-    S, rows = pools["S"][layer], pools["conv"][layer]
-    out, new, nxt = module.decode_rows(config, a, S, rows, *projected)
+    in_place = _steps_in_place(config, i)
+    S = None if in_place else pools["S"][layer]
+    rows = pools["conv"][layer]
 
     def live(new, old):  # active [B] against [B, ...]
         return jnp.where(active[(slice(None),) + (None,) * (old.ndim - 1)],
                          new, old)
 
-    with jax.named_scope(module.SCOPE):  # the write is the recurrence's
-        pools["S"] = pools["S"].at[layer].set(live(new, S))
+    if in_place:
+        out, pools["S"], nxt = module.decode_rows(
+            config, a, pools["S"], rows, *projected, layer=layer,
+            active=active)
+    else:
+        out, new, nxt = module.decode_rows(config, a, S, rows, *projected)
+        with jax.named_scope(module.SCOPE):  # the write is the recurrence's
+            pools["S"] = pools["S"].at[layer].set(live(new, S))
     pools["conv"] = pools["conv"].at[layer].set(
         live(nxt.astype(rows.dtype), rows))
     return out

@@ -741,7 +741,8 @@ class InferenceEngine:
         active = sum(1 for s in self.slots if s is not None)
         from ..models.moe import grouped_form
         from ..models.paged import (decode_attention_form,
-                                    prefill_attention_form, trace_count)
+                                    prefill_attention_form,
+                                    recurrent_decode_form, trace_count)
 
         return {
             "steps": self.step_count,
@@ -772,6 +773,10 @@ class InferenceEngine:
                        "total_bytes": (self._state_bytes
                                        * self.config.batch_slots)}
                       if self._state_layers else None),
+            # The form the decode program steps that state in: "kernel"
+            # (ops/ssm_decode.py: one pass over the pool where it lies) or
+            # "jnp" (the module's recurrent form on a layer's slice).
+            "recurrent_decode": recurrent_decode_form(self.model_config),
             "window_pages": ({"ring_entries": self.ring,
                               "free": (self.ring_scratch
                                        - self._ring_pages_held()),
@@ -1889,6 +1894,10 @@ class LLMServer:
             print(f"the decode step's attention: {stats['decode_attention']}"
                   f", the prefills': {stats['prefill_attention']}",
                   file=sys.stderr, flush=True)
+            if stats["recurrent_decode"]:
+                print("the decode step's recurrent layers: "
+                      f"{stats['recurrent_decode']}",
+                      file=sys.stderr, flush=True)
 
     def load_adapter(self, name: str, source: Any = None) -> str:
         """Register a LoRA adapter on this replica's engine.  ``source``
