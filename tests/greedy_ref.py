@@ -22,16 +22,22 @@ def _logits_at(cfg, params, padded, last):
     return llama_apply(cfg, params, padded)[0, last]
 
 
+def logits_after(cfg, params, context):
+    """The full forward pass's logits [V] for the token after ``context`` (a
+    list of ints)."""
+    assert len(context) <= PAD_TO, len(context)
+    toks = np.zeros((1, PAD_TO), np.int32)
+    toks[0, :len(context)] = context
+    return _logits_at(cfg, params, jnp.asarray(toks), len(context) - 1)
+
+
 def greedy_tokens(cfg, params, prompt, n_new, stream=None):
     """The ``n_new`` tokens greedy decoding emits after ``prompt`` (a list
     of ints); ``stream(token)`` is called with each as it is chosen."""
-    n = len(prompt)
-    assert n + n_new <= PAD_TO, (n, n_new)
-    toks = np.zeros((1, PAD_TO), np.int32)
-    toks[0, :n] = prompt
-    for i in range(n, n + n_new):
-        toks[0, i] = int(jnp.argmax(
-            _logits_at(cfg, params, jnp.asarray(toks), i - 1)))
+    assert len(prompt) + n_new <= PAD_TO, (len(prompt), n_new)
+    toks = list(prompt)
+    for _ in range(n_new):
+        toks.append(int(jnp.argmax(logits_after(cfg, params, toks))))
         if stream is not None:
-            stream(int(toks[0, i]))
-    return toks[0, n:n + n_new].tolist()
+            stream(toks[-1])
+    return toks[len(prompt):]

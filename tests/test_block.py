@@ -152,16 +152,18 @@ def test_the_routed_ffn_of_one_expert_is_the_dense_layer():
     they can be: one expert, taken with probability 1."""
     dense, routed = _config(False), dataclasses.replace(
         _config(True), n_experts=1, top_k=1, qk_norm=False)
-    layer = llama_init(dense, jax.random.PRNGKey(3))["layers"][0]
+    layer = jax.jit(llama_init, static_argnums=0)(
+        dense, jax.random.PRNGKey(3))["layers"][0]
     as_routed = dict(layer, moe_norm=layer["mlp_norm"], moe={
         "router": jnp.zeros((dense.d_model, 1), jnp.float32),
         **{k: w[None] for k, w in layer["mlp"].items()}})
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 8, dense.d_model))
     attend = lambda q, k, v: q.reshape(*q.shape[:-2], -1)  # noqa: E731
-    want, no_aux, no_counts = block.decoder_layer(dense, layer, x, attend,
-                                                  routed=False)
-    got, aux, counts = block.decoder_layer(routed, as_routed, x, attend,
-                                           routed=True)
+    # One program each, where the eager form compiles every operation.
+    want, no_aux, no_counts = jax.jit(lambda layer, x: block.decoder_layer(
+        dense, layer, x, attend, routed=False))(layer, x)
+    got, aux, counts = jax.jit(lambda layer, x: block.decoder_layer(
+        routed, layer, x, attend, routed=True))(as_routed, x)
     assert no_aux is None and no_counts is None
     assert counts.tolist() == [16] and float(aux) == pytest.approx(1.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),

@@ -506,7 +506,7 @@ def test_preemption_drain_checkpoint_and_elastic_downsize(tmp_path):
         trainer = DataParallelTrainer(
             _elastic_train_loop,
             train_loop_config={
-                "total_steps": 60, "ckpt_every": 1000, "step_time_s": 0.1,
+                "total_steps": 30, "ckpt_every": 1000, "step_time_s": 0.1,
                 "marker": marker, "marker_step": 3,
             },
             scaling_config=ScalingConfig(
@@ -524,7 +524,7 @@ def test_preemption_drain_checkpoint_and_elastic_downsize(tmp_path):
         assert inj.preemptions == 1
         hist = result.metrics_history
         steps = [m["step"] for m in hist]
-        assert result.metrics["step"] == 60  # full run completed
+        assert result.metrics["step"] == 30  # full run completed
         assert any(m.get("drain_save") for m in hist), \
             "no drain-triggered checkpoint round observed"
         bounds = [i for i in range(1, len(steps)) if steps[i] <= steps[i - 1]]

@@ -37,14 +37,17 @@ STEP_FIELDS = {
 }
 
 
-def _tiny_engine(**overrides):
+def _tiny_engine(layers=2, **overrides):
+    import dataclasses
+
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.models import LlamaConfig, llama_init
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
-    cfg = LlamaConfig.tiny(remat=False, dtype=jnp.float32)
+    cfg = dataclasses.replace(
+        LlamaConfig.tiny(remat=False, dtype=jnp.float32), n_layers=layers)
     params = llama_init(cfg, jax.random.PRNGKey(0))
     kw = dict(GEOMETRY, max_queue=16)
     kw.update(overrides)
@@ -596,7 +599,11 @@ def served():
     from ray_tpu.util import tracing
 
     steprec.drain_buffered()
-    eng = _tiny_engine()
+    # Eight layers: a step of 2-4 ms, of which the two gauge writes and the
+    # record's own close are a twentieth.  At two layers a step is 0.5 ms
+    # and on a loaded machine they were 11-14% of it
+    # (``test_phases_leave_only_the_gauges_of_the_step_wall`` holds 10%).
+    eng = _tiny_engine(layers=8)
     try:
         threads = [threading.Thread(target=lambda i=i: list(eng.submit(
             [1 + i, 2, 3, 4 + i], max_new_tokens=12))) for i in range(8)]

@@ -315,8 +315,8 @@ def rt_gang_tight():
     if ray_tpu.is_initialized():
         ray_tpu.shutdown()
     ray_tpu.init(num_cpus=8, system_config={
-        "health_window_s": 10.0,
-        "health_resolve_after_s": 4.0,
+        "health_window_s": 6.0,
+        "health_resolve_after_s": 2.0,
     })
     yield ray_tpu
     chaos.disarm_straggler()
@@ -435,8 +435,8 @@ def test_seeded_straggler_opens_one_incident_then_resolves(
     assert scripts.main(["gang"]) == 0
     assert "r" + str(expected_rank) in capsys.readouterr().out
 
-    # Heal: the run ended with the slowdown, profiles age out of the 10s
-    # window, 4s of detector quiet resolves the incident.
+    # Heal: the run ended with the slowdown, profiles age out of the 6s
+    # window, 2s of detector quiet resolves the incident.
     deadline = time.monotonic() + 45.0
     while time.monotonic() < deadline:
         items = _incidents("gang_straggler")["items"]

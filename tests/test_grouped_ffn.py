@@ -7,6 +7,7 @@ replace on a TPU, and through ``moe._moe_ffn`` itself
 the chip's compiler says of them is in ``tests/test_chip_compile.py``."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -60,17 +61,25 @@ def _rows(dtype, n, d=256, seed=1):
                        dtype)
 
 
-def _ragged_form(xs, w1, w3, w2, counts, act="silu", acc=jnp.float32):
-    """The three ``ragged_dot`` calls of ``_moe_ffn``
-    (``grouped_ffn.grouped_ffn_ragged``, which accumulates in float32),
-    written out so that a test can accumulate them in another dtype."""
+@functools.partial(jax.jit, static_argnames=("act", "acc"))
+def _three_calls(xs, w1, w3, w2, counts, act, acc):
     def grouped(rows, w):
-        return jax.lax.ragged_dot(rows, w, jnp.asarray(counts, jnp.int32),
+        return jax.lax.ragged_dot(rows, w, counts,
                                   preferred_element_type=acc)
 
     h = (grouped_ffn.ACTS[act](grouped(xs, w1).astype(jnp.float32))
          * grouped(xs, w3).astype(jnp.float32)).astype(xs.dtype)
-    return np.asarray(grouped(h, w2), np.float32)
+    return grouped(h, w2)
+
+
+def _ragged_form(xs, w1, w3, w2, counts, act="silu", acc=jnp.float32):
+    """The three ``ragged_dot`` calls of ``_moe_ffn``
+    (``grouped_ffn.grouped_ffn_ragged``, which accumulates in float32),
+    written out so that a test can accumulate them in another dtype (one
+    program a shape: eagerly every operation of it is compiled a shape)."""
+    return np.asarray(_three_calls(
+        xs, w1, w3, w2, jnp.asarray(counts, jnp.int32), act=act, acc=acc),
+        np.float32)
 
 
 def _exact(xs, ws, counts):
@@ -393,10 +402,13 @@ def test_a_geometry_the_kernel_cannot_take_raises_before_it_is_traced(
 def _ffn(cfg, layer, x, valid, stream, monkeypatch):
     """``_moe_ffn`` as this backend takes it, or (``stream``) as a TPU
     takes the rows (a decode step's through the stream, a prefill's in row
-    blocks), the kernel interpreted."""
+    blocks), the kernel interpreted.  Jitted, a function a call: jit keeps a
+    trace by its function and arguments, not by what ``on_tpu`` answered."""
     monkeypatch.setattr(grouped_ffn, "on_tpu", lambda: stream)
     with pltpu.force_tpu_interpret_mode():
-        out, aux, counts = _moe_ffn(cfg, layer, x, valid)
+        out, aux, counts = jax.jit(
+            lambda layer, x, valid: _moe_ffn(cfg, layer, x, valid))(
+            layer, x, valid)
     return np.asarray(out, np.float32), float(aux), np.asarray(counts)
 
 
@@ -559,8 +571,8 @@ def test_the_loss_through_the_kernel_has_the_ragged_dot_forms_gradient(
         jax.clear_caches()
         fn = lambda p: moe_loss(cfg, p, tokens, tokens)  # noqa: E731
         text = str(jax.make_jaxpr(jax.grad(fn))(params))
-        with pltpu.force_tpu_interpret_mode():
-            return (text, *jax.value_and_grad(fn)(params))
+        with pltpu.force_tpu_interpret_mode():  # a new ``fn``: traced anew
+            return (text, *jax.jit(jax.value_and_grad(fn))(params))
 
     assert moe.grouped_form(cfg, tokens.size) == "ragged_dot"
     ragged_text, ragged_loss, ragged = loss_and_grads(False)

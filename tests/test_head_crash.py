@@ -293,8 +293,18 @@ def test_head_kill_node_manifest_and_named_actor_adoption(tmp_path, monkeypatch)
         # The node's manifest replayed: the pre-crash object still reads.
         arr = ray_tpu.get(ref, timeout=60)
         assert int(arr[:3].sum()) == 3
-        # And get_actor resolves the SAME adopted instance.
-        k2 = ray_tpu.get_actor("headkill-keeper")
+        # And get_actor resolves the SAME adopted instance, once its worker
+        # has resynced its name into the new head's table (the direct call
+        # above never touched the head); the deadline is the failure only.
+        deadline = time.monotonic() + 20
+        while True:
+            try:
+                k2 = ray_tpu.get_actor("headkill-keeper")
+                break
+            except ValueError:
+                assert time.monotonic() < deadline, \
+                    "the adopted actor's name never reached the new head"
+                time.sleep(0.25)
         assert ray_tpu.get(k2.add.remote("again"), timeout=60) == 3
     finally:
         try:

@@ -400,7 +400,10 @@ def test_put_stage_accounting_and_object_plane_cli(rt_shared, capsys):
     deadline = time.monotonic() + 15.0
     while time.monotonic() < deadline:
         rows = ctx.client.call("list_state", {"kind": "metrics"})["items"]
+        # THIS put's: another stage's row (an earlier test's small puts
+        # allocate too) says nothing of the flush that carries serialize.
         if any(r["name"] == "ray_tpu_put_copy_seconds" and "sum" in r
+               and r.get("tags", {}).get("stage") == "serialize"
                for r in rows):
             break
         time.sleep(0.5)
@@ -455,8 +458,8 @@ def rt_health_tight():
     ray_tpu.init(num_cpus=4, system_config={
         "peer_call_deadline_s": 1.0,
         "peer_quarantine_probe_s": 0.5,
-        "health_window_s": 10.0,
-        "health_resolve_after_s": 4.0,
+        "health_window_s": 5.0,
+        "health_resolve_after_s": 2.0,
     })
     yield ray_tpu
     netfault.disarm()
@@ -527,7 +530,7 @@ def test_partition_opens_one_incident_with_evidence_then_resolves(
     assert scripts.main(["incidents"]) == 0
     assert "partition_suspicion" in capsys.readouterr().out
 
-    # Heal: counter delta falls out of the 10s window, then 4s of quiet
+    # Heal: counter delta falls out of the 5s window, then 2s of quiet
     # resolves the incident and the grade returns to OK.
     deadline = time.monotonic() + 40.0
     while time.monotonic() < deadline:

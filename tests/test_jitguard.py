@@ -151,11 +151,12 @@ def test_engine_warmup_arms_and_steady_state_never_retraces(tmp_path):
         from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
         cfg = LlamaConfig.tiny(remat=False, dtype=jnp.float32)
-        params = llama_init(cfg, jax.random.PRNGKey(0))
-        eng = InferenceEngine(
+        params = jax.jit(llama_init, static_argnums=0)(
+            cfg, jax.random.PRNGKey(0))
+        eng = InferenceEngine(  # one bucket (8) of each prefill program
             cfg, params,
-            EngineConfig(batch_slots=4, page_size=8, max_prompt_len=16,
-                         max_new_tokens_cap=32, max_queue=16),
+            EngineConfig(batch_slots=4, page_size=8, max_prompt_len=8,
+                         max_new_tokens_cap=16, max_queue=16),
             seed=0)
         eng.warmup()
         assert jitguard.armed(), "warmup must arm under RT_DEBUG_JIT=1"

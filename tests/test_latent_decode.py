@@ -6,7 +6,6 @@ CPU cannot see).  What the chip's compiler says of it is in
 ``tests/benchmark/test_benchmark_chip_compile_glm4_moe_lite.py``."""
 
 import dataclasses
-import types
 
 import jax
 import jax.numpy as jnp
@@ -14,13 +13,15 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.models import MoEConfig, init_and_apply, paged
+import walk_ref
+from ray_tpu.models import MoEConfig, paged
 from ray_tpu.ops import latent_decode
 from ray_tpu.ops.latent_decode import latent_decode_attention
+from walk_ref import LAYER
 
-PAGE, MAXP, POOL, LAYERS, LAYER = 16, 5, 24, 2, 1
+PAGE, MAXP, POOL, LAYERS = 16, 5, 24, 2
 HEADS, RANK, ROPE, WIDTH = 5, 128, 8, 256
-SCALE = 48 ** -0.5
+HEAD_DIM = 48  # an expanded head's q and k: the scores' scale
 
 #: One batch that holds every kind of slot at once: (name, rows already
 #: cached).  The step's own row is written at that position, so a slot sees
@@ -48,28 +49,28 @@ def _tables():
 
 
 def _inputs(dtype, seed=0):
-    rng = np.random.default_rng(seed)
-    kv = rng.standard_normal((LAYERS, POOL + 1, PAGE, WIDTH))
-    q = rng.standard_normal((len(SLOTS), HEADS, WIDTH))
-    kv[..., RANK + ROPE:] = 0  # the rows' padding, zero on both sides
-    q[..., RANK + ROPE:] = 0
-    return jnp.asarray(q, dtype), jnp.asarray(kv, dtype)
+    """(queries, the pool): the rows' padding zero on both sides."""
+    return (walk_ref.seeded((len(SLOTS), HEADS, WIDTH), dtype, seed,
+                            RANK + ROPE),
+            walk_ref.seeded((LAYERS, POOL + 1, PAGE, WIDTH), dtype,
+                            seed + 100, RANK + ROPE))
 
 
 def _gather_form(q, kv, tables, lens):
-    cfg = types.SimpleNamespace(n_heads=HEADS, n_kv_heads=HEADS,
-                                kv_lora_rank=RANK, head_dim=48)
     visible = jnp.arange(MAXP * PAGE)[None, None, :] \
         <= jnp.asarray(lens)[:, None, None]
-    out = paged._attend_pages(cfg, q[:, None], kv, None, LAYER,
-                              jnp.asarray(tables), visible)
-    return np.asarray(out.reshape(len(lens), HEADS, RANK), np.float32)
+    return walk_ref.gather_form(q[:, None], kv, None, tables, visible,
+                                HEAD_DIM, RANK)[:, 0]
 
 
-def _kernel(q, kv, tables, lens, interpret=True):
-    return np.asarray(latent_decode_attention(
-        q, kv, LAYER, jnp.asarray(tables), jnp.asarray(lens), rank=RANK,
-        sm_scale=SCALE, interpret=interpret), np.float32)
+def _kernel(q, kv, tables, lens, interpret=True, per_block=None):
+    """Compiled once a batch's shapes and a block's pages."""
+    blocks = (("PAGES_PER_BLOCK", per_block),) if per_block else ()
+    call = walk_ref.blocked(latent_decode, latent_decode_attention, blocks,
+                            rank=RANK, sm_scale=HEAD_DIM ** -0.5,
+                            interpret=interpret)
+    return np.asarray(call((q, kv), jnp.asarray(tables), jnp.asarray(lens)),
+                      np.float32)
 
 
 @pytest.fixture(scope="module", params=[jnp.float32, jnp.bfloat16],
@@ -102,13 +103,13 @@ def test_the_shared_pages_are_read_for_each_slot_that_holds_them(both):
 
 
 @pytest.mark.parametrize("per_block", [1, 2, 3, 8])
-def test_the_walk_does_not_depend_on_the_blocks_size(monkeypatch, per_block):
+def test_the_walk_does_not_depend_on_the_blocks_size(per_block):
     """Blocks of one page, blocks that the live pages fill unevenly, and a
     block wider than the table: one answer."""
     q, kv = _inputs(jnp.float32, seed=per_block)
     tables = _tables()
-    monkeypatch.setattr(latent_decode, "PAGES_PER_BLOCK", per_block)
-    np.testing.assert_allclose(_kernel(q, kv, tables, LENS),
+    np.testing.assert_allclose(_kernel(q, kv, tables, LENS,
+                                       per_block=per_block),
                                _gather_form(q, kv, tables, LENS),
                                atol=2e-6, rtol=2e-6)
 
@@ -116,13 +117,9 @@ def test_the_walk_does_not_depend_on_the_blocks_size(monkeypatch, per_block):
 def _poisoned(kv, tables, lens, keep):
     """``kv`` with NaN in every page of every layer except the pages
     ``keep(b, p)`` of ``LAYER`` among the slots' LIVE ones."""
-    live = {int(tables[b, p]) for b, n in enumerate(lens)
-            for p in range(n // PAGE + 1) if keep(b, p)}
-    dead = [p for p in range(POOL + 1) if p not in live]
-    kv = np.array(kv)
-    kv[:, dead] = np.nan
-    kv[1 - LAYER] = np.nan
-    return jnp.asarray(kv)
+    return walk_ref.poisoned(kv, {
+        int(tables[b, p]) for b, n in enumerate(lens)
+        for p in range(n // PAGE + 1) if keep(b, p)})
 
 
 @pytest.mark.parametrize("interpret", [True, pltpu.InterpretParams()],
@@ -191,33 +188,9 @@ def _config(dtype):
         routed_scaling_factor=1.8, remat=False)
 
 
-def _decode(cfg, walk, monkeypatch):
-    """One decode step of the batch above on a pool of seeded rows: (tokens
-    and counters, logits).  ``walk``: as on a TPU, the kernel interpreted."""
-    monkeypatch.setattr(latent_decode, "on_tpu", lambda: walk)
-    params = init_and_apply(cfg)[0](cfg, jax.random.PRNGKey(0))
-    pools = paged.init_paged_pools(cfg, POOL, PAGE)
-    rows = jax.random.normal(jax.random.PRNGKey(1), pools["kv"].shape,
-                             jnp.float32)
-    pools = {"kv": rows.at[..., RANK + ROPE:].set(0).astype(cfg.dtype)}
-    adapters = paged.init_adapter_pool(cfg, 1, 2)
-    b = len(SLOTS)
-    active = jnp.asarray(LENS > 0)
-    args = (jnp.arange(b, dtype=jnp.int32) + 7, jnp.asarray(_tables()),
-            jnp.asarray(LENS), active)
-    ids = jnp.ones((b,), jnp.int32)
-    with pltpu.force_tpu_interpret_mode():
-        logits, _, _ = paged.decode_logits(
-            cfg, params, dict(pools), adapters, *args, ids)
-        out, *_ = paged.paged_decode_step.__wrapped__(
-            cfg, params, dict(pools), adapters, *args,
-            jnp.zeros((b,), jnp.float32), ids, jax.random.PRNGKey(2))
-    return np.asarray(out), np.asarray(logits)
-
-
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
-def test_the_decode_program_through_the_kernel(monkeypatch, dtype):
+def test_the_decode_program_through_the_kernel(dtype):
     """The decode step of a latent model as a TPU takes it, against the
     gather form: the same logits, ``kv_rows_live`` as it was, and
     ``kv_rows_read`` the pages the kernel visits x page x layers where the
@@ -225,8 +198,11 @@ def test_the_decode_program_through_the_kernel(monkeypatch, dtype):
     cfg = _config(dtype)
     assert paged.latent_row_width(cfg) == WIDTH
     assert paged.counter_keys(cfg)[-2:] == paged.KV_KEYS
-    walked, logits = _decode(cfg, True, monkeypatch)
-    gathered, ref = _decode(cfg, False, monkeypatch)
+    model = walk_ref.model_of(cfg, POOL, PAGE)
+    walked, logits = walk_ref.decode(cfg, latent_decode, True, model,
+                                     _tables(), LENS)
+    gathered, ref = walk_ref.decode(cfg, latent_decode, False, model,
+                                    _tables(), LENS)
     tol = 1e-4 if dtype == jnp.float32 else 0.15
     np.testing.assert_allclose(logits, ref, atol=tol, rtol=0)
     b = len(SLOTS)

@@ -9,8 +9,6 @@ and who walks.  What the chip's compiler says of it is in
 ``tests/benchmark/test_benchmark_latent_prefill.py``."""
 
 import functools
-import os
-import types
 
 import jax
 import jax.numpy as jnp
@@ -18,50 +16,44 @@ import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
+import walk_ref
 from ray_tpu.models import paged
 from ray_tpu.ops import latent_decode, latent_prefill, paged_decode
 from ray_tpu.ops.latent_prefill import latent_prefill_attention
+from walk_ref import LAYER, TINY_PAGE
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-#: A pool row as both cells keep it: 512 + 64 numbers in five lane tiles.
-PAGE, WIDTH, RANK, ROPE, ROWS, LAYER = 128, 640, 512, 64, 128, 1
+#: A pool row as both cells keep it: 512 + 64 numbers in five lane tiles
+#: (what the DMAs move).  Lengths at a QUARTER of the cells': a page of 32
+#: rows for one of 128, a call of 32 rows for one of 128, the table's 24
+#: pages as they were: a walk visits the pages it visits there.
+PAGE, WIDTH, RANK, ROPE, ROWS = 32, 640, 512, 64, 32
+BLOCK = ROWS // 2  # two blocks of query rows a call
 ENTRIES = 24
-SCALE = 256 ** -0.5  # an expanded head's q and k are 192 + 64 wide
+HEAD_DIM = 256  # an expanded head's q and k are 192 + 64 wide: the scale
 
 #: Query heads over the one shared row: GLM-4.7-Flash's and Kimi-Linear's.
 GEOMETRIES = {"glm-20-heads": 20, "kimi-linear-32-heads": 32}
 
-#: The calls of ROWS query rows, two blocks of 64: name -> (first position,
+#: The calls of ROWS query rows, two blocks of 16: name -> (first position,
 #: length).  Rows at or past ``length`` are the bucket's padding.
 CALLS = {
     "first-rows": (0, ROWS),
-    "length-inside-the-first-block": (0, 40),
+    "length-inside-the-first-block": (0, 10),
     "prefix-on-a-pages-edge": (8 * PAGE, 8 * PAGE + ROWS),
-    "prefix-inside-a-page": (1000, 1000 + ROWS - 19),
-    "one-real-row": (517, 518),
+    "prefix-inside-a-page": (250, 250 + ROWS - 5),
+    "one-real-row": (129, 130),
     "the-tables-last-pages": (ENTRIES * PAGE - ROWS, ENTRIES * PAGE - 3)}
 
 
-@functools.lru_cache(maxsize=None)
-def _pool(dtype, page=PAGE, entries=ENTRIES, width=WIDTH, used=RANK + ROPE,
-          seed=0):
-    """A seeded pool of two layers whose rows are zero past their ``used``
-    numbers, and a table that names its pages out of order; page ``pool -
-    1`` is the scratch page."""
-    rng = np.random.default_rng(seed)
-    pool = entries + 5
-    rows = rng.standard_normal((2, pool, page, width), np.float32)
-    rows[..., used:] = 0
-    return jnp.asarray(rows, dtype), \
-        rng.permutation(pool - 1)[:entries].astype(np.int32)
+def _pool(dtype, page=PAGE, entries=ENTRIES, width=WIDTH, used=RANK + ROPE):
+    """A seeded pool whose rows are zero past their ``used`` numbers, and a
+    table that names its pages out of order."""
+    return walk_ref.pool_and_table(dtype, page, entries, (width,), used)
 
 
 def _queries(heads, dtype, rows=ROWS, width=WIDTH, used=RANK + ROPE, seed=1,
              scale=1.0):
-    rng = np.random.default_rng(seed)
-    q = scale * rng.standard_normal((rows, heads, width), np.float32)
-    q[..., used:] = 0
-    return jnp.asarray(q, dtype)
+    return walk_ref.seeded((rows, heads, width), dtype, seed, used, scale)
 
 
 def _visible(first, rows, keys):
@@ -71,41 +63,25 @@ def _visible(first, rows, keys):
         <= (first + jnp.arange(rows))[None, :, None]
 
 
-def _gather_form(q, kv, table, first, rank=RANK, attend=paged._attend_pages):
-    S, H, _ = q.shape
-    cfg = types.SimpleNamespace(n_heads=H, n_kv_heads=H, head_dim=256,
-                                kv_lora_rank=rank)
-    visible = _visible(first, S, len(table) * kv.shape[2])
-    out = attend(cfg, q[None], kv, None, LAYER, jnp.asarray(table)[None],
-                 visible)
-    return np.asarray(out.reshape(S, H, rank), np.float32)
+def _gather_form(q, kv, table, first, rank=RANK):
+    visible = _visible(first, q.shape[0], len(table) * kv.shape[2])
+    return walk_ref.gather_form(q[None], kv, None, table[None], visible,
+                                HEAD_DIM, rank)[0]
 
 
-@functools.lru_cache(maxsize=None)
-def _jitted(rank, rows, keys, interpret=True):
-    """The kernel at one blocking, compiled once a shape: the first position
-    and the length are data."""
-    def call(q, kv, table, first, length):
-        was = latent_prefill.BLOCK_ROWS, latent_prefill.BLOCK_KEYS
-        latent_prefill.BLOCK_ROWS, latent_prefill.BLOCK_KEYS = rows, keys
-        try:
-            return latent_prefill_attention(
-                q, kv, LAYER, table, first, length, rank=rank,
-                sm_scale=SCALE, interpret=interpret)
-        finally:
-            latent_prefill.BLOCK_ROWS, latent_prefill.BLOCK_KEYS = was
-    return jax.jit(call)
+def _kernel(q, kv, table, first, length, rank=RANK, rows=BLOCK,
+            keys=2 * PAGE, interpret=True):
+    """Two blocks of query rows a call, two pages a block of keys; compiled
+    once a shape: the first position and the length are data."""
+    call = walk_ref.blocked(
+        latent_prefill, latent_prefill_attention,
+        (("BLOCK_ROWS", rows), ("BLOCK_KEYS", keys)), rank=rank,
+        sm_scale=HEAD_DIM ** -0.5, interpret=interpret)
+    return np.asarray(call((q, kv), jnp.asarray(table), jnp.int32(first),
+                           jnp.int32(length)), np.float32)
 
 
-def _kernel(q, kv, table, first, length, rank=RANK, rows=64, keys=256,
-            interpret=True):
-    """Blocks of 64 query rows, two 128-row pages a block of keys."""
-    return np.asarray(_jitted(rank, rows, keys, interpret)(
-        q, kv, jnp.asarray(table), jnp.int32(first), jnp.int32(length)),
-        np.float32)
-
-
-def _walk(first, length, total=ROWS, rows=64, page=PAGE):
+def _walk(first, length, total=ROWS, rows=BLOCK, page=PAGE):
     """The last page each block of ``rows`` query rows visits (its walk
     starts at page 0), None of a block wholly in the padding."""
     out = []
@@ -141,9 +117,9 @@ def test_the_calls_hold_the_walks_they_are_named_for():
     # A call whose length leaves padding rows, one whose last block of rows
     # is all padding, and one with a single real row.
     assert walks["length-inside-the-first-block"] == [0, None]
-    assert walks["one-real-row"] == [517 // PAGE, None]
+    assert walks["one-real-row"] == [129 // PAGE, None]
     first, length = CALLS["prefix-inside-a-page"]
-    assert first % PAGE and first + 64 < length < first + ROWS
+    assert first % PAGE and first + BLOCK < length < first + ROWS
     assert CALLS["prefix-on-a-pages-edge"][0] % PAGE == 0
     assert walks["the-tables-last-pages"][1] == ENTRIES - 1
     for name, (first, length) in CALLS.items():
@@ -158,7 +134,7 @@ def test_either_dtype_and_page_size(dtype, page, heads):
     DMAs move whole), rows of float32 and of bfloat16, a prefix that ends
     inside a page (a prefix hit's copied page): float32 within float32
     rounding of the gather form."""
-    entries = 3072 // page
+    entries = 12
     first, length = 9 * page + 5, 9 * page + 5 + ROWS - 9
     kv, table = _pool(jnp.dtype(dtype).type, page, entries)
     q = _queries(heads, jnp.dtype(dtype).type)
@@ -186,13 +162,14 @@ def test_a_bucket_smaller_than_one_block_of_rows(dtype, bucket, first, real):
     np.testing.assert_allclose(out[:real], ref[:real], atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("rows, keys", [(128, 128), (32, 128), (64, 512),
-                                        (16, 1024), (128, 8192)])
+@pytest.mark.parametrize("rows, keys", [(32, 32), (8, 32), (16, 128),
+                                        (8, 256), (32, 2048)])
 @pytest.mark.parametrize("call", ["prefix-inside-a-page",
                                   "the-tables-last-pages"])
 def test_the_answer_does_not_depend_on_the_blocks(call, rows, keys):
-    """One block of query rows or eight, a page a block of keys or more
-    than the table holds: one answer (a float32 pool, so to rounding)."""
+    """One block of query rows or four (of a float32 sublane tile), a page
+    a block of keys or more than the table holds: one answer (a float32
+    pool, so to rounding)."""
     first, length = CALLS[call]
     kv, table = _pool(jnp.float32)
     q = _queries(8, jnp.float32, seed=rows)
@@ -206,12 +183,9 @@ def _poisoned(kv, table, walks, keep=lambda block, page: True):
     """``kv`` with NaN in every page of every layer except the pages
     ``keep(block, page)`` of ``LAYER`` among those the blocks' walks visit:
     the table's dead tail, the pool's spare pages and the scratch page."""
-    live = {int(table[p]) for b, last in enumerate(walks)
-            if last is not None for p in range(last + 1) if keep(b, p)}
-    kv = np.array(kv)
-    kv[:, [p for p in range(kv.shape[1]) if p not in live]] = np.nan
-    kv[1 - LAYER] = np.nan
-    return jnp.asarray(kv)
+    return walk_ref.poisoned(kv, {
+        int(table[p]) for b, last in enumerate(walks)
+        if last is not None for p in range(last + 1) if keep(b, p)})
 
 
 @pytest.mark.parametrize("interpret", [True, pltpu.InterpretParams()],
@@ -246,7 +220,7 @@ def test_both_ends_of_a_blocks_walk_are_visited(block, end):
     """A NaN in page 0 or in the last page of one block's walk reaches that
     block's rows: the walk is ``0 .. hi // page`` and no shorter; the page
     after the first block's last row does not reach the first block."""
-    first = 8 * PAGE + 64  # the two blocks of rows end in different pages
+    first = 8 * PAGE + BLOCK  # the two blocks of rows end in different pages
     length = first + ROWS
     kv, table = _pool(jnp.float32)
     q = _queries(8, jnp.float32)
@@ -256,66 +230,23 @@ def test_both_ends_of_a_blocks_walk_are_visited(block, end):
     bad = np.array(kv)
     bad[LAYER, table[at]] = np.nan
     out = _kernel(q, jnp.asarray(bad), table, first, length)
-    assert np.isnan(out[64 * block:64 * (block + 1)]).all()
+    assert np.isnan(out[BLOCK * block:BLOCK * (block + 1)]).all()
     if end == "last" and block == 1:  # after the first block's last row
-        assert np.isfinite(out[:64]).all()
-
-
-def _float64_form(q, kv, table, first):
-    """The arithmetic itself on the operands as they are rounded, in
-    float64: what both forms approximate."""
-    q, kv = (np.asarray(x, np.float64) for x in (q, kv))
-    rows = kv[LAYER, table].reshape(-1, kv.shape[-1])
-    visible = np.asarray(_visible(first, q.shape[0], rows.shape[0])[0])
-    out = np.zeros((*q.shape[:2], RANK))
-    for h in range(q.shape[1]):
-        s = q[:, h] @ rows.T * SCALE
-        s[~visible] = -np.inf
-        p = np.exp(s - s.max(axis=1, keepdims=True))
-        out[:, h] = (p / p.sum(axis=1, keepdims=True)) @ rows[:, :RANK]
-    return out
-
-
-def _attend_in(acc):
-    """``_attend_pages``' latent branch with both products accumulated in
-    ``acc``."""
-    def attend(cfg, q, kv, _, layer, tables, visible):
-        B, Q = q.shape[:2]
-        rows = kv[layer, tables].reshape(B, -1, q.shape[-1])
-        scores = jnp.einsum("bqhd,bkd->bhqk", q, rows,
-                            preferred_element_type=acc).astype(jnp.float32) \
-            * (cfg.head_dim ** -0.5)
-        scores = jnp.where(visible[:, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
-        return jnp.einsum("bhqk,bkd->bqhd", probs,
-                          rows[..., :cfg.kv_lora_rank],
-                          preferred_element_type=acc)
-    return attend
+        assert np.isfinite(out[:BLOCK]).all()
 
 
 @pytest.mark.parametrize("geometry", GEOMETRIES)
 def test_bfloat16_pools_are_accumulated_in_float32(geometry):
-    """Against float64 arithmetic on the same bfloat16 operands the kernel
-    is as close as the gather form (the rounding of the probabilities and
-    of the output); a form that accumulates its products in bfloat16 is
-    not, by the tolerance the kernel passes.  Scores four times as wide as
+    """``walk_ref.accumulates_in_float32``, at scores four times as wide as
     a unit draw's over 576 numbers."""
     heads = GEOMETRIES[geometry]
     first, length = 6 * PAGE, 6 * PAGE + ROWS
     kv, table = _pool(jnp.bfloat16)
     q = _queries(heads, jnp.bfloat16, scale=4.0)
-    exact = _float64_form(q, kv, table, first)
-
-    def off(out):
-        return float(np.abs(out - exact).max())
-
-    tol = 2.5e-2
-    assert off(_kernel(q, kv, table, first, length)) < tol
-    assert off(_gather_form(q, kv, table, first)) < tol
-    assert off(_gather_form(q, kv, table, first,
-                            attend=_attend_in(jnp.float32))) < tol
-    assert off(_gather_form(q, kv, table, first,
-                            attend=_attend_in(jnp.bfloat16))) > 3 * tol
+    walk_ref.accumulates_in_float32(
+        _kernel(q, kv, table, first, length), q[None], kv, None,
+        table[None], _visible(first, ROWS, ENTRIES * PAGE), HEAD_DIM,
+        tol=2.5e-2, rank=RANK)
 
 
 def _shapes(**over):
@@ -362,71 +293,14 @@ def test_a_geometry_the_kernel_cannot_take_raises_before_it_is_traced(
 
 # ------------------------------------------------ through the prefill programs
 
-TINY_PAGE, TINY_SEQ = 8, 64
 TINY = {"glm4-moe-lite-tiny": "rotary on the shared key",
         "kimi-linear-tiny": "no position; three KDA layers to a latent one"}
 
 
 def _tiny(name):
-    """The benchmark's tiny configuration of that name with a latent of 128
-    (what the kernel's value product takes whole; rows of 256 with the 8
-    rotary numbers): its layer pattern and everything else as the rehearsal
-    runs them, in float32."""
-    from benchmarks import spec
-
-    model = spec.load_json(os.path.join(
-        ROOT, "benchmarks", "configs", name + ".json"))
-    model = {**model, "kv_lora_rank": 128}
-    return spec.family(model).program_config(model, remat=False,
-                                             max_seq=TINY_SEQ)
-
-
-@functools.lru_cache(maxsize=None)
-def _program(logits, walk):
-    """jit keeps a trace by its arguments, not by what ``on_tpu`` answered:
-    one jitted program a form."""
-    return jax.jit(logits, static_argnums=0)
-
-
-def _prefill(cfg, walk, monkeypatch, prompt, chunk, first=0):
-    """A prompt's rows from ``first`` on through the prefill programs,
-    ``chunk`` rows a call, into pools of seeded rows (what lies before
-    ``first`` is a prefix hit's cached pages): (the logits of each call, the
-    pools' real rows after the last).  ``walk``: as on a TPU, the kernel
-    interpreted, and every call the suffix program's, a prompt's first rows
-    at ``prefix_len`` 0 (the engine's rule)."""
-    from ray_tpu.models import init_and_apply
-
-    monkeypatch.setattr(latent_decode, "on_tpu", lambda: walk)
-    maxp = TINY_SEQ // TINY_PAGE
-    params = init_and_apply(cfg)[0](cfg, jax.random.PRNGKey(0))
-    state = 2 if paged.state_layers(cfg) else 0
-    pools = paged.init_paged_pools(cfg, 2 * maxp, TINY_PAGE,
-                                   state_slots=state)
-    pools = {name: jax.random.normal(jax.random.PRNGKey(i), x.shape, x.dtype)
-             for i, (name, x) in enumerate(sorted(pools.items()))}
-    used = cfg.kv_lora_rank + cfg.qk_rope_head_dim
-    pools["kv"] = pools["kv"].at[..., used:].set(0)
-    adapters = paged.init_adapter_pool(cfg, 1, 2)
-    table = jnp.arange(maxp, dtype=jnp.int32)[::-1] + maxp
-    tokens = np.random.default_rng(3).integers(1, 500, prompt)
-    slot = jnp.int32(1) if state else None
-    logits = []
-    with pltpu.force_tpu_interpret_mode():
-        for start in range(first, prompt, chunk):
-            end = min(start + chunk, prompt)
-            toks = np.zeros((1, chunk), np.int32)
-            toks[0, :end - start] = tokens[start:end]
-            args = (cfg, params, pools, adapters, jnp.asarray(toks))
-            tail = (jnp.int32(end), table, jnp.int32(1), None, slot)
-            out, pools, _ = _program(paged.prefill_prefix_logits, walk)(
-                *args, jnp.int32(start), *tail) if start or walk \
-                else _program(paged.prefill_logits, walk)(*args, *tail)
-            logits.append(np.asarray(out))
-    at = np.arange(prompt)
-    held = np.asarray(pools["kv"])[:, np.asarray(table)[at // TINY_PAGE],
-                                   at % TINY_PAGE]
-    return logits, held
+    """``walk_ref.tiny`` with a latent of 128 (what the kernel's value
+    product takes whole; rows of 256 with the 8 rotary numbers)."""
+    return walk_ref.tiny(name, kv_lora_rank=128)
 
 
 @pytest.mark.parametrize("prompt, chunk, first", [
@@ -434,8 +308,7 @@ def _prefill(cfg, walk, monkeypatch, prompt, chunk, first=0):
     ids=["a-cold-prompt-in-one-call", "three-chunks", "eight-chunks-of-a-page",
          "a-prefix-hit-inside-a-page"])
 @pytest.mark.parametrize("name", TINY)
-def test_the_prefill_programs_through_the_kernel(monkeypatch, name, prompt,
-                                                 chunk, first):
+def test_the_prefill_programs_through_the_kernel(name, prompt, chunk, first):
     """The prefill calls of the tiny GLM-4.7-Flash (rotary on the 8 shared
     columns) and Kimi-Linear (no position, KDA layers beside the latent one
     whose chunk form carries its state in the slot) as a TPU takes them (the
@@ -443,20 +316,10 @@ def test_the_prefill_programs_through_the_kernel(monkeypatch, name, prompt,
     the cold program (which EXPANDS its rows) and the suffix program in the
     gather form: the same logits after every call, within the tolerance
     ``tests/test_paged_prefill_programs.py`` holds the K/V-pair walk to, and
-    the same rows left in the pool."""
+    the same rows left in the pool (``walk_ref.same_prefills``)."""
     cfg = _tiny(name)
     assert paged.latent_row_width(cfg) == 256 and cfg.kv_lora_rank == 128
-    walked, pool = _prefill(cfg, True, monkeypatch, prompt, chunk, first)
-    assert paged.prefill_attention_form(cfg) == "walk"
-    gathered, ref_pool = _prefill(cfg, False, monkeypatch, prompt, chunk,
-                                  first)
-    assert paged.prefill_attention_form(cfg) == "gather"
-    assert len(walked) == -(-(prompt - first) // chunk)
-    for out, ref in zip(walked, gathered):
-        scale = float(np.abs(ref).max())
-        np.testing.assert_allclose(out, ref, atol=3e-5 * scale, rtol=0)
-    scale = float(np.abs(ref_pool).max())
-    np.testing.assert_allclose(pool, ref_pool, atol=3e-5 * scale, rtol=0)
+    walk_ref.same_prefills(cfg, latent_decode, prompt, chunk, first)
 
 
 @pytest.mark.parametrize("name", TINY)
@@ -524,12 +387,7 @@ def test_a_configuration_without_a_latent_pool_answers_as_it_did(name, walks):
     """One whole-length kind of K/V pairs never walks; a model with window
     layers walks where ``paged_decode.on_tpu`` says so, whatever the latent
     kernels' predicate answers."""
-    from benchmarks import spec
-
-    model = spec.load_json(os.path.join(
-        ROOT, "benchmarks", "configs", name + ".json"))
-    cfg = spec.family(model).program_config(model, remat=False,
-                                            max_seq=TINY_SEQ)
+    cfg = walk_ref.tiny(name)
     assert paged.prefill_attention_form(cfg) == "gather"
     was = paged_decode.on_tpu, latent_decode.on_tpu
     try:

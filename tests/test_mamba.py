@@ -4,6 +4,8 @@ the equations, the state and convolution rows across chunk edges and padded
 rows, and what the paged programs do with a slot's state (an inactive slot,
 a slot used again)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,7 +30,8 @@ def layer():
     """(configuration, one Mamba layer's weights with D, the norms and the
     convolution's bias away from their initial ones and zeros)."""
     cfg = _config()
-    a = dict(mamba.init(cfg, jax.random.PRNGKey(0)))
+    a = dict(jax.jit(mamba.init, static_argnums=0)(
+        cfg, jax.random.PRNGKey(0)))
     ks = jax.random.split(jax.random.PRNGKey(1), 5)
     for k, name in zip(ks, ("D", "dt_norm", "b_norm", "c_norm")):
         a[name] = jax.random.uniform(k, a[name].shape, jnp.float32, 0.5, 1.5)
@@ -70,9 +73,11 @@ def _naive(cfg, a, pre):
     return np.stack(ys), H, rows[-(taps - 1):]
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def _chunk(cfg, a, H, rows, pre, length=None):
     """The chunk form on pre [B, S, I] behind (H, rows): y with the D skip,
-    the new state, the next rows."""
+    the new state, the next rows.  Jitted: one compile a length, where the
+    eager form compiles every operation of it."""
     valid = None if length is None \
         else jnp.arange(pre.shape[1])[None] < length[:, None]
     xs, nxt = mamba.conv(cfg, a, pre, rows, length)
@@ -92,8 +97,9 @@ def test_the_two_forms_and_a_naive_loop_agree(layer):
     y_c, H_c, rows_c = _chunk(cfg, a, *_zeros(cfg, 2), pre)
     H, rows = _zeros(cfg, 2)
     ys = []
+    decode_rows = jax.jit(mamba.decode_rows, static_argnums=0)
     for t in range(pre.shape[1]):  # the recurrent form, a row at a time
-        (y, xs), H, rows = mamba.decode_rows(cfg, a, H, rows, pre[:, t])
+        (y, xs), H, rows = decode_rows(cfg, a, H, rows, pre[:, t])
         ys.append(y + a["D"] * xs)
     y_r = jnp.stack(ys, axis=1)
     for b in range(2):
@@ -145,6 +151,8 @@ def test_padded_rows_change_nothing(layer):
 
 
 ENGINE = dict(page=4, maxp=8, slots=3)
+_prefill_logits = jax.jit(paged.prefill_logits, static_argnums=0)
+_decode_logits = jax.jit(paged.decode_logits, static_argnums=0)
 
 
 def _programs(cfg, params, pools, slot, prompt):
@@ -154,7 +162,7 @@ def _programs(cfg, params, pools, slot, prompt):
                         jnp.int32)
     pad = np.zeros((1, 16), np.int32)
     pad[0, :len(prompt)] = prompt
-    _, pools, _ = paged.prefill_logits(
+    _, pools, _ = _prefill_logits(
         cfg, params, pools, adapters, jnp.asarray(pad),
         jnp.asarray(len(prompt), jnp.int32), table,
         jnp.asarray(1, jnp.int32), None, jnp.asarray(slot, jnp.int32))
@@ -164,7 +172,8 @@ def _programs(cfg, params, pools, slot, prompt):
 @pytest.fixture(scope="module")
 def model():
     cfg = _config()
-    params = moe_init(cfg, jax.random.PRNGKey(5))
+    params = jax.jit(moe_init, static_argnums=0)(
+        cfg, jax.random.PRNGKey(5))
     return cfg, params
 
 
@@ -209,7 +218,7 @@ def test_an_inactive_slots_state_is_bit_identical_after_a_decode_step(model):
     b, maxp = ENGINE["slots"], ENGINE["maxp"]
     tables = np.arange(b * maxp, dtype=np.int32).reshape(b, maxp)
     active = np.asarray([True, False, False])
-    _, pools, _ = paged.decode_logits(
+    _, pools, _ = _decode_logits(
         cfg, params, pools, paged.init_adapter_pool(cfg, 1, 2),
         jnp.asarray([5, 6, 7], jnp.int32), jnp.asarray(tables),
         jnp.asarray([7, 0, 7], jnp.int32), jnp.asarray(active),

@@ -29,6 +29,10 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.parallel import MeshConfig, make_mesh, set_mesh
 
 
+#: One program a shape, where the eager form compiles every operation.
+_apply = jax.jit(llama_apply, static_argnums=0)
+
+
 @pytest.fixture(scope="module")
 def tiny():
     cfg = LlamaConfig.tiny(remat=False, dtype=jnp.float32)
@@ -46,7 +50,7 @@ class TestLlama:
     def test_forward_shapes(self, tiny):
         cfg, params = tiny
         toks = _tokens(cfg)
-        logits = llama_apply(cfg, params, toks)
+        logits = _apply(cfg, params, toks)
         assert logits.shape == (2, 64, cfg.vocab_size)
         assert logits.dtype == jnp.float32
         assert bool(jnp.isfinite(logits).all())
@@ -55,9 +59,9 @@ class TestLlama:
         """Changing a future token must not change past logits."""
         cfg, params = tiny
         toks = _tokens(cfg, B=1)
-        logits1 = llama_apply(cfg, params, toks)
+        logits1 = _apply(cfg, params, toks)
         toks2 = toks.at[0, -1].set((toks[0, -1] + 1) % cfg.vocab_size)
-        logits2 = llama_apply(cfg, params, toks2)
+        logits2 = _apply(cfg, params, toks2)
         np.testing.assert_allclose(
             logits1[0, :-1], logits2[0, :-1], atol=1e-5
         )
@@ -112,8 +116,8 @@ class TestLlama:
         lora = lora_init(cfg, jax.random.PRNGKey(1), rank=4)
         toks = _tokens(cfg, B=2, S=32)
         # B zero-initialized: LoRA output == base output initially.
-        base = llama_apply(cfg, params, toks)
-        with_lora = llama_apply(cfg, params, toks, lora)
+        base = _apply(cfg, params, toks)
+        with_lora = _apply(cfg, params, toks, lora)
         np.testing.assert_allclose(base, with_lora, atol=1e-6)
 
         # Train only the adapters; base stays frozen.
@@ -134,8 +138,8 @@ class TestLlama:
         # Merge: merged model output == adapter-applied output.
         merged = lora_merge(cfg, params, state.params)
         np.testing.assert_allclose(
-            llama_apply(cfg, merged, toks),
-            llama_apply(cfg, params, toks, state.params),
+            _apply(cfg, merged, toks),
+            _apply(cfg, params, toks, state.params),
             atol=2e-3, rtol=2e-3,
         )
 
@@ -143,7 +147,7 @@ class TestLlama:
         cfg = LlamaConfig.tiny(dtype=jnp.float32, remat=False)
         assert cfg.n_kv_heads < cfg.n_heads  # tiny config exercises GQA
         params = llama_init(cfg, jax.random.PRNGKey(0))
-        logits = llama_apply(cfg, params, _tokens(cfg, B=1, S=16))
+        logits = _apply(cfg, params, _tokens(cfg, B=1, S=16))
         assert bool(jnp.isfinite(logits).all())
 
     def test_param_count_7b(self):

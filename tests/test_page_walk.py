@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import walk_ref
 from ray_tpu.ops import (latent_decode, latent_prefill, page_walk,
                          paged_decode, paged_prefill)
 
@@ -18,15 +19,13 @@ WIDTH, RANK, HEADS = 256, 128, 4
 PAGES = {jnp.bfloat16: 16, jnp.float32: 8}
 
 
-def _pool(dtype, entries, slots=1, seed=0):
+def _pool(dtype, entries, slots=1):
     """A latent pool of two layers, its last page the scratch page, and
     ``slots`` tables of ``entries`` pages that share none."""
-    rng = np.random.default_rng(seed)
-    page, pages = PAGES[dtype], slots * entries + 3
-    kv = jnp.asarray(rng.standard_normal((2, pages + 1, page, WIDTH),
-                                         np.float32), dtype)
-    tables = rng.permutation(pages)[:slots * entries].reshape(slots, entries)
-    return kv, jnp.asarray(tables, jnp.int32)
+    pages = slots * entries + 3
+    kv = walk_ref.seeded((2, pages + 1, PAGES[dtype], WIDTH), dtype, 0)
+    tables = np.random.default_rng(0).permutation(pages)[:slots * entries]
+    return kv, jnp.asarray(tables.reshape(slots, entries), jnp.int32)
 
 
 def _as_pair(kv):
@@ -36,37 +35,34 @@ def _as_pair(kv):
     return k, k.at[..., RANK:].set(0)
 
 
-def _queries(dtype, rows, seed=1):
-    rng = np.random.default_rng(seed)
-    return jnp.asarray(rng.standard_normal((rows, HEADS, WIDTH), np.float32),
-                       dtype)
+def _same(latent, pair, rows):
+    latent, pair = (np.asarray(x, np.float32) for x in (latent, pair))
+    assert latent.shape == (rows, HEADS, RANK)
+    assert np.isfinite(latent).all()
+    np.testing.assert_array_equal(latent, pair[..., :RANK])
+    assert not pair[..., RANK:].any()
 
 
 @pytest.mark.parametrize("dtype", list(PAGES), ids=["bfloat16", "float32"])
-def test_a_latent_is_the_pair_with_one_pool_and_one_head_a_slot(
-        monkeypatch, dtype):
+def test_a_latent_is_the_pair_with_one_pool_and_one_head_a_slot(dtype):
     """One query row a slot: an empty slot (an all-scratch table), one
     inside its first page, a partial block, two blocks and a part."""
     page, entries = PAGES[dtype], 5
     kv, tables = _pool(dtype, entries, slots=4)
     tables = tables.at[0].set(kv.shape[1] - 1)
     lens = jnp.asarray([0, page - 3, 2 * page - 1, 4 * page + 2], jnp.int32)
-    q = _queries(dtype, 4)
+    q = walk_ref.seeded((4, HEADS, WIDTH), dtype, 1)
     k, v = _as_pair(kv)
     # Two pages a block on both fronts, so the slots' blocks are the same.
-    monkeypatch.setattr(latent_decode, "PAGES_PER_BLOCK", 2)
-    monkeypatch.setattr(paged_decode, "BLOCK_BYTES", 2 * 2 * k[0, 0].nbytes)
-    assert paged_decode._pages_per_block(k, entries) == 2
-    latent = latent_decode.latent_decode_attention(
-        q, kv, 1, tables, lens, rank=RANK, sm_scale=0.1, interpret=True)
-    pair = paged_decode.paged_decode_attention(
-        q, k, v, 1, tables, jnp.zeros_like(lens), lens, sm_scale=0.1,
-        interpret=True)
-    assert latent.shape == (4, HEADS, RANK)
-    assert np.isfinite(np.asarray(latent, np.float32)).all()
-    np.testing.assert_array_equal(np.asarray(latent, np.float32),
-                                  np.asarray(pair[..., :RANK], np.float32))
-    assert not np.asarray(pair[..., RANK:], np.float32).any()
+    latent = walk_ref.blocked(
+        latent_decode, latent_decode.latent_decode_attention,
+        (("PAGES_PER_BLOCK", 2),), rank=RANK, sm_scale=0.1, interpret=True)(
+        (q, kv), tables, lens)
+    pair = walk_ref.blocked(
+        paged_decode, paged_decode.paged_decode_attention,
+        (("BLOCK_BYTES", 2 * 2 * k[0, 0].nbytes),), sm_scale=0.1,
+        interpret=True)((q, k, v), tables, jnp.zeros_like(lens), lens)
+    _same(latent, pair, 4)
 
 
 @pytest.mark.parametrize("first, real", [
@@ -75,27 +71,22 @@ def test_a_latent_is_the_pair_with_one_pool_and_one_head_a_slot(
          "a-chunk-behind-cached-pages"])
 @pytest.mark.parametrize("dtype", list(PAGES), ids=["bfloat16", "float32"])
 def test_a_latent_is_the_pair_with_one_pool_and_one_head_a_block_of_rows(
-        monkeypatch, dtype, first, real):
+        dtype, first, real):
     """A block of query rows of one sequence, ``window`` 0: two blocks of 16
-    rows against blocks of 32 keys on both fronts."""
-    page = PAGES[dtype]
-    kv, tables = _pool(dtype, (first + 32) // page + 2)
-    q = _queries(dtype, 32)
+    rows against blocks of 32 keys on both fronts, over a table long enough
+    for every case (one compile a dtype and front)."""
+    kv, tables = _pool(dtype, (5 * 16 + 3 + 32) // PAGES[dtype] + 2)
+    q = walk_ref.seeded((32, HEADS, WIDTH), dtype, 1)
     k, v = _as_pair(kv)
-    for front in (latent_prefill, paged_prefill):
-        monkeypatch.setattr(front, "BLOCK_ROWS", 16)
-        monkeypatch.setattr(front, "BLOCK_KEYS", 32)
-    latent = latent_prefill.latent_prefill_attention(
-        q, kv, 1, tables[0], first, first + real, rank=RANK, sm_scale=0.1,
-        interpret=True)
-    pair = paged_prefill.paged_prefill_attention(
-        q, k, v, 1, tables[0], first, first + real, window=0, sm_scale=0.1,
-        interpret=True)
-    assert latent.shape == (32, HEADS, RANK)
-    assert np.isfinite(np.asarray(latent, np.float32)).all()
-    np.testing.assert_array_equal(np.asarray(latent, np.float32),
-                                  np.asarray(pair[..., :RANK], np.float32))
-    assert not np.asarray(pair[..., RANK:], np.float32).any()
+    blocks = (("BLOCK_ROWS", 16), ("BLOCK_KEYS", 32))
+    at = (tables[0], jnp.int32(first), jnp.int32(first + real))
+    latent = walk_ref.blocked(
+        latent_prefill, latent_prefill.latent_prefill_attention, blocks,
+        rank=RANK, sm_scale=0.1, interpret=True)((q, kv), *at)
+    pair = walk_ref.blocked(
+        paged_prefill, paged_prefill.paged_prefill_attention, blocks,
+        window=0, sm_scale=0.1, interpret=True)((q, k, v), *at)
+    _same(latent, pair, 32)
 
 
 # --------------------------------------------------- the shared geometry check

@@ -6,21 +6,19 @@ TPU (``paged._attend_pages``), and through the decode program itself
 the chip's compiler says of it is in
 ``tests/benchmark/test_benchmark_chip_compile_paged_decode.py``."""
 
-import os
-import types
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
+import walk_ref
 from ray_tpu.models import paged
 from ray_tpu.ops import paged_decode
 from ray_tpu.ops.paged_decode import paged_decode_attention
+from walk_ref import LAYER
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-PAGE, ENTRIES, POOL, LAYERS, LAYER, DIM = 8, 6, 44, 2, 1, 128
+PAGE, ENTRIES, POOL, DIM = 8, 6, 44, 128
 WINDOW = 20  # three or four pages of a ring of six
 SCALE = DIM ** -0.5
 
@@ -72,35 +70,30 @@ def _pages_visited(lo, hi):
 
 
 def _inputs(heads, n_kv, slots, dtype, seed=0):
-    rng = np.random.default_rng(seed)
-    shape = (LAYERS, POOL + 1, PAGE, n_kv, DIM)
-    return (jnp.asarray(rng.standard_normal((slots, heads, DIM)), dtype),
-            jnp.asarray(rng.standard_normal(shape), dtype),
-            jnp.asarray(rng.standard_normal(shape), dtype))
+    shape = (2, POOL + 1, PAGE, n_kv, DIM)
+    return (walk_ref.seeded((slots, heads, DIM), dtype, seed),
+            walk_ref.seeded(shape, dtype, seed + 100),
+            walk_ref.seeded(shape, dtype, seed + 200))
 
 
-def _gather_form(q, k, v, tables, visible, attend=paged._attend_pages):
-    B, H, _ = q.shape
-    cfg = types.SimpleNamespace(n_heads=H, n_kv_heads=k.shape[3],
-                                head_dim=DIM)
-    out = attend(cfg, q[:, None], k, v, LAYER, jnp.asarray(tables), visible)
-    return np.asarray(out.reshape(B, H, DIM), np.float32)
+def _gather_form(q, k, v, tables, visible):
+    return walk_ref.gather_form(q[:, None], k, v, tables, visible, DIM)[:, 0]
 
 
-def _kernel(q, k, v, tables, lo, hi, interpret=True):
-    return np.asarray(paged_decode_attention(
-        q, k, v, LAYER, jnp.asarray(tables), jnp.asarray(lo),
-        jnp.asarray(hi), sm_scale=SCALE, interpret=interpret), np.float32)
+def _block_bytes(pages, k):
+    """``BLOCK_BYTES`` of that many pages of K and V a block."""
+    return pages * 2 * k[0, 0].nbytes
 
 
-@pytest.fixture
-def pages_a_block(monkeypatch):
-    """Set the walk's block to that many pages of the batch's pools."""
-    def set_to(n, n_kv, dtype):
-        monkeypatch.setattr(
-            paged_decode, "BLOCK_BYTES",
-            n * 2 * PAGE * n_kv * DIM * jnp.dtype(dtype).itemsize)
-    return set_to
+def _kernel(q, k, v, tables, lo, hi, interpret=True, per_block=3):
+    """At three pages a block: a walk of one page, of a block exactly, of a
+    block and a part, of two blocks.  Compiled once a batch's shapes."""
+    call = walk_ref.blocked(
+        paged_decode, paged_decode_attention,
+        (("BLOCK_BYTES", _block_bytes(per_block, k)),), sm_scale=SCALE,
+        interpret=interpret)
+    return np.asarray(call((q, k, v), jnp.asarray(tables), jnp.asarray(lo),
+                           jnp.asarray(hi)), np.float32)
 
 
 @pytest.fixture(scope="module", params=[
@@ -108,19 +101,13 @@ def pages_a_block(monkeypatch):
     for dtype in ("float32", "bfloat16")], ids="-".join)
 def both(request):
     """(the kernel's output, the gather form's, the dtype) on the batch of
-    one kind of cache, at three pages a block: a walk of one page, of a
-    block exactly, of a block and a part, of two blocks."""
+    one kind of cache."""
     kind, heads, dtype = request.param
     heads, n_kv = HEADS[heads]
     tables, lo, hi = _walk(kind)
     q, k, v = _inputs(heads, n_kv, len(hi), dtype)
-    was = paged_decode.BLOCK_BYTES
-    paged_decode.BLOCK_BYTES = 3 * 2 * k[0, 0].nbytes
-    try:
-        out = _kernel(q, k, v, tables, lo, hi)
-    finally:
-        paged_decode.BLOCK_BYTES = was
-    return out, _gather_form(q, k, v, tables, _visible(kind, hi)), dtype
+    return (_kernel(q, k, v, tables, lo, hi),
+            _gather_form(q, k, v, tables, _visible(kind, hi)), dtype)
 
 
 def test_the_kernel_is_the_gather_form(both):
@@ -157,17 +144,18 @@ def test_the_batches_hold_the_walks_they_are_named_for(kind):
 
 @pytest.mark.parametrize("kind", KINDS)
 @pytest.mark.parametrize("per_block", [1, 2, 4, ENTRIES, 64])
-def test_the_walk_does_not_depend_on_the_blocks_size(pages_a_block, kind,
+def test_the_walk_does_not_depend_on_the_blocks_size(monkeypatch, kind,
                                                      per_block):
     """Blocks of one page, blocks that the walks fill unevenly, one that
     holds the whole table and one wider than it: one answer."""
     tables, lo, hi = _walk(kind)
     q, k, v = _inputs(16, 2, len(hi), jnp.float32, seed=per_block)
-    pages_a_block(per_block, 2, jnp.float32)
+    monkeypatch.setattr(paged_decode, "BLOCK_BYTES",
+                        _block_bytes(per_block, k))
     assert paged_decode._pages_per_block(k, ENTRIES) \
         == min(per_block, ENTRIES)
     np.testing.assert_allclose(
-        _kernel(q, k, v, tables, lo, hi),
+        _kernel(q, k, v, tables, lo, hi, per_block=per_block),
         _gather_form(q, k, v, tables, _visible(kind, hi)),
         atol=2e-6, rtol=2e-6)
 
@@ -188,20 +176,15 @@ def test_a_block_is_sized_by_its_bytes():
 def _poisoned(pool, tables, lo, hi, keep=lambda b, p: True):
     """``pool`` with NaN in every page of every layer except the pages
     ``keep(b, p)`` of ``LAYER`` among those the slots' walks visit."""
-    live = {int(tables[b, p % ENTRIES]) for b in range(len(hi))
-            for p in range(lo[b] // PAGE, hi[b] // PAGE + 1) if keep(b, p)}
-    dead = [p for p in range(POOL + 1) if p not in live]
-    pool = np.array(pool)
-    pool[:, dead] = np.nan
-    pool[1 - LAYER] = np.nan
-    return jnp.asarray(pool)
+    return walk_ref.poisoned(pool, {
+        int(tables[b, p % ENTRIES]) for b in range(len(hi))
+        for p in range(lo[b] // PAGE, hi[b] // PAGE + 1) if keep(b, p)})
 
 
 @pytest.mark.parametrize("interpret", [True, pltpu.InterpretParams()],
                          ids=["interpret", "tpu-interpreter-nan-scratch"])
 @pytest.mark.parametrize("kind", KINDS)
-def test_a_poisoned_dead_page_does_not_reach_the_output(pages_a_block, kind,
-                                                        interpret):
+def test_a_poisoned_dead_page_does_not_reach_the_output(kind, interpret):
     """NaN in every page no walk visits (a ring's entries behind the window
     and a table's tail name some), in the scratch page (the empty slot reads
     it as its one page, so it is left out of this batch) and in the other
@@ -210,7 +193,6 @@ def test_a_poisoned_dead_page_does_not_reach_the_output(pages_a_block, kind,
     is NaN too: a block the walk does not fill meets zeros, not that."""
     tables, lo, hi = (x[1:] for x in _walk(kind))
     q, k, v = _inputs(16, 2, len(hi), jnp.float32)
-    pages_a_block(3, 2, jnp.float32)
     sound = _kernel(q, k, v, tables, lo, hi)
     bad_k, bad_v = (_poisoned(x, tables, lo, hi) for x in (k, v))
     out = _kernel(q, bad_k, bad_v, tables, lo, hi, interpret=interpret)
@@ -225,14 +207,13 @@ def test_a_poisoned_dead_page_does_not_reach_the_output(pages_a_block, kind,
     (kind, slot) for kind, slots in KINDS.items()
     for slot in range(1, len(slots))],
     ids=lambda x: x if isinstance(x, str) else str(x))
-def test_both_ends_of_a_walk_are_visited(pages_a_block, kind, slot, end):
+def test_both_ends_of_a_walk_are_visited(kind, slot, end):
     """A NaN in the first or the last page of one slot's walk (a value the
     mask inside that page lets through) reaches that slot's output, so the
     walk is ``lo // page .. hi // page`` and no shorter, and no other
     slot's."""
     tables, lo, hi = _walk(kind)
     q, k, v = _inputs(16, 2, len(hi), jnp.float32)
-    pages_a_block(3, 2, jnp.float32)
     at = (lo if end == "first" else hi)[slot] // PAGE
     bad_v = _poisoned(v, tables, lo, hi,
                       lambda b, p: (b, p) != (slot, at) or b == 0)
@@ -242,67 +223,17 @@ def test_both_ends_of_a_walk_are_visited(pages_a_block, kind, slot, end):
         assert np.isnan(out[b]).any() == (b == slot), (b, slot)
 
 
-def _float64_form(q, k, v, tables, visible):
-    """The arithmetic itself on the operands as they are rounded, in
-    float64: what both forms approximate."""
-    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
-    B, H, _ = q.shape
-    n_rep = H // k.shape[3]
-    out = np.zeros((B, H, DIM))
-    for b in range(B):
-        ks = k[LAYER, tables[b]].reshape(-1, k.shape[3], DIM)
-        vs = v[LAYER, tables[b]].reshape(ks.shape)
-        for h in range(H):
-            s = ks[:, h // n_rep] @ q[b, h] * SCALE
-            s[~np.asarray(visible[b, 0])] = -np.inf
-            p = np.exp(s - s.max())
-            out[b, h] = (p / p.sum()) @ vs[:, h // n_rep]
-    return out
-
-
-def _attend_in(acc):
-    """``_attend_pages`` with both products accumulated in ``acc``."""
-    def attend(cfg, q, k_pool, v_pool, layer, tables, visible):
-        B = q.shape[0]
-        n_kv = cfg.n_kv_heads
-        k_seq = k_pool[layer, tables].reshape(B, -1, n_kv, DIM)
-        v_seq = v_pool[layer, tables].reshape(k_seq.shape)
-        qg = q.reshape(B, 1, n_kv, cfg.n_heads // n_kv, DIM)
-        scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_seq,
-                            preferred_element_type=acc).astype(jnp.float32) \
-            * SCALE
-        scores = jnp.where(visible[:, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(v_seq.dtype)
-        return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_seq,
-                          preferred_element_type=acc).reshape(B, 1, -1)
-    return attend
-
-
 @pytest.mark.parametrize("kind", KINDS)
-def test_bfloat16_pools_are_accumulated_in_float32(pages_a_block, kind):
-    """Against float64 arithmetic on the same bfloat16 operands the kernel
-    is as close as the gather form (the rounding of the probabilities and
-    of the output); a form that accumulates its products in bfloat16 is
-    not, by the tolerance the kernel passes.  Scores eight times as wide as
+def test_bfloat16_pools_are_accumulated_in_float32(kind):
+    """``walk_ref.accumulates_in_float32``, at scores eight times as wide as
     a unit draw's (up to ~25): a bfloat16 score is then off by up to 0.06,
     a probability by 6%."""
     tables, lo, hi = _walk(kind)
     q, k, v = _inputs(16, 2, len(hi), jnp.bfloat16)
     q = (8 * q).astype(jnp.bfloat16)
-    pages_a_block(3, 2, jnp.bfloat16)
-    visible = _visible(kind, hi)
-    exact = _float64_form(q, k, v, tables, visible)
-
-    def off(out):
-        return float(np.abs(out - exact).max())
-
-    tol = 1.5e-2
-    assert off(_kernel(q, k, v, tables, lo, hi)) < tol
-    assert off(_gather_form(q, k, v, tables, visible)) < tol
-    assert off(_gather_form(q, k, v, tables, visible,
-                            _attend_in(jnp.float32))) < tol
-    assert off(_gather_form(q, k, v, tables, visible,
-                            _attend_in(jnp.bfloat16))) > 3 * tol
+    walk_ref.accumulates_in_float32(
+        _kernel(q, k, v, tables, lo, hi), q[:, None], k, v, tables,
+        _visible(kind, hi), DIM, tol=1.5e-2)
 
 
 def _shapes(**over):
@@ -340,59 +271,9 @@ SLOT_LENS = {"empty": 0, "inside-the-window": 5, "at-the-window": 7,
              "wrapped-twice": 37}
 
 
-def _tiny(name, heads):
-    """The benchmark's tiny configuration of that name with heads of 128
-    (what the kernel's DMAs move whole) and ``heads`` query heads over its
-    two KV heads: its layer pattern, window (8) and everything else as the
-    rehearsal runs them, in float32."""
-    from benchmarks import spec
-
-    model = spec.load_json(os.path.join(
-        ROOT, "benchmarks", "configs", name + ".json"))
-    model = {**model, "head_dim": DIM, "num_attention_heads": heads}
-    return spec.family(model).program_config(model, remat=False, max_seq=48)
-
-
-def _decode(cfg, walk, monkeypatch):
-    """One decode step over slots of ``SLOT_LENS`` on pools of seeded rows:
-    (tokens and counters, logits).  ``walk``: as on a TPU, the kernel
-    interpreted."""
-    from ray_tpu.models import init_and_apply
-
-    monkeypatch.setattr(paged_decode, "on_tpu", lambda: walk)
-    lens = np.array(list(SLOT_LENS.values()), np.int32)
-    b, maxp = len(lens), 48 // PAGE
-    ring = paged.ring_entries(cfg, PAGE, PAGE)
-    params = init_and_apply(cfg)[0](cfg, jax.random.PRNGKey(0))
-    pools = paged.init_paged_pools(cfg, b * maxp, PAGE, b * ring)
-    pools = {name: jax.random.normal(jax.random.PRNGKey(i), x.shape, x.dtype)
-             for i, (name, x) in enumerate(sorted(pools.items()))}
-    adapters = paged.init_adapter_pool(cfg, 1, 2)
-    tables = np.arange(b * maxp, dtype=np.int32).reshape(b, maxp)
-    rings = np.arange(b * ring, dtype=np.int32).reshape(b, ring)
-    tables[0], rings[0] = b * maxp, b * ring  # the empty slot: scratch
-    args = (jnp.arange(b, dtype=jnp.int32) + 7, jnp.asarray(tables),
-            jnp.asarray(lens), jnp.asarray(lens > 0))
-    ids = jnp.ones((b,), jnp.int32)
-    seen, real = [], paged.decode_logits
-
-    def decode_logits(*args, **kwargs):  # the step's own, looked at
-        seen.append(real(*args, **kwargs))
-        return seen[-1]
-
-    monkeypatch.setattr(paged, "decode_logits", decode_logits)
-    with pltpu.force_tpu_interpret_mode():
-        out, *_ = paged.paged_decode_step.__wrapped__(
-            cfg, params, dict(pools), adapters, *args,
-            jnp.zeros((b,), jnp.float32), ids, jax.random.PRNGKey(2),
-            jnp.asarray(rings))
-    monkeypatch.setattr(paged, "decode_logits", real)
-    return np.asarray(out), np.asarray(seen[0][0]), ring
-
-
 @pytest.mark.parametrize("name, heads", [("smallthinker-tiny", 14),
                                          ("trinity-mini-tiny", 16)])
-def test_the_decode_program_through_the_kernel(monkeypatch, name, heads):
+def test_the_decode_program_through_the_kernel(name, heads):
     """The decode step of the tiny SmallThinker (7:1, a whole-length layer
     before three rings) and Trinity-Mini (8:1, three rings to a whole-length
     layer, gate and norms around them) as a TPU takes it, against the gather
@@ -400,18 +281,23 @@ def test_the_decode_program_through_the_kernel(monkeypatch, name, heads):
     the ring's wrap (two pages of 8) and past two of them; ``kv_rows_live``
     as it was; ``kv_rows_read`` the pages the kernel's walks visit x page,
     where the gather form's is every slot's whole table and ring."""
-    cfg = _tiny(name, heads)
+    cfg = walk_ref.tiny_pair(name, 48)
     whole, window = paged.kv_layers(cfg)
-    assert whole and window and cfg.window == PAGE
+    assert whole and window and cfg.window == PAGE and cfg.n_heads == heads
     assert paged.counter_keys(cfg)[-2:] == paged.KV_KEYS
-    walked, logits, ring = _decode(cfg, True, monkeypatch)
-    assert paged.decode_attention_form(cfg) == "walk"
-    gathered, ref, _ = _decode(cfg, False, monkeypatch)
-    assert paged.decode_attention_form(cfg) == "gather"
+    lens = np.array(list(SLOT_LENS.values()), np.int32)
+    b, maxp = len(lens), 48 // PAGE
+    ring = paged.ring_entries(cfg, PAGE, PAGE)
+    model = walk_ref.model_of(cfg, b * maxp, PAGE, b * ring)
+    tables = np.arange(b * maxp, dtype=np.int32).reshape(b, maxp)
+    rings = np.arange(b * ring, dtype=np.int32).reshape(b, ring)
+    tables[0], rings[0] = b * maxp, b * ring  # the empty slot: scratch
+    walked, logits = walk_ref.decode(cfg, paged_decode, True, model, tables,
+                                     lens, rings)
+    gathered, ref = walk_ref.decode(cfg, paged_decode, False, model, tables,
+                                    lens, rings)
     scale = float(np.abs(ref).max())
     np.testing.assert_allclose(logits, ref, atol=2e-5 * scale, rtol=0)
-    lens = np.array(list(SLOT_LENS.values()))
-    b = len(lens)
     np.testing.assert_array_equal(walked[:b], gathered[:b])
     lo = np.maximum(0, lens - cfg.window + 1)
     visited = len(whole) * int(_pages_visited(0 * lens, lens).sum()) \
@@ -431,7 +317,7 @@ def test_off_the_tpu_the_decode_program_is_the_gather_form():
     backend answers."""
     import dataclasses
 
-    cfg = _tiny("trinity-mini-tiny", 16)
+    cfg = walk_ref.tiny_pair("trinity-mini-tiny", 48)
     assert not paged._walks_live_pages(cfg)
     assert paged.decode_attention_form(cfg) == "gather"
     plain = dataclasses.replace(cfg, window=0, window_layout=(),

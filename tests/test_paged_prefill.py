@@ -7,27 +7,31 @@ it are in ``tests/test_paged_prefill_programs.py``; what the chip's compiler
 says of it in
 ``tests/benchmark/test_benchmark_chip_compile_paged_prefill.py``."""
 
-import functools
-import types
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 
+import walk_ref
 from ray_tpu.models import paged
 from ray_tpu.ops import paged_prefill
 from ray_tpu.ops.paged_prefill import paged_prefill_attention
+from walk_ref import LAYER
 
-PAGE, DIM, ROWS, LAYER = 128, 128, 256, 1
-SCALE = DIM ** -0.5
+#: Lengths at an EIGHTH of the cells' (a page of 16 rows for one of 128, a
+#: call of 32 rows for one of 256), the widths whole (heads of 128 over 4
+#: KV heads: the tiles the DMAs move): a walk visits the pages it visits
+#: there, and the interpreter moves an eighth of the rows.
+PAGE, DIM, ROWS = 16, 128, 32
+BLOCK = ROWS // 2  # two blocks of query rows a call
 
 #: The geometries: (query heads, KV heads, window; 0 a whole-length table).
-#: SmallThinker's 7:1 at 4 KV heads under its window of 4096, Trinity-Mini's
-#: 8:1 under 2048, and each one's whole-length layers (a table of 40 pages).
-GEOMETRIES = {"smallthinker-ring-4096": (28, 4, 4096),
-              "trinity-mini-ring-2048": (32, 4, 2048),
+#: SmallThinker's 7:1 at 4 KV heads under its window of 4096 (here 512: 32
+#: pages), Trinity-Mini's 8:1 under 2048 (256: 16 pages), and each one's
+#: whole-length layers (a table of 40 pages).
+GEOMETRIES = {"smallthinker-ring-4096": (28, 4, 4096 // 8),
+              "trinity-mini-ring-2048": (32, 4, 2048 // 8),
               "smallthinker-whole": (28, 4, 0),
               "trinity-mini-whole": (32, 4, 0)}
 WHOLE_ENTRIES = 40
@@ -45,45 +49,38 @@ def _calls(window):
     if not window:
         return {
             "first-chunk": (0, ROWS),
-            "length-inside-the-first-block": (0, 100),
+            "length-inside-the-first-block": (0, 12),
             "prefix-on-a-pages-edge": (8 * PAGE, 8 * PAGE + ROWS),
-            "prefix-inside-a-page": (1000, 1000 + ROWS - 56),
+            "prefix-inside-a-page": (125, 125 + ROWS - 7),
             "the-tables-last-pages": (WHOLE_ENTRIES * PAGE - ROWS,
                                       WHOLE_ENTRIES * PAGE - 3)}
     lap = _entries(window) * PAGE
     return {
         "first-chunk": (0, ROWS),
-        "length-inside-the-first-block": (0, 100),
+        "length-inside-the-first-block": (0, 12),
         "prefix-on-a-pages-edge": (5 * PAGE, 5 * PAGE + ROWS),
         # Rows before the window is full beside rows whose window has left
         # position 0 behind, in one query block.
         "a-block-straddles-the-windows-edge": (window - PAGE,
-                                               window + PAGE - 7),
+                                               window + PAGE - 3),
         "the-ring-filled-to-its-last-page": (lap - ROWS, lap),
         "lapped-once": (lap + 2 * PAGE, lap + 2 * PAGE + ROWS),
         "lapped-twice-with-padding": (2 * lap + 3 * PAGE,
-                                      2 * lap + 3 * PAGE + 200)}
+                                      2 * lap + 3 * PAGE + 25)}
 
 
 CASES = [(g, c) for g, (_, _, w) in GEOMETRIES.items() for c in _calls(w)]
 
 
-@functools.lru_cache(maxsize=None)
-def _pools(n_kv, entries, dtype, seed=0):
-    """Seeded pools of two layers and a table that names its pages out of
-    order; page ``pool - 1`` is the scratch page."""
-    rng = np.random.default_rng(seed)
-    pool = entries + 5
-    shape = (2, pool, PAGE, n_kv, DIM)
-    k, v = (jnp.asarray(rng.standard_normal(shape, np.float32), dtype)
-            for _ in range(2))
-    return k, v, rng.permutation(pool - 1)[:entries].astype(np.int32)
+def _pools(n_kv, entries, dtype):
+    """Seeded K and V pools and a table that names its pages out of
+    order."""
+    return walk_ref.pool_and_table(dtype, PAGE, entries, (n_kv, DIM),
+                                   pairs=2)
 
 
 def _queries(heads, dtype, seed=1, scale=1.0):
-    rng = np.random.default_rng(seed)
-    return jnp.asarray(scale * rng.standard_normal((ROWS, heads, DIM),
-                                                   np.float32), dtype)
+    return walk_ref.seeded((ROWS, heads, DIM), dtype, seed, scale=scale)
 
 
 def _visible(window, entries, first, length):
@@ -100,40 +97,23 @@ def _visible(window, entries, first, length):
             & (age < window))[None]
 
 
-def _gather_form(q, k, v, table, visible, attend=paged._attend_pages):
-    S, H, _ = q.shape
-    cfg = types.SimpleNamespace(n_heads=H, n_kv_heads=k.shape[3],
-                                head_dim=DIM)
-    out = attend(cfg, q[None], k, v, LAYER, jnp.asarray(table)[None],
-                 visible)
-    return np.asarray(out.reshape(S, H, DIM), np.float32)
+def _gather_form(q, k, v, table, visible):
+    return walk_ref.gather_form(q[None], k, v, table[None], visible, DIM)[0]
 
 
-@functools.lru_cache(maxsize=None)
-def _jitted(window, rows, keys, interpret=True):
-    """The kernel at one blocking, compiled once a geometry: the first
-    position and the length are data."""
-    def call(q, k, v, table, first, length):
-        was = paged_prefill.BLOCK_ROWS, paged_prefill.BLOCK_KEYS
-        paged_prefill.BLOCK_ROWS, paged_prefill.BLOCK_KEYS = rows, keys
-        try:
-            return paged_prefill_attention(
-                q, k, v, LAYER, table, first, length, window=window,
-                sm_scale=SCALE, interpret=interpret)
-        finally:
-            paged_prefill.BLOCK_ROWS, paged_prefill.BLOCK_KEYS = was
-    return jax.jit(call)
+def _kernel(q, k, v, table, first, length, window, rows=BLOCK,
+            keys=2 * PAGE, interpret=True):
+    """Two blocks of query rows a call, two pages a block of keys; compiled
+    once a geometry: the first position and the length are data."""
+    call = walk_ref.blocked(
+        paged_prefill, paged_prefill_attention,
+        (("BLOCK_ROWS", rows), ("BLOCK_KEYS", keys)), window=window,
+        sm_scale=DIM ** -0.5, interpret=interpret)
+    return np.asarray(call((q, k, v), jnp.asarray(table), jnp.int32(first),
+                           jnp.int32(length)), np.float32)
 
 
-def _kernel(q, k, v, table, first, length, window, rows=128, keys=256,
-            interpret=True):
-    """Two blocks of query rows a call, two pages a block of keys."""
-    return np.asarray(_jitted(window, rows, keys, interpret)(
-        q, k, v, jnp.asarray(table), jnp.int32(first), jnp.int32(length)),
-        np.float32)
-
-
-def _walk(first, length, window, rows=128):
+def _walk(first, length, window, rows=BLOCK):
     """The pages each block of ``rows`` query rows visits: a list of
     (first page, last page), None of a block wholly in the padding."""
     out = []
@@ -182,8 +162,8 @@ def test_the_calls_hold_the_walks_they_are_named_for(geometry):
         assert walks["the-tables-last-pages"][1][1] == entries - 1
         return
     first, length = calls["a-block-straddles-the-windows-edge"]
-    assert first < window - 1 < first + 128  # inside the first block
-    assert first + 128 - window + 1 > 0  # the second block's rows: all past
+    assert first < window - 1 < first + BLOCK  # inside the first block
+    assert first + BLOCK - window + 1 > 0  # the second block's rows: all past
     assert walks["the-ring-filled-to-its-last-page"][1][1] == entries - 1
     lap = entries * PAGE
     assert calls["lapped-once"][0] // lap == 1
@@ -191,7 +171,7 @@ def test_the_calls_hold_the_walks_they_are_named_for(geometry):
     # Its walk crosses the ring's end: the page numbers wrap in between.
     lo, hi = walks["lapped-once"][0]
     assert lo // entries != hi // entries
-    # A block of 128 rows under a window of w walks w / PAGE + 1 pages,
+    # A block of a page's rows under a window of w walks w / PAGE + 1 pages,
     # where the table has w / PAGE + 2 and the whole bucket's scores
     # (w + ROWS) keys.
     assert hi - lo + 1 == window // PAGE + 1
@@ -210,7 +190,7 @@ def test_the_heads_are_parted_in_either_dtype(geometry, dtype, heads, n_kv):
     rounding of the gather form."""
     _, _, window = GEOMETRIES[geometry]
     entries = _entries(window)
-    first, length = 3 * PAGE, 3 * PAGE + ROWS - 9
+    first, length = 3 * PAGE, 3 * PAGE + ROWS - 3
     k, v, table = _pools(n_kv, entries, jnp.dtype(dtype).type)
     q = _queries(heads, jnp.dtype(dtype).type)
     out = _kernel(q, k, v, table, first, length, window)
@@ -221,13 +201,14 @@ def test_the_heads_are_parted_in_either_dtype(geometry, dtype, heads, n_kv):
     np.testing.assert_allclose(out[:real], ref[:real], atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("rows, keys", [(256, 128), (64, 128), (128, 512),
-                                        (32, 1024), (256, 8192)])
+@pytest.mark.parametrize("rows, keys", [(32, 16), (8, 16), (16, 64),
+                                        (8, 128), (32, 1024)])
 @pytest.mark.parametrize("geometry", ["smallthinker-ring-4096",
                                       "smallthinker-whole"])
 def test_the_answer_does_not_depend_on_the_blocks(geometry, rows, keys):
-    """One block of query rows or eight, a page a block of keys or more
-    than the table holds: one answer (float32 pools, so to rounding)."""
+    """One block of query rows or four (of a float32 sublane tile), a page
+    a block of keys or more than the table holds: one answer (float32
+    pools, so to rounding)."""
     heads, n_kv, window = 8, 2, GEOMETRIES[geometry][2]
     entries = _entries(window)
     calls = _calls(window)
@@ -246,14 +227,10 @@ def _poisoned(pool, table, walks, entries, keep=lambda block, page: True):
     """``pool`` with NaN in every page of every layer except the pages
     ``keep(block, page)`` of ``LAYER`` among those the blocks' walks
     visit."""
-    live = {int(table[p % entries]) for b, walk in enumerate(walks)
-            if walk is not None for p in range(walk[0], walk[1] + 1)
-            if keep(b, p)}
-    pool = np.array(pool)
-    dead = [p for p in range(pool.shape[1]) if p not in live]
-    pool[:, dead] = np.nan
-    pool[1 - LAYER] = np.nan
-    return jnp.asarray(pool)
+    return walk_ref.poisoned(pool, {
+        int(table[p % entries]) for b, walk in enumerate(walks)
+        if walk is not None for p in range(walk[0], walk[1] + 1)
+        if keep(b, p)})
 
 
 @pytest.mark.parametrize("interpret", [True, pltpu.InterpretParams()],
@@ -315,56 +292,18 @@ def test_both_ends_of_a_blocks_walk_are_visited(geometry, block, end):
     bad_v = np.array(v)
     bad_v[LAYER, table[at % entries]] = np.nan
     out = _kernel(q, k, jnp.asarray(bad_v), table, first, length, window)
-    rows = slice(128 * block, 128 * (block + 1))
+    rows = slice(BLOCK * block, BLOCK * (block + 1))
     assert np.isnan(out[rows]).any()
     if end == "first" and block == 0:  # behind the second block's window
-        assert np.isfinite(out[128:]).all() == bool(window)
+        assert np.isfinite(out[BLOCK:]).all() == bool(window)
     if end == "last" and block == 1:  # after the first block's last row
-        assert np.isfinite(out[:128]).all()
-
-
-def _float64_form(q, k, v, table, visible):
-    """The arithmetic itself on the operands as they are rounded, in
-    float64: what both forms approximate."""
-    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
-    S, H, _ = q.shape
-    n_rep = H // k.shape[3]
-    ks = k[LAYER, table].reshape(-1, k.shape[3], DIM)
-    vs = v[LAYER, table].reshape(ks.shape)
-    out = np.zeros((S, H, DIM))
-    for h in range(H):
-        s = q[:, h] @ ks[:, h // n_rep].T * SCALE
-        s[~np.asarray(visible[0])] = -np.inf
-        p = np.exp(s - s.max(axis=1, keepdims=True))
-        out[:, h] = (p / p.sum(axis=1, keepdims=True)) @ vs[:, h // n_rep]
-    return out
-
-
-def _attend_in(acc):
-    """``_attend_pages`` with both products accumulated in ``acc``."""
-    def attend(cfg, q, k_pool, v_pool, layer, tables, visible):
-        B, Q = q.shape[:2]
-        n_kv = cfg.n_kv_heads
-        k_seq = k_pool[layer, tables].reshape(B, -1, n_kv, DIM)
-        v_seq = v_pool[layer, tables].reshape(k_seq.shape)
-        qg = q.reshape(B, Q, n_kv, cfg.n_heads // n_kv, DIM)
-        scores = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_seq,
-                            preferred_element_type=acc).astype(jnp.float32) \
-            * SCALE
-        scores = jnp.where(visible[:, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(v_seq.dtype)
-        return jnp.einsum("bgrqk,bkgd->bqgrd", probs, v_seq,
-                          preferred_element_type=acc).reshape(B, Q, -1)
-    return attend
+        assert np.isfinite(out[:BLOCK]).all()
 
 
 @pytest.mark.parametrize("geometry", ["trinity-mini-ring-2048",
                                       "smallthinker-whole"])
 def test_bfloat16_pools_are_accumulated_in_float32(geometry):
-    """Against float64 arithmetic on the same bfloat16 operands the kernel
-    is as close as the gather form (the rounding of the probabilities and
-    of the output); a form that accumulates its products in bfloat16 is
-    not, by the tolerance the kernel passes.  Scores eight times as wide as
+    """``walk_ref.accumulates_in_float32``, at scores eight times as wide as
     a unit draw's: a bfloat16 score is then off by up to 0.06, a
     probability by 6%."""
     heads, n_kv, window = 8, 2, GEOMETRIES[geometry][2]
@@ -372,19 +311,10 @@ def test_bfloat16_pools_are_accumulated_in_float32(geometry):
     first, length = 6 * PAGE, 6 * PAGE + ROWS
     k, v, table = _pools(n_kv, entries, jnp.bfloat16)
     q = _queries(heads, jnp.bfloat16, scale=8.0)
-    visible = _visible(window, entries, first, length)
-    exact = _float64_form(q, k, v, table, visible)
-
-    def off(out):
-        return float(np.abs(out - exact).max())
-
-    tol = 1.5e-2
-    assert off(_kernel(q, k, v, table, first, length, window)) < tol
-    assert off(_gather_form(q, k, v, table, visible)) < tol
-    assert off(_gather_form(q, k, v, table, visible,
-                            _attend_in(jnp.float32))) < tol
-    assert off(_gather_form(q, k, v, table, visible,
-                            _attend_in(jnp.bfloat16))) > 3 * tol
+    walk_ref.accumulates_in_float32(
+        _kernel(q, k, v, table, first, length, window), q[None], k, v,
+        table[None], _visible(window, entries, first, length), DIM,
+        tol=1.5e-2)
 
 
 def _shapes(**over):

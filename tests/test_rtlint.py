@@ -12,6 +12,8 @@ import sys
 import textwrap
 from pathlib import Path
 
+import pytest
+
 from ray_tpu.devtools import rules_api, rules_async, rules_concurrency, \
     rules_config, rules_deadline, rules_jax, rules_metrics, \
     rules_resources, rules_rpc, rules_threads
@@ -981,12 +983,23 @@ class TestAllowlist:
 # -- the gate: the real package must lint clean --------------------------------
 
 
+@pytest.fixture(scope="module")
+def package_lint():
+    """The live package linted ONCE (about a minute): (root, allowlist,
+    unallowlisted findings)."""
+    root = default_package_root()
+    allow = default_allowlist(root)
+    return root, allow, run_lint(root, allow)[0]
+
+
 class TestPackageGate:
-    def test_package_lint_clean(self):
+    def test_package_lint_clean(self, package_lint):
         """The self-check every future PR inherits: rtlint over the live
-        package with the repo allowlist must report nothing."""
-        root = default_package_root()
-        kept, _ = run_lint(root, default_allowlist(root))
+        package with the repo allowlist must report nothing.  The root and
+        the allowlist are the ones `python -m ray_tpu lint` resolves when
+        it is given neither."""
+        root, allow, kept = package_lint
+        assert root == REPO_ROOT / "ray_tpu" and allow.is_file()
         assert kept == [], "unallowlisted rtlint findings:\n" + "\n".join(
             f"{f.path}:{f.line}: {f.rule} {f.message}" for f in kept
         )
@@ -998,26 +1011,28 @@ class TestPackageGate:
         assert names == [f"check_rt{i:03d}" for i in range(1, 13)]
 
     def test_cli_exit_codes(self, tmp_path):
-        """`python -m ray_tpu lint` is the operator surface: 0 on the
-        clean tree, non-zero once a violation is seeded."""
-        clean = subprocess.run(
-            [sys.executable, "-m", "ray_tpu", "lint"],
-            cwd=REPO_ROOT, capture_output=True, text=True, timeout=120,
-        )
+        """`python -m ray_tpu lint` is the operator surface: 0 on a clean
+        tree, non-zero once a violation is seeded.  Both trees are small:
+        the exit code is this test's subject, the live package's
+        cleanliness `test_package_lint_clean`'s."""
+        def lint(name, body):
+            root = make_pkg(tmp_path / name, {"core/head.py": f"""
+                import asyncio
+                import time
+
+
+                async def h_x(conn, body):
+                    {body}
+            """})
+            return subprocess.run(
+                [sys.executable, "-m", "ray_tpu", "lint", "--root", str(root),
+                 "--allowlist", str(tmp_path / "none")],
+                cwd=REPO_ROOT, capture_output=True, text=True, timeout=120,
+            )
+
+        clean = lint("clean", "await asyncio.sleep(1)")
         assert clean.returncode == 0, clean.stdout + clean.stderr
-
-        seeded = make_pkg(tmp_path, {"core/head.py": """
-            import time
-
-
-            async def h_x(conn, body):
-                time.sleep(1)
-        """})
-        bad = subprocess.run(
-            [sys.executable, "-m", "ray_tpu", "lint",
-             "--root", str(seeded), "--allowlist", str(tmp_path / "none")],
-            cwd=REPO_ROOT, capture_output=True, text=True, timeout=120,
-        )
+        bad = lint("seeded", "time.sleep(1)")
         assert bad.returncode == 1, bad.stdout + bad.stderr
         assert "RT001" in bad.stdout
 

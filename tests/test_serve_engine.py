@@ -74,14 +74,15 @@ def test_paged_parity_for_every_kv_grouping(monkeypatch, n_heads,
     import jax.numpy as jnp
 
     import ray_tpu.models.paged as paged_mod
-    from greedy_ref import greedy_tokens
-    from ray_tpu.models import LlamaConfig, llama_apply, llama_init
+    from greedy_ref import greedy_tokens, logits_after
+    from ray_tpu.models import LlamaConfig, llama_init
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
     cfg = LlamaConfig(vocab_size=512, d_model=128, n_layers=2,
                       n_heads=n_heads, n_kv_heads=n_kv_heads, d_ff=256,
                       max_seq=256, remat=False, dtype=jnp.float32)
-    params = llama_init(cfg, jax.random.PRNGKey(1))
+    params = jax.jit(llama_init, static_argnums=0)(
+        cfg, jax.random.PRNGKey(1))
     copies, real_copy = [], paged_mod.copy_page
 
     def counted_copy(pools, src, dst):
@@ -94,8 +95,7 @@ def test_paged_parity_for_every_kv_grouping(monkeypatch, n_heads,
         return greedy_tokens(cfg, params, prompt, n)
 
     def best_logit_gap(context, token):
-        logits = np.asarray(llama_apply(
-            cfg, params, jnp.asarray([context], jnp.int32)))[0, -1]
+        logits = np.asarray(logits_after(cfg, params, context))
         return float(logits.max() - logits[token])
 
     eng = InferenceEngine(cfg, params,

@@ -6,6 +6,8 @@ refuses, who chooses it (``mamba._steps_in_place``), and a Mamba layer's
 and the decode program's work through it against the same through
 ``recurrent``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,6 +18,7 @@ from ray_tpu.models import MoEConfig, mamba, moe_init, paged
 from ray_tpu.ops import ssm_decode, ssm_decode_step
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
 def _operands(layers, slots, n, i, seed=0):
     """(the pool, A_log as Mamba-1 draws it, delta, xs, bm, cm): a step
     between 0.001 and 0.1 as ``dt_bias`` is drawn, so that the decay of the
@@ -50,8 +53,8 @@ def test_the_kernel_is_the_recurrent_form(width, slots, layers, layer):
     active = np.asarray(_active(slots))
     y, new = ssm_decode_step(S, layer, A_log, delta, xs, bm, cm,
                              jnp.asarray(active), interpret=True)
-    want_y, want = mamba.recurrent({"A_log": A_log}, S[layer], xs, delta,
-                                   bm, cm)
+    want_y, want = jax.jit(mamba.recurrent)({"A_log": A_log}, S[layer], xs,
+                                            delta, bm, cm)
     assert y.shape == (slots, width) and y.dtype == jnp.float32
     assert new.shape == S.shape and new.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(y)[active],
@@ -199,19 +202,20 @@ def test_a_layers_decode_rows_through_the_kernel(monkeypatch):
     kernel, against ``decode_rows`` on that layer's slice through
     ``recurrent``: y, xs, the state, the convolution rows."""
     cfg = _config()
-    a = mamba.init(cfg, jax.random.PRNGKey(0))
+    a = jax.jit(mamba.init, static_argnums=0)(cfg, jax.random.PRNGKey(0))
     shapes = mamba.state_shapes(cfg, 3, 5)
     k = jax.random.split(jax.random.PRNGKey(3), 3)
     S = jax.random.normal(k[0], shapes["S"].shape, jnp.float32)
     rows = jax.random.normal(k[1], shapes["conv"].shape[1:], jnp.float32)
     pre = jax.random.normal(k[2], (5, cfg.ssm_inner), jnp.float32)
     active = jnp.asarray([True, True, False, True, True])
-    (want_y, want_xs), want, want_nxt = mamba.decode_rows(
-        cfg, a, S[2], rows, pre)
+    # One program each, where the eager form compiles every operation.
+    (want_y, want_xs), want, want_nxt = jax.jit(
+        lambda *rest: mamba.decode_rows(cfg, *rest))(a, S[2], rows, pre)
     monkeypatch.setattr(ssm_decode, "on_tpu", lambda: True)
     with pltpu.force_tpu_interpret_mode():
-        (y, xs), new, nxt = mamba.decode_rows(cfg, a, S, rows, pre, layer=2,
-                                              active=active)
+        (y, xs), new, nxt = jax.jit(lambda *rest: mamba.decode_rows(
+            cfg, *rest, layer=2, active=active))(a, S, rows, pre)
     live = np.asarray(active)
     np.testing.assert_allclose(np.asarray(y)[live], np.asarray(want_y)[live],
                                rtol=1e-5, atol=1e-6)
@@ -225,7 +229,9 @@ def test_a_layers_decode_rows_through_the_kernel(monkeypatch):
 
 def _decode(cfg, params, pools, steps=3):
     """``steps`` decode steps of four slots, the third dead: (the logits a
-    step, the pools at the end)."""
+    step, the pools at the end).  Jitted, a function a call: jit keeps a
+    trace by its function and arguments, not by what ``on_tpu`` answered."""
+    decode_logits = jax.jit(lambda *args: paged.decode_logits(cfg, *args))
     b, maxp, ps = 4, 4, 8
     adapters = paged.init_adapter_pool(cfg, 1, 4)
     tables = np.arange(b * maxp, dtype=np.int32).reshape(b, maxp)
@@ -238,8 +244,8 @@ def _decode(cfg, params, pools, steps=3):
         toks = jnp.asarray((np.arange(b) * 7 + step * 3) % cfg.vocab_size,
                            jnp.int32)
         lens = jnp.where(active, 5 + step, 0).astype(jnp.int32)
-        logits, pools, _ = paged.decode_logits(
-            cfg, params, pools, adapters, toks, tables, lens, active, ids)
+        logits, pools, _ = decode_logits(
+            params, pools, adapters, toks, tables, lens, active, ids)
         rows.append(np.asarray(logits))
     return np.stack(rows), pools
 
@@ -250,7 +256,7 @@ def test_the_decode_program_through_the_kernel(monkeypatch):
     against the same through ``recurrent``: every live row's logits, the
     state pool, the convolution pool and the K/V pools' own pages."""
     cfg = _config()
-    params = moe_init(cfg, jax.random.PRNGKey(0))
+    params = jax.jit(moe_init, static_argnums=0)(cfg, jax.random.PRNGKey(0))
     k = jax.random.PRNGKey(5)
 
     def pools():
@@ -261,7 +267,7 @@ def test_the_decode_program_through_the_kernel(monkeypatch):
     want, want_pools = _decode(cfg, params, pools())
     monkeypatch.setattr(ssm_decode, "on_tpu", lambda: True)
     jax.clear_caches()
-    handed = []
+    handed = []  # as the step is traced: the three steps run one program
     real = ssm_decode.ssm_decode_step
     monkeypatch.setattr(
         ssm_decode, "ssm_decode_step",
@@ -270,7 +276,7 @@ def test_the_decode_program_through_the_kernel(monkeypatch):
     with pltpu.force_tpu_interpret_mode():
         got, got_pools = _decode(cfg, params, pools())
     jax.clear_caches()
-    assert handed == [((3, 4, 16, 128), i) for i in (0, 1, 2)] * 3
+    assert handed == [((3, 4, 16, 128), i) for i in (0, 1, 2)]
     live = [0, 1, 3]
     np.testing.assert_allclose(got[:, live], want[:, live], rtol=2e-5,
                                atol=2e-5)

@@ -222,7 +222,16 @@ workflow.run(node, workflow_id="surv", storage={wf_store!r})
             break
         if line == "" and proc.poll() is not None:
             raise AssertionError(proc.stderr.read())
-    time.sleep(2.5)  # pre() checkpoint lands; the event step is polling
+    # pre()'s checkpoint lands (written whole: os.replace) and the head has
+    # a snapshot to restart from; the event step is polling.  The deadline
+    # is the failure only.
+    surv = os.path.join(wf_store, "surv")
+    deadline = time.time() + 60
+    while not (os.path.exists(state) and os.path.isdir(surv) and any(
+            "pre" in f and not f.endswith(".tmp") for f in os.listdir(surv))):
+        assert time.time() < deadline, "pre()'s checkpoint never landed"
+        assert proc.poll() is None, proc.stderr.read()
+        time.sleep(0.05)
     proc.send_signal(signal.SIGKILL)
     proc.wait(timeout=30)
     time.sleep(2)
