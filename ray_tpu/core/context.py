@@ -6,7 +6,7 @@ Analog of the reference's global worker singleton
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Any, Dict, Optional
 
 
 class RuntimeContext:
@@ -36,3 +36,22 @@ ctx = RuntimeContext()
 
 def get_runtime_context() -> RuntimeContext:
     return ctx
+
+
+#: What this process's direct streams (streaming tasks whose items the
+#: submitter pulls from the worker that runs them) have done, cumulative
+#: since its start: items handed to a pull, the seconds from the producing
+#: generator's ``next`` returning to the item's append (``store``:
+#: serialising it, then the worker's ``_streams_lock``), the seconds from
+#: the append to the reply that carries the item (``pull``), and the items
+#: appended while a pull was already waiting for them (a consumer that
+#: keeps pace).  Written by ``core.worker_main`` alone, every field under
+#: ``_streams_lock``, where the append and the reply already are.
+stream_counts = {"items": 0, "store_s": 0.0, "pull_s": 0.0, "waiting": 0}
+
+
+def direct_stream_counts() -> Dict[str, Any]:
+    """A copy of the direct streams' cumulative counters, for whoever
+    differences them over a period of its own (the serving engine's step
+    record, as it does ``devmem.compile_count()``)."""
+    return dict(stream_counts)

@@ -37,7 +37,7 @@ from . import serialization
 from ..devtools.locks import guarded, make_lock
 from .client import Client
 from .config import get_config
-from .context import ctx
+from .context import ctx, stream_counts
 from .ids import ActorID, ObjectID, TaskID
 from .object_ref import ObjectRef, _TopLevelRef
 
@@ -828,6 +828,11 @@ class Worker:
                     "items": [], "done": None, "error": None,
                     # (loop, future) of a pull waiting for the next item.
                     "waiter": None,
+                    # time.perf_counter() at each item's append (beside
+                    # the items: nothing of it goes on the wire), and how
+                    # many items a reply has carried (a pull asked again
+                    # counts none twice).
+                    "stamps": [], "handed": 0,
                 }
         self.task_queue.put(spec)
         return await fut
@@ -876,6 +881,14 @@ class Worker:
                         ahead.append(info)
                     if ahead:
                         reply["ahead"] = ahead
+                    end = index + 1 + len(ahead)
+                    first = max(index, st["handed"])
+                    if end > first:
+                        now = time.perf_counter()
+                        stream_counts["items"] += end - first
+                        stream_counts["pull_s"] += (end - first) * now - sum(
+                            st["stamps"][first:end])
+                        st["handed"] = end
                     return reply
                 if st["error"] is not None:
                     return {"error": st["error"]}
@@ -1081,6 +1094,7 @@ class Worker:
                 direct = "_direct_reply" in spec
                 count = 0
                 for item in result:
+                    got = time.perf_counter()  # the generator's next returned
                     oid = ObjectID.for_task_return(TaskID(task_id), count + 1000)
                     info = self._store_value(oid, item)
                     if direct:
@@ -1091,6 +1105,11 @@ class Worker:
                             st = self.direct_streams.get(task_id)
                             if st is not None:
                                 st["items"].append(info)
+                                now = time.perf_counter()
+                                st["stamps"].append(now)
+                                stream_counts["store_s"] += now - got
+                                if st["waiter"] is not None:
+                                    stream_counts["waiting"] += 1
                         self._wake_stream(st)
                     else:
                         self.client.call_bg(

@@ -154,6 +154,8 @@ def cmd_status(args) -> int:
                       f"queued {row['queued']}  pages {row['pages']}  "
                       f"stall {row['stall%']}%  "
                       f"starved {row['starved%']}%  host {row['host%']}%  "
+                      f"cpu {row['cpu%']}%  wait {row['wait%']}%  "
+                      f"proc {row['proc%']}%  exit {row['exit_ms']} ms  "
                       f"ahead {row['ahead%']}%  "
                       f"queue wait {row['qwait_ms']} ms  "
                       f"loop {row['loop%']}%  compiles {row['compiles']}  "
@@ -193,6 +195,20 @@ def _engine_rows(engines, devmem_items) -> list:
         host = total("between_s", "upload_s", "dispatch_s", "emit_s")
         ahead = [r["ahead"] for r in recs if "ahead" in r and r["occupancy"]]
         starved = [r["starved_s"] for r in recs if "starved_s" in r]
+        # Who had the loop thread, over the records that say: the share of
+        # their periods it ran, the share it was in a phase and did not
+        # (the interpreter lock, the engine's lock), the whole process's
+        # CPU over them (100: one core), and a token's mean way from its
+        # emit to the reply that carries it out of the replica.
+        held = [r for r in recs if "cpu_s" in r]
+        held_loop = sum(r["wall_s"] + r["between_s"] for r in held)
+
+        def held_share(key, held=held, held_loop=held_loop):
+            return (f"{100.0 * sum(r[key] for r in held) / held_loop:.1f}"
+                    if held_loop > 0 else "-")
+
+        out = sum(r["tokens_out"] for r in held)
+        exit_s = sum(r["wake_s"] + r["store_s"] + r["pull_s"] for r in held)
         waits = sorted(e["queue_s"] for r in recs
                        for e in r.get("first_tokens") or ())
         accounted = "t0" in latest
@@ -216,6 +232,10 @@ def _engine_rows(engines, devmem_items) -> list:
                         if starved and loop > 0 else "-",
             "host%": f"{100.0 * host / loop:.1f}"
                      if accounted and loop > 0 else "-",
+            "cpu%": held_share("cpu_s"),
+            "wait%": held_share("wait_s"),
+            "proc%": held_share("proc_cpu_s"),
+            "exit_ms": f"{1e3 * exit_s / out:.2f}" if out else "-",
             "ahead%": f"{100.0 * sum(ahead) / len(ahead):.1f}"
                       if ahead else "-",
             "qwait_ms": f"{1e3 * waits[len(waits) // 2]:.1f}"
@@ -371,8 +391,8 @@ def _render_top(cl) -> str:
         _format_table(
             _engine_rows(engines, devmem),
             ["engine", "slots", "queued", "stall%", "starved%", "host%",
-             "ahead%", "qwait_ms", "loop%", "compiles", "pages", "adapters",
-             "hbm", "tenants"],
+             "cpu%", "wait%", "proc%", "exit_ms", "ahead%", "qwait_ms",
+             "loop%", "compiles", "pages", "adapters", "hbm", "tenants"],
             empty="(no engines reporting — flight recorder off or no "
                   "serve traffic yet)",
         ),

@@ -50,6 +50,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from ..core.context import direct_stream_counts
 from ..util.profiling import annotation
 
 #: Engine identity within one process: step records carry
@@ -162,7 +163,7 @@ class _Request:
         "last_token_t", "slot",
         "trace_ctx", "submit_wall", "admit_t",
         "tenant", "weight", "adapter", "adapter_slot", "match",
-        "cow_ref", "cache_hit_len",
+        "cow_ref", "cache_hit_len", "wake_s", "wake_seen",
     )
 
     def __init__(self, req_id: int, prompt: np.ndarray, max_new: int,
@@ -191,6 +192,14 @@ class _Request:
         # Both at emission in the loop, not where a consumer took the
         # token: their distance over the tokens between is the mean gap.
         self.last_token_t: Optional[float] = None
+        # The seconds this request's tokens waited for its consumer, from
+        # their emit pass's start to TokenStream.__next__ handing them on:
+        # summed by the consuming thread alone (one writer, no lock), and
+        # how much of the sum the loop has put on a step record, which is
+        # the loop's to write while the request holds a slot and the
+        # consumer's once its stream has ended.
+        self.wake_s = 0.0
+        self.wake_seen = 0.0
         self.slot = -1
         # Tracing: the submitter's span context (None when the request
         # arrived untraced/unsampled — then the engine emits nothing).
@@ -299,6 +308,41 @@ class _Starved:
         return out
 
 
+class _Held:
+    """Who had the loop thread: its CPU clock (``time.thread_time()``)
+    beside the wall's, so that a record's period splits into the seconds
+    the thread ran and those it did not (it waited for the chip, for the
+    interpreter lock, for ``self._lock`` or for a core).  ONE read of the
+    thread's clock and one of the process's where a record ends, no more:
+    a kernel may answer a CPU clock by a trap (the chip machines' sandboxed
+    one: 6.3 us a read where this repo's own answers in 0.4, and the thread
+    that asks holds the interpreter), and a read at every phase stamp cost
+    the widest cell 2.4% of its tokens a second (builder's chip runs,
+    PR 54).  Such a kernel's clocks also advance by TICKS (10 ms there), so
+    one record reads no CPU or a whole tick: sums and means over records
+    are unbiased and are what to read.  The loop thread's alone: a thread's
+    CPU clock is its own."""
+
+    __slots__ = ("cpu", "proc", "proc_idle")
+
+    def __init__(self):
+        #: The thread's and the PROCESS's CPU clocks where the last record
+        #: closed (a thread's starts at 0 with the thread), and what of the
+        #: process's has passed since then while the loop idled.
+        self.cpu = 0.0
+        self.proc = time.process_time()
+        self.proc_idle = 0.0
+
+    def take(self) -> Tuple[float, float]:
+        """For the record that ends now: the thread's CPU seconds since
+        the last record ended, and the process's over the same stretch
+        less the idling."""
+        cpu, proc = time.thread_time(), time.process_time()
+        out = (cpu - self.cpu, proc - self.proc - self.proc_idle)
+        self.cpu, self.proc, self.proc_idle = cpu, proc, 0.0
+        return out
+
+
 class _Phase(annotation):
     """A phase of the loop that the chip may stand still in: a
     ``util.profiling.annotation`` that hands its two stamps to the
@@ -325,21 +369,34 @@ class TokenStream:
         self._engine = engine
         self._req = req
         self.steps: List[int] = []   # decode-step index of each token
+        # The ``wake`` hop is timed only where a step record takes it.
+        self._timed = engine.config.step_record
 
     def __iter__(self):
         return self
 
     def __next__(self) -> int:
+        req = self._req
         try:
-            kind, payload, step = self._req.out_q.get(
+            item = req.out_q.get(
                 timeout=self._engine.config.stream_timeout_s)
         except _queue.Empty:
             self.cancel()
             raise RuntimeError(
                 "engine stream stalled past stream_timeout_s") from None
+        kind, payload = item[0], item[1]
         if kind == "tok":
-            self.steps.append(step)
+            if self._timed:  # this thread has the token: its hop's end
+                req.wake_s += time.perf_counter() - item[3]
+            self.steps.append(item[2])
             return int(payload)
+        # The stream's end.  The loop took its last look at this request
+        # before it put this item: what it had not seen is handed over
+        # (nothing with the step record off: no record would take it).
+        late = req.wake_s - req.wake_seen
+        if late:
+            req.wake_seen = req.wake_s
+            self._engine._wake_late.append(late)
         if kind == "err":
             raise payload
         raise StopIteration  # ("done", reason)
@@ -555,6 +612,15 @@ class InferenceEngine:
         self._acct: Optional[_Account] = None
         self._prev_end = round(time.perf_counter(), 6)
         self._starved = _Starved()
+        self._held = _Held()
+        # A token's way out (_record_step): the wake seconds the loop has
+        # taken off live requests since the last record, those that ended
+        # streams handed over (a deque: appended once a request, by its
+        # consumer, and drained by every record), and the process's
+        # direct-stream counters as the last record read them.
+        self._wake_s = 0.0
+        self._wake_late: "collections.deque[float]" = collections.deque()
+        self._stream_counts = direct_stream_counts()
         # Device-memory attribution: the engine owns the big allocations,
         # so it names them for util/devmem snapshots.  Weights bytes are
         # static; pool/adapter lambdas chase the live arrays (donation
@@ -1162,6 +1228,7 @@ class InferenceEngine:
             self.completed += 1
             rec["completed"] += 1
             self._m_completed.inc(1)
+        self._take_wake(req)  # the last look: the stream's end follows
         if reason == "shutdown":
             # Loudly: a truncated generation must not look complete.
             req.out_q.put(("err", RuntimeError(
@@ -1360,13 +1427,25 @@ class InferenceEngine:
         self._temps[slot] = req.temperature
         self._adapter_slots[slot] = req.adapter_slot
         self._dirty = True
-        self._emit_token(req, first, self.step_count)
+        self._m_tokens.inc(1)
+        self._emit_token(req, first, self.step_count, now)
         return routing
 
-    def _emit_token(self, req: _Request, token: int, step: int) -> None:
+    def _take_wake(self, req: _Request) -> None:
+        """Onto the open record: what ``req``'s consumer has added to its
+        wake seconds since the loop last looked."""
+        wake_s = req.wake_s
+        self._wake_s += wake_s - req.wake_seen
+        req.wake_seen = wake_s
+
+    def _emit_token(self, req: _Request, token: int, step: int,
+                    emitted: float) -> None:
+        """Hand ``token`` to ``req``'s stream.  ``emitted`` is the
+        ``time.perf_counter()`` at which the pass that emits it began (the
+        token was on the host): it rides with the token, and the consumer
+        ends the ``wake`` hop against it."""
         req.generated += 1
-        self._m_tokens.inc(1)
-        req.out_q.put(("tok", token, step))
+        req.out_q.put(("tok", token, step, emitted))
         if req.stop_token is not None and token == req.stop_token:
             self._evict(req.slot, "stop")
         elif req.generated >= req.max_new:
@@ -1399,6 +1478,7 @@ class InferenceEngine:
                 req.adapter_slot = -1
             req.finished = True
             self.slots[slot] = None
+            self._take_wake(req)
             req.out_q.put(("err", exc, self.step_count))
         # The pools are rebuilt below, so every cached KV page and every
         # resident adapter slot is garbage: drop the tree's refs and
@@ -1467,8 +1547,10 @@ class InferenceEngine:
                     # Nothing to run is not starvation: this turn's seconds
                     # are idle_s, and nobody's.
                     self._starved.stop()
+                    proc = time.process_time()
                     with annotation(PH_IDLE, self._gap_acct):
                         self._wake.wait(timeout=0.05)
+                    self._held.proc_idle += time.process_time() - proc
                     continue
             # Model work runs OUTSIDE the lock: pools/slot arrays belong
             # to this thread; submit() only appends to the wait queue.
@@ -1615,6 +1697,7 @@ class InferenceEngine:
             routing = dict(zip(
                 self._counter_keys,
                 toks[self.config.batch_slots:].tolist()))
+            gaps, emitted = [], 0
             for slot, req in enumerate(step.owners):
                 if req is None or self.slots[slot] is not req:
                     continue  # stopped under this step: the token is dropped
@@ -1622,9 +1705,16 @@ class InferenceEngine:
                 req.length += 1
                 self._tokens[slot] = toks[slot]
                 if req.last_token_t is not None:
-                    self._m_itl.observe(now - req.last_token_t)
+                    gaps.append(now - req.last_token_t)
                 req.last_token_t = now
-                self._emit_token(req, int(toks[slot]), step.number)
+                emitted += 1
+                if rec is not None:
+                    self._take_wake(req)
+                self._emit_token(req, int(toks[slot]), step.number, now)
+            # Each instrument once a step, not once a token: a lock each.
+            if emitted:
+                self._m_tokens.inc(emitted)
+                self._m_itl.observe_many(gaps)
         self._m_active.set(
             sum(1 for s in self.slots if s is not None),
             tags=self._pid_tags)
@@ -1660,7 +1750,34 @@ class InferenceEngine:
         of the engine's (:class:`_Starved`), ``starved`` the same by the
         phase it passed in (``between``: in none), and ``traced`` is 1 on
         a record closed while a profiler session was open, so that a reader
-        of a device trace takes the records of the traced seconds."""
+        of a device trace takes the records of the traced seconds.
+
+        Who had the loop thread (:class:`_Held`), over the same period
+        ``wall_s + between_s``: ``cpu_s`` is the seconds the thread ran
+        (its own CPU clock, read where a record ends), and ``wait_s`` what
+        is left of the period once those and the chip's are taken: the
+        thread neither ran nor waited for the chip (the interpreter lock,
+        ``self._lock``, a core).  The chip's are ``readback_s`` and, of a
+        prefill, ``prefill_wait``, which is booked on its ``first_tokens``
+        entry (``prefill_wait_s``): so on the record's own numbers ``cpu_s
+        + wait_s + readback_s`` plus the entries' ``prefill_wait_s`` IS
+        ``wall_s + between_s``, to the rounding (the thread's few CPU
+        microseconds inside a wait for the chip are in ``cpu_s``).  Not by
+        phase: a clock read at every phase stamp cost more than it told,
+        and what it told is in ``PERF.md`` section 5.  Under a CPU clock
+        that ticks, every one of these is a sample: see :class:`_Held`.
+        ``proc_cpu_s`` is the PROCESS's CPU seconds over the period: near
+        the period itself, one core's worth of interpreter is saturated
+        (a bound: the runtime's own threads count too).
+
+        A token's way out, over the items whose hop ENDED in the period:
+        ``wake_s`` from the start of the pass that emitted a token to its
+        stream's thread having it (``TokenStream.__next__``), and the
+        process's direct-stream counters (``core.context``), differenced:
+        ``store_s`` from there to the item's append, ``pull_s`` from the
+        append to the reply that carries it, ``tokens_out`` the items such
+        replies carried and ``pull_waiting`` those appended while a pull
+        already waited for them (all 0 where no worker pulls the streams)."""
         import jax
 
         from ..models.paged import trace_counts
@@ -1670,6 +1787,13 @@ class InferenceEngine:
         t0, stall_s = acct.t0, acct.stall_s
         end = time.perf_counter()
         wall_s = end - t0
+        cpu_s, proc_cpu_s = self._held.take()
+        while self._wake_late:
+            self._wake_s += self._wake_late.popleft()
+        wake_s, self._wake_s = self._wake_s, 0.0
+        streams0 = self._stream_counts
+        streams = direct_stream_counts()
+        self._stream_counts = streams
         # The record ends here, inside its own last phase: what the chip
         # stands still in the rest of it is the next record's.
         self._starved.spend(PH_RECORD, phase.t0, end)
@@ -1696,6 +1820,12 @@ class InferenceEngine:
         between_s = round(t0 - self._prev_end - idle_s, 6)
         self._prev_end = t0 + wall_s
         first_tokens, phases = acct.first_tokens, acct.phases
+        # Who had the loop thread: what of the period it neither ran nor
+        # waited for the chip, on the record's own (rounded) numbers.
+        cpu_s = round(cpu_s, 6)
+        readback_s = round(phases.get(PH_READBACK, 0.0), 6)
+        wait_s = round(wall_s + between_s - cpu_s - readback_s - sum(
+            e["prefill_wait_s"] for e in first_tokens), 6)
         rec = {
             "t": round(now, 3),
             "engine": self.engine_id,
@@ -1708,10 +1838,18 @@ class InferenceEngine:
             "idle_s": idle_s,
             "upload_s": round(phases.get(PH_UPLOAD, 0.0), 6),
             "dispatch_s": round(phases.get(PH_DISPATCH, 0.0), 6),
-            "readback_s": round(phases.get(PH_READBACK, 0.0), 6),
+            "readback_s": readback_s,
             "emit_s": round(phases.get(PH_EMIT, 0.0), 6),
             "starved_s": round(sum(starved.values(), 0.0), 6),
             "starved": {name: s for name, s in starved.items() if s},
+            "cpu_s": cpu_s,
+            "wait_s": wait_s,
+            "proc_cpu_s": round(proc_cpu_s, 6),
+            "tokens_out": streams["items"] - streams0["items"],
+            "wake_s": round(wake_s, 6),
+            "store_s": round(streams["store_s"] - streams0["store_s"], 6),
+            "pull_s": round(streams["pull_s"] - streams0["pull_s"], 6),
+            "pull_waiting": streams["waiting"] - streams0["waiting"],
             "first_tokens": first_tokens,
             "occupancy": sum(1 for s in self.slots if s is not None),
             "slots": self.config.batch_slots,

@@ -194,19 +194,30 @@ class Histogram(_Metric):
         self._counts: Dict[Tuple, int] = {}
 
     def observe(self, value: float, tags: Optional[Dict[str, str]] = None):
+        self.observe_many((value,), tags)
+
+    def observe_many(self, values: Sequence[float],
+                     tags: Optional[Dict[str, str]] = None):
+        """Every value of ``values`` under one hold of the lock (a decode
+        step's gaps, one a slot): buckets, sum and count end exactly where
+        one ``observe`` a value, in this order, leaves them."""
+        if not values:
+            return
         k = _tags_key(tags)
+        bounds = self.boundaries
         with self._lock:
-            b = self._buckets.setdefault(
-                k, [0.0] * (len(self.boundaries) + 1)
-            )
-            for i, bound in enumerate(self.boundaries):
-                if value <= bound:
-                    b[i] += 1
-                    break
-            else:
-                b[-1] += 1
-            self._sums[k] = self._sums.get(k, 0.0) + value
-            self._counts[k] = self._counts.get(k, 0) + 1
+            b = self._buckets.setdefault(k, [0.0] * (len(bounds) + 1))
+            total = self._sums.get(k, 0.0)
+            for value in values:
+                for i, bound in enumerate(bounds):
+                    if value <= bound:
+                        b[i] += 1
+                        break
+                else:
+                    b[-1] += 1
+                total += value
+            self._sums[k] = total
+            self._counts[k] = self._counts.get(k, 0) + len(values)
 
     def _snapshot(self) -> List[dict]:
         with self._lock:

@@ -287,8 +287,9 @@ def test_the_decode_spans_mean_gap_is_what_a_list_of_gaps_gave():
     gaps = []
     try:
         list(eng.submit([3, 5, 7], max_new_tokens=2))  # compiled before
-        observe = eng._m_itl.observe
-        eng._m_itl.observe = lambda v: (gaps.append(v), observe(v))[1]
+        observe = eng._m_itl.observe_many  # a step's gaps in one call
+        eng._m_itl.observe_many = lambda vs: (gaps.extend(vs),
+                                              observe(vs))[1]
         tracing.drain_buffered()
         with tracing.trace("req_root", force=True) as root:
             assert len(list(eng.submit([5, 7, 11, 13],
