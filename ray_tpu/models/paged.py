@@ -335,6 +335,11 @@ SHARE_KEYS = ("expert_pairs_routed",)
 #: rows the program brought in (a gather's whole tables, or the pages a
 #: kernel walks) and the rows a query could see.
 KV_KEYS = ("kv_rows_read", "kv_rows_live")
+#: Behind them where the decode program is compiled in the shared form
+#: (``paged_decode_step(..., shared=True)``): the rows the walk slot by slot
+#: would have fetched and this one did not, because a run of pages that
+#: several slots hold was fetched once for all of them (0: nothing shared).
+SHARED_KEYS = ("kv_rows_shared",)
 
 
 def _counts_kv_rows(config) -> bool:
@@ -352,18 +357,19 @@ def routing_keys(config) -> tuple:
     return ROUTING_KEYS + (SHARE_KEYS if share else ())
 
 
-def counter_keys(config) -> tuple:
+def counter_keys(config, shared: bool = False) -> tuple:
     """The names of the int32 counters the decode program of ``config``
-    appends to the tokens it returns, in their order (a prefill appends
-    the ``routing_keys`` among them)."""
+    (``shared``: in the shared form) appends to the tokens it returns, in
+    their order (a prefill appends the ``routing_keys`` among them)."""
     return routing_keys(config) \
-        + (KV_KEYS if _counts_kv_rows(config) else ())
+        + (KV_KEYS if _counts_kv_rows(config) else ()) \
+        + (SHARED_KEYS if shared else ())
 
 
-def routing_width(config) -> int:
+def routing_width(config, shared: bool = False) -> int:
     """How many int32 counters the decode program of ``config`` appends to
-    the tokens it returns: ``len(counter_keys(config))``."""
-    return len(counter_keys(config))
+    the tokens it returns: ``len(counter_keys(config, shared))``."""
+    return len(counter_keys(config, shared))
 
 
 def _with_routing(config, toks: jax.Array,
@@ -403,10 +409,22 @@ def _walks_live_pages(config) -> bool:
     return _counts_kv_rows(config) and kernel.on_tpu()
 
 
-def decode_attention_form(config) -> str:
+def shares_walked_pages(config) -> bool:
+    """Whether the decode program of ``config`` has a shared form (a run of
+    pages that several slots hold walked once for all of them): where it
+    walks a latent pool.  An engine asks for it
+    (``paged_decode_step(..., shared=True)``) where its prefix cache can put
+    one page into two tables, and nowhere else."""
+    return block.is_latent(config) and _walks_live_pages(config)
+
+
+def decode_attention_form(config, shared: bool = False) -> str:
     """The form the decode program of ``config`` attends its cache in, by
-    name (``LLMServer.stats()["decode_attention"]``)."""
-    return "walk" if _walks_live_pages(config) else "gather"
+    name (``LLMServer.stats()["decode_attention"]``); ``shared``: as the
+    engine compiles it (``shares_walked_pages``)."""
+    if not _walks_live_pages(config):
+        return "gather"
+    return "walk+shared" if shared else "walk"
 
 
 def prefill_attention_form(config) -> str:
@@ -444,7 +462,7 @@ def _window_lo(config, seq_lens: jax.Array) -> jax.Array:
 
 def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
                   ring_tables: Optional[jax.Array], seq_lens: jax.Array,
-                  active: jax.Array, ps: int) -> jax.Array:
+                  active: jax.Array, ps: int, runs=None) -> jax.Array:
     """``toks`` followed (where ``config`` has window layers, a latent
     pool or recurrent layers) by the decode step's ``KV_KEYS``, summed over
     the layers that keep rows and over slots:
@@ -454,7 +472,10 @@ def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
     is every slot's whole table, live or not, where it gathers; where a
     kernel walks (``_walks_live_pages``), the pages it visits: of a
     whole-length table ``seq_lens // page + 1`` a slot, of a ring those from
-    the window's first position to ``seq_lens``' (one of an empty slot)."""
+    the window's first position to ``seq_lens``' (one of an empty slot).
+    In the shared form (``runs``: the step's ``shared_runs``) a run's pages
+    count once a pass, whatever its holders, and ``SHARED_KEYS`` follows:
+    the rows that saved, so that the two sum to the per-slot walk's."""
     whole, window = kv_layers(config)
     if not _counts_kv_rows(config):
         return toks
@@ -470,9 +491,11 @@ def _with_kv_rows(config, toks: jax.Array, page_tables: jax.Array,
     rows = jnp.where(active, seq_lens + 1, 0)
     live = len(whole) * jnp.sum(rows) \
         + len(window) * jnp.sum(jnp.minimum(rows, config.window))
-    return jnp.concatenate(
-        [toks, jnp.stack([jnp.asarray(read, jnp.int32),
-                          live.astype(jnp.int32)])])
+    counts = [jnp.asarray(read, jnp.int32), live.astype(jnp.int32)]
+    if runs is not None:
+        saved = (ps * len(whole) * runs.pages_saved).astype(jnp.int32)
+        counts = [counts[0] - saved, counts[1], saved]
+    return jnp.concatenate([toks, jnp.stack(counts)])
 
 
 def _first_token(config, tok: jax.Array, counts) -> jax.Array:
@@ -817,7 +840,7 @@ def _latent_row(config, k_r: jax.Array, c: jax.Array, cos, sin,
 def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
                    cos, sin, positions, page_idx, off, tables, visible,
                    scope: str, walk_lens: Optional[jax.Array] = None,
-                   walk_rows: Optional[tuple] = None):
+                   walk_rows: Optional[tuple] = None, runs=None):
     """What the decode step and the suffix prefill do in layer ``i`` of a
     latent model with the new rows' q [N, H, nope + rope], latent c
     [N, rank] and rotary key k_r [N, rope] at ``positions`` [N] (N = B * Q
@@ -831,7 +854,8 @@ def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
     the live pages in place of the gather (``visible`` is then not read, and
     may be None): ``walk_lens`` [B] is the decode step's ``seq_lens`` (Q is
     1); ``walk_rows`` a prefill's ``(first position, length)`` (B is 1, as
-    ``_paged_attend`` takes it).  Returns [N, H * v]."""
+    ``_paged_attend`` takes it); ``runs`` the decode step's shared runs
+    (``latent_decode.shared_runs``, the shared form).  Returns [N, H * v]."""
     B = tables.shape[0]
     Q = q.shape[0] // B
     nope = config.qk_nope_head_dim
@@ -849,7 +873,8 @@ def _latent_attend(config, pools: PagedPools, i: int, q, c, k_r, wkv_b, *,
         if walk_lens is not None:  # Q is 1
             o_lat = latent_decode.latent_decode_attention(
                 q_abs, pools["kv"], i, tables, walk_lens,
-                rank=config.kv_lora_rank, sm_scale=config.head_dim ** -0.5)
+                rank=config.kv_lora_rank, sm_scale=config.head_dim ** -0.5,
+                runs=runs)
         elif walk_rows is not None:  # B is 1
             o_lat = latent_prefill_op.latent_prefill_attention(
                 q_abs, pools["kv"], i, tables[0], *walk_rows,
@@ -866,9 +891,10 @@ def decode_logits(config, params: Params, pools: PagedPools,
                   adapters: AdapterArrays, tokens: jax.Array,
                   page_tables: jax.Array, seq_lens: jax.Array,
                   active: jax.Array, adapter_ids: jax.Array,
-                  ring_tables: Optional[jax.Array] = None):
+                  ring_tables: Optional[jax.Array] = None, runs=None):
     """``paged_decode_step`` up to its sampling: (logits [B, V] float32,
-    pools, per-layer expert counts)."""
+    pools, per-layer expert counts).  ``runs``: the step's shared runs
+    (``latent_decode.shared_runs``), handed to every latent layer's walk."""
     B, maxp = page_tables.shape
     ps = _page_size(pools)
     pools = dict(pools)
@@ -904,7 +930,7 @@ def decode_logits(config, params: Params, pools: PagedPools,
             _latent_attend, config, pools, cos=cos, sin=sin,
             positions=seq_lens, page_idx=page_idx, off=off,
             tables=page_tables, visible=visible, scope="attn_latent",
-            walk_lens=walk_lens)
+            walk_lens=walk_lens, runs=runs)
 
     x, counts = _stack(config, params, tokens[:B], attend,
                        _adapter_lora(adapters, adapter_ids), active,
@@ -913,14 +939,16 @@ def decode_logits(config, params: Params, pools: PagedPools,
     return logits, pools, counts
 
 
-@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,))
+@functools.partial(jax.jit, static_argnums=(0,), donate_argnums=(2,),
+                   static_argnames=("shared",))
 def paged_decode_step(config: LlamaConfig, params: Params,
                       pools: PagedPools, adapters: AdapterArrays,
                       tokens: jax.Array, page_tables: jax.Array,
                       seq_lens: jax.Array, active: jax.Array,
                       temps: jax.Array, adapter_ids: jax.Array,
                       key: jax.Array,
-                      ring_tables: Optional[jax.Array] = None):
+                      ring_tables: Optional[jax.Array] = None, *,
+                      shared: bool = False):
     """One decode step for every batch slot at once.
 
     tokens int32, the last sampled token of each slot in its first B
@@ -952,18 +980,34 @@ def paged_decode_step(config: LlamaConfig, params: Params,
     (next_tokens [B], new_seq_lens [B], new_key, pools); behind
     next_tokens come the configuration's ``counter_keys``: a routed FFN's
     ``ROUTING_KEYS`` (``_with_routing``), window layers' ``KV_KEYS``
-    (``_with_kv_rows``)."""
+    (``_with_kv_rows``).
+
+    ``shared`` (static; ``shares_walked_pages`` says whether ``config`` has
+    the form) is what an engine whose prefix cache can put ONE page into
+    several slots' tables asks for: the step finds the runs of pages that
+    slots open with in common and every latent layer's walk fetches a run
+    once for all its holders (``ops/latent_decode.py``); ``SHARED_KEYS``
+    follows the counters.  Without it this is the program it was, text for
+    text."""
     _bump("decode", tokens=tokens, page_tables=page_tables,
           seq_lens=seq_lens, temps=temps, adapter_ids=adapter_ids, key=key)
+    runs = None
+    if shared:  # found once, from what the step already has
+        if not shares_walked_pages(config):
+            raise ValueError("the decode program of this configuration has "
+                             "no shared form on this backend")
+        runs = latent_decode.shared_runs(
+            page_tables, seq_lens, active, page=_page_size(pools),
+            heads=config.n_heads)
     logits, pools, counts = decode_logits(
         config, params, pools, adapters, tokens, page_tables, seq_lens,
-        active, adapter_ids, ring_tables)
+        active, adapter_ids, ring_tables, runs)
     key, sub = jax.random.split(key)
     toks = _sample_tokens(logits, temps, sub)
     new_lens = jnp.where(active, seq_lens + 1, 0)
     out = _with_kv_rows(config, _with_routing(config, toks, counts),
                         page_tables, ring_tables, seq_lens, active,
-                        _page_size(pools))
+                        _page_size(pools), runs)
     return out, new_lens, key, pools
 
 

@@ -1,6 +1,7 @@
 """The walk of a sequence's live pages: what the four paged-attention kernels
 (``latent_decode``, ``paged_decode``, ``latent_prefill``, ``paged_prefill``)
-share, written once, over a ROW KIND and in two FORMS.
+share, written once, over a ROW KIND and in two FORMS (and a third for runs
+of pages that several slots hold).
 
 A row kind says what a page of the cache holds and how a block of it is
 multiplied:
@@ -44,10 +45,15 @@ not computed and thrown away.  Both forms:
   is every kernel's reference.
 
 The forms are ``walk_slots`` (one query row a slot: a decode step) and
-``walk_rows`` (a block of query rows of one sequence: a prefill call).  The
-four modules beside this one are fronts: a kind, a form, the block sizes
-measured for them, a geometry check in their own words and the name their
-``pallas_call`` carries into the compiled program.  Off the TPU nothing here
+``walk_rows`` (a block of query rows of one sequence: a prefill call).
+Where several slots of a decode step open with the SAME pages (a prefix
+cache's), ``shared_runs`` finds those runs and ``walk_shared`` fetches each
+once for the stacked query rows of all its holders (a latent pool's; the
+pair's kind is not written), leaving a softmax that ``walk_slots`` goes on
+from over each slot's own tail.  The four modules beside this one are
+fronts: a kind, a form, the block sizes measured for them, a geometry check
+in their own words and the name their ``pallas_call`` carries into the
+compiled program.  Off the TPU nothing here
 runs unless a test asks for ``interpret``: ``models/paged.py`` chooses."""
 
 from __future__ import annotations
@@ -55,6 +61,7 @@ from __future__ import annotations
 import functools
 import itertools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -179,10 +186,11 @@ def _refs(refs, n_scalars: int, n_pools: int) -> tuple:
 # ------------------------------------------------------- one query row a slot
 
 
-def _slots_kernel(*refs, n_pools: int, bounded: bool, entries: int,
-                  page: int, n_rep: int, sm_scale: float):
+def _slots_kernel(*refs, n_pools: int, bounded: bool, seeded: bool,
+                  entries: int, page: int, n_rep: int, sm_scale: float):
     scalars, q_ref, pools, o_ref, bufs, sems, (half_ref,) = _refs(
-        refs, 3 + bounded, n_pools)
+        refs, 3 + bounded + 3 * seeded, n_pools)
+    scalars, seed = scalars[:3 + bounded], scalars[3 + bounded:]
     layer_ref, *lo_ref, hi_ref, tables_ref = scalars
     b, n_slots = pl.program_id(0), pl.num_programs(0)
     _, per_block, page_rows, dim = bufs[0].shape
@@ -277,8 +285,11 @@ def _slots_kernel(*refs, n_pools: int, bounded: bool, entries: int,
         _online_softmax(s, m, l, acc, values, q.dtype)
         return m.x, l.x, acc.x
 
+    # The softmax goes on from where a shared pass left it (``walk_shared``:
+    # the slot's maximum, sum and accumulator over the pages before ``lo``).
     m, l, acc = jax.lax.fori_loop(
         0, n_blocks, body,
+        tuple(ref[...] for ref in seed) if seeded else
         (jnp.full((heads, 1), NEG_INF, jnp.float32),
          jnp.zeros((heads, 1), jnp.float32),
          jnp.zeros((heads, rank), jnp.float32)))
@@ -288,7 +299,7 @@ def _slots_kernel(*refs, n_pools: int, bounded: bool, entries: int,
 def walk_slots(name: str, q: jax.Array, pools: tuple, layer,
                tables: jax.Array, lo: jax.Array | None, hi: jax.Array, *,
                rank: int, per_block: int, sm_scale: float,
-               interpret: bool) -> jax.Array:
+               interpret: bool, seed: tuple = ()) -> jax.Array:
     """One query row a head and slot, q [B, H, D], over the pages of
     ``tables`` [B, T] in ``pools``' ``layer``, ``per_block`` pages at a
     time: slot ``b`` is one grid step and sees the positions ``lo[b] <= p <=
@@ -296,22 +307,29 @@ def walk_slots(name: str, q: jax.Array, pools: tuple, layer,
     visits pages ``lo[b] // page .. hi[b] // page``; an empty slot (``lo =
     hi = 0``, an all-scratch table) costs one page.  A slot's last block
     puts the next slot's first in flight.  The pages are unrolled in the
-    kernel's text, which is traced a call.  Returns [B, H, rank]."""
+    kernel's text, which is traced a call.  ``seed``: a softmax begun
+    elsewhere over positions before ``lo`` (``walk_shared``'s float32
+    maximum and sum [B, H, 1] and accumulator [B, H, rank]), which a slot's
+    walk goes on from.  Returns [B, H, rank]."""
     B, H, D = q.shape
     page, n_kv = pools[0].shape[2], _kv_heads(pools[0])
     # Whole sublane tiles of query rows; the padding's outputs are dropped.
     tile = sublanes(q.dtype)
     heads = -(-H // tile) * tile
-    q = jnp.pad(q, ((0, 0), (0, heads - H), (0, 0)))
+    q, *seed = (jnp.pad(x, ((0, 0), (0, heads - H), (0, 0)))
+                for x in (q, *seed))
     bounds = [hi] if lo is None else [lo, hi]
     out = pl.pallas_call(
         functools.partial(_slots_kernel, n_pools=len(pools),
-                          bounded=lo is not None, entries=tables.shape[1],
-                          page=page, n_rep=H // n_kv, sm_scale=sm_scale),
+                          bounded=lo is not None, seeded=bool(seed),
+                          entries=tables.shape[1], page=page,
+                          n_rep=H // n_kv, sm_scale=sm_scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2 + len(bounds),
             grid=(B,),
-            in_specs=[pl.BlockSpec((None, heads, D), lambda b, *_: (b, 0, 0))]
+            in_specs=[pl.BlockSpec((None, heads, x.shape[-1]),
+                                   lambda b, *_: (b, 0, 0))
+                      for x in (*seed, q)]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=pl.BlockSpec((None, heads, rank),
                                    lambda b, *_: (b, 0, 0)),
@@ -328,8 +346,249 @@ def walk_slots(name: str, q: jax.Array, pools: tuple, layer,
         name=name,
     )(jnp.asarray(layer, jnp.int32).reshape(1),
       *(x.astype(jnp.int32) for x in bounds),
-      tables.astype(jnp.int32).reshape(-1), q, *map(_flat, pools))
+      tables.astype(jnp.int32).reshape(-1), *seed, q, *map(_flat, pools))
     return out[:, :H]
+
+
+# ------------------------------------- a run of pages that several slots hold
+
+
+class Runs(NamedTuple):
+    """What ``shared_runs`` finds in a decode step's tables, once a step for
+    every layer's call: which slots open with the same pages, and what
+    ``walk_shared`` and the slots' own walks each take of them."""
+    #: [B] the leading pages of slot ``b``'s table that its group's pass
+    #: walks for it (0: it walks its whole table alone).
+    run: jax.Array
+    #: [B] the slots with a group's members side by side, the groups in
+    #: their leaders' order, the slots of no group behind them; and [B]
+    #: slot ``b``'s place among them.
+    order: jax.Array
+    place: jax.Array
+    #: [4 x B] by LEADER slot, one after the other: its first member's
+    #: place in ``order``, its members (0: the slot leads no pass), the
+    #: pages of its pass (the longest run of a member) and the shortest.
+    passes: jax.Array
+    #: [R, 1] by stacked query row (``heads`` a slot in ``order``, padded to
+    #: whole blocks of rows): its slot's leader (-1: none) and the first
+    #: position it does not see in the pass (``run x page``).
+    group: jax.Array
+    limit: jax.Array
+    #: The pages the per-slot walk would visit and this one does not, a
+    #: layer: every member's run less one visit a pass.
+    pages_saved: jax.Array
+
+
+def shared_runs(tables: jax.Array, seq_lens: jax.Array, active: jax.Array,
+                *, page: int, heads: int, rows: int) -> Runs:
+    """The runs of a decode step's ``tables`` [B, T]: a slot's LEADER is the
+    first active slot whose table opens with the same page, its RUN the
+    leading entries equal to the leader's, cut to the whole pages below both
+    slots' ``seq_lens // page`` (a shared page is whole and wholly live; the
+    page a step writes is never shared).  A leader's own run is the longest
+    of its followers'.  An inactive slot never groups (every empty slot's
+    table is all scratch), and sharing below a group's own (two followers
+    that go on together past their leader's end) is not looked for: those
+    pages are walked slot by slot.  ``heads`` query rows a slot are stacked
+    in blocks of ``rows`` (``walk_shared``).  A few comparisons on [B, T]
+    int32, in the program."""
+    B, T = tables.shape
+    slot = jnp.arange(B, dtype=jnp.int32)
+    whole = jnp.where(active, seq_lens // page, 0).astype(jnp.int32)
+    same = (tables[:, :1] == tables[None, :, 0]) \
+        & active[:, None] & active[None, :]
+    leader = jnp.where(active, jnp.argmax(same, axis=1), slot)
+    differs = tables != tables[leader]
+    common = jnp.where(jnp.any(differs, axis=1),
+                       jnp.argmax(differs, axis=1), T)
+    run = jnp.where(leader == slot, 0, jnp.minimum(
+        common, jnp.minimum(whole, whole[leader]))).astype(jnp.int32)
+    longest = jnp.zeros_like(run).at[leader].max(run)
+    run = jnp.where(leader == slot, longest, run)
+    member = run > 0
+    count = jnp.zeros_like(run).at[leader].add(member.astype(jnp.int32))
+    shortest = jnp.full_like(run, T).at[leader].min(
+        jnp.where(member, run, T))
+    order = jnp.argsort(jnp.where(member, leader, B),
+                        stable=True).astype(jnp.int32)
+    place = jnp.zeros_like(order).at[order].set(slot)
+    padded = -(-B * heads // rows) * rows
+
+    def by_row(x, fill):  # [B] by slot -> [R, 1] by stacked query row
+        return jnp.pad(jnp.repeat(x[order], heads), (0, padded - B * heads),
+                       constant_values=fill)[:, None]
+
+    return Runs(run, order, place,
+                jnp.concatenate([jnp.cumsum(count) - count, count, longest,
+                                 shortest]).astype(jnp.int32),
+                by_row(jnp.where(member, leader, -1), -1),
+                by_row(run * page, 0),
+                jnp.sum(run) - jnp.sum(longest))
+
+
+def _shared_kernel(layer_ref, passes_ref, tables_ref, q_ref, group_ref,
+                   limit_ref, pool, m_ref, l_ref, acc_ref, buf, sems, *,
+                   slots: int, heads: int, entries: int, page: int,
+                   rows: int, sm_scale: float):
+    keys, dim = buf.shape[1:]
+    rank = acc_ref.shape[-1]
+    per_block = keys // page
+    layer = layer_ref[0]
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+
+    def one_pass(leader, _):
+        first, count, longest, shortest = (
+            passes_ref[i * slots + leader] for i in range(4))
+        # The members' stacked rows, and the blocks of rows that hold them.
+        r0, r1 = first * heads, (first + count) * heads
+        n_blocks = pl.cdiv(longest, per_block)
+
+        def pages(block):
+            return jnp.minimum(per_block, longest - block * per_block)
+
+        def part(k):
+            return pl.ds(pl.multiple_of(k * page, page), page)
+
+        def each_page(block, half, then):
+            def one(k, _):
+                at = _entry(tables_ref, block * per_block + k, entries,
+                            leader)
+                for copy in _page_copies((pool,), (buf,), sems, layer, at,
+                                         half, part(k)):
+                    then(copy)
+                return 0
+
+            jax.lax.fori_loop(0, pages(block), one, 0)
+
+        def body(j, _):
+            half = j % 2
+
+            @pl.when(j + 1 < n_blocks)
+            def _():
+                each_page(j + 1, 1 - half, lambda copy: copy.start())
+
+            each_page(j, half, lambda copy: copy.wait())
+
+            def zero(k, _):  # what the run does not fill of the last block
+                buf[half, part(k)] = jnp.zeros((page, dim), buf.dtype)
+                return 0
+
+            jax.lax.fori_loop(pages(j), per_block, zero, 0)
+            k0 = j * keys  # the block's first position
+
+            def block_of_rows(c, _):
+                at = pl.ds(pl.multiple_of(c * rows, rows), rows)
+
+                def attend(masked: bool):
+                    block = buf[half]
+                    s = jax.lax.dot_general(
+                        q_ref[at, :], block, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * sm_scale
+                    if masked:
+                        # A row of another group, of none, or of the
+                        # padding sees nothing; a member, its own run.
+                        limit = jnp.where(group_ref[at, :] == leader,
+                                          limit_ref[at, :], 0) - k0
+                        s = jnp.where(pos < limit, s, NEG_INF)
+                    _online_softmax(s, m_ref.at[at], l_ref.at[at],
+                                    acc_ref.at[at],
+                                    lambda: block[:, :rank], buf.dtype)
+
+                # Every row of the block is a member, and every member's
+                # run holds the whole of these keys.
+                whole = (c * rows >= r0) & ((c + 1) * rows <= r1) \
+                    & (k0 + keys <= shortest * page)
+                pl.when(whole)(functools.partial(attend, False))
+                pl.when(jnp.logical_not(whole))(
+                    functools.partial(attend, True))
+                return 0
+
+            jax.lax.fori_loop(r0 // rows, pl.cdiv(r1, rows), block_of_rows,
+                              0)
+            return 0
+
+        @pl.when(count > 0)
+        def _():
+            each_page(0, 0, lambda copy: copy.start())
+            jax.lax.fori_loop(0, n_blocks, body, 0)
+
+        return 0
+
+    jax.lax.fori_loop(0, slots, one_pass, 0)
+
+
+def walk_shared(name: str, q: jax.Array, pool: jax.Array, layer,
+                tables: jax.Array, runs: Runs, *, rank: int, rows: int,
+                per_block: int, vmem_limit_bytes: int, sm_scale: float,
+                interpret: bool) -> tuple:
+    """The part of a decode step's softmax that lies in pages several slots
+    hold, each run FETCHED ONCE for all its holders: for every leader of
+    ``runs`` with followers, the run's pages of the leader's table in
+    ``pool``'s ``layer`` (a latent pool [L, P+1, page, W]), ``per_block``
+    pages at a time through the walk's page DMAs and double buffer,
+    multiplied against the stacked query rows of ALL its members (q
+    [B, H, D]: ``H`` rows a slot, the members side by side, in blocks of
+    ``rows``), a member masked past its own run.  A step with no run
+    anywhere walks nothing.  Returns the online softmax where the passes
+    left it, float32 by slot: (maximum [B, H, 1], sum [B, H, 1], accumulator
+    [B, H, rank]); of a slot in no run ``NEG_INF``, and what a first visible
+    score wipes (its rows may have stood in a member's block, masked: a
+    probability of exp(0) under a maximum of ``NEG_INF``, times ``exp(NEG_INF
+    - m)``, exactly 0, when a score comes).  ``walk_slots`` goes on from
+    them over each slot's own tail (``seed``, ``lo = run x page``)."""
+    return _shared_call(jnp.asarray(layer, jnp.int32).reshape(1), q, pool,
+                        tables.astype(jnp.int32), runs, name=name,
+                        rank=rank, rows=rows,
+                        per_block=min(per_block, tables.shape[1]),
+                        vmem_limit_bytes=vmem_limit_bytes, sm_scale=sm_scale,
+                        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "name", "rank", "rows", "per_block", "vmem_limit_bytes", "sm_scale",
+    "interpret"))
+def _shared_call(layer, q, pool, tables, runs, *, name, rank, rows,
+                 per_block, vmem_limit_bytes, sm_scale, interpret):
+    """``walk_shared``'s kernel call between the stacking of the queries and
+    the partials' way back to their slots, jitted on its own as ``_call``
+    is: the layers of a program share one trace and one lowering of it."""
+    B, H, D = q.shape
+    page = pool.shape[2]
+    R = runs.group.shape[0]
+    stacked = jnp.pad(q[runs.order].reshape(B * H, D),
+                      ((0, R - B * H), (0, 0)))
+
+    def whole(width):  # every stacked row at once, in VMEM
+        return pl.BlockSpec((R, width), lambda i, *_: (0, 0))
+
+    def kept(width):  # float32, a row a stacked query row
+        return jax.ShapeDtypeStruct((R, width), jnp.float32)
+
+    partials = pl.pallas_call(
+        functools.partial(_shared_kernel, slots=B, heads=H,
+                          entries=tables.shape[1], page=page, rows=rows,
+                          sm_scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(1,),
+            in_specs=[whole(D), whole(1), whole(1),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[whole(1), whole(1), whole(rank)],
+            scratch_shapes=[pltpu.VMEM((2, per_block * page, D), q.dtype),
+                            pltpu.SemaphoreType.DMA((1, 2))],
+        ),
+        out_shape=[kept(1), kept(1), kept(rank)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit_bytes),
+        interpret=interpret,
+        name=name,
+    )(layer, runs.passes, tables.reshape(-1), stacked, runs.group,
+      runs.limit, pool)
+    return tuple(x[:B * H].reshape(B, H, -1)[runs.place] for x in partials)
 
 
 # -------------------------------------- a block of query rows of one sequence

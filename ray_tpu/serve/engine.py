@@ -419,7 +419,8 @@ class InferenceEngine:
         from ..devtools import jitguard
         from ..models.paged import (PAGED_PROGRAMS, PageAllocator,
                                     counter_keys, kv_layers, ring_entries,
-                                    routing_keys, state_bytes, state_layers)
+                                    routing_keys, shares_walked_pages,
+                                    state_bytes, state_layers)
         from ..util.metrics import get_counter, get_gauge, get_histogram
 
         # A fresh engine means fresh geometry: re-registering stands the
@@ -454,8 +455,6 @@ class InferenceEngine:
         self._state_bytes = state_bytes(model_config)  # a slot's
         self.allocator = PageAllocator(cfg.pool_pages)
         self.pools = self._new_pools()
-        #: The names of the counters behind the decode step's tokens.
-        self._counter_keys = counter_keys(model_config)
         self._routing_keys = routing_keys(model_config)
         # For ``kv_rows_distinct``: how many decoding slots hold each page,
         # and over the pages held by several, the holders past the first.
@@ -485,6 +484,13 @@ class InferenceEngine:
                   f"{'pages' if self.ring else 'state'} the prefix cache "
                   f"cannot share: prefix_cache is off",
                   file=sys.stderr, flush=True)
+        # Only the prefix cache puts one page into two slots' tables: where
+        # it can, and the decode program walks its pages, the program is
+        # compiled in the form that walks a shared run once.
+        self._shared_walk = self._cache is not None \
+            and shares_walked_pages(model_config)
+        #: The names of the counters behind the decode step's tokens.
+        self._counter_keys = counter_keys(model_config, self._shared_walk)
         self._adapter_evictions_seen = 0
         # ONE device-resident PRNG key threads through every prefill and
         # decode call (each program splits and returns the successor):
@@ -862,7 +868,10 @@ class InferenceEngine:
             # The form the decode program attends its cache in: "walk" (a
             # kernel over the live pages: ops/latent_decode.py,
             # ops/paged_decode.py) or "gather".
-            "decode_attention": decode_attention_form(self.model_config),
+            # "walk+shared": a run of pages that several slots hold is
+            # fetched once for all of them (the prefix cache is on).
+            "decode_attention": decode_attention_form(self.model_config,
+                                                      self._shared_walk),
             # And the prefills: "walk" (ops/paged_prefill.py: a block of
             # query rows over the live pages, no scores in HBM; every call
             # then goes through the suffix program, a first one at 0).
@@ -1673,7 +1682,7 @@ class InferenceEngine:
                 self.adapter_pool.arrays,
                 self._d_tokens, self._d_page_tables, self._d_seq_lens,
                 self._d_active, self._d_temps, self._d_adapter_slots,
-                self._d_key, self._d_ring_tables)
+                self._d_key, self._d_ring_tables, shared=self._shared_walk)
         # The chip has work again.  What it stood still since the last
         # admission's first token is that admission's.
         since_token = self._starved.stop()

@@ -175,6 +175,155 @@ def test_a_geometry_the_kernel_cannot_take_raises_before_it_is_traced(
         latent_decode.check_geometry(q, kv, kw.get("rank", 128))
 
 
+# ---------------------------------- a run of pages that several slots hold
+
+
+def _scene(*slots):
+    """(lens, tables, the runs ``shared_runs`` has to find, the slots'
+    activity) of slots given as (rows already cached, the table's live
+    pages, the run expected); a slot of no rows is empty and inactive, its
+    table all scratch."""
+    tables = np.full((len(slots), MAXP), POOL, np.int32)
+    for b, (n, pages, _) in enumerate(slots):
+        assert len(pages) == (n // PAGE + 1 if n else 0)
+        tables[b, :len(pages)] = pages
+    lens = np.array([n for n, _, _ in slots], np.int32)
+    return lens, tables, [run for _, _, run in slots]
+
+
+#: A follower's run is what it has in common with its LEADER (the first
+#: active slot that opens with the same page), in whole pages below both
+#: slots' last; a leader's own the longest of its followers'.
+SCENES = {
+    "every-slot-one-run": _scene(
+        (2 * PAGE + 3, [0, 1, 2], 2), (2 * PAGE + 9, [0, 1, 3], 2),
+        (3 * PAGE + 1, [0, 1, 4, 5], 2), (2 * PAGE, [0, 1, 6], 2)),
+    "two-groups-of-different-runs-beside-a-loner": _scene(
+        (3 * PAGE + 5, [0, 1, 2, 3], 3), (PAGE + 2, [8, 9], 1),
+        (4 * PAGE + 15, [0, 1, 2, 4, 5], 3), (2 * PAGE + 7, [10, 11, 12], 0),
+        (PAGE, [8, 13], 1)),
+    "a-follower-whose-run-is-shorter-than-its-leaders": _scene(
+        (4 * PAGE + 1, [0, 1, 2, 3, 4], 4), (4 * PAGE + 9, [0, 1, 2, 3, 5], 4),
+        (3 * PAGE + 3, [0, 1, 6, 7], 2), (PAGE + 4, [0, 1], 1)),
+    "a-run-of-one-page-and-of-a-whole-table-less-one": _scene(
+        (PAGE + 1, [0, 1], 1), (5 * PAGE - 1, [2, 3, 4, 5, 6], 4),
+        (PAGE + 8, [0, 7], 1), (4 * PAGE, [2, 3, 4, 5, 8], 4)),
+    "inactive-slots-with-all-scratch-tables-are-not-grouped": _scene(
+        (0, [], 0), (2 * PAGE + 2, [0, 1, 2], 2), (0, [], 0),
+        (2 * PAGE + 6, [0, 1, 3], 2), (0, [], 0)),
+    "two-followers-that-go-on-together-past-their-leaders-end": _scene(
+        (PAGE + 3, [0, 1], 1), (3 * PAGE + 2, [0, 4, 5, 6], 1),
+        (3 * PAGE + 9, [0, 4, 5, 7], 1)),
+    "no-run-anywhere": _scene(
+        (2 * PAGE + 5, [0, 1, 2], 0), (0, [], 0), (PAGE - 1, [3], 0),
+        (5 * PAGE - 1, [4, 5, 6, 7, 8], 0), (1, [9], 0)),
+}
+
+
+def _shared_attention(q, kv, layer, tables, lens, **static):
+    """The front in the shared form, the runs found as the program finds
+    them: (the heads' outputs, the runs)."""
+    runs = latent_decode.shared_runs(tables, lens, lens > 0, page=PAGE,
+                                     heads=HEADS)
+    return latent_decode_attention(q, kv, layer, tables, lens, runs=runs,
+                                   **static), runs
+
+
+def _shared(q, kv, tables, lens, interpret=True, rows=None, per_block=None):
+    """The shared form on the scene, compiled once a batch's shapes and a
+    block setting: (the heads' outputs, the runs it found)."""
+    blocks = tuple((name, value) for name, value in (
+        ("SHARED_ROWS", rows), ("SHARED_PAGES_PER_BLOCK", per_block))
+        if value)
+    call = walk_ref.blocked(latent_decode, _shared_attention, blocks,
+                            rank=RANK, sm_scale=HEAD_DIM ** -0.5,
+                            interpret=interpret)
+    out, runs = call((q, kv), jnp.asarray(tables), jnp.asarray(lens))
+    return np.asarray(out, np.float32), runs
+
+
+def _scene_inputs(scene, dtype, seed=0):
+    lens, tables, _ = SCENES[scene]
+    q = walk_ref.seeded((len(lens), HEADS, WIDTH), dtype, seed, RANK + ROPE)
+    return q, _inputs(dtype, seed)[1], tables, lens
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("scene", SCENES)
+def test_the_shared_form_is_the_gather_form(scene, dtype):
+    """A run fetched once for all its holders, and each slot's own tail
+    from the run's end: the softmax over the same keys, in float32 to 1e-4
+    and in bfloat16 to the per-slot walk's tolerance."""
+    q, kv, tables, lens = _scene_inputs(scene, dtype)
+    out, _ = _shared(q, kv, tables, lens)
+    tol = 1e-4 if dtype == jnp.float32 else 2e-2
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, _gather_form(q, kv, tables, lens),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("scene", SCENES)
+def test_the_runs_are_the_pages_a_slot_shares_with_its_leader(scene):
+    lens, tables, expected = SCENES[scene]
+    runs = latent_decode.shared_runs(
+        jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(lens > 0),
+        page=PAGE, heads=HEADS)
+    assert np.asarray(runs.run).tolist() == expected
+    # A group's members stand side by side, and every slot has its place.
+    order = np.asarray(runs.order)
+    assert sorted(order.tolist()) == list(range(len(lens)))
+    np.testing.assert_array_equal(np.asarray(runs.place)[order],
+                                  np.arange(len(lens)))
+    first, count, longest, shortest = np.asarray(runs.passes).reshape(
+        4, -1)
+    saved = 0
+    for leader in np.flatnonzero(count):
+        members = order[first[leader]:first[leader] + count[leader]]
+        assert tables[members, 0].tolist() == [tables[leader, 0]] * len(
+            members)
+        member_runs = [expected[b] for b in members]
+        assert min(member_runs) == shortest[leader] > 0
+        assert max(member_runs) == longest[leader] == expected[leader]
+        saved += sum(member_runs) - longest[leader]
+    assert sum(count) == sum(r > 0 for r in expected)
+    assert int(runs.pages_saved) == saved
+
+
+def test_with_no_run_anywhere_the_shared_form_is_the_walk_bit_for_bit():
+    q, kv, tables, lens = _scene_inputs("no-run-anywhere", jnp.float32)
+    out, runs = _shared(q, kv, tables, lens)
+    assert not np.asarray(runs.run).any()
+    np.testing.assert_array_equal(out, _kernel(q, kv, tables, lens))
+
+
+@pytest.mark.parametrize("rows, per_block", [(16, 1), (32, 3), (48, 8)])
+def test_the_shared_pass_does_not_depend_on_its_blocks_size(rows, per_block):
+    """Blocks of rows that part a slot's heads and a group's members, and
+    blocks of pages that a run fills unevenly: one answer."""
+    scene = "two-groups-of-different-runs-beside-a-loner"
+    q, kv, tables, lens = _scene_inputs(scene, jnp.float32, seed=rows)
+    out, _ = _shared(q, kv, tables, lens, rows=rows, per_block=per_block)
+    np.testing.assert_allclose(out, _gather_form(q, kv, tables, lens),
+                               atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("interpret", [True, pltpu.InterpretParams()],
+                         ids=["interpret", "tpu-interpreter-nan-scratch"])
+def test_the_shared_pass_fetches_its_runs_and_nothing_else(interpret):
+    """NaN in every page that is no slot's live one: a pass walks its run's
+    pages and no further (the last block's other pages meet zeros), and a
+    tail starts where the run ended."""
+    scene = "a-follower-whose-run-is-shorter-than-its-leaders"
+    q, kv, tables, lens = _scene_inputs(scene, jnp.float32)
+    sound, _ = _shared(q, kv, tables, lens)
+    bad = _poisoned(kv, tables, lens, lambda b, p: True)
+    out, _ = _shared(q, bad, tables, lens, interpret=interpret,
+                     per_block=3)
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out, sound, atol=1e-6, rtol=1e-6)
+
+
 # ------------------------------------------------- through the decode program
 
 
@@ -213,6 +362,108 @@ def test_the_decode_program_through_the_kernel(dtype):
     assert walked[-1] == gathered[-1] == live
     if dtype == jnp.float32:
         np.testing.assert_array_equal(walked[:b], gathered[:b])
+
+
+@pytest.mark.parametrize("scene", [
+    "two-groups-of-different-runs-beside-a-loner",
+    "inactive-slots-with-all-scratch-tables-are-not-grouped",
+    "no-run-anywhere"])
+def test_the_shared_decode_program_counts_a_run_once(scene):
+    """The decode step in the shared form against the gather form and the
+    per-slot walk: the same logits; ``kv_rows_read`` a run's pages once a
+    pass and a tail's once a slot, ``kv_rows_shared`` what that saved, the
+    two together what the per-slot walk counts; ``kv_rows_live`` as it
+    was; with nothing shared the per-slot walk's tokens and counts."""
+    cfg = _config(jnp.float32)
+    assert paged.counter_keys(cfg, True)[-3:] \
+        == paged.KV_KEYS + paged.SHARED_KEYS
+    model = walk_ref.model_of(cfg, POOL, PAGE)
+    lens, tables, runs = SCENES[scene]
+    shared, logits = walk_ref.decode(cfg, latent_decode, True, model, tables,
+                                     lens, shared=True)
+    walked, _ = walk_ref.decode(cfg, latent_decode, True, model, tables,
+                                lens)
+    gathered, ref = walk_ref.decode(cfg, latent_decode, False, model, tables,
+                                    lens)
+    np.testing.assert_allclose(logits, ref, atol=1e-4, rtol=0)
+    b = len(lens)
+    np.testing.assert_array_equal(shared[:b], gathered[:b])
+    read, live, saved = shared[-3:]
+    passes = {}  # by a group's first page: its longest run, the leader's
+    for first, r in zip(tables[:, 0], runs):
+        passes[first] = max(passes.get(first, 0), r)
+    tails = int(sum(n // PAGE + 1 - r for n, r in zip(lens, runs)))
+    assert read == (sum(passes.values()) + tails) * PAGE * LAYERS
+    assert saved == (sum(runs) - sum(passes.values())) * PAGE * LAYERS
+    assert read + saved == walked[-2]
+    assert live == walked[-1] == gathered[-1]
+    if not any(runs):
+        np.testing.assert_array_equal(shared[:-1], walked)
+
+
+def test_a_program_that_does_not_walk_has_no_shared_form():
+    """Off the TPU the latent decode step gathers: asking it for the shared
+    form is refused where the program is traced."""
+    cfg = _config(jnp.float32)
+    assert not paged.shares_walked_pages(cfg)
+    model = walk_ref.model_of(cfg, POOL, PAGE)
+    lens, tables, _ = SCENES["no-run-anywhere"]
+    with pytest.raises(ValueError, match="no shared form"):
+        walk_ref.decode(cfg, latent_decode, False, model, tables, lens,
+                        shared=True)
+
+
+def _engine_and_its_decode_text(cfg, steer):
+    """(an engine's ``stats()``, its counters' names, its decode program as
+    it dispatches it, lowered for the TPU platform with ``steer.on_tpu``
+    answering true: nothing is compiled, nothing runs)."""
+    from ray_tpu.models import init_and_apply
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    params = jax.jit(init_and_apply(cfg)[0], static_argnums=0)(
+        cfg, jax.random.PRNGKey(0))
+    was, steer.on_tpu = steer.on_tpu, lambda: True
+    try:
+        eng = InferenceEngine(cfg, params, EngineConfig(
+            batch_slots=4, page_size=8, max_prompt_len=16,
+            max_new_tokens_cap=32), seed=0)
+        text = paged.paged_decode_step.trace(
+            cfg, params, eng.pools, eng.adapter_pool.arrays,
+            *map(jnp.asarray, (eng._tokens, eng._page_tables, eng._seq_lens,
+                               eng._active, eng._temps, eng._adapter_slots)),
+            eng._d_key, eng._d_ring_tables, shared=eng._shared_walk).lower(
+            lowering_platforms=("tpu",)).as_text()
+        return eng.stats(), eng._counter_keys, text
+    finally:
+        steer.on_tpu = was
+        jax.clear_caches()
+
+
+@pytest.mark.parametrize("name, off, form", [
+    ("kimi-linear-tiny", "recurrent layers", "walk"),
+    ("smallthinker-tiny", "window layers", "walk"),
+    ("glm4-moe-lite-tiny", None, "walk+shared")])
+def test_an_engine_asks_for_the_shared_form_only_where_pages_can_be_shared(
+        name, off, form):
+    """Only the prefix cache puts one page into two slots' tables.  Where a
+    configuration runs without it (recurrent layers, window layers) the
+    decode program is compiled as it was, with no shared pass in its text
+    and no ``kv_rows_shared`` behind its tokens; a latent model with the
+    cache on gets the pass, and its replica says so."""
+    from ray_tpu.ops import paged_decode
+
+    if name == "smallthinker-tiny":
+        cfg, steer = walk_ref.tiny_pair(name), paged_decode
+    else:
+        cfg, steer = walk_ref.tiny(name, kv_lora_rank=128), latent_decode
+    stats, keys, text = _engine_and_its_decode_text(cfg, steer)
+    assert stats["prefix_cache_off"] == off
+    assert stats["decode_attention"] == form
+    shared = form == "walk+shared"
+    assert ("kv_rows_shared" in keys) == shared
+    assert keys[-2 - shared:][:2] == paged.KV_KEYS
+    assert ("latent_decode_shared" in text) == shared
+    assert ("latent_decode" in text) == (steer is latent_decode)
 
 
 def test_off_the_tpu_the_decode_program_is_the_gather_form():

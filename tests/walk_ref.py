@@ -234,9 +234,9 @@ def program(fn, walk):
 
 
 def _step_and_logits(cfg, params, pools, adapters, tokens, tables, lens,
-                     active, rings):
-    """``paged_decode_step`` at temperature 0 with the logits it sampled
-    from, looked at on their way."""
+                     active, rings, shared=False):
+    """``paged_decode_step`` (``shared``: in the shared form) at temperature
+    0 with the logits it sampled from, looked at on their way."""
     seen, real = [], paged.decode_logits
 
     def decode_logits(*args, **kwargs):
@@ -249,24 +249,26 @@ def _step_and_logits(cfg, params, pools, adapters, tokens, tables, lens,
         out, *_ = paged.paged_decode_step.__wrapped__(
             cfg, params, dict(pools), adapters, tokens, tables, lens, active,
             jnp.zeros((b,), jnp.float32), jnp.ones((b,), jnp.int32),
-            jax.random.PRNGKey(2), rings)
+            jax.random.PRNGKey(2), rings, shared=shared)
     finally:
         paged.decode_logits = real
     return out, seen[0][0]
 
 
-def decode(cfg, steer, walk, model, tables, lens, rings=None):
+def decode(cfg, steer, walk, model, tables, lens, rings=None, shared=False):
     """One decode step over slots of ``lens`` cached rows on the ``model``
     (``model_of``): (tokens and counters, logits).  ``walk``: as on a TPU
-    (``steer``'s ``on_tpu`` answers true), the kernel interpreted."""
+    (``steer``'s ``on_tpu`` answers true), the kernel interpreted;
+    ``shared``: the program in the shared form."""
     lens = np.asarray(lens, np.int32)
+    step = functools.partial(_step_and_logits, shared=shared)
     with as_on_a_tpu(steer, walk):
-        out, logits = program(_step_and_logits, walk)(
+        out, logits = program(step, walk)(
             cfg, *model, jnp.arange(len(lens), dtype=jnp.int32) + 7,
             jnp.asarray(tables), jnp.asarray(lens), jnp.asarray(lens > 0),
             None if rings is None else jnp.asarray(rings))
-        assert paged.decode_attention_form(cfg) == \
-            ("walk" if walk else "gather")
+        assert paged.decode_attention_form(cfg, shared) == \
+            (("walk+shared" if shared else "walk") if walk else "gather")
     return np.asarray(out), np.asarray(logits)
 
 
