@@ -35,7 +35,17 @@ recurrent layer of either kind):
   position to position by a ``lax.scan`` (a whole chunk's ``[2048, I, N]``
   float32 states are 671 MB a layer at the published widths and are never
   formed); a row that holds no token (``valid`` false) has Delta 0, which
-  leaves the state exactly as it is.
+  leaves the state exactly as it is, and costs a trip of the loop all the
+  same.  XLA writes the state to HBM and reads it back every trip, so on a
+  TPU a PREFILL call (``prefill_rows``: one sequence's bucket, chunk or
+  suffix) hands the recurrence to the Pallas kernel ``ops/ssm_scan.py``
+  instead, one call a layer: the state stays on the chip over the chunk's
+  positions and only the real rows are walked (``_scans_on_chip`` chooses,
+  by the backend, the state's tiles and the chunk's length; ``rows_walked``
+  says what the form in use went over).  ``chunked`` is that kernel's
+  reference, every other backend's form, and the full forward's on every
+  backend (``full_attend``: the trainer differentiates through it, and the
+  kernel has no gradient).
 
 THE STATE LIES TRANSPOSED, ``[N, I]`` (and ``A_log`` with it): the TPU
 tiles an array's last two dimensions in (8, 128), so ``[I, 16]`` float32
@@ -55,7 +65,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops import ssm_decode
+from ..ops import ssm_decode, ssm_scan
 from ..ops.norms import rms_norm
 
 Params = Dict[str, Any]
@@ -245,6 +255,26 @@ def _steps_in_place(config) -> bool:
     return ssm_decode.on_tpu() and ssm_decode.takes(n, i, jnp.float32)
 
 
+def _scans_on_chip(config, rows: Optional[int] = None) -> bool:
+    """Whether a prefill call of ``rows`` rows (with none named: of some
+    length) takes the recurrence of ``config``'s Mamba layers through
+    ``ops.ssm_scan_chunk`` (one call a layer, the state on the chip over the
+    chunk, the real rows only), in place of ``chunked``'s loop.  The one
+    place that chooses, by what it can see: the kernel needs a TPU, a state
+    it can cut (as ``_steps_in_place``) and a chunk of whole position
+    blocks (the engine's buckets all are)."""
+    i, n, _ = widths(config)
+    return ssm_scan.on_tpu() and ssm_scan.takes(n, i, jnp.float32) \
+        and (rows is None or ssm_scan.takes_rows(rows))
+
+
+def rows_walked(config, rows: int, real: int) -> int:
+    """The positions the chunk form steps the state over in a prefill call
+    of ``rows`` rows of which ``real`` hold a token: the kernel walks the
+    real ones, ``chunked``'s loop all of them."""
+    return real if _scans_on_chip(config, rows) else rows
+
+
 def decode_rows(config, a: Params, H: jax.Array, rows: jax.Array,
                 pre: jax.Array, layer=None,
                 active: Optional[jax.Array] = None):
@@ -274,9 +304,19 @@ def prefill_rows(config, a: Params, H: jax.Array, rows: jax.Array,
     [S_pad, I] (``valid`` [S_pad] the real ones), behind the state H
     [1, N, I] and convolution rows [1, .] they follow: the chunk form.
     Returns ((y, xs) for ``output``, each [1, S_pad, I], the state and the
-    convolution rows behind the last real row, each [1, .])."""
+    convolution rows behind the last real row, each [1, .]).
+
+    Where ``_scans_on_chip(config, S_pad)``, the recurrence is the kernel's
+    (the real rows lead: ``valid`` is a count), and y of a row that holds
+    no token is zero."""
     n = jnp.sum(valid, dtype=jnp.int32)
     xs, nxt = conv(config, a, pre[None], rows, n[None])
+    if _scans_on_chip(config, pre.shape[0]):
+        delta, bm, cm = drive(config, a, xs)
+        with jax.named_scope(SCOPE):
+            y, H = ssm_scan.ssm_scan_chunk(a["A_log"], H[0], delta[0],
+                                           xs[0], bm[0], cm[0], n)
+        return (y[None], xs), H[None], nxt
     y, H = chunked(a, H, xs, *drive(config, a, xs), valid[None])
     return (y, xs), H, nxt
 

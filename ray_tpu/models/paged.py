@@ -85,7 +85,10 @@ only.  ``block.recurrent`` names the module; its ``state_shapes``,
 ``decode_rows`` and ``prefill_rows`` are all this file asks of it; on a TPU
 the decode step hands the state-space module the ``S`` pool whole with the
 layer's place in it, and the module's kernel updates it where it lies
-(``_steps_in_place``).
+(``_steps_in_place``), and a prefill call's recurrence is one kernel a
+layer over the call's real rows (``mamba._scans_on_chip``;
+``recurrent_prefill_form`` names the form, ``recurrent_rows_walked`` counts
+what it went over).
 
 Compile counts are observable via ``trace_count()`` — the jitted bodies
 bump a counter when TRACED (python executes only at trace time), which is
@@ -715,6 +718,31 @@ def recurrent_decode_form(config) -> Optional[str]:
     if not state_layers(config):
         return None
     return "kernel" if _steps_in_place(config) else "jnp"
+
+
+def recurrent_prefill_form(config) -> Optional[str]:
+    """The form the prefill programs of ``config`` carry their recurrent
+    layers' state over a call's rows in, by name
+    (``LLMServer.stats()["recurrent_prefill"]``): ``"kernel"`` (one call a
+    layer, the state on the chip, the real rows only: a state-space layer
+    where ``mamba._scans_on_chip`` says so) or ``"scan"`` (the module's
+    chunk form over the whole bucket); None without recurrent layers."""
+    layers = state_layers(config)
+    if not layers:
+        return None
+    return "kernel" if block.recurrent(config, layers[0]) is mamba \
+        and mamba._scans_on_chip(config) else "scan"
+
+
+def recurrent_rows_walked(config, rows: int, real: int) -> int:
+    """The positions the recurrent layers' chunk form steps the state over
+    in ONE prefill call of ``rows`` rows, ``real`` of them real
+    (``first_tokens[].scan_rows_padded`` is this less ``real``, over a
+    prompt's calls): what the state-space module says (``rows_walked``);
+    a gated delta-rule layer's chunk form goes over the bucket."""
+    module = block.recurrent(config, state_layers(config)[0])
+    return module.rows_walked(config, rows, real) if module is mamba \
+        else rows
 
 
 def _state_decode(config, pools: PagedPools, active: jax.Array, i: int,

@@ -6,7 +6,8 @@ parallelism), decode attention over a latent paged pool and over paged K/V
 pairs, prefill attention over a latent paged pool and over paged K/V
 pairs (four fronts of the one walk in ``page_walk.py``), the routed FFN
 streamed expert by expert (a step's rows, a prompt's rows), a state-space
-layer's decode step in one pass over the slots' state, fused RMSNorm, rotary
+layer's decode step in one pass over the slots' state and its recurrence over
+a prompt's chunk with the state kept on the chip, fused RMSNorm, rotary
 embeddings.
 """
 
@@ -20,10 +21,11 @@ from .paged_prefill import paged_prefill_attention
 from .rotary import apply_rotary, rope_frequencies
 from .ring_attention import ring_attention
 from .ssm_decode import ssm_decode_step
+from .ssm_scan import ssm_scan_chunk
 
 __all__ = [
     "flash_attention", "mha_reference", "latent_decode_attention",
     "latent_prefill_attention", "paged_decode_attention", "paged_prefill_attention", "grouped_ffn_rows", "grouped_ffn_stream",
-    "rms_norm", "ssm_decode_step",
+    "rms_norm", "ssm_decode_step", "ssm_scan_chunk",
     "apply_rotary", "rope_frequencies", "ring_attention",
 ]

@@ -814,7 +814,8 @@ class InferenceEngine:
         from ..models.moe import grouped_form
         from ..models.paged import (decode_attention_form,
                                     prefill_attention_form,
-                                    recurrent_decode_form, trace_count)
+                                    recurrent_decode_form,
+                                    recurrent_prefill_form, trace_count)
 
         return {
             "steps": self.step_count,
@@ -849,6 +850,10 @@ class InferenceEngine:
             # (ops/ssm_decode.py: one pass over the pool where it lies) or
             # "jnp" (the module's recurrent form on a layer's slice).
             "recurrent_decode": recurrent_decode_form(self.model_config),
+            # And the form the prefill programs carry it over a call's rows
+            # in: "kernel" (ops/ssm_scan.py: one call a layer, the real
+            # rows only) or "scan" (the module's chunk form, the bucket).
+            "recurrent_prefill": recurrent_prefill_form(self.model_config),
             "window_pages": ({"ring_entries": self.ring,
                               "free": (self.ring_scratch
                                        - self._ring_pages_held()),
@@ -1298,7 +1303,8 @@ class InferenceEngine:
 
         from ..models.paged import (attn_pairs, paged_prefill,
                                     paged_prefill_prefix,
-                                    prefill_attention_form)
+                                    prefill_attention_form,
+                                    recurrent_rows_walked)
 
         n = int(req.prompt.size)
         prefix_len = int(req.cache_hit_len)
@@ -1307,7 +1313,7 @@ class InferenceEngine:
         # them; the last one's token is the request's first.
         chunk = self.config.prefill_buckets()[-1]
         starts = list(range(prefix_len, n, chunk))
-        rows, firsts, routing = 0, [], {}
+        rows, walked, firsts, routing = 0, 0, [], {}
         # Where the prefills walk the live pages, a prompt's first rows are
         # a suffix behind nothing: the same kernel at a first position of
         # 0, so one program a bucket serves both, and the cold program
@@ -1327,6 +1333,9 @@ class InferenceEngine:
                         if self._state_layers else None
                 s_pad = self._bucket_len(end - start)
                 rows += s_pad
+                if self._state_layers:
+                    walked += recurrent_rows_walked(self.model_config,
+                                                    s_pad, end - start)
                 toks = np.zeros((1, s_pad), np.int32)
                 toks[0, :end - start] = req.prompt[start:end]
                 if start or walks:
@@ -1358,10 +1367,12 @@ class InferenceEngine:
                 for start in starts)
         if self._state_layers:
             # What the recurrent layers' chunk form went over: the real
-            # positions, and the rows of the calls' buckets behind them
-            # (each costs a position's step and leaves the state alone).
+            # positions, and the rows behind them that the form in use
+            # WALKED all the same, as the model code says (the scan: the
+            # calls' buckets, each row a position's step that leaves the
+            # state alone; the kernel of a TPU's state-space layers: none).
             routing["scan_rows"] = n - prefix_len
-            routing["scan_rows_padded"] = rows - (n - prefix_len)
+            routing["scan_rows_padded"] = walked - (n - prefix_len)
         return rows, len(starts), routing
 
     def _prefill_prepare(self, req: _Request) -> None:

@@ -140,6 +140,33 @@ def test_rms_norm_kernel_compiles(v5e):
     assert text.count("tpu_custom_call") == 1
 
 
+#: A prefill call's rows in the state-space cell's buckets (Jamba2-3B's
+#: chunk, its smallest bucket): the state is [16, 5120] at each.
+SCAN_ROWS = [2048, 128]
+
+
+@pytest.mark.parametrize("rows", SCAN_ROWS)
+def test_ssm_scan_compiles_and_fits_fast_memory(v5e, rows):
+    """``ops/ssm_scan.py`` at Jamba2-3B's widths: one Mosaic call under its
+    name, which the compiler refuses where what it keeps in VMEM (both
+    halves of a position block of every stream, and the state) does not
+    fit, and nothing of the chunk left in HBM beside the operands (``B``
+    and ``C`` laid N on the sublanes: 2 x 16 MB)."""
+    from ray_tpu.ops import ssm_scan
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    f32 = functools.partial(_on, one, dtype=jnp.float32)
+    compiled = jax.jit(ssm_scan.ssm_scan_chunk).lower(
+        f32((16, 5120)), f32((16, 5120)), f32((rows, 5120)),
+        f32((rows, 5120)), f32((rows, 16)), f32((rows, 16)),
+        _on(one, (), jnp.int32)).compile()
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1 and "ssm_scan" in calls[0], calls
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= 2 * rows * 16 * 128 * 4
+
+
 #: The decode step's grouped products of the three routed cells: (rows =
 #: slots x top_k, d, f, the gate's non-linearity); 64 experts each.
 STREAM_SHAPES = {"olmoe": (128, 2048, 1024, "silu"),
@@ -516,7 +543,8 @@ def test_b1_train_step_partitions_over_four_chips(v5e, kernels_as_on_chip):
 def _engine_args(v5e, cfg, ec):
     """Shapes of everything the paged programs take, on one described chip."""
     from ray_tpu.models import init_and_apply
-    from ray_tpu.models.paged import init_adapter_pool, init_paged_pools
+    from ray_tpu.models.paged import (init_adapter_pool, init_paged_pools,
+                                      state_layers)
 
     init = init_and_apply(cfg)[0]  # the model's own
 
@@ -525,8 +553,9 @@ def _engine_args(v5e, cfg, ec):
         jax.tree.map, lambda x: _on(one, x.shape, x.dtype))
     params = place(jax.eval_shape(
         lambda: init(cfg, jax.random.PRNGKey(0))))
-    pools = place(jax.eval_shape(
-        lambda: init_paged_pools(cfg, ec.pool_pages, ec.page_size)))
+    pools = place(jax.eval_shape(lambda: init_paged_pools(
+        cfg, ec.pool_pages, ec.page_size,
+        state_slots=ec.batch_slots if state_layers(cfg) else 0)))
     adapters = place(jax.eval_shape(
         lambda: init_adapter_pool(cfg, ec.max_adapters, ec.lora_rank)))
     key = place(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
@@ -555,7 +584,8 @@ def _lower_paged(program, args, bucket=None):
     if program == "paged_prefill_prefix":
         return paged.paged_prefill_prefix.lower(
             cfg, params, pools, adapters, toks, scalar, scalar, table,
-            scalar, temp, key)
+            scalar, temp, key, None,
+            scalar if paged.state_layers(cfg) else None)
     assert program == "copy_page"
     return paged.copy_page.lower(pools, scalar, scalar)
 
@@ -637,3 +667,52 @@ def test_paged_programs_never_copy_the_pool(v5e, program, model):
         allowed += 2 * ec.batch_slots * ec.pages_per_seq \
             * int(np.prod(pool.shape[2:])) * pool.dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < allowed
+
+
+@pytest.mark.slow  # ~25 s
+def test_the_state_space_cells_chunk_program_scans_on_the_chip(
+        v5e, monkeypatch):
+    """Jamba2-3B's 2048-token chunk of the suffix prefill at the serving
+    cell's geometry, with the answers only a TPU gives steered on: one
+    ``ssm_scan`` call a Mamba layer, no loop left under the recurrence's
+    scope (``chunked``'s scan was a ``while`` a layer), and the 1.09 GB
+    state pool written where it lies (a slot's state in, a slot's state
+    out: no copy of the pool)."""
+    import re
+
+    from benchmarks import spec
+    from ray_tpu.models import mamba, paged
+    from ray_tpu.ops import paged_decode, ssm_scan
+    from ray_tpu.serve.engine import EngineConfig
+
+    load = lambda *p: spec.load_json(  # noqa: E731
+        os.path.join(ROOT, "benchmarks", *p))
+    model = load("configs", "jamba2-3b.json")
+    ec = EngineConfig(**load(
+        "traffic", "serve-reasoning-wide-batch.json")["engine"])
+    cfg = spec.family(model).program_config(
+        model, remat=False, max_seq=ec.pages_per_seq * ec.page_size)
+    args = _engine_args(v5e, cfg, ec)
+    pools, bucket = args[3], ec.prefill_buckets()[-1]
+    assert bucket == 2048 and not mamba._scans_on_chip(cfg, bucket)
+    monkeypatch.setattr(paged_decode, "on_tpu", lambda: True)
+    monkeypatch.setattr(ssm_scan, "on_tpu", lambda: True)
+    jax.clear_caches()
+    try:
+        assert mamba._scans_on_chip(cfg, bucket)
+        assert paged.recurrent_prefill_form(cfg) == "kernel"
+        text = _lower_paged("paged_prefill_prefix", args,
+                            bucket).compile().as_text()
+    finally:
+        jax.clear_caches()
+    calls = re.findall(r"^\s*%?(\S+) = .*? custom-call\(", text, re.M)
+    assert sum(x.startswith("ssm_scan") for x in calls) == 26, calls
+    assert sum(x.startswith("paged_prefill") for x in calls) == 2, calls
+    assert "attn_ssm" in text
+    assert not [ln for ln in text.splitlines()
+                if " while(" in ln and "attn_ssm" in ln]
+    shape = "[" + ",".join(map(str, pools["S"].shape)) + "]"
+    made = [op for kind, op in re.findall(
+        r"^\s*(?:ROOT\s+)?\S+ = (.*?) ([\w-]+)\(", text, re.M)
+        if shape in kind]
+    assert "parameter" in made and "copy" not in made, made

@@ -1,8 +1,9 @@
 """The selective state-space layer (``ray_tpu/models/mamba.py``) in float32 on
 the CPU: its two forms against a naive loop over positions written here from
 the equations, the state and convolution rows across chunk edges and padded
-rows, and what the paged programs do with a slot's state (an inactive slot,
-a slot used again)."""
+rows, a prefill call's rows through the chunk kernel against the same
+through the scan, and what the paged programs do with a slot's state (an
+inactive slot, a slot used again)."""
 
 import functools
 
@@ -145,6 +146,50 @@ def test_padded_rows_change_nothing(layer):
                           jnp.where(real, pre, 1e3 * pre + 7.0), length)
     np.testing.assert_array_equal(H2, H)
     np.testing.assert_array_equal(rows2, rows)
+
+
+@pytest.mark.parametrize("rows, real, behind", [
+    (16, 16, False), (16, 5, False), (32, 9, True), (32, 32, True),
+    (8, 1, True)])
+def test_prefill_rows_through_the_kernel_is_prefill_rows_through_the_scan(
+        monkeypatch, rows, real, behind):
+    """``prefill_rows`` with ``_scans_on_chip`` steered on (the kernel,
+    interpreted) against the same with it off: the layer's (y, xs) on the
+    real rows, the state and the convolution rows, from zeros and behind a
+    state; the kernel's y of a row that holds no token is zero."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    from ray_tpu.ops import ssm_scan
+
+    cfg = _config(ssm_inner=128)  # a state of whole tiles
+    a = jax.jit(mamba.init, static_argnums=0)(cfg, jax.random.PRNGKey(0))
+    H, conv_rows = _zeros(cfg, 1)
+    if behind:
+        k = jax.random.split(jax.random.PRNGKey(7))
+        H = jax.random.normal(k[0], H.shape, jnp.float32)
+        conv_rows = jax.random.normal(k[1], conv_rows.shape, jnp.float32)
+    pre = _pre(cfg, rows, seed=6, batch=1)[0]
+    valid = jnp.arange(rows) < real
+    monkeypatch.setattr(ssm_scan, "POSITIONS_BLOCK", 8)
+
+    def run():  # a function a form: jit keeps a trace by its function
+        return jax.jit(lambda *rest: mamba.prefill_rows(cfg, *rest))(
+            a, H, conv_rows, valid, pre)
+
+    assert not mamba._scans_on_chip(cfg, rows)
+    (want_y, want_xs), want, want_nxt = run()
+    monkeypatch.setattr(ssm_scan, "on_tpu", lambda: True)
+    assert mamba._scans_on_chip(cfg, rows)
+    with pltpu.force_tpu_interpret_mode():
+        (y, xs), new, nxt = run()
+    assert y.shape == want_y.shape == (1, rows, 128)
+    np.testing.assert_allclose(np.asarray(y)[0, :real],
+                               np.asarray(want_y)[0, :real],
+                               rtol=1e-5, atol=1e-6)
+    assert not np.asarray(y)[0, real:].any()
+    np.testing.assert_array_equal(xs, want_xs)
+    np.testing.assert_array_equal(nxt, want_nxt)
+    np.testing.assert_allclose(new, want, rtol=1e-6, atol=1e-6)
 
 
 # ------------------------------------------------------ through the programs

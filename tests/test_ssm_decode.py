@@ -13,8 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
+from walk_ref import ssm_toy as _config
 
-from ray_tpu.models import MoEConfig, mamba, moe_init, paged
+from ray_tpu.models import mamba, moe_init, paged
 from ray_tpu.ops import ssm_decode, ssm_decode_step
 
 
@@ -137,18 +138,6 @@ def test_a_geometry_the_kernel_cannot_take_is_refused(what, n, i, dtype,
 
 
 # -------------------------------------------------------- who chooses it
-
-
-def _config(**kw):
-    """One period at toy widths whose state is whole tiles: 3 Mamba layers
-    around 1 attention layer."""
-    return MoEConfig(**{**dict(
-        vocab_size=128, d_model=32, n_layers=4, n_heads=2, n_kv_heads=1,
-        attn_layout=("ssm", "ssm", "kv", "ssm"), ssm_inner=128, ssm_state=16,
-        ssm_dt_rank=4, ssm_conv=4, rope_layout=(0,) * 4,
-        ffn_layout=(0,) * 4, dense_d_ff=48, d_ff=48, n_experts=1, top_k=1,
-        tie_embeddings=True, max_seq=64, dtype=jnp.float32, remat=False),
-        **kw})
 
 
 @pytest.fixture

@@ -198,6 +198,21 @@ def tiny_pair(name, max_seq=TINY_SEQ, whole=False):
                 num_attention_heads=heads)
 
 
+def ssm_toy(**over):
+    """One period of a state-space model at toy widths whose state is whole
+    (8, 128) tiles, what the two state-space kernels can cut: 3 Mamba
+    layers around 1 attention layer, in float32."""
+    from ray_tpu.models import MoEConfig
+
+    return MoEConfig(**{**dict(
+        vocab_size=128, d_model=32, n_layers=4, n_heads=2, n_kv_heads=1,
+        attn_layout=("ssm", "ssm", "kv", "ssm"), ssm_inner=128, ssm_state=16,
+        ssm_dt_rank=4, ssm_conv=4, rope_layout=(0,) * 4,
+        ffn_layout=(0,) * 4, dense_d_ff=48, d_ff=48, n_experts=1, top_k=1,
+        tie_embeddings=True, max_seq=64, dtype=jnp.float32, remat=False),
+        **over})
+
+
 @functools.lru_cache(maxsize=None)
 def model_of(cfg, pages, page, ring_pages=0, state_slots=0):
     """(parameters, pools of seeded rows, adapters) of a configuration, made
